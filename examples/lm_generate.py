@@ -28,6 +28,7 @@ import numpy as np
 import tony_tpu.runtime as rt
 from tony_tpu.checkpoint import CheckpointManager
 from tony_tpu.models import DecodeSession, init_params
+from tony_tpu.parallel.plan import compile_cache_summary
 
 
 def parse_args(argv):
@@ -59,10 +60,12 @@ def main(argv=None) -> int:
     ctx = rt.initialize()
     # Shared derivation: a checkpoint written by lm_train.py restores
     # here only if the arg→config mapping is byte-identical.
-    from lm_train import model_config_from_args
+    from lm_train import learning_rate_for, model_config_from_args
 
     cfg = model_config_from_args(args, max_seq=args.max_seq)
     mesh = rt.build_job_mesh()
+    print(f"[{ctx.job_name}:{ctx.task_index}] generating on mesh "
+          f"{dict(mesh.shape)} {rt.describe_devices()}", flush=True)
     if not args.ckpt:
         params = init_params(jax.random.key(args.seed), cfg)
     else:
@@ -74,7 +77,9 @@ def main(argv=None) -> int:
         # serving job reassembles from all shard files and re-shards.
         from tony_tpu.models import make_train_step
 
-        init_fn, _ = make_train_step(cfg, mesh, learning_rate=1e-2)
+        init_fn, _ = make_train_step(
+            cfg, mesh, learning_rate=learning_rate_for(cfg)
+        )
         mgr = CheckpointManager(
             args.ckpt, process_id=ctx.process_id,
             num_processes=ctx.num_processes,
@@ -94,40 +99,39 @@ def main(argv=None) -> int:
         [int(t) for t in row.split(",") if t.strip()]
         for row in args.prompt.split(":")
     ]
-    width = max(len(r) for r in rows)
-    # Left-pad ragged prompts with token 0 so the batch is rectangular
-    # (position 0 padding attends causally like a BOS run).
-    prompt = jnp.asarray(
-        [[0] * (width - len(r)) + r for r in rows], jnp.int32
-    )
-
     # Serve sharded in place when the job mesh is bigger than one device
     # (fused weights megatron-split over tp, KV cache sharded); a 1-device
     # mesh serves exactly like the plain session.
     session = DecodeSession(
         params, cfg, mesh=mesh if mesh.devices.size > 1 else None
     )
-    out = session.generate(
-        prompt, max_new_tokens=args.max_new,
-        temperature=args.temperature, top_k=args.top_k, top_p=args.top_p,
-        eos_token=None if args.eos < 0 else args.eos,
-        key=(jax.random.key(args.seed)
-             if args.temperature > 0 else None),
-    )
-    if ctx.num_processes > 1:
-        # Multi-process job: `out` is a global array whose shards live on
-        # other hosts too — fetching it directly raises. Gather the full
-        # value onto every host first.
-        from jax.experimental import multihost_utils
-
-        out_rows = np.asarray(
-            multihost_utils.process_allgather(out, tiled=True)
+    # Ragged prompts decode in groups of equal length (one compiled loop
+    # per length) — never padded, so every row comes out exactly as it
+    # would alone, which is what a serving engine is compared against.
+    out_rows: dict[int, np.ndarray] = {}
+    for width in sorted({len(r) for r in rows}):
+        idx = [i for i, r in enumerate(rows) if len(r) == width]
+        out = session.generate(
+            jnp.asarray([rows[i] for i in idx], jnp.int32),
+            max_new_tokens=args.max_new,
+            temperature=args.temperature, top_k=args.top_k,
+            top_p=args.top_p,
+            eos_token=None if args.eos < 0 else args.eos,
+            key=(jax.random.key(args.seed)
+                 if args.temperature > 0 else None),
         )
-    else:
-        out_rows = np.asarray(out)
-    for i, row in enumerate(out_rows):
-        print(f"generated[{i}]: {','.join(str(int(t)) for t in row)}",
-              flush=True)
+        if ctx.num_processes > 1:
+            # Multi-process job: `out` is a global array whose shards
+            # live on other hosts too — fetching it directly raises.
+            # Gather the full value onto every host first.
+            from jax.experimental import multihost_utils
+
+            out = multihost_utils.process_allgather(out, tiled=True)
+        out_rows.update(zip(idx, np.asarray(out)))
+    for i in range(len(rows)):
+        print(f"generated[{i}]: "
+              f"{','.join(str(int(t)) for t in out_rows[i])}", flush=True)
+    print(compile_cache_summary(), flush=True)
     return 0
 
 

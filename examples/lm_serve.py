@@ -44,6 +44,7 @@ import tony_tpu.runtime as rt
 from tony_tpu import constants
 from tony_tpu.checkpoint import CheckpointManager
 from tony_tpu.models import DecodeSession, init_params
+from tony_tpu.parallel.plan import compile_cache_summary
 from tony_tpu.serving import ServingEngine
 from tony_tpu.serving.http import ServingServer
 
@@ -123,10 +124,12 @@ def _addr_file(args) -> str:
 def main(argv=None) -> int:
     args = parse_args(sys.argv[1:] if argv is None else argv)
     ctx = rt.initialize()
-    from lm_train import model_config_from_args
+    from lm_train import learning_rate_for, model_config_from_args
 
     cfg = model_config_from_args(args, max_seq=args.max_seq)
     mesh = rt.build_job_mesh()
+    print(f"[{ctx.job_name}:{ctx.task_index}] serving on mesh "
+          f"{dict(mesh.shape)} {rt.describe_devices()}", flush=True)
     if not args.ckpt:
         params = init_params(jax.random.key(args.seed), cfg)
     else:
@@ -134,7 +137,9 @@ def main(argv=None) -> int:
         # checkpoints the full TrainState; serving keeps only .params.
         from tony_tpu.models import make_train_step
 
-        init_fn, _ = make_train_step(cfg, mesh, learning_rate=1e-2)
+        init_fn, _ = make_train_step(
+            cfg, mesh, learning_rate=learning_rate_for(cfg)
+        )
         mgr = CheckpointManager(
             args.ckpt, process_id=ctx.process_id,
             num_processes=ctx.num_processes,
@@ -175,7 +180,9 @@ def main(argv=None) -> int:
         def _load(ckpt_dir=mdir):
             from tony_tpu.models import make_train_step
 
-            m_init, _ = make_train_step(cfg, mesh, learning_rate=1e-2)
+            m_init, _ = make_train_step(
+                cfg, mesh, learning_rate=learning_rate_for(cfg)
+            )
             m_mgr = CheckpointManager(
                 ckpt_dir, process_id=ctx.process_id,
                 num_processes=ctx.num_processes,
@@ -214,6 +221,7 @@ def main(argv=None) -> int:
         server.stop()
         engine.close()
     print(f"serving done: {engine.stats()}", flush=True)
+    print(compile_cache_summary(), flush=True)
     return 0
 
 

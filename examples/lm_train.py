@@ -38,6 +38,7 @@ from tony_tpu import observability
 from tony_tpu.checkpoint import CheckpointManager
 from tony_tpu.models import TransformerConfig, make_train_step
 from tony_tpu.parallel.mesh import MeshSpec
+from tony_tpu.parallel.plan import compile_cache_summary
 
 
 def add_model_args(p: argparse.ArgumentParser) -> None:
@@ -84,8 +85,21 @@ def model_config_from_args(args, *, max_seq: int) -> TransformerConfig:
         head_dim=max(8, args.d_model // args.n_heads),
         d_ff=args.d_model * 4, max_seq=max_seq,
         n_kv_heads=args.n_kv_heads, n_experts=args.n_experts,
-        dtype=args.dtype, remat=False,
+        # Save matmul outputs, recompute the elementwise work between
+        # them: with remat off the rolled layer scan stacks three fp32
+        # [batch, seq, d_ff] SwiGLU intermediates per layer, and the
+        # flagship widths at batch 8 x 2048 then need 16.2 GB of a v5e's
+        # 15.75 (the TPU compiler's own count); this way they need 11.
+        dtype=args.dtype, remat=True, remat_policy="dots",
     )
+
+
+def learning_rate_for(cfg: TransformerConfig) -> float:
+    """1e-2 suits the CPU-sized defaults; past d_model 64 the step scales
+    with 1/width, as adamw needs: at the flagship's d_model 1024 a flat
+    1e-2 drove the loss from 10.9 up to 15.6 on the chip before it fell,
+    and left a model that emits one token."""
+    return min(1e-2, 0.64 / cfg.d_model)
 
 
 def synthetic_tokens(seed: int, n_docs: int, seq: int, vocab: int):
@@ -205,10 +219,12 @@ def main(argv=None) -> int:
     mesh = rt.build_job_mesh()
     print(f"[{ctx.job_name}:{ctx.task_index}] process {ctx.process_id}/"
           f"{ctx.num_processes} slice {ctx.slice_index}/{ctx.num_slices} "
-          f"mesh {dict(mesh.shape)}", flush=True)
+          f"mesh {dict(mesh.shape)} {rt.describe_devices()}", flush=True)
 
     cfg = model_config_from_args(args, max_seq=args.seq + 1)
-    init_fn, step_fn = make_train_step(cfg, mesh, learning_rate=1e-2)
+    init_fn, step_fn = make_train_step(
+        cfg, mesh, learning_rate=learning_rate_for(cfg)
+    )
 
     # Per-process corpus shard via the framework's exactly-once sharding
     # identity (the py4j-reader analogue) — file-backed with --data,
@@ -315,6 +331,7 @@ def main(argv=None) -> int:
         print(f"loss did not descend: {first} -> {last}", file=sys.stderr)
         return 1
     print(f"done: loss {first:.4f} -> {last:.4f}", flush=True)
+    print(compile_cache_summary(), flush=True)
     return 0
 
 

@@ -1,5 +1,5 @@
 """Asserts per-slice identity when slices span MULTIPLE hosts — the
-placement path VERDICT r3 weak #1 found untested: with hosts_per_slice>1,
+placement path one host per slice leaves untested: with hosts_per_slice>1,
 task index i must land on slice i // hosts as in-slice process i % hosts.
 Run with 4 workers x tpus=4 pinned to v4-16 => 2 slices of 2 hosts each."""
 import os

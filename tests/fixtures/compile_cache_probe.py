@@ -16,8 +16,11 @@ import jax  # noqa: E402  (after initialize: cache config must precede use)
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-if os.environ.get("TONY_COMPILE_CACHE_DIR", "") != \
-        jax.config.jax_compilation_cache_dir:
+# A cache placed from outside (JAX_COMPILATION_CACHE_DIR) wins over the
+# conf the executor exported; otherwise the conf's dir must have arrived.
+expected = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+    os.environ.get("TONY_COMPILE_CACHE_DIR", "")
+if expected != jax.config.jax_compilation_cache_dir:
     print("compile cache env not wired into jax config", file=sys.stderr)
     sys.exit(2)
 

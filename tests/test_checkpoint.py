@@ -245,7 +245,7 @@ def _write_slab_checkpoint(directory, step, slabs, *, extra_leaf=None,
 def test_cross_topology_restore_to_single_process(tmp_path):
     """The train-on-a-slice / serve-on-one-host lifecycle: a 2-process
     slab checkpoint restores into a 1-process full template, every leaf
-    reassembled exactly from all shard files (VERDICT r4 missing #1 — the
+    reassembled exactly from all shard files (the
     reference got this from TF full-tensor checkpoints,
     tony-examples/mnist-tensorflow/mnist_distributed.py:46-48)."""
     w = np.arange(16.0, dtype=np.float32).reshape(8, 2)
@@ -432,7 +432,7 @@ def test_resnet_gang_fault_restart_e2e(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# Object-store (gs://) checkpointing — VERDICT r3 missing #2: per-object
+# Object-store (gs://) checkpointing: per-object
 # PUTs are atomic, metadata.json is the commit marker, completeness is
 # reader-side. Runs over FileObjectStorage (the MiniDFS analogue).
 # ---------------------------------------------------------------------------

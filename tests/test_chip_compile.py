@@ -1,0 +1,205 @@
+"""Compiles for a described (not attached) TPU v5e, kept as tests.
+
+The TPU compiler ships with the installed jax/libtpu and compiles for a
+``v5e:2x2`` topology that is only described, so these run on the CPU-only
+test box and cost no chip time. They catch what interpret mode and the
+CPU backend cannot: a kernel the Mosaic compiler refuses (tiling, VMEM),
+and — the reason the file exists — a Pallas call reached on sharded
+operands outside a ``shard_map`` ("Mosaic kernels cannot be automatically
+partitioned"), which is how every multi-device layout failed before the
+ops learned to run per shard.
+
+Nothing executes: a compile that passes says nothing about results or
+times. ``_on_tpu`` is steered from here (the ambient backend is the CPU);
+the program has no option for it.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from tony_tpu.models import decode as decode_lib
+from tony_tpu.models.train import make_train_step
+from tony_tpu.models.transformer import TransformerConfig, init_params
+from tony_tpu.ops import attention, norms
+from tony_tpu.parallel.mesh import AXES, MeshSpec
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # no libtpu, or one that cannot describe it
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc}")
+    return list(topo.devices)
+
+
+@pytest.fixture(autouse=True)
+def _chip_compile_env(monkeypatch):
+    """Take the TPU branch everywhere, and keep the persistent cache out
+    of it: a compile for a described chip is written there but can never
+    be read back, so the next run would warn and compile again."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda mesh=None: True)
+    monkeypatch.setattr(norms, "_on_tpu", lambda mesh=None: True)
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _mosaic_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def _flash_fwd(bh, t_q, t_k, d, block):
+    fn = functools.partial(
+        attention._flash_attention_pallas, causal=True, scale=d ** -0.5,
+        block_q=block, block_k=block, return_lse=True,
+    )
+    shapes = [(bh, t_q, d), (bh, t_k, d), (bh, t_k, d)]
+    return fn, [jnp.bfloat16] * 3, shapes
+
+
+def _flash_bwd(bh, t, d, block):
+    fn = functools.partial(
+        attention._flash_attention_pallas_bwd, causal=True, scale=d ** -0.5,
+        block_q=block, block_k=block,
+    )
+    shapes = [(bh, t, d)] * 4 + [(bh, t)] + [(bh, t, d)]
+    dtypes = [jnp.bfloat16] * 4 + [jnp.float32, jnp.bfloat16]
+    return fn, dtypes, shapes
+
+
+def _rms(rows, d):
+    fn = functools.partial(norms._rms_norm_pallas, eps=1e-6, block_rows=256)
+    return fn, [jnp.bfloat16, jnp.float32], [(rows, d), (d,)]
+
+
+# (B·H, T, D, block) as bench/chip_smoke run them: 200M 16x64 and 8x128 at
+# 2k, the 1B 16x128 at 2k, both head dims at 8k; decode is one query row
+# over a 2048-key cache; RMSNorm at a train (8x2048 rows) and a decode
+# (8 rows) row count of the 1B width.
+KERNELS = {
+    "flash_fwd_2k_d128": (_flash_fwd, (64, 2048, 2048, 128, 512), 1),
+    "flash_fwd_2k_d64": (_flash_fwd, (128, 2048, 2048, 64, 512), 1),
+    "flash_fwd_8k_d128": (_flash_fwd, (16, 8192, 8192, 128, 1024), 1),
+    "flash_fwd_8k_d64": (_flash_fwd, (16, 8192, 8192, 64, 1024), 1),
+    "flash_bwd_2k_d128": (_flash_bwd, (64, 2048, 128, 512), 2),
+    "flash_bwd_2k_d64": (_flash_bwd, (128, 2048, 64, 512), 2),
+    "flash_bwd_8k_d128": (_flash_bwd, (16, 8192, 128, 1024), 2),
+    "flash_bwd_8k_d64": (_flash_bwd, (16, 8192, 64, 1024), 2),
+    "flash_decode_tq1": (_flash_fwd, (64, 1, 2048, 128, 512), 1),
+    "rms_norm_train_rows": (_rms, (16384, 2048), 1),
+    "rms_norm_decode_rows": (_rms, (8, 2048), 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(v5e, name):
+    build, dims, n_calls = KERNELS[name]
+    fn, dtypes, shapes = build(*dims)
+    one_chip = SingleDeviceSharding(v5e[0])
+    args = [
+        jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+        for s, dt in zip(shapes, dtypes)
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _mosaic_calls(compiled) == n_calls
+
+
+# Flagship 200M widths (bench_transformer: d_model 1024, 16 heads x 64,
+# d_ff 4096, vocab 32000, bf16) with GQA 4 and depth cut to 1 per pipeline
+# stage — every layer compiles the same kernels.
+def _cfg(n_layers: int, seq: int) -> TransformerConfig:
+    return TransformerConfig(
+        vocab_size=32_000, d_model=1024, n_layers=n_layers, n_heads=16,
+        head_dim=64, d_ff=4096, max_seq=seq, n_kv_heads=4, dtype="bfloat16",
+        remat=False,
+    )
+
+
+def _mesh(devices, **axes) -> Mesh:
+    spec = MeshSpec(**axes).validate(len(devices))
+    return Mesh(np.asarray(devices).reshape(spec.shape), AXES)
+
+
+LAYOUTS = {
+    "dp4": (dict(dp=4), {}),
+    "dp2_tp2": (dict(dp=2, tp=2), {}),
+    "dp2_sp2": (dict(dp=2, sp=2), {}),
+    "pp2_tp2": (dict(pp=2, tp=2), dict(pipeline_microbatches=2)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LAYOUTS))
+def test_train_step_compiles_on_four_chips(v5e, name):
+    """``make_train_step``'s own program (forward, backward, adamw) over
+    a 4-chip mesh: before the ops ran per shard, each of these raised
+    ``Mosaic kernels cannot be automatically partitioned`` in under two
+    seconds."""
+    axes, pipeline = LAYOUTS[name]
+    mesh = _mesh(v5e, **axes)
+    batch, seq = 8, 2048
+    cfg = _cfg(n_layers=axes.get("pp", 1), seq=seq)
+    init_fn, step_fn = make_train_step(cfg, mesh, **pipeline)
+    state = jax.eval_shape(init_fn.__wrapped__, jax.random.key(0))
+    tokens = jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32)
+    compiled = step_fn.lower(state, tokens).compile()
+    # Per layer: flash fwd + dq + dkv (the sp ring runs them per ring
+    # step) and the RMSNorm forwards; the count only has to show that the
+    # kernels, not the blockwise path, were lowered.
+    assert _mosaic_calls(compiled) >= 5
+
+
+def test_sharded_decode_step_compiles_on_four_chips(v5e):
+    """``DecodeSession(mesh=)``'s layout — fused weights megatron-split
+    over tp, KV cache batch-over-dp / kv-heads-over-tp — through one
+    prefill and one single-token ``advance`` under the ambient mesh."""
+    mesh = _mesh(v5e, dp=2, tp=2)
+    cfg = _cfg(n_layers=1, seq=2048)
+    batch, prompt = 8, 512
+    fused = jax.eval_shape(
+        lambda: decode_lib.decode_weights(
+            init_params(jax.random.key(0), cfg), cfg
+        )
+    )
+    specs = decode_lib.decode_param_specs(cfg)
+    fused = jax.tree.map(
+        lambda spec, p: jax.ShapeDtypeStruct(
+            p.shape, p.dtype, sharding=NamedSharding(mesh, spec)
+        ),
+        specs, fused, is_leaf=lambda x: isinstance(x, P),
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (batch, prompt), jnp.int32, sharding=NamedSharding(mesh, P("dp"))
+    )
+
+    def prefill_then_step(params, tokens):
+        cache = decode_lib.init_cache(cfg, batch, prompt + 1)
+        logits, cache = decode_lib.advance(
+            params, cache, tokens, cfg, prefill=True
+        )
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        logits, _ = decode_lib.advance(params, cache, nxt[:, None], cfg)
+        return logits
+
+    with jax.sharding.set_mesh(mesh):
+        compiled = jax.jit(prefill_then_step).lower(fused, tokens).compile()
+    # Prefill: flash + 2 layer norms; step: 2 layer norms; 2 final norms.
+    assert _mosaic_calls(compiled) >= 5

@@ -178,7 +178,7 @@ class TestNotebookFlow:
 
 
 class TestClusterNotebookUrl:
-    """Cluster-notebook discovery (VERDICT r4 missing #3): the tunnel
+    """Cluster-notebook discovery: the tunnel
     must target the notebook TASK's registered http URL — on a TPU-VM
     backend that is the REMOTE executor's host:port — with the
     coordinator-status tensorboard_url only as fallback."""
@@ -257,3 +257,27 @@ class TestClusterNotebookUrl:
         urls = {(u.name, u.index): u.url for u in session.task_urls()}
         assert urls[("notebook", 0)] == "http://tpu-vm-3:40001"
         assert urls[("worker", 0)] == "file:///worker-0.log"
+
+
+@pytest.mark.parametrize("module", [
+    "tony_tpu.client.cli",
+    "tony_tpu.coordinator.app_master",
+    "tony_tpu.executor.task_executor",
+    "tony_tpu.scheduler.service",
+])
+def test_control_plane_imports_no_jax(module):
+    """A chip belongs to one process at a time, and that process is the
+    user script: the client, coordinator, executor and scheduler must
+    never import jax (let alone initialise a backend), or the chain that
+    launches a job would hold the chip its own child needs."""
+    import subprocess
+
+    repo = Path(__file__).resolve().parents[1]
+    out = subprocess.run(
+        [sys.executable, "-c",
+         f"import sys, {module}; "
+         "print(sorted(m for m in sys.modules if m.split('.')[0] == 'jax'))"],
+        cwd=repo, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "[]"

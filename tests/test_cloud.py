@@ -298,7 +298,7 @@ class TestUrllibTransportAuth:
 
 
 # ---------------------------------------------------------------------------
-# Queued-resources API lifecycle (VERDICT r2 item 1's "Done" list)
+# Queued-resources API lifecycle
 # ---------------------------------------------------------------------------
 
 def _qr_state(state: str) -> dict:
@@ -369,7 +369,7 @@ class TestGcpQueuedResourceApi:
         job: 8 host indexes -> 2 nodes x 4 ssh workers. Real multihost v5e
         is tiled from 4-chip host VMs (ct5lp-hightpu-4t), so a v5litepod-16
         has 4 workers — an 8-chip-host model would launch half the
-        executors onto a truncated worker list (VERDICT r3 weak #1)."""
+        executors onto a truncated worker list."""
         t = FakeTransport()
         runner = FakeRunner()
         api = self._api(t, runner)
@@ -800,7 +800,7 @@ class TestBackendSelection:
 
 
 class TestJanitor:
-    """Cloud-resource janitor (VERDICT r4 weak #5): a coordinator that
+    """Cloud-resource janitor: a coordinator that
     dies uncleanly after create_slice leaks ACTIVE queued resources; a
     SECOND process must be able to find them by the deterministic
     {app}-{job} prefix and free them — the TPU-VM stand-in for YARN's RM

@@ -86,7 +86,7 @@ def test_multislice_identity_reaches_user_script(cluster):
     """2 workers x tpus=8 pinned to v5litepod-8 => a 2-slice plan; each
     executor must see its slice index, in-slice process id, and the
     megascale/DCN env, while jax.distributed stays one flat process list
-    (VERDICT r2 item 2: multi-slice must be driveable end to end)."""
+    (multi-slice must be driveable end to end)."""
     conf = _job(cluster, "check_multislice_env.py", workers=2)
     conf.set(keys.tpus_key("worker"), 8)
     conf.set(keys.K_TPU_ACCELERATOR_TYPE, "v5litepod-8")
@@ -100,7 +100,7 @@ def test_multihost_slice_identity_reaches_user_script(cluster):
     """4 workers x tpus=4 pinned to v4-16 (a 2-host slice shape) => 2
     slices x 2 hosts; each executor must see slice index task//2 and
     in-slice process id task%2 — the hosts_per_slice>1 placement path
-    (VERDICT r3 weak #1: previously only 1-host-per-slice was e2e'd)."""
+    (one host per slice alone would not cover it)."""
     conf = _job(cluster, "check_multihost_slice_env.py", workers=4)
     conf.set(keys.tpus_key("worker"), 4)
     conf.set(keys.K_TPU_ACCELERATOR_TYPE, "v4-16")
@@ -134,7 +134,7 @@ def test_sharded_reader_handoff_exactly_once(cluster, tmp_path):
 
 
 def test_sharded_reader_over_gs_uris(cluster, tmp_path):
-    """The remote-storage data plane end to end (VERDICT r3 missing #1):
+    """The remote-storage data plane end to end:
     executors stream a gs:// corpus via ranged reads — no staging, the way
     the reference's reader opens HDFS directly
     (HdfsAvroFileSplitReader.java:347-416). TONY_GCS_EMULATOR_DIR (the
@@ -172,7 +172,7 @@ def test_sharded_reader_over_gs_uris(cluster, tmp_path):
 def test_cross_process_psum(cluster):
     """A REAL jax.distributed collective through the full stack: 2 executor
     subprocesses each call tony_tpu.runtime.initialize() and run a pmap psum
-    whose value proves cross-process data movement (VERDICT r1 item 2)."""
+    whose value proves cross-process data movement."""
     status, coord = cluster.run_job(
         _job(cluster, "jax_psum.py", workers=2), timeout_s=300
     )
@@ -182,7 +182,7 @@ def test_cross_process_psum(cluster):
 def test_succeeded_session_reaps_blocked_ps_processes(cluster):
     """A SUCCEEDED session must leave ZERO job processes behind — including
     an untracked ps whose user script blocks forever in Server.join() and
-    the grandchildren it spawned (VERDICT r3 weak #6: such orphans were
+    the grandchildren it spawned (such orphans were once
     found on the build box). The reference kills whole containers on
     reset/stop (TonyApplicationMaster.java:526-542, 621-637); here the
     TERM->reap handshake between backend.kill and the executor's death

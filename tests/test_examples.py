@@ -2,7 +2,8 @@
 analogue of the reference shipping runnable tony-examples and exercising
 them through its e2e harness (TestTonyE2E.java:27-253). These run
 ``python -m tony_tpu.client.cli local`` as a genuine subprocess, exactly as
-a user would, covering BASELINE.md configs 1–3."""
+a user would: the MNIST examples in all three frameworks, the LM
+train → generate chain and the serving task."""
 
 import os
 import subprocess

@@ -151,8 +151,8 @@ class TestHistoryServer:
 
     def test_per_job_run_stats_page(self, tmp_path):
         """The /job/<id> page renders the coordinator's terminal record:
-        state, run stats, slice plans, per-task exits — the VERDICT r2
-        item 7 page; /api/job/<id> serves the raw record."""
+        state, run stats, slice plans, per-task exits;
+        /api/job/<id> serves the raw record."""
         from tony_tpu.history.writer import write_final_status
 
         now = int(time.time() * 1000)

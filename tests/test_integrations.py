@@ -67,7 +67,7 @@ class TestPropsMapping:
                               working_dir=tmp_path)
 
     def test_round_trip_local_submission(self, tmp_path):
-        """The done-criterion from VERDICT r1 item 10: a props dict maps to
+        """The done-criterion: a props dict maps to
         a successful local submission end-to-end."""
         rc = submit_from_props(
             {

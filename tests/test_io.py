@@ -340,7 +340,7 @@ class TestNativeDecoder:
 
 
 # ---------------------------------------------------------------------------
-# gs:// data plane (VERDICT r3 missing #1): the reader opens remote corpora
+# gs:// data plane: the reader opens remote corpora
 # directly, the way the reference's reader opens HDFS
 # (HdfsAvroFileSplitReader.java:347-416) — no manual staging.
 # ---------------------------------------------------------------------------

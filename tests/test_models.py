@@ -20,18 +20,6 @@ from tony_tpu.models import (
 from tony_tpu.models.train import make_classifier_step
 from tony_tpu.parallel.mesh import MeshSpec, build_mesh
 
-# jax < 0.5: the shard_map grad/transpose path re-runs the out-spec
-# replication check even under check_vma/check_rep=False, and rejects the
-# MoE pipeline's psum-replicated aux scalars with a _SpecError; the
-# router-collapse numerics also differ under the old PRNG. The affected
-# tests run on current jax.
-OLD_JAX = tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5)
-moe_pipeline_old_jax = pytest.mark.skipif(
-    OLD_JAX,
-    reason="jax < 0.5 shard_map transpose cannot express the MoE "
-           "pipeline's replicated aux outputs (_SpecError)",
-)
-
 CFG = TransformerConfig(
     vocab_size=256,
     d_model=64,
@@ -122,11 +110,6 @@ class TestTrainStep:
         for k in ("moe_balance", "moe_zloss", "moe_drop_rate", "moe_entropy"):
             assert np.isfinite(float(metrics[k])), k
 
-    @pytest.mark.skipif(
-        OLD_JAX,
-        reason="router-collapse initial entropy differs under the "
-               "pre-0.5 jax PRNG",
-    )
     def test_moe_balance_loss_recovers_biased_router(self):
         """Start from a router collapsed onto expert 0 (shrunk weights plus
         an expert-0 column aligned with the batch's activation directions):
@@ -279,7 +262,7 @@ class TestTrainStep:
     def test_interleaved_pp4_v4_matches_gpipe_loss_and_grads(self):
         """pp=4, virtual=4 (16 virtual stages over a 16-layer trunk): the
         index algebra in _pipeline_interleaved_local is exactly the kind
-        that can pass at 2/2 and break at 4/4 (VERDICT r3 weak #7), so pin
+        that can pass at 2/2 and break at 4/4, so pin
         loss AND grads against GPipe on the same mesh at depth."""
         cfg16 = TransformerConfig(
             vocab_size=128, d_model=32, n_layers=16, n_heads=2, head_dim=16,
@@ -344,9 +327,8 @@ class TestTrainStep:
             pytest.approx(m * layers / pp)
         )
 
-    @moe_pipeline_old_jax
     def test_moe_pipeline_matches_gspmd_loss_and_grads(self):
-        """MoE through the pipeline trunk (VERDICT r4 weak #1): pp=2×ep=2
+        """MoE through the pipeline trunk: pp=2×ep=2
         ×tp=2 manual-collective experts (resident E/ep slabs, all_to_all
         token exchange) produce the same total loss AND gradients as the
         GSPMD MoE trunk on a dp=2×ep=2×tp=2 mesh. Capacity factor = E so
@@ -380,7 +362,6 @@ class TestTrainStep:
                 err_msg=str(path),
             )
 
-    @moe_pipeline_old_jax
     def test_moe_pipeline_microbatched_aux_metrics(self):
         """Microbatched (m=2) MoE pipeline: aux losses accumulate across
         microbatches and average — the train step surfaces finite router
@@ -714,7 +695,7 @@ class TestDecode:
     @pytest.mark.parametrize("n_experts", [4, 16])
     def test_routed_moe_decode_token_exact_vs_dense(self, n_experts):
         """Top-k-only (gathered) expert evaluation vs the dense mixture:
-        identical greedy tokens at E=4 and E=16 (VERDICT r3 weak #3). On
+        identical greedy tokens at E=4 and E=16. On
         v5e the dense mixture measured FASTER at every tested (B, E) so
         it stays the default; this parity pin is what lets either mode be
         chosen on perf grounds alone."""

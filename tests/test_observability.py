@@ -875,9 +875,21 @@ def test_mini_cluster_stepstats_training_e2e(tmp_path, capsys):
 
     repo = FIXTURES.parent.parent
     cache_dir = tmp_path / "xla-cache"
+    # The peak table has no entry for a CPU (a CPU run reports no MFU),
+    # so this test names its own peak: a launcher that adds one to the
+    # table and then runs the real example unchanged.
+    lm_train = str(repo / "examples" / "lm_train.py")
+    launcher = tmp_path / "lm_train_with_peak.py"
+    launcher.write_text(
+        "import runpy, sys\n"
+        "from tony_tpu.observability import stepstats\n"
+        "stepstats.PEAK_FLOPS['cpu'] = 1e11\n"
+        f"sys.argv[0] = {lm_train!r}\n"
+        f"runpy.run_path({lm_train!r}, run_name='__main__')\n"
+    )
     cluster = MiniTonyCluster(tmp_path)
     conf = cluster.base_conf()
-    conf.set(keys.K_EXECUTES, str(repo / "examples" / "lm_train.py"))
+    conf.set(keys.K_EXECUTES, str(launcher))
     conf.set(keys.K_PYTHON_BINARY, sys.executable)
     conf.set(keys.instances_key("worker"), 1)
     conf.set(keys.instances_key("ps"), 0)

@@ -120,7 +120,7 @@ class TestRingAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
     def test_long_sequence_bounded_memory(self):
-        """t_local >= 1k (VERDICT r1 item 8): the per-shard kv scan runs
+        """t_local >= 1k: the per-shard kv scan runs
         block_k keys at a time, so the [Tlocal, Tlocal] score matrix is
         never materialized; correctness is cross-checked against dense
         attention at seq 2048 over sp=2."""
@@ -222,8 +222,7 @@ class TestRingAttention:
 
 class TestMultiSlice:
     """Multi-slice (DCN-spanning) mesh: dp rows tile slice-by-slice so
-    inner-axis collectives never cross the slice boundary — the VERDICT r2
-    item 2 contract."""
+    inner-axis collectives never cross the slice boundary."""
 
     def test_dp_outermost_tiles_slices(self):
         devices = jax.devices()[:8]
@@ -493,3 +492,149 @@ def test_ring_cross_length_causal_skip_exact():
     p = jax.nn.softmax(s, axis=-1)
     ref = jnp.einsum("bhqk,bkhd->bqhd", p, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels under a mesh: per_shard (parallel/sharding.py)
+# ---------------------------------------------------------------------------
+# On a TPU every op that lowers to a Mosaic kernel must run inside a
+# shard_map (XLA cannot partition the call). These tests take exactly that
+# path on the virtual CPU mesh: `_on_tpu` is steered true from here and
+# the kernels run in Pallas interpret mode, so what is compared with the
+# single-device result is the kernel path itself, not the blockwise one.
+
+
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
+    from tony_tpu.ops import attention, norms
+
+    def interpreted(fn):
+        def call(*args, **kwargs):
+            kwargs["interpret"] = True
+            return fn(*args, **kwargs)
+        return call
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda mesh=None: True)
+    monkeypatch.setattr(norms, "_on_tpu", lambda mesh=None: True)
+    for mod, name in (
+        (attention, "_flash_attention_pallas"),
+        (attention, "_flash_attention_pallas_bwd"),
+        (norms, "_rms_norm_pallas"),
+    ):
+        monkeypatch.setattr(mod, name, interpreted(getattr(mod, name)))
+
+
+def _max_err(got, want):
+    """Largest error over the leaves, relative to each leaf's scale."""
+    got, want = jax.device_get((got, want))
+    return max(
+        float(np.max(np.abs(g - w)) / max(np.max(np.abs(w)), 1.0))
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want))
+    )
+
+
+class TestKernelsPerShard:
+    @pytest.mark.parametrize("case", ["gqa", "kv_heads_not_divisible",
+                                      "batch_not_divisible"])
+    def test_flash_attention_matches_single_device(
+        self, interpreted_kernels, case
+    ):
+        from tony_tpu.ops import flash_attention
+
+        b, h_kv = {"gqa": (4, 2), "kv_heads_not_divisible": (4, 1),
+                   "batch_not_divisible": (3, 2)}[case]
+        mesh = build_mesh(MeshSpec(dp=2, tp=2), devices=jax.devices()[:4])
+        keys = jax.random.split(jax.random.key(0), 3)
+        q = jax.random.normal(keys[0], (b, 32, 4, 16))
+        k = jax.random.normal(keys[1], (b, 32, h_kv, 16))
+        v = jax.random.normal(keys[2], (b, 32, h_kv, 16))
+
+        def loss(q, k, v, mesh):
+            out = flash_attention(q, k, v, block_q=16, block_k=16, mesh=mesh)
+            return (out ** 2).sum()
+
+        want = jax.jit(jax.value_and_grad(
+            lambda *a: loss(*a, None), argnums=(0, 1, 2)))(q, k, v)
+        got = jax.jit(jax.value_and_grad(
+            lambda *a: loss(*a, mesh), argnums=(0, 1, 2)))(q, k, v)
+        assert _max_err(got, want) < 1e-5
+
+    def test_rms_norm_matches_single_device(self, interpreted_kernels):
+        from tony_tpu.ops import rms_norm
+
+        mesh = build_mesh(MeshSpec(dp=2, sp=2, tp=2))
+        x = jax.random.normal(jax.random.key(0), (4, 32, 64))
+        w = jax.random.normal(jax.random.key(1), (64,))
+
+        def loss(x, w, mesh):
+            return (rms_norm(x, w, mesh=mesh) ** 2 * jnp.arange(64.0)).sum()
+
+        want = jax.jit(jax.value_and_grad(
+            lambda *a: loss(*a, None), argnums=(0, 1)))(x, w)
+        step = jax.jit(jax.value_and_grad(
+            lambda *a: loss(*a, mesh), argnums=(0, 1)))
+        assert _max_err(step(x, w), want) < 1e-5
+        # x is replicated over tp: the loss and dw are reduced, dx never —
+        # a backward that transposed the shard_map would all-reduce dx
+        # (a [2, 16, 64] block per device) over tp as well.
+        reduced = [
+            line for line in step.lower(x, w).compile().as_text().splitlines()
+            if " all-reduce(" in line
+        ]
+        assert reduced and not any("[2,16,64]" in line for line in reduced)
+
+    def test_called_directly_where_every_axis_is_manual(
+        self, interpreted_kernels
+    ):
+        """Inside a shard_map over the whole mesh (pipeline stages, the
+        ring) there is nothing left to partition: the op must not wrap
+        itself a second time over axes that are already manual."""
+        from tony_tpu.ops import rms_norm
+        from tony_tpu.parallel.sharding import auto_axes
+
+        mesh = build_mesh(MeshSpec(dp=2, tp=2), devices=jax.devices()[:4])
+        x = jax.random.normal(jax.random.key(0), (4, 8, 64))
+        w = jnp.ones((64,))
+        seen = []
+
+        def body(x, w):
+            seen.append(auto_axes())
+            return rms_norm(x, w)
+
+        got = jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=(P("dp"), P()), out_specs=P("dp"),
+            check_vma=False,
+        ))(x, w)
+        assert seen == [{}]
+        assert _max_err(got, rms_norm(x, w, force_jax=True)) < 1e-5
+
+    @pytest.mark.parametrize("layout", ["dp2_tp2", "dp2_sp2", "pp2_tp2"])
+    def test_lm_loss_and_grads_match_single_device(
+        self, interpreted_kernels, layout
+    ):
+        """Forward and gradients of the whole LM loss: GSPMD trunk with
+        heads over tp, GSPMD trunk with the sp ring, and the manual
+        pipeline trunk (whose embedding-side norm is the one call outside
+        its shard_map)."""
+        from tony_tpu.models import TransformerConfig, init_params, lm_loss
+
+        axes, kwargs = {
+            "dp2_tp2": (dict(dp=2, tp=2), {}),
+            "dp2_sp2": (dict(dp=2, sp=2), {}),
+            "pp2_tp2": (dict(pp=2, tp=2), dict(pipeline_microbatches=2)),
+        }[layout]
+        cfg = TransformerConfig(
+            vocab_size=64, d_model=32, n_layers=2, n_heads=4, head_dim=8,
+            d_ff=64, max_seq=32, n_kv_heads=2, dtype="float32", remat=False,
+        )
+        params = init_params(jax.random.key(0), cfg)
+        tokens = jax.random.randint(jax.random.key(1), (4, 33), 0, 64)
+        one = build_mesh(MeshSpec(), devices=jax.devices()[:1])
+        four = build_mesh(MeshSpec(**axes), devices=jax.devices()[:4])
+
+        def run(mesh, **kw):
+            return jax.jit(jax.value_and_grad(
+                lambda p, t: lm_loss(p, t, cfg, mesh, **kw)
+            ))(params, tokens)
+
+        assert _max_err(run(four, **kwargs), run(one)) < 1e-4

@@ -438,6 +438,25 @@ class TestBenchGate:
         assert bench.main(["--check", "--baseline", baseline,
                            "--input", str(other)]) == 0
 
+    @pytest.mark.parametrize("extras, want", [
+        ({"moe": {"tokens_per_sec_per_chip": 1.0}}, 0),
+        ({"moe": {"error": "RuntimeError: boom"}}, 1),
+    ])
+    def test_main_fails_when_a_phase_of_this_run_failed(
+        self, monkeypatch, capsys, extras, want
+    ):
+        """`_safe` keeps one failed extra from losing the line, but the
+        run that recorded it must not exit 0."""
+        bench = _bench()
+        monkeypatch.setattr(bench, "run_benches", lambda: {
+            "platform": "cpu", "device_kind": "cpu", "device_count": 1,
+            "metric": "m", "value": 1.0, "extras": extras,
+        })
+        monkeypatch.setattr(plan_lib, "configure_compile_cache",
+                            lambda *a, **k: None)
+        assert bench.main([]) == want
+        assert json.loads(capsys.readouterr().out)["platform"] == "cpu"
+
     def test_update_baseline_roundtrip(self, tmp_path):
         bench = _bench()
         target = tmp_path / "BASELINE.json"
@@ -500,9 +519,14 @@ print("PROBE" + json.dumps({
 """
 
 
+def _clean_env() -> dict:
+    return {k: v for k, v in os.environ.items()
+            if not k.startswith(("TONY_", "XLA_"))
+            and k != "JAX_COMPILATION_CACHE_DIR"}
+
+
 def _run_probe(cache_dir: Path) -> dict:
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith(("TONY_", "XLA_"))}
+    env = _clean_env()
     env.update({
         "JAX_PLATFORMS": "cpu",
         "TONY_COMPILE_CACHE_DIR": str(cache_dir),
@@ -575,3 +599,74 @@ def test_second_identical_run_hits_compile_cache(tmp_path):
     # with the suite), but a served cache must beat a cold XLA compile.
     assert warm["wall_s"] < cold["wall_s"]
     assert warm["compile_ms"] < cold["compile_ms"]
+
+
+# ---------------------------------------------------------------------------
+# Where the cache goes: placed from outside, else one fixed path
+# ---------------------------------------------------------------------------
+
+_PLACEMENT_PROBE = r"""
+import json, os, sys
+import jax
+
+set_in_code = []
+_update = jax.config.update
+def recording_update(name, value):
+    set_in_code.append(name)
+    return _update(name, value)
+jax.config.update = recording_update
+
+from tony_tpu.parallel import plan as plan_lib
+explicit = os.environ.get("PROBE_EXPLICIT_DIR") or None
+resolved = plan_lib.configure_compile_cache(explicit)
+print("PROBE" + json.dumps({
+    "resolved": resolved,
+    "active": plan_lib.active_cache_dir(),
+    "index": plan_lib.CompileCache.active()._index,
+    "default": plan_lib.default_cache_dir(),
+    "set_in_code": set_in_code,
+}))
+"""
+
+
+@pytest.mark.parametrize("case", ["variable_set", "unset", "conf_and_variable"])
+def test_compile_cache_placement(tmp_path, case):
+    """``JAX_COMPILATION_CACHE_DIR`` places the cache from outside: JAX's
+    own reading of it stands, the code sets no directory, and it wins
+    over the conf-exported variable and an explicit argument alike.
+    Without it (and without conf) the cache sits at one fixed path inside
+    the checkout, whatever $HOME or the working directory are."""
+    outside = tmp_path / "placed-from-outside"
+    conf_dir = tmp_path / "conf-dir"
+    explicit = tmp_path / "explicit-arg"
+    env = _clean_env()
+    env.update({
+        "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(REPO),
+        "HOME": str(tmp_path / "some-home"),
+    })
+    if case != "unset":
+        env["JAX_COMPILATION_CACHE_DIR"] = str(outside)
+    if case == "conf_and_variable":
+        env["TONY_COMPILE_CACHE_DIR"] = str(conf_dir)
+        env["PROBE_EXPLICIT_DIR"] = str(explicit)
+    out = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_PROBE], env=env, cwd=tmp_path,
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = next(l for l in out.stdout.splitlines() if l.startswith("PROBE"))
+    got = json.loads(line[len("PROBE"):])
+
+    fixed = str(REPO / ".tony_cache" / "xla-cache")
+    assert got["default"] == fixed
+    want = fixed if case == "unset" else str(outside)
+    assert got["resolved"] == got["active"] == want
+    assert got["index"].startswith(want + os.sep)
+    if case == "unset":
+        assert "jax_compilation_cache_dir" in got["set_in_code"]
+    else:
+        assert "jax_compilation_cache_dir" not in got["set_in_code"]
+        # The thresholds are still lowered so every executable is kept.
+        assert "jax_persistent_cache_min_compile_time_secs" in \
+            got["set_in_code"]
+        assert not conf_dir.exists() and not explicit.exists()

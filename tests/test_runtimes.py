@@ -48,7 +48,7 @@ def test_jax_env_multislice_megascale(monkeypatch):
     """With the coordinator's slice identity in the executor env, the JAX
     runtime injects the megascale/DCN variables (slice id, slice count,
     coordinator host) alongside the flat jax.distributed identity —
-    VERDICT r2 item 2's per-slice env contract."""
+    the per-slice env contract."""
     monkeypatch.setenv("TONY_SLICE_INDEX", "1")
     monkeypatch.setenv("TONY_SLICE_PROCESS_ID", "0")
     monkeypatch.setenv("TONY_NUM_SLICES", "2")

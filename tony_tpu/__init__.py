@@ -11,14 +11,3 @@ jax.sharding meshes, pjit, and Pallas TPU kernels.
 """
 
 __version__ = "0.1.0"
-
-# Alias current jax public-API names onto their pre-0.5 equivalents when
-# running against an older jax (no-op otherwise). Must happen before any
-# submodule touches jax.shard_map / jax.sharding.set_mesh.
-try:
-    from tony_tpu import _jax_compat as _jax_compat  # noqa: F401
-except ImportError:
-    # jax absent entirely (pure control-plane install): the compute-plane
-    # modules that need it will fail on their own import, with a clearer
-    # error than a shim failure here.
-    pass

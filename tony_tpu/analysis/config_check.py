@@ -415,8 +415,8 @@ def _cross_key_checks(conf, job_names: set[str]) -> list[Finding]:
             "TONY-C010", WARNING,
             f"tony.compile.cache-dir={cache_dir} points at non-persistent "
             f"scratch — the XLA compile cache will be cold on every run",
-            suggestion="use a home- or durable-volume path (empty = "
-                       "~/.cache/tony_tpu/xla-cache), or set "
+            suggestion="use a durable-volume path (empty = "
+                       ".tony_cache/xla-cache in the checkout), or set "
                        "tony.compile.cache-enabled=false",
         ))
 

@@ -252,10 +252,11 @@ class TonyClient:
         cwd would silently recompile cold). The dir is created eagerly:
         a bad path surfaces here, at submission, not as a cold cache on
         the fleet. An EMPTY key stays empty — each host then resolves
-        its own per-user default (pinning the client's expanded $HOME
-        would hand executors running as another user an uncreatable
-        path). ``gs://`` URIs pass through — jax's cache layer reads
-        them natively on TPU-VMs."""
+        its own default (``plan.default_cache_dir``: a fixed path inside
+        that host's checkout). ``gs://`` URIs pass through — jax's cache
+        layer reads them natively on TPU-VMs. A process started with
+        ``JAX_COMPILATION_CACHE_DIR`` set keeps its cache there whatever
+        this key says."""
         if not self.conf.get_bool(keys.K_COMPILE_CACHE_ENABLED, True):
             return
         raw = self.conf.get_str(keys.K_COMPILE_CACHE_DIR, "")
@@ -393,9 +394,9 @@ class TonyClient:
 
     def _connect_rpc(self) -> ApplicationRpcClient | None:
         addr_file = self.app_dir / "coordinator.addr"
-        # A fresh interpreter can take tens of seconds to reach prepare()
-        # (e.g. a sitecustomize that imports jax), so the address wait gets
-        # its own generous deadline; per-call retries are a separate knob.
+        # A fresh interpreter on a loaded host can take tens of seconds to
+        # reach prepare(), so the address wait gets its own generous
+        # deadline; per-call retries are a separate knob.
         timeout_s = self.conf.get_int(keys.K_CLIENT_CONNECT_TIMEOUT_MS, 60000) / 1000.0
         retries = self.conf.get_int(keys.K_CLIENT_CONNECT_RETRIES, 3)
 

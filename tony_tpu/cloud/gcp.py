@@ -409,7 +409,7 @@ class GcpQueuedResourceApi:
         # `tpu.nodeSpec[].node.acceleratorType` back from a GET). The
         # endpoint's lenient JSON accepts snake_case on writes too, but
         # one spelling on both sides keeps requests diffable against
-        # recorded responses (VERDICT r3 missing #3).
+        # recorded responses.
         node = {
             "acceleratorType": accelerator_type,
             "runtimeVersion": (
@@ -530,7 +530,7 @@ class GcpQueuedResourceApi:
         by resource-id prefix. Returns ``[{"name": short_id, "state":
         STATE, "nodes": n}, ...]``.
 
-        This is the janitor's discovery half (VERDICT r4 weak #5): slice
+        This is the janitor's discovery half: slice
         names are deterministic ``{app}-{job}``, so a SECOND process can
         find — and ``delete_slice`` — the groups a crashed coordinator
         leaked. The reference inherited this protection from YARN (the RM

@@ -121,9 +121,11 @@ K_IO_CHUNK_RECORDS = IO_PREFIX + "chunk-records"
 # Persistent XLA compile cache: coordinator-driven retries, checkpoint
 # resumes, and scheduler re-submits of an unchanged program skip
 # compilation entirely. The client resolves cache-dir at staging (empty =
-# per-user ~/.cache/tony_tpu/xla-cache; relative paths are absolutized so
-# every process agrees on one dir), the executor exports TONY_COMPILE_*
-# env, and runtime.initialize()/plan.configure_compile_cache wire jax.
+# the fixed .tony_cache/xla-cache inside each host's checkout; relative
+# paths are absolutized so every process agrees on one dir), the executor
+# exports TONY_COMPILE_* env, and runtime.initialize()/
+# plan.configure_compile_cache wire jax — unless the process was started
+# with JAX_COMPILATION_CACHE_DIR, which wins.
 COMPILE_PREFIX = TONY_PREFIX + "compile."
 K_COMPILE_CACHE_DIR = COMPILE_PREFIX + "cache-dir"
 K_COMPILE_CACHE_ENABLED = COMPILE_PREFIX + "cache-enabled"

@@ -123,7 +123,7 @@ class LocalProcessBackend:
     # SIGTERM first: the executor's death handler reaps the USER process
     # group (a separate session a killpg here cannot reach — ps servers
     # blocked in join() would otherwise outlive the job, the orphan leak
-    # VERDICT r3 weak #6 observed). SIGKILL only after the grace window —
+    # once observed on the build box). SIGKILL only after the grace window —
     # and because SIGKILL runs no handler, the user group is then reaped
     # from the pgid file the executor advertised at spawn.
     KILL_GRACE_S = 5.0

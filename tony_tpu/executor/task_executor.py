@@ -39,7 +39,7 @@ MAX_CONSECUTIVE_HB_FAILURES = 5
 # The in-flight user process (its own session via execute_shell's
 # start_new_session): every executor death path must reap ITS process
 # group, or ps-style servers blocked in join() outlive the job — the
-# orphan leak VERDICT r3 weak #6 found on this very box. The reference
+# orphan leak once found on the build box. The reference
 # has no such gap because YARN kills the whole container cgroup
 # (TonyApplicationMaster.reset/stop, TonyApplicationMaster.java:526-542).
 _user_proc: subprocess.Popen | None = None

@@ -262,7 +262,7 @@ def _layer_decode(x, lp, k_all, v_all, layer, length, cfg, cos, sin,
             raise ValueError(f"unknown moe_decode_mode {mode!r}")
         # auto -> dense: measured on v5e, streaming all experts beats
         # per-token top-k weight gathers at every tested (B, E) — see
-        # TransformerConfig.moe_decode_mode and BASELINE.md. Routed
+        # TransformerConfig.moe_decode_mode. Routed
         # applies only to single-token steps even when selected: its
         # gathered [B, T, K, d, 2f] weight copy scales with T — a
         # 1024-token prefill would materialize hundreds of GB.
@@ -588,8 +588,8 @@ class DecodeSession:
 
     ``generate()`` on raw training params re-runs the ``decode_weights``
     fusion every call — one extra jitted dispatch plus the fusion compute
-    (measured 113 ms of the 186 ms wall for a 128-token batch-8 call on
-    v5e, BENCH_r03: wall 5.5k tok/s vs 14.1k steady-state). A served
+    (once more than half the wall of a 128-token batch-8 call; not
+    re-measured on the current chip). A served
     model pays fusion once; this class is that once. Subsequent calls
     dispatch only the cached ``_generate_loop`` executable.
 

@@ -126,8 +126,8 @@ def _group_norm(x, gn, groups, eps=1e-5):
     accumulation directly off the bf16 activations. The naive form
     (upcast the whole tensor, two-pass mean/var) materialized fp32 copies
     of stage-1-sized activations several times per norm — rewriting it
-    this way cut the ResNet-50 train step ~2.7× (see BASELINE.md for the
-    measurement of record): the norm fuses into a pair of reduces plus
+    this way cut the ResNet-50 train step severalfold on the earlier
+    platform (not re-measured on the current chip): the norm fuses into a pair of reduces plus
     one elementwise pass. E[x²]−E[x]² cancellation is a non-issue at
     post-conv activation scale with fp32 accumulation (clamped at 0)."""
     b, h, w, c = x.shape

@@ -125,8 +125,7 @@ def _to_global_batch(batch, sharding):
     A batch that is ALREADY a device array with an equivalent sharding
     (the device_prefetch pipeline places batches with the step's exact
     spec) passes through untouched — re-putting it would queue a second
-    device round-trip per batch, which on tunneled transports costs as
-    much as the first transfer."""
+    device round-trip per batch."""
     if sharding.is_fully_addressable:
         current = getattr(batch, "sharding", None)
         if current is not None:
@@ -202,7 +201,8 @@ def make_train_step(
 
     init_fn(key) -> TrainState, every leaf placed by its logical roles.
     step_fn(state, tokens[B, T+1]) -> (state', {"loss": f32}); donates the
-    old state so params update in place in HBM.
+    old state so params update in place in HBM. ``step_fn.lower`` is the
+    jitted step's own ``.lower``.
 
     ``plan`` (parallel/plan.py) is the declarative alternative to the
     mesh + pipeline kwargs: it supplies the mesh (built from its spec
@@ -346,7 +346,13 @@ def make_train_step(
         stats.set_workload(tokens.shape[0], max(tokens.shape[1] - 1, 1))
         return jit_step(state, tokens)
 
-    return jit_init, _instrumented(step, stats)
+    step = _instrumented(step, stats)
+    # The very program the step dispatches, ahead of time:
+    # ``step.lower(state, tokens)`` (arrays or ShapeDtypeStructs) gives the
+    # ``jax.stages.Lowered`` whose text / compile() show the kernels and
+    # collectives — for any devices the mesh names, attached or described.
+    step.lower = jit_step.__wrapped__.lower
+    return jit_init, step
 
 
 def make_classifier_step(

@@ -199,7 +199,7 @@ def _attention(x, lp, cfg, cos, sin, *, manual: bool, mesh: Mesh | None):
     RoPE positions offset by the shard's global start.
     """
     dt = cfg.compute_dtype
-    h = rms_norm(x, lp["ln1"]).astype(dt)
+    h = rms_norm(x, lp["ln1"], mesh=mesh).astype(dt)
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dt))
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dt))
     v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dt))
@@ -236,7 +236,7 @@ def _attention(x, lp, cfg, cos, sin, *, manual: bool, mesh: Mesh | None):
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         o = ring_attention(q, expand_kv(k), expand_kv(v), mesh, causal=True)
     else:
-        o = flash_attention(q, k, v, causal=True)
+        o = flash_attention(q, k, v, causal=True, mesh=mesh)
     out = jnp.einsum("bthk,hkd->btd", o.astype(dt), lp["wo"].astype(dt))
     return with_logical_constraint(out, "batch", "seq", "embed", mesh=mesh)
 
@@ -250,7 +250,7 @@ def _dense_mlp(
     ``constrain=False`` skips the sharding constraint for mesh-free callers
     (the KV-cache decode path reuses this exact math)."""
     dt = cfg.compute_dtype
-    h = rms_norm(x, lp["ln2"]).astype(dt)
+    h = rms_norm(x, lp["ln2"], mesh=mesh).astype(dt)
     g = jnp.einsum("btd,df->btf", h, lp["w_gate"].astype(dt))
     u = jnp.einsum("btd,df->btf", h, lp["w_up"].astype(dt))
     act = jax.nn.silu(g.astype(jnp.float32)).astype(dt) * u
@@ -297,7 +297,7 @@ def _moe_mlp(x, lp, cfg, mesh: Mesh):
     e, kk = cfg.n_experts, cfg.expert_top_k
     cap = max(1, int(cfg.capacity_factor * b * t * kk / e))
 
-    hn = rms_norm(x, lp["ln2"])
+    hn = rms_norm(x, lp["ln2"], mesh=mesh)
     gate_logits, probs, gvals, gidx = _route_tokens(hn, lp["router"], kk)
     onehot_e = jax.nn.one_hot(gidx, e, dtype=jnp.float32)  # [b,t,k,E]
 
@@ -496,7 +496,7 @@ def forward(
         x, aux_layers = lax.scan(
             layer_fn, x, params["layers"], unroll=cfg.layer_scan_unroll
         )
-    x = rms_norm(x, params["final_norm"]).astype(dt)
+    x = rms_norm(x, params["final_norm"], mesh=mesh).astype(dt)
     logits = jnp.einsum("btd,dv->btv", x, params["unembed"].astype(dt))
     logits = with_logical_constraint(logits, "batch", "seq", "vocab", mesh=mesh)
     if not return_aux:
@@ -555,7 +555,7 @@ def forward_pipeline(
     the in-shard_map sp ring. MoE stages route through ``_moe_mlp_manual``
     (experts resident per ep rank, all_to_all token exchange); their
     router aux losses are accumulated across microbatches inside the
-    schedule and averaged, so pp×ep composes (VERDICT r4 weak #1).
+    schedule and averaged, so pp×ep composes.
 
     ``return_aux=True`` additionally returns the layer- and
     microbatch-averaged MoE aux dict (empty for dense configs), mirroring
@@ -662,7 +662,7 @@ def forward_pipeline(
     else:
         x, aux = out, {}
     x = with_logical_constraint(x, "batch", "seq", "embed", mesh=mesh)
-    x = rms_norm(x, params["final_norm"]).astype(dt)
+    x = rms_norm(x, params["final_norm"], mesh=mesh).astype(dt)
     logits = jnp.einsum("btd,dv->btv", x, params["unembed"].astype(dt))
     logits = with_logical_constraint(logits, "batch", "seq", "vocab", mesh=mesh)
     if not return_aux:

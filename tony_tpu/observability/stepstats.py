@@ -75,8 +75,8 @@ _ENV_CALIBRATE = "TONY_STEPSTATS_CALIBRATE"
 _ENV_WINDOW = "TONY_STEPSTATS_WINDOW"
 
 # Per-chip peak dense bf16 throughput, for MFU (bench.py imports this —
-# one table, one MFU definition), keyed by jax device_kind. "cpu" is
-# nominal so smoke runs still produce a number instead of a blank column.
+# one table, one MFU definition), keyed by jax device_kind. A device that
+# is not here (a CPU included) has no peak, and its runs report no MFU.
 PEAK_FLOPS = {
     "TPU v2": 46e12,
     "TPU v3": 123e12,
@@ -86,29 +86,21 @@ PEAK_FLOPS = {
     "TPU v5p": 459e12,
     "TPU v6 lite": 918e12,
     "TPU v6e": 918e12,
-    "cpu": 1e11,
 }
 
 
 def peak_flops_per_chip(device=None) -> float:
-    """Peak dense flops/sec for one chip (device kind, else platform).
-    Lazy-imports jax; 0.0 without a backend OR for an accelerator
-    generation the table doesn't know — MFU is then simply not
-    reported. (An unknown TPU must NOT fall back to the nominal CPU
-    figure: a v7 at a true 0.5 MFU would publish tony_mfu in the
-    thousands, poisoning the gauge, the detectors, and the gated bench
-    sub-metrics.)"""
-    try:
-        if device is None:
+    """Peak dense flops/sec for one chip, by device kind. Lazy-imports
+    jax; 0.0 without jax or for a device the table doesn't know — MFU
+    is then simply not reported, never computed against a made-up
+    peak."""
+    if device is None:
+        try:
             import jax
-
-            device = jax.devices()[0]
-    except Exception:
-        return 0.0
-    return PEAK_FLOPS.get(
-        getattr(device, "device_kind", ""),
-        PEAK_FLOPS.get(getattr(device, "platform", ""), 0.0),
-    )
+        except ImportError:
+            return 0.0
+        device = jax.devices()[0]
+    return PEAK_FLOPS.get(getattr(device, "device_kind", ""), 0.0)
 
 
 def model_flops_per_step(cfg, batch: int, seq: int) -> float | None:

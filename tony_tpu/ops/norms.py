@@ -11,7 +11,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from jax.sharding import Mesh
+
 from tony_tpu.ops.attention import _on_tpu
+from tony_tpu.parallel.sharding import per_shard
 
 
 def _rms_norm_kernel(x_ref, w_ref, o_ref, *, eps):
@@ -44,18 +47,29 @@ def _rms_norm_pallas(x, w, eps, block_rows, interpret=False):
     )(x, w)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _rms_core(x, w, eps, block_rows, force_jax):
-    if not (_on_tpu() and not force_jax):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5))
+def _rms_core(x, w, eps, block_rows, kernel, mesh):
+    """Only the forward kernel runs per shard; the custom_vjp sits
+    OUTSIDE ``per_shard`` so the (plain-JAX) backward stays under XLA's
+    own partitioning — x is replicated over tp/pp, and transposing the
+    shard_map would all-reduce every dx over those axes."""
+    if not kernel:
         return _rms_norm_jax(x, w, eps)
-    return _rms_norm_pallas(x, w, eps, block_rows)
+
+    def local(x, w):
+        rows = x.reshape(-1, x.shape[-1])
+        return _rms_norm_pallas(rows, w, eps, block_rows).reshape(x.shape)
+
+    # Rows are normed where they live: leading dim over dp/ep, the second
+    # of a [b, t, d] input over sp; the feature dim is never split.
+    return per_shard(local, mesh, (("batch", "seq")[: x.ndim - 1], ()), x, w)
 
 
-def _rms_fwd(x, w, eps, block_rows, force_jax):
-    return _rms_core(x, w, eps, block_rows, force_jax), (x, w)
+def _rms_fwd(x, w, eps, block_rows, kernel, mesh):
+    return _rms_core(x, w, eps, block_rows, kernel, mesh), (x, w)
 
 
-def _rms_bwd(eps, block_rows, force_jax, res, g):
+def _rms_bwd(eps, block_rows, kernel, mesh, res, g):
     x, w = res
     _, vjp = jax.vjp(lambda x, w: _rms_norm_jax(x, w, eps), x, w)
     return vjp(g)
@@ -71,8 +85,10 @@ def rms_norm(
     eps: float = 1e-6,
     block_rows: int = 256,
     force_jax: bool = False,
+    mesh: Mesh | None = None,
 ) -> jax.Array:
-    """RMSNorm over the last axis. x: [..., d], w: [d]."""
-    shape = x.shape
-    out = _rms_core(x.reshape(-1, shape[-1]), w, eps, block_rows, force_jax)
-    return out.reshape(shape)
+    """RMSNorm over the last axis. x: [..., d], w: [d]. Under a
+    multi-device ``mesh`` (explicit, or the ambient ``set_mesh`` one) the
+    kernel runs per shard through ``per_shard``."""
+    kernel = _on_tpu(mesh) and not force_jax
+    return _rms_core(x, w, eps, block_rows, kernel, mesh)

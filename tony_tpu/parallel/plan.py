@@ -16,8 +16,8 @@ Three cooperating pieces:
   (GSPMD jit-with-shardings vs the shard_map pipeline).
 * the planner — ``candidate_plans`` enumerates every legal factoring of
   the device count over (dp, pp, ep, sp, tp) for a model config;
-  ``plan_for`` ranks them with an analytic cost model seeded from the
-  BENCH/MULTICHIP sweeps and REFINED by measured ``step_time_ms``
+  ``plan_for`` ranks them with an analytic cost model REFINED by
+  measured ``step_time_ms``
   (``record_step_time`` persists measurements next to the compile
   cache; measured plans recalibrate the estimates of unmeasured ones).
 * the compile cache — ``configure_compile_cache`` wires the JAX
@@ -79,15 +79,14 @@ def _is_remote_uri(path: str) -> bool:
 def _local_sidecar_dir(cache_dir: str) -> str:
     """Where the key index / measurement table live for a REMOTE (gs://)
     XLA cache: jax reads the artifact cache from the bucket natively,
-    but the sidecar files use plain open()/rename — they get a per-user
-    local mirror keyed by the URI. Hits then mean "this host compiled
-    this plan against this bucket before": the honest local
-    approximation, instead of a marker layer that silently never
-    records."""
+    but the sidecar files use plain open()/rename — they get a local
+    mirror keyed by the URI, beside the default cache dir. Hits then
+    mean "this host compiled this plan against this bucket before": the
+    honest local approximation, instead of a marker layer that silently
+    never records."""
     digest = hashlib.sha256(cache_dir.encode()).hexdigest()[:16]
     return os.path.join(
-        os.path.expanduser("~"), ".cache", "tony_tpu", "plan-sidecar",
-        digest,
+        os.path.dirname(default_cache_dir()), "plan-sidecar", digest
     )
 
 
@@ -250,12 +249,15 @@ def plan_cache_key(
 
 
 def default_cache_dir() -> str:
-    """Per-user default when ``tony.compile.cache-dir`` is empty: a
-    HOME-anchored path, deliberately NOT /tmp — a cache on reboot-scoped
-    scratch is silently cold every run (lint rule TONY-C010)."""
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "tony_tpu", "xla-cache"
+    """Where the cache goes when neither ``JAX_COMPILATION_CACHE_DIR``
+    nor ``tony.compile.cache-dir`` names a place: one fixed, git-ignored
+    path inside the checkout. The directory is part of the cache's key,
+    so it must not move between runs — never $HOME (differs per user and
+    per machine image), a temporary name, a pid or the time."""
+    checkout = os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     )
+    return os.path.join(checkout, ".tony_cache", "xla-cache")
 
 
 def _env_bool(name: str, default: bool) -> bool:
@@ -270,16 +272,24 @@ def configure_compile_cache(
     enabled: bool | None = None,
     min_entry_size: int | None = None,
 ) -> str | None:
-    """Point JAX's persistent compilation cache at ``cache_dir`` and
-    drop the min-compile-time floor so even fast steps get cached
-    (retry/resume wants EVERY executable back, not just the slow ones).
+    """Switch on JAX's persistent compilation cache and drop the
+    min-compile-time floor so even fast steps get cached (retry/resume
+    wants EVERY executable back, not just the slow ones).
 
-    Arguments default from the executor-exported env
-    (``TONY_COMPILE_CACHE_DIR`` / ``_ENABLED`` / ``_MIN_ENTRY_SIZE``,
-    i.e. the ``tony.compile.*`` conf keys); outside a tony-launched
-    process both are empty and the per-user default dir applies.
-    Returns the resolved cache dir, or None when disabled. Safe to call
-    before or after backend init, and idempotent.
+    Where the cache lives, first match wins:
+
+    1. ``JAX_COMPILATION_CACHE_DIR`` — whoever runs the process placed
+       the cache from outside. JAX reads the variable itself and this
+       function sets no directory, whatever ``cache_dir``, the conf or a
+       scheduler's per-slice pin say.
+    2. ``cache_dir``, else the executor-exported ``TONY_COMPILE_CACHE_DIR``
+       (the ``tony.compile.cache-dir`` conf key).
+    3. ``default_cache_dir()``.
+
+    ``enabled`` / ``min_entry_size`` default from
+    ``TONY_COMPILE_CACHE_ENABLED`` / ``_MIN_ENTRY_SIZE``. Returns the
+    directory in use, or None when disabled. Safe to call before or
+    after backend init, and idempotent.
     """
     from tony_tpu import constants
 
@@ -287,10 +297,6 @@ def configure_compile_cache(
         enabled = _env_bool(constants.TONY_COMPILE_CACHE_ENABLED, True)
     if not enabled:
         return None
-    if cache_dir is None:
-        cache_dir = os.environ.get(constants.TONY_COMPILE_CACHE_DIR, "")
-    cache_dir = os.path.expanduser(cache_dir) if cache_dir \
-        else default_cache_dir()
     if min_entry_size is None:
         try:
             min_entry_size = int(
@@ -298,34 +304,46 @@ def configure_compile_cache(
             )
         except ValueError:
             min_entry_size = 0
-    if not _is_remote_uri(cache_dir):
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-        except OSError:
-            return None  # unwritable cache location: run cold, don't crash
 
     import jax
 
-    for opt, val in (
-        ("jax_compilation_cache_dir", cache_dir),
-        ("jax_persistent_cache_min_entry_size_bytes", min_entry_size),
-        ("jax_persistent_cache_min_compile_time_secs", 0),
-    ):
-        try:
-            jax.config.update(opt, val)
-        except (AttributeError, ValueError):
-            pass  # older jax without the knob: partial wiring beats none
-    return cache_dir
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not placed:
+        if cache_dir is None:
+            cache_dir = os.environ.get(constants.TONY_COMPILE_CACHE_DIR, "")
+        cache_dir = os.path.expanduser(cache_dir) if cache_dir \
+            else default_cache_dir()
+        if not _is_remote_uri(cache_dir):
+            try:
+                os.makedirs(cache_dir, exist_ok=True)
+            except OSError:
+                return None  # unwritable location: run cold, don't crash
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update(
+        "jax_persistent_cache_min_entry_size_bytes", min_entry_size
+    )
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return active_cache_dir()
 
 
 def active_cache_dir() -> str | None:
-    """The cache dir JAX is currently configured with (None = cold)."""
+    """The cache dir JAX is currently configured with (None = cold) —
+    ``JAX_COMPILATION_CACHE_DIR`` where set, since JAX's config reads
+    it."""
     import jax
 
-    try:
-        return jax.config.jax_compilation_cache_dir or None
-    except AttributeError:
-        return None
+    return jax.config.jax_compilation_cache_dir or None
+
+
+def compile_cache_summary() -> str:
+    """``compile cache: dir=... hits=N misses=M`` — where this process
+    keeps its cache and what its instrumented first compiles found there
+    (``tony_compile_cache_hits_total`` / ``_misses_total``), for a user
+    script's closing log line."""
+    counters = _registry().snapshot()["counters"]
+    return (f"compile cache: dir={active_cache_dir()} "
+            f"hits={int(counters.get(_CACHE_HITS_COUNTER, 0))} "
+            f"misses={int(counters.get(_CACHE_MISSES_COUNTER, 0))}")
 
 
 class CompileCache:
@@ -602,8 +620,8 @@ def _microbatch_options(
 # Planner: cost model
 # ---------------------------------------------------------------------------
 
-# Relative per-byte cost of a collective on each axis, seeded from the
-# BENCH/MULTICHIP sweeps (r01–r05): tp rides the innermost ICI hops
+# Relative per-byte cost of a collective on each axis — priors, not
+# measurements of the current chip: tp rides the innermost ICI hops
 # (cheapest), sp's ring overlaps with attention compute, ep's all_to_all
 # is bursty, pp moves only stage-boundary activations point-to-point,
 # and dp's gradient psum is the most latency-tolerant (overlappable)
@@ -732,9 +750,8 @@ def estimate_cost(
     compute: total model flops / devices, inflated by (a) the pipeline
     bubble (pp-1)/m on the gpipe trunk and (b) an MXU-fill penalty when
     a tp split drives the per-shard contraction dims under the 128-deep
-    MXU width (the BENCH r05 lesson: hd128 runs 0.65 MFU where the
-    half-filled default runs 0.53 — splits that leave narrow matmuls
-    waste the array even at perfect balance).
+    MXU width (splits that leave narrow matmuls waste the array even
+    at perfect balance).
     comm: per-axis byte estimates weighted by ``_COMM_COST`` (see
     ``estimate_phases`` for the decomposition itself).
     """

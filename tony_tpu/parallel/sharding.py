@@ -8,6 +8,7 @@ place, mechanism elsewhere).
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -59,6 +60,51 @@ def with_logical_constraint(
     if mesh is not None:
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
     return jax.lax.with_sharding_constraint(x, spec)
+
+
+def auto_axes(mesh: Mesh | None = None) -> dict[str, int]:
+    """Sizes of the mesh axes XLA still partitions automatically at this
+    point of the trace: the axes of ``mesh`` (or, with none given, of the
+    ambient ``jax.sharding.set_mesh`` mesh) minus those an enclosing
+    ``shard_map`` already made manual. Empty outside any mesh."""
+    ambient = jax.sharding.get_abstract_mesh()
+    shape = ambient.shape if mesh is None else mesh.shape
+    return {n: s for n, s in shape.items() if n not in ambient.manual_axes}
+
+
+def local_spec(shape, roles, axes: dict[str, int]) -> P:
+    """PartitionSpec of one ``per_shard`` operand: each dim takes the
+    ``axes`` its role maps to, and replicates where they do not divide
+    it (sharding is a placement choice, never a correctness one). Dims
+    past the end of ``roles`` replicate."""
+    out = []
+    for dim, role in zip(shape, roles):
+        names = LOGICAL_RULES[role] if role else ()
+        names = (names,) if isinstance(names, str) else tuple(names or ())
+        names = tuple(n for n in names if axes.get(n, 1) > 1)
+        size = math.prod(axes[n] for n in names)
+        out.append(names if names and dim % size == 0 else None)
+    return P(*out)
+
+
+def per_shard(fn, mesh: Mesh | None, roles, *args):
+    """``fn(*args)`` run once per device on that device's block of each
+    operand. This is how the Pallas kernels meet a mesh: Mosaic calls
+    cannot be partitioned by XLA, so every op that may lower to one goes
+    through here — training, decode and serving alike. ``roles`` names
+    each operand's dims (``LOGICAL_RULES`` keys; None = replicated); the
+    result is laid out like ``args[0]``. With no axis left to partition —
+    no mesh, one device, or a body already inside a ``shard_map`` over
+    every axis (pipeline stages, the ring) — ``fn`` is called directly,
+    so wrappers never nest over axes that are already manual."""
+    axes = auto_axes(mesh)
+    if math.prod(axes.values()) == 1:
+        return fn(*args)
+    specs = tuple(local_spec(a.shape, r, axes) for a, r in zip(args, roles))
+    return jax.shard_map(  # tony: noqa[TONY-X001] — built while the caller's jitted step traces, not per dispatch
+        fn, mesh=mesh, in_specs=specs, out_specs=specs[0],
+        axis_names=frozenset(axes), check_vma=False,
+    )(*args)
 
 
 def shard_pytree(tree: Any, spec_tree: Any, mesh: Mesh) -> Any:

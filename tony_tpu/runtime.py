@@ -68,11 +68,9 @@ def initialize(**kwargs) -> TaskContext:
     job (or in a single-process job) this is a no-op, so scripts run
     unchanged locally."""
     ctx = task_context()
-    # Persistent compile cache first: the executor exported TONY_COMPILE_*
-    # (tony.compile.* conf), and wiring it before any compilation means a
-    # retried/resumed session of an unchanged program skips XLA entirely.
-    # Outside a tony job this resolves the per-user default dir — local
-    # iteration gets warm compiles too.
+    # Persistent compile cache first: wiring it before any compilation
+    # means a retried/resumed session of an unchanged program skips XLA
+    # entirely. Where it lives: parallel/plan.configure_compile_cache.
     from tony_tpu.parallel.plan import configure_compile_cache
 
     configure_compile_cache()
@@ -80,19 +78,10 @@ def initialize(**kwargs) -> TaskContext:
         import jax
 
         if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-            # Multi-process collectives on the CPU backend need the gloo
-            # transport enabled explicitly on older jax (newer releases
-            # default to it); without this every cross-process psum fails
-            # with "Multiprocess computations aren't implemented".
-            for opt, val in (
-                ("jax_cpu_collectives_implementation", "gloo"),
-                ("jax_cpu_enable_gloo_collectives", True),
-            ):
-                try:
-                    jax.config.update(opt, val)
-                    break
-                except (AttributeError, ValueError):
-                    continue
+            # Multi-process collectives on the CPU backend go over gloo;
+            # without it every cross-process psum fails with
+            # "Multiprocess computations aren't implemented".
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=ctx.coordinator_address,
             num_processes=ctx.num_processes,
@@ -114,6 +103,19 @@ def initialize(**kwargs) -> TaskContext:
         except (ValueError, TypeError):
             pass
     return ctx
+
+
+def describe_devices() -> str:
+    """``platform=tpu device_kind="TPU v5 lite" device_count=1`` — the
+    backend this process holds, for a user script's start-up line: a
+    job's log then says which device it actually ran on (a chip belongs
+    to one process, and that process is the user script). Initialises
+    the backend; a backend that cannot start raises."""
+    import jax
+
+    dev = jax.devices()[0]
+    return (f'platform={dev.platform} device_kind="{dev.device_kind}" '
+            f"device_count={jax.device_count()}")
 
 
 def tensorboard_port() -> int | None:

@@ -3,9 +3,9 @@
 The single-shot ``generate`` path compiles one executable per (batch,
 prompt width, horizon) signature and runs every row to the full static
 horizon — fine for eval generation, a throughput wall for serving
-(BENCH r03–r05: the marginal GQA decode step sustains 12.4k tok/s/chip
-while ``generate_wall`` sits at ~5.5k; the kernel is fine, the
-orchestration is the tax). This module is the orchestration fix: TWO
+(on the earlier platform a ``generate`` call's wall rate sat at under
+half of what its marginal decode step sustained; the kernel is fine, the
+orchestration is the tax — not re-measured on the current chip). This module is the orchestration fix: TWO
 executables total, compiled once per engine lifetime, shared by every
 request that ever passes through —
 
