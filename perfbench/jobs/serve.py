@@ -1,0 +1,188 @@
+"""Job kind ``serve``: the user script of a serving job, submitted through
+the normal path (client -> coordinator -> executor -> this file). It makes
+the weights on the device from the seed in the served dtype, builds the
+program's ``ServingEngine`` behind its ``ServingServer``, publishes its
+address atomically and serves until ``POST /shutdown``. The harness drives
+it over HTTP. After shutdown it frees the engine and runs the plain
+reference over the sample of served requests the harness left for it.
+
+Names of the program this file depends on: ``rt.initialize``,
+``rt.build_job_mesh``, ``TransformerConfig``, ``decode_weights`` (the
+fused serving layout), ``ServingEngine`` (constructor, ``start``,
+``step``, ``submit``, ``stats``, ``close``), ``ServingServer`` (``start``,
+``wait_shutdown``, ``stop``), and the executor's ``TB_PORT`` and
+``TONY_SERVING_PREFILL_CHUNK`` / ``TONY_SERVING_DECODE_WINDOW``."""
+
+from __future__ import annotations
+
+import gc
+import os
+import sys
+import time
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from yardstick.jobside import (CompileLog, Tracer, Work, device_report,  # noqa: E402
+                           memory_peak_bytes, program_compile_s)
+from _shared import load_reference, program_params  # noqa: E402
+
+
+def main() -> int:
+    work = Work(sys.argv[sys.argv.index("--params") + 1])
+    work.stage("script_main")
+    p = work.params
+    cfg, run = p["config"], p["config"]["run"]
+    seed = int(p["seed"])
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import tony_tpu.runtime as rt
+    from tony_tpu.models import TransformerConfig, decode_weights
+    from tony_tpu.serving import ServingEngine
+    from tony_tpu.serving.http import ServingServer
+
+    from yardstick import weights
+
+    rt.initialize()
+    compiles = CompileLog()
+    mesh = rt.build_job_mesh()
+    device = device_report()
+    work.publish("device.json", device)
+    work.stage("devices")
+    print(f"bench serve job: mesh {dict(mesh.shape)} {rt.describe_devices()}",
+          flush=True)
+
+    tcfg = TransformerConfig(
+        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
+        n_layers=cfg["num_hidden_layers"], n_heads=cfg["num_attention_heads"],
+        head_dim=cfg["head_dim"], d_ff=cfg["intermediate_size"],
+        max_seq=int(run["max_seq"]), rope_theta=float(cfg["rope_theta"]),
+        n_kv_heads=cfg["num_key_value_heads"], dtype=run["weights_dtype"],
+    )
+    # One jitted call from the seed, in the served dtype, straight into
+    # the engine's fused layout: no float32 copy of the model ever exists.
+    dtype = jnp.dtype(run["weights_dtype"])
+    fused = jax.jit(lambda k: decode_weights(
+        program_params(k, cfg, dtype), tcfg))(weights.seed_key(seed))
+    jax.block_until_ready(fused)
+    work.stage("weights_on_device")
+
+    # The two knobs a deployment sets through its job conf come from the
+    # executor's environment, as examples/lm_serve.py reads them: the
+    # values the program ships are what is measured.
+    chunk = int(os.environ.get("TONY_SERVING_PREFILL_CHUNK") or 32)
+    window = int(os.environ.get("TONY_SERVING_DECODE_WINDOW") or 1)
+    engine = ServingEngine(
+        fused, tcfg, slots=int(run["slots"]), max_len=int(run["max_seq"]),
+        prefill_chunk=chunk, decode_window=window,
+        max_queue=int(run["max_queue"]), seed=seed & 0x7FFFFFFF,
+        kv_quant="none",     # the configuration states a bfloat16 cache
+    )
+    del fused
+    if p.get("trace"):
+        inner_step = engine.step
+
+        def traced_step():
+            with jax.profiler.TraceAnnotation("bench:engine-step"):
+                return inner_step()
+
+        engine.step = traced_step
+    if p.get("fault"):   # test only: an answer altered where it is made
+        inner_submit = engine.submit
+
+        def faulty_submit(*a, **kw):
+            req = inner_submit(*a, **kw)
+            inner_result = req.result
+
+            def result(timeout=None):
+                out = inner_result(timeout)
+                if p["fault"] == "alter_token" and len(out["tokens"]) > 1:
+                    out["tokens"][1] = (out["tokens"][1] + 1) % cfg["vocab_size"]
+                # ends a request early (the short warm-up ones it spares)
+                if p["fault"] == "truncate_answer" and len(out["tokens"]) > \
+                        p["traffic"]["warmup"]["max_new_tokens"]:
+                    out["tokens"] = out["tokens"][:-1]
+                    out["length"] = len(out["tokens"])
+                return out
+
+            req.result = result
+            return req
+
+        engine.submit = faulty_submit
+    engine.start()
+    server = ServingServer(engine, port=int(os.environ.get("TB_PORT") or 0))
+    port = server.start()
+    work.publish("addr.json", {"host": "127.0.0.1", "port": port})
+    work.stage("server_listening")
+
+    # Serve until /shutdown, sampling slot occupancy and minding the
+    # harness's request for a trace.
+    samples = []
+    tracer, traced = None, False
+    try:
+        while not server.wait_shutdown(timeout=0.05):
+            now = time.time()
+            s = engine.stats()
+            samples.append([now, s["active_slots"], s["queue_depth"],
+                            s["prefilling"]])
+            if p.get("trace") and not traced:
+                want = work.read("trace_request.json")
+                if want and tracer is None and now >= want["start"]:
+                    tracer = Tracer(work.dir / "trace")
+                    tracer.start()
+                elif tracer is not None and now >= want["start"] + want["len_s"]:
+                    tracer.stop()
+                    tracer, traced = None, True
+    finally:
+        if tracer is not None:
+            tracer.stop()
+        work.stage("shutdown_received")
+        # No drain: the harness has already waited for what it wanted;
+        # whatever is still queued is answered 503 and counted as cut.
+        engine.close()
+        server.stop()
+    work.stage("engine_closed")
+    result = {
+        "device": device, "memory_peak_bytes": memory_peak_bytes(),
+        "compile_s": program_compile_s(),
+        "compile_times": compiles.times, "occupancy": samples,
+        "slots": int(run["slots"]), "prefill_chunk": chunk,
+        "decode_window": window, "control": p.get("control"),
+        "engine_stats": engine.stats(),
+    }
+    work.publish("window.json", result)
+
+    # Free the program's state, then the reference over the sample.
+    engine.params = None
+    engine._resident = None
+    engine._k = engine._v = None
+    del engine, server
+    gc.collect()
+    check = work.read("check.json")
+    if check:
+        reference = load_reference(p["config_path"])
+        t_ref = time.monotonic()
+        n, width = len(check["requests"]), int(check["pad_to"])
+        tokens = np.zeros((n, width), np.int32)
+        lens_prompt = np.zeros(n, np.int32)
+        lens_total = np.zeros(n, np.int32)
+        for i, r in enumerate(check["requests"]):
+            row = r["prompt"] + r["tokens"]
+            tokens[i, :len(row)] = row
+            lens_prompt[i], lens_total[i] = len(r["prompt"]), len(row)
+        # With a control, the tokens judged are the ones the lower
+        # precision puts first, in the served tokens' place: the run's own
+        # comparison then holds them to the cell's own limit.
+        result["numbers"] = reference.served_token_gaps(
+            cfg, seed, tokens, lens_prompt, lens_total, dtype=str(dtype),
+            lowp_control=p.get("control"))
+        result["reference_s"] = time.monotonic() - t_ref
+        work.stage("reference_done")
+    work.publish("result.json", result)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -1,0 +1,256 @@
+"""The yardstick's own arithmetic, checked without a chip.
+Run: JAX_PLATFORMS=cpu python3 -m pytest perfbench/tests -q"""
+
+from __future__ import annotations
+
+import json
+import math
+from pathlib import Path
+
+import pytest
+
+from yardstick import compare, counts, spec, stats, traffic, xplane
+
+DATA = Path(__file__).resolve().parent / "data"
+MISTRAL = dict(hidden_size=4096, intermediate_size=14336,
+               num_attention_heads=32, num_key_value_heads=8, head_dim=128,
+               vocab_size=32000, num_hidden_layers=2)
+
+
+# -- trace reduction ---------------------------------------------------------
+def synthetic():
+    return xplane.reduce(json.loads((DATA / "synthetic_trace.json").read_text()))
+
+
+def test_idle_share_is_one_minus_the_union_of_op_intervals():
+    r = synthetic()
+    assert r["window_s"] == pytest.approx(0.010)
+    # device 0: busy [0,5) [6,7) [8,9) = 7 ms; device 1: busy all 10 ms
+    assert r["devices"]["0"]["busy_s"] == pytest.approx(0.007)
+    assert r["devices"]["1"]["idle_s"] == pytest.approx(0.0)
+    assert r["busy_s"] == pytest.approx(0.0085)
+
+
+def test_time_by_program_and_by_operation():
+    r = synthetic()
+    step = xplane.program(r, "jit_step")
+    assert step["calls"] == 3 and step["total_s"] == pytest.approx(0.018)
+    assert step["median_s"] == pytest.approx(0.005)
+    assert xplane.program(r, "absent") is None
+    assert dict(r["device_ops"])["flash_fwd"] == pytest.approx(0.001)
+    assert r["device_op_calls"]["flash_fwd"] == pytest.approx(1.0)
+    # 2 + 4 ms over 2 devices, ranked before the 1 ms of all-reduce x 2
+    assert dict(r["device_ops"])["fusion:b"] == pytest.approx(0.003)
+    assert [n for n, _ in r["device_ops"]][-1] == "flash_fwd"
+
+
+def test_idle_gaps_go_to_the_innermost_host_span_open_at_the_time():
+    gaps = dict(synthetic()["idle_gaps"])
+    # device 0 idles [5,6) [7,8) [9,10): outer covers [4,9), inner [7,8)
+    assert gaps["bench:outer"] == pytest.approx(0.0005)
+    assert gaps["bench:inner"] == pytest.approx(0.0005)
+    assert gaps[xplane.NO_SPAN] == pytest.approx(0.0005)
+
+
+def test_exposed_collective_time_is_what_no_compute_overlaps():
+    r = synthetic()
+    # device 0: all-reduce [2,4), compute covers [3,5): 1 ms exposed
+    assert r["devices"]["0"]["collective_exposed_s"] == pytest.approx(0.001)
+    assert r["devices"]["1"]["collective_exposed_s"] == pytest.approx(0.002)
+
+
+@pytest.mark.parametrize("name,programs,top", [
+    ("recorded_serve_trace.json", ("prefill_chunks",),
+     "dynamic-slice_bitcast_fusion:bf16[32,2048,8,128]"),
+    ("recorded_train_trace.json", ("step",),
+     None),
+])
+def test_recorded_chip_trace_agrees_with_a_count_on_a_grid(name, programs, top):
+    """Cuts of real TPU v5e traces (PR 24's runs on the chip: a steady
+    serving window, a training step). Busy time is counted again here in
+    another way, on a grid of 1 microsecond, and must agree."""
+    import numpy as np
+
+    trace = json.loads((DATA / name).read_text())
+    r = xplane.reduce(trace)
+    lo, hi = xplane.window_of(trace)
+    grid = np.zeros(int((hi - lo) // 1000) + 1, bool)
+    for _, s, d in trace["devices"]["0"]["ops"]:
+        a, b = max(s, lo), min(s + d, hi)
+        if b > a:
+            grid[int((a - lo) // 1000): int(-(-(b - lo) // 1000))] = True
+    assert r["busy_s"] == pytest.approx(grid.sum() * 1e-6, rel=0.02)
+    assert 0.5 * r["window_s"] < r["busy_s"] < r["window_s"]
+    assert sum(t for _, t in r["device_ops"]) == pytest.approx(
+        r["busy_s"], rel=0.02), "self times add up to the busy time"
+    for needle in programs:
+        assert xplane.program(r, needle)["calls"] >= 1
+    if top:
+        # the two whole-slab cache copies lead
+        assert top in [n for n, _ in r["device_ops"][:2]]
+        assert dict(r["idle_gaps"])["bench:engine-step"] > 0
+    else:
+        assert any(n.startswith("mosaic:(bf16[128,2048,128],f32[")
+                   for n, _ in r["device_ops"]), "flash forward is there"
+
+
+# -- percentiles and rates ---------------------------------------------------
+def req(i, due, answered, ttft_ms, wall_ms, length, status=200):
+    r = stats.Request(i, due, 10, length)
+    r.sent, r.answered, r.status = due, answered, status
+    r.length, r.ttft_ms, r.wall_ms = length, ttft_ms, wall_ms
+    return r
+
+
+def steady(n=20, stall=0.0):
+    out = []
+    for i in range(n):
+        due = 10.0 + i * 0.5
+        wait = stall if i >= 10 else 0.0        # a stall delays later ones
+        out.append(req(i, due, due + wait + 1.0, 100.0, 1000.0, 10))
+    return out
+
+
+def test_percentile_is_over_all_requests_and_nearest_rank():
+    assert stats.percentile(range(1, 101), 90) == 90
+    assert stats.percentile([5.0], 90) == 5.0
+    assert stats.percentile([1, 2, math.inf], 90) == math.inf
+
+
+def test_a_stall_lowers_the_rate_and_raises_the_tail():
+    calm = stats.serving_metrics(steady(), 10.0, 20.0)
+    stalled = stats.serving_metrics(steady(stall=3.0), 10.0, 20.0)
+    assert calm["serve_ttft_p90_ms"] == pytest.approx(100.0)
+    # timed from when it was DUE: the stall is charged to the request
+    assert stalled["serve_ttft_p90_ms"] == pytest.approx(3100.0)
+    assert stalled["serve_tokens_per_s"] < calm["serve_tokens_per_s"]
+    assert calm["serve_tpot_p90_ms"] == pytest.approx(100.0)
+
+
+def test_a_failed_request_misses_every_limit():
+    rs = steady(10)
+    rs[3].status = 503             # two of ten reach into the p90: one
+    rs[4].length -= 1              # refused, one ended a token early
+    assert not rs[4].ok and rs[4].tpot_ms() is None
+    m = stats.serving_metrics(rs, 10.0, 15.0)
+    assert m["serve_ttft_p90_ms"] == math.inf
+    # eight answers land inside the window; the two failed ones count nothing
+    assert m["serve_tokens_per_s"] == pytest.approx(6 * 10 / 5.0)
+    rs = steady(20)
+    rs[3].status = 503             # one of twenty is outside the p90
+    m = stats.serving_metrics(rs, 10.0, 20.0)
+    assert math.isfinite(m["serve_ttft_p90_ms"])
+    # answers arrive a second after they were due: 18 inside, one failed
+    assert m["serve_tokens_per_s"] == pytest.approx(17 * 10 / 10.0)
+
+
+def test_tokens_count_where_their_answer_arrives():
+    rs = [req(0, 5.0, 12.0, 50.0, 500.0, 7),     # due in the pre-roll
+          req(1, 19.5, 21.0, 50.0, 500.0, 9)]    # answered after the window
+    m = stats.serving_metrics(rs, 10.0, 20.0)
+    assert m["serve_tokens_per_s"] == pytest.approx(0.7)
+    assert m["due_in_window"] == 1
+
+
+def test_train_rate_counts_steps_finished_inside_over_the_whole_window():
+    calm, stalled = [0.5, 1.0, 1.5, 2.0], [0.5, 1.0, 1.5, 2.5]
+    assert stats.train_rate(calm, 100, 2.0, 1) == pytest.approx(200.0)
+    # a stall before the last step: the same work over more time
+    assert stats.train_rate(stalled, 100, 2.5, 1) == pytest.approx(160.0)
+    assert stats.train_rate(stalled, 100, 2.0, 1) == pytest.approx(150.0)
+    assert stats.train_rate(calm, 100, 2.0, 4) == pytest.approx(50.0)
+
+
+def test_spread_is_the_quartile_distance_over_the_median():
+    assert stats.spread([10, 10, 10, 10, 10, 10]) == 0
+    assert stats.spread([1, 2, 3, 4, 5, 6]) == pytest.approx(3.5 / 3.5)
+
+
+# -- operations and bytes ----------------------------------------------------
+def test_counts_agree_with_sums_made_by_hand():
+    layer = (4096 * 4096 * 2 + 4096 * 1024 * 2 + 3 * 4096 * 14336)
+    assert counts.layer_matmul_params(MISTRAL) == layer == 218_103_808
+    assert counts.params_total(MISTRAL) == (
+        2 * (layer + 2 * 4096) + 2 * 32000 * 4096 + 4096)
+    # head yes, lookup no; causal attention 6·L·S·H·Dh a token
+    want = 6 * (2 * layer + 4096 * 32000) + 6 * 2 * 2048 * 32 * 128
+    assert counts.train_flops_per_token(MISTRAL, 2048) == want
+    assert want == pytest.approx(3.504e9, rel=1e-3)
+
+
+def test_flash_and_decode_bytes():
+    fwd = counts.flash_call_cost("fwd", 4, 2048, 32, 8, 128)
+    assert fwd["flops"] == 2.0 * 4 * 32 * 2048 * 2048 * 128
+    q = 4 * 2048 * 32 * 128 * 2
+    assert fwd["bytes"] == 2 * q + 2 * (q // 4) + 4 * 32 * 2048 * 4
+    bwd = (counts.flash_call_cost("dq", 4, 2048, 32, 8, 128)["flops"]
+           + counts.flash_call_cost("dkv", 4, 2048, 32, 8, 128)["flops"])
+    assert bwd == 2.5 * fwd["flops"]
+    cfg = dict(MISTRAL, num_hidden_layers=16)
+    w = counts.weight_bytes(cfg)
+    assert w == (16 * (218_103_808 + 8192) + 4096 * 32000 + 4096) * 2
+    need = counts.decode_iter_bytes(cfg, live_positions=10_000,
+                                    active_slots=20)
+    row = 8 * 128 * 2
+    assert need == w + 2 * 16 * 10_000 * row + 2 * 16 * 20 * row + 20 * 8192
+    t, bound = counts.roofline_seconds(0.0, need, {"flops_bf16": 197e12,
+                                                   "hbm_bytes_per_s": 819e9})
+    assert bound == "memory" and t == pytest.approx(need / 819e9)
+
+
+def test_an_unknown_device_kind_is_an_error():
+    assert spec.peaks("TPU v5 lite")["flops_bf16"] == 197e12
+    with pytest.raises(spec.SpecError):
+        spec.peaks("TPU v9 imaginary")
+
+
+# -- traffic -----------------------------------------------------------------
+def chat():
+    return json.loads((spec.ROOT / "traffic" / "chat-steady.json").read_text())
+
+
+def test_traffic_is_a_pure_function_of_the_seed():
+    a = traffic.serving_schedule(chat(), 2**31 + 5, 20, 32000)
+    b = traffic.serving_schedule(chat(), 2**31 + 5, 20, 32000)
+    c = traffic.serving_schedule(chat(), 7, 20, 32000)
+    assert a == b and a != c
+    x = traffic.training_records({"records": 8, "seq": 16}, 3, 512)
+    y = traffic.training_records({"records": 8, "seq": 16}, 3, 512)
+    assert (x == y).all() and len({r.tobytes() for r in x}) == 8
+
+
+def test_every_seed_gets_the_same_sizes_and_gaps_in_another_order():
+    a = traffic.serving_schedule(chat(), 1, 30, 32000)["requests"]
+    c = traffic.serving_schedule(chat(), 2, 30, 32000)["requests"]
+    for key in (lambda r: len(r["prompt"]), lambda r: r["max_new_tokens"]):
+        assert sorted(map(key, a)) == sorted(map(key, c))
+    assert [key(r) for r in a] != [key(r) for r in c]
+    assert len(a) == len(c)
+    fixed = dict(chat(), seed_turns_order=False)   # the seed draws ids only
+    f1 = traffic.serving_schedule(fixed, 1, 30, 32000)["requests"]
+    f2 = traffic.serving_schedule(fixed, 2, 30, 32000)["requests"]
+    assert [(r["due"], len(r["prompt"])) for r in f1] == [
+        (r["due"], len(r["prompt"])) for r in f2]
+    assert [r["prompt"] for r in f1] != [r["prompt"] for r in f2]
+    t = chat()
+    assert all(t["prompt_len"]["min"] <= len(r["prompt"])
+               <= t["prompt_len"]["max"] for r in a)
+    assert all(0 <= r["due"] < t["preroll_s"] + 30 for r in a)
+    assert traffic.warmup_requests(t, 32000) == traffic.warmup_requests(t, 32000)
+
+
+# -- the comparison ----------------------------------------------------------
+def test_worst_leaf_gap_is_against_the_larger_of_leaf_and_median():
+    ref = {"a": 10.0, "b": 1.0, "c": 1e-6}
+    gap, leaf = compare.worst_leaf_gap({"a": 10.5, "b": 1.0, "c": 2e-6}, ref)
+    assert leaf == "a" and gap == pytest.approx(0.05)
+    gap, leaf = compare.worst_leaf_gap({"a": 10.0, "b": 1.0}, ref)
+    assert gap == math.inf and "c" in leaf
+
+
+def test_judge_needs_every_number_and_keeps_each_limit():
+    ok, table = compare.judge({"x": 0.1, "y": 0}, {"x": 0.2, "y": 0})
+    assert ok and table["x"] == {"value": 0.1, "limit": 0.2, "ok": True}
+    assert not compare.judge({"x": 0.3, "y": 0}, {"x": 0.2, "y": 0})[0]
+    assert not compare.judge({"y": 0}, {"x": 0.2, "y": 0})[0]
+    assert not compare.judge({"x": float("nan")}, {"x": 0.2})[0]
