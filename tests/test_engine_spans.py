@@ -1,0 +1,254 @@
+"""Spans and counters inside ``ServingEngine`` (the names in
+``serving/scheduler.py``'s docstring are a contract the benchmark's
+readers match on). ``step()`` is driven by hand on a tiny model, so the
+counts are exact; the times are only held to their orderings and sums.
+"""
+
+from __future__ import annotations
+
+import jax
+import numpy as np
+import pytest
+
+from tony_tpu.models import TransformerConfig, init_params
+from tony_tpu.observability import trace as obs_trace
+from tony_tpu.observability.metrics import MetricsRegistry
+from tony_tpu.serving import ServingEngine
+from tony_tpu.serving.scheduler import _PHASES, _chunk_plan, _summary
+
+ENGINE_SPANS = {"tony:engine.admit", "tony:engine.prefill_round",
+                "tony:engine.prefill_assemble", "tony:engine.prefill_device",
+                "tony:engine.decode_device", "tony:engine.emit",
+                "tony:engine.publish"}
+PROMPT_LENS = (5, 9, 13, 17, 3, 22)
+
+
+def _engine(**kw) -> ServingEngine:
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=2, head_dim=16,
+        d_ff=64, max_seq=96, dtype="float32", remat=False,
+    )
+    params = init_params(jax.random.key(0), cfg)
+    eng = ServingEngine(params, cfg, registry=MetricsRegistry(), **kw)
+    eng._tracer = obs_trace.Tracer(proc="test-engine")
+    return eng
+
+
+def _drive(eng: ServingEngine, reqs, limit: int = 500) -> None:
+    for _ in range(limit):
+        if all(r.done() for r in reqs):
+            return
+        eng.step()
+    raise AssertionError("requests did not retire")
+
+
+def _spans(eng: ServingEngine) -> list[dict]:
+    return [e for e in eng._tracer.to_chrome_events() if e["ph"] == "X"]
+
+
+@pytest.fixture(scope="module", params=[1, 3], ids=["window1", "window3"])
+def served(request):
+    """Six mixed-length requests through three slots (so some queue and
+    slots are reused), retired and the engine closed."""
+    eng = _engine(slots=3, prefill_chunk=4, prefill_batch=2, max_len=64,
+                  decode_window=request.param)
+    rng = np.random.default_rng(0)
+    reqs = [eng.submit(rng.integers(0, 64, n).astype(np.int32), 6)
+            for n in PROMPT_LENS]
+    _drive(eng, reqs)
+    before_close = eng.stats()
+    eng.close()
+    return eng, reqs, before_close
+
+
+def test_request_stamps_are_ordered(served):
+    _, reqs, _ = served
+    for r in reqs:
+        assert r.t_submit <= r.t_admit <= r.t_first_token <= r.t_done
+
+
+def test_request_spans_share_the_request_id(served):
+    eng, reqs, _ = served
+    by_request: dict[str, dict] = {}
+    for e in _spans(eng):
+        if e["name"].startswith("tony:request."):
+            by_request.setdefault(e["args"]["request"], {})[e["name"]] = e
+    assert set(by_request) == {r.id for r in reqs}
+    for r in reqs:
+        mine = by_request[r.id]
+        assert set(mine) == {"tony:request.queue", "tony:request.prefill",
+                             "tony:request.decode"}
+        queue, prefill, decode = (mine[f"tony:request.{k}"]
+                                  for k in ("queue", "prefill", "decode"))
+        # one life, end to end on one clock (microseconds in the export)
+        assert abs(queue["ts"] + queue["dur"] - prefill["ts"]) <= 2
+        assert abs(prefill["ts"] + prefill["dur"] - decode["ts"]) <= 2
+        assert prefill["args"]["rounds"] == len(
+            _chunk_plan(r.prompt.size, eng.prefill_chunk))
+        assert decode["args"]["tokens"] == len(r.tokens) == 6
+        assert len({e["args"]["span_id"] for e in mine.values()}) == 3
+
+
+def test_engine_spans_descend_from_their_step_and_lie_inside_it(served):
+    eng, _, _ = served
+    spans = [e for e in _spans(eng) if e["name"].startswith("tony:engine.")]
+    by_id = {e["args"]["span_id"]: e for e in spans}
+    steps = [e for e in spans if e["name"] == "tony:engine.step"]
+    assert steps and all(e["args"]["parent_id"] is None for e in steps)
+    assert ([e["args"]["iteration"] for e in steps]
+            == sorted(e["args"]["iteration"] for e in steps))
+    children = [e for e in spans if e["name"] != "tony:engine.step"]
+    assert {e["name"] for e in children} == ENGINE_SPANS
+    for e in children:
+        parent = by_id[e["args"]["parent_id"]]
+        if e["name"] in ("tony:engine.prefill_assemble",
+                         "tony:engine.prefill_device"):
+            assert parent["name"] == "tony:engine.prefill_round"
+        elif e["name"] != "tony:engine.emit":    # emit: round's or step's
+            assert parent["name"] == "tony:engine.step"
+        while parent["name"] != "tony:engine.step":
+            parent = by_id[parent["args"]["parent_id"]]
+        # microsecond export: a child may round one tick past its parent
+        assert parent["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1
+    rounds = [e for e in spans if e["name"] == "tony:engine.prefill_round"]
+    assert all(1 <= e["args"]["batch"] <= eng.prefill_batch
+               and e["args"]["chunk"] == eng.prefill_chunk for e in rounds)
+    decodes = [e for e in spans if e["name"] == "tony:engine.decode_device"]
+    assert all(1 <= e["args"]["slots"] <= eng.slots
+               and e["args"]["window"] == eng.decode_window for e in decodes)
+
+
+def test_phases_are_inside_the_working_wall(served):
+    eng, _, _ = served
+    st = eng.stats()
+    assert set(st["phase_ms"]) == set(_PHASES)
+    assert all(v > 0 for v in st["phase_ms"].values())
+    assert sum(st["phase_ms"].values()) <= st["working_wall_ms"]
+    steps = [e for e in _spans(eng) if e["name"] == "tony:engine.step"]
+    assert st["working_iterations"] == len(steps) <= st["iterations"]
+
+
+def test_phases_sum_to_the_working_wall():
+    """Within 5%: what is left is host time between spans, some tens of
+    microseconds an iteration, so the model here is one whose iteration
+    takes milliseconds on a CPU even with its programs compiled."""
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=256, n_layers=4, n_heads=4, head_dim=64,
+        d_ff=1024, max_seq=256, dtype="float32", remat=False,
+    )
+    eng = ServingEngine(init_params(jax.random.key(0), cfg), cfg, slots=8,
+                        max_len=256, registry=MetricsRegistry())
+    rng = np.random.default_rng(1)
+    reqs = [eng.submit(rng.integers(0, 512, n).astype(np.int32), 8)
+            for n in (40, 90, 130, 17)]
+    _drive(eng, reqs)
+    st = eng.stats()
+    total = sum(st["phase_ms"].values())
+    assert 0.95 * st["working_wall_ms"] <= total <= st["working_wall_ms"]
+    host = st["working_wall_ms"] - (st["phase_ms"]["prefill_device"]
+                                    + st["phase_ms"]["decode_device"])
+    assert 0 < host < st["working_wall_ms"]
+
+
+def test_counters_count_what_the_spans_show(served):
+    eng, reqs, _ = served
+    st = eng.stats()
+    spans = _spans(eng)
+    decodes = [e for e in spans if e["name"] == "tony:engine.decode_device"]
+    rounds = [e for e in spans if e["name"] == "tony:engine.prefill_round"]
+    assert st["decode_iterations"] == len(decodes)
+    assert st["decode_slots_sum"] == sum(e["args"]["slots"] for e in decodes)
+    assert st["prefill_rounds"] == len(rounds)
+    rows = sum(e["args"]["batch"] for e in rounds)
+    assert rows == sum(len(_chunk_plan(n, 4)) for n in PROMPT_LENS)
+    assert st["prefill_rows_padded"] == 2 * len(rounds) - rows
+    assert st["prefill_tokens_valid"] == sum(
+        n for p in PROMPT_LENS for _, n in _chunk_plan(p, 4))
+    assert st["queue_wait_ms"]["n"] == st["prefill_span_ms"]["n"] == len(reqs)
+    waits = sorted((r.t_admit - r.t_submit) * 1000.0 for r in reqs)
+    assert st["queue_wait_ms"]["max"] == pytest.approx(waits[-1])
+    assert st["queue_wait_ms"]["p50"] == pytest.approx(waits[2])
+    # three slots, six requests: the later ones waited for a slot
+    assert waits[-1] > waits[0]
+
+
+def test_live_kv_never_exceeds_what_is_reserved(served):
+    eng, _, _ = served
+    kv = eng.stats()["kv"]
+    assert kv["reserved_positions"] == 3 * 64
+    # float32 K and V rows of 2 layers x 2 heads x 16
+    assert kv["bytes_per_position"] == 2 * 2 * 2 * 16 * 4
+    mean_live = kv["live_position_ms"] / eng.stats()["working_wall_ms"]
+    assert 0 < mean_live <= kv["reserved_positions"]
+    # no more than every prompt and every output token, all at once
+    assert mean_live <= sum(PROMPT_LENS) + 6 * len(PROMPT_LENS)
+
+
+def test_close_leaves_the_counters_as_they_were(served):
+    eng, _, before_close = served
+    after = eng.stats()
+    for key in ("working_iterations", "working_wall_ms", "phase_ms",
+                "decode_iterations", "decode_slots_sum", "prefill_rounds",
+                "prefill_tokens_valid", "prefill_rows_padded", "kv",
+                "queue_wait_ms", "prefill_span_ms", "retired"):
+        assert after[key] == before_close[key], key
+
+
+@pytest.mark.parametrize("prompt_len,chunk", [(3, 8), (16, 8), (20, 8),
+                                              (33, 4)])
+def test_prefill_rounds_of_one_prompt_are_its_chunk_plan(prompt_len, chunk):
+    eng = _engine(slots=2, prefill_chunk=chunk, max_len=64)
+    req = eng.submit(np.arange(prompt_len, dtype=np.int32) % 64, 2)
+    _drive(eng, [req])
+    st = eng.stats()
+    assert st["prefill_rounds"] == len(_chunk_plan(prompt_len, chunk))
+    assert st["prefill_rows_padded"] == (
+        st["prefill_rounds"] * (eng.prefill_batch - 1))
+    assert st["prefill_span_ms"]["n"] == 1
+
+
+def test_idle_polls_record_and_count_nothing():
+    eng = _engine(slots=2, max_len=32)
+    assert eng.step() is False and eng.step() is False
+    st = eng.stats()
+    assert st["iterations"] == 2 and st["working_iterations"] == 0
+    assert st["working_wall_ms"] == 0 and not any(st["phase_ms"].values())
+    assert st["queue_wait_ms"] == {"n": 0, "mean": None, "p50": None,
+                                   "p90": None, "max": None}
+    assert len(eng._tracer) == 0
+
+
+def test_disaggregated_halves_have_only_the_spans_they_lived():
+    """A prefill-only request never decodes and one injected with shipped
+    KV never prefills: no such span, and no made-up prefill time."""
+    pre = _engine(slots=2, prefill_chunk=4, max_len=32)
+    prompt = np.arange(9, dtype=np.int32)
+    half = pre.prefill_only(prompt, 4)
+    _drive(pre, [half])
+    names = {e["name"] for e in _spans(pre)
+             if e["name"].startswith("tony:request.")}
+    assert names == {"tony:request.queue", "tony:request.prefill"}
+    assert pre.stats()["prefill_span_ms"]["n"] == 1
+
+    dec = _engine(slots=2, prefill_chunk=4, max_len=32)
+    kv_k, kv_v = half.kv
+    rest = dec.submit_with_kv(kv_k, kv_v, half.tokens[0], prompt.size, 3)
+    _drive(dec, [rest])
+    names = {e["name"] for e in _spans(dec)
+             if e["name"].startswith("tony:request.")}
+    assert names == {"tony:request.queue", "tony:request.decode"}
+    st = dec.stats()
+    assert st["queue_wait_ms"]["n"] == 1 and st["prefill_span_ms"]["n"] == 0
+    assert st["prefill_rounds"] == 0 and st["phase_ms"]["prefill_device"] == 0
+    assert rest.t_submit <= rest.t_admit <= rest.t_done
+
+
+def test_latency_summary_by_hand():
+    s = _summary([float(v) for v in range(1, 11)])      # 1..10
+    assert s == {"n": 10, "mean": 5.5, "p50": 5.0, "p90": 9.0, "max": 10.0}
+    assert _summary([7.0]) == {"n": 1, "mean": 7.0, "p50": 7.0, "p90": 7.0,
+                               "max": 7.0}
+    s = _summary([float(v) for v in range(100, 0, -1)])  # 100..1, unsorted
+    assert (s["p50"], s["p90"], s["max"]) == (50.0, 90.0, 100.0)
+    assert _summary([1.0, 2.0, 3.0])["p90"] == 3.0

@@ -5,6 +5,7 @@ piggyback over real RPC, and the mini-cluster e2e that drives the whole
 telemetry plane through a 2-task job (jax-free fixture)."""
 
 import json
+import subprocess
 import sys
 import threading
 import time
@@ -29,6 +30,7 @@ from tony_tpu.observability.aggregator import (
 )
 
 FIXTURES = Path(__file__).resolve().parent / "fixtures"
+REPO = Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------------------
@@ -190,6 +192,105 @@ class TestTrace:
         assert obs_trace.Tracer().trace_id == "feedbeef"
         monkeypatch.delenv(constants.TONY_TRACE_ID)
         assert obs_trace.Tracer().trace_id != ""
+
+    def test_span_names_its_parent_and_lies_inside_it(self):
+        tracer = obs_trace.Tracer()
+        with tracer.span("outer") as outer:
+            with tracer.span("inner") as inner:
+                begun = tracer.begin("begun")
+            begun.end()             # ended outside where it began
+        after = tracer.begin("after")
+        after.end()
+        assert outer.parent_id is None and after.parent_id is None
+        assert inner.parent_id == outer.span_id
+        assert begun.parent_id == inner.span_id
+        assert outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
+        assert inner.dur_ns == inner.end_ns - inner.start_ns
+        args = {e["name"]: e["args"] for e in tracer.to_chrome_events()
+                if e["ph"] == "X"}
+        assert args["inner"]["parent_id"] == args["outer"]["span_id"]
+        assert len({a["span_id"] for a in args.values()}) == 4
+
+    def test_parent_is_per_thread(self):
+        tracer = obs_trace.Tracer()
+        seen = []
+
+        def other():
+            with tracer.span("elsewhere") as s:
+                seen.append(s.parent_id)
+
+        with tracer.span("here"):
+            t = threading.Thread(target=other)
+            t.start()
+            t.join(timeout=10)
+        assert seen == [None]
+
+    def test_record_after_the_fact(self):
+        tracer = obs_trace.Tracer()
+        t0 = obs_trace.now_ns()
+        with tracer.span("open"):
+            span_id = tracer.record("tony:request.queue", t0 - 5_000_000,
+                                    t0, request="req-7")
+        event = next(e for e in tracer.to_chrome_events()
+                     if e["name"] == "tony:request.queue")
+        assert event["ts"] == (t0 - 5_000_000) // 1000
+        assert event["dur"] == 5000
+        assert event["args"]["span_id"] == span_id
+        assert event["args"]["parent_id"] is None   # not the open span's
+        assert event["args"]["request"] == "req-7"
+
+    def test_ring_keeps_the_newest_within_its_bound(self, monkeypatch):
+        monkeypatch.setattr(obs_trace, "RING_SPANS", 64)
+        tracer = obs_trace.Tracer()
+        for i in range(640):
+            with tracer.span("tick", i=i):
+                pass
+        assert len(tracer) == 64
+        spans = [e for e in tracer.to_chrome_events() if e["ph"] == "X"]
+        assert [e["args"]["i"] for e in spans] == list(range(576, 640))
+
+    def test_clock_is_monotonic_and_on_the_epoch(self):
+        a = obs_trace.now_ns()
+        wall = time.time_ns()
+        perf = time.perf_counter()
+        b = obs_trace.now_ns()
+        assert a <= b
+        # anchored once: off the wall clock only by its slew since import
+        assert abs(a - wall) < 2_000_000_000
+        assert a <= obs_trace.perf_counter_to_ns(perf) <= b
+
+    def test_span_enters_the_profiler_annotation(self, monkeypatch):
+        calls = []
+
+        class Annotation:
+            def __init__(self, name, **metadata):
+                self.key = (name, metadata)
+
+            def __enter__(self):
+                calls.append(("enter", self.key))
+
+            def __exit__(self, *exc):
+                calls.append(("exit", self.key))
+
+        monkeypatch.setattr(obs_trace, "_annotate", Annotation)
+        tracer = obs_trace.Tracer()
+        with tracer.span("tony:engine.step", iteration=3) as s:
+            tracer.begin("not-entered").end()
+        key = ("tony:engine.step", {"span_id": s.span_id})
+        assert calls == [("enter", key), ("exit", key)]
+
+    def test_imports_and_records_without_jax(self):
+        code = (
+            "import sys\n"
+            "from tony_tpu import observability\n"
+            "with observability.span('load') as s:\n"
+            "    pass\n"
+            "assert s.end_ns >= s.start_ns\n"
+            "assert 'jax' not in sys.modules, 'observability pulled in jax'\n"
+        )
+        proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                              capture_output=True, text=True, timeout=60)
+        assert proc.returncode == 0, proc.stderr
 
 
 # ---------------------------------------------------------------------------

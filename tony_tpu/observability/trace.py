@@ -14,18 +14,41 @@ trace event per line), and the coordinator merges every file with its
 own spans into one ``trace.json`` per job at stop — loadable directly
 in ``chrome://tracing`` / Perfetto, where staging → rendezvous wait →
 first step reads as a waterfall.
+
+A span records its name, start, end, its own id and the id of the span
+that caused it (``args.span_id`` / ``args.parent_id``: the span open in
+the same context when it began), and feeds three sinks at once:
+
+* the tracer's ring of the newest ``RING_SPANS`` spans (bounded, so a
+  hot loop may record every iteration for weeks);
+* the profiler: a ``with tracer.span(...)`` also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name when jax is already
+  loaded — a no-op outside a profiler session, and inside one the span
+  sits in the ``.xplane.pb`` beside the device ops, on the profiler's
+  clock, carrying its ``span_id``;
+* the caller: ``start_ns`` / ``end_ns`` / ``dur_ns`` stay on the span, so
+  a counter summed at the same boundary reads the same two stamps.
+
+One clock for all of it: ``now_ns()``, an epoch anchor taken once per
+process plus ``perf_counter_ns`` deltas — monotonic, and on the
+epoch-nanosecond timeline the profiler stamps host events with (an
+``.xplane.pb`` counts from its session's ``profile_start_time``, itself
+an epoch stamp; measured on a TPU host, the two records of a span start
+within 2 µs of each other).
 """
 
 from __future__ import annotations
 
-import contextlib
 import contextvars
+import itertools
 import json
 import logging
 import os
+import sys
 import threading
 import time
 import uuid
+from collections import deque
 from pathlib import Path
 from typing import Any
 from tony_tpu.analysis import sync_sanitizer as _sync
@@ -33,6 +56,61 @@ from tony_tpu.analysis import sync_sanitizer as _sync
 log = logging.getLogger(__name__)
 
 TRACE_ID_ENV = "TONY_TRACE_ID"
+
+# Spans a tracer keeps: the newest win. The serving engine records about
+# ten per working iteration, so this holds its last ~1,500 iterations.
+RING_SPANS = 16384
+
+_PERF_ANCHOR_NS = time.perf_counter_ns()
+_EPOCH_ANCHOR_NS = time.time_ns()
+
+
+def now_ns() -> int:
+    """Epoch nanoseconds that never step back: the process's epoch
+    anchor plus the monotonic clock's advance since."""
+    return _EPOCH_ANCHOR_NS + time.perf_counter_ns() - _PERF_ANCHOR_NS
+
+
+def perf_counter_to_ns(t: float) -> int:
+    """A ``time.perf_counter()`` reading on ``now_ns()``'s timeline."""
+    return _EPOCH_ANCHOR_NS + int(t * 1e9) - _PERF_ANCHOR_NS
+
+
+# Process-wide, so (pid, span_id) names one span of a merged job trace.
+_span_ids = itertools.count(1)
+
+# os.getpid() is a system call (microseconds where calls are sandboxed,
+# as on the TPU hosts measured): read once, and again in a forked child.
+_pid = os.getpid()
+
+
+def _refresh_pid() -> None:
+    global _pid
+    _pid = os.getpid()
+
+
+os.register_at_fork(after_in_child=_refresh_pid)
+
+# The id of the span open in this context (thread or task), the parent
+# of whatever begins next.
+_current_span: contextvars.ContextVar[int | None] = contextvars.ContextVar(
+    "tony_current_span", default=None
+)
+
+# tony_tpu.profiling.annotate, once jax is loaded: observability must
+# stay importable (and cheap) in the processes that never import jax.
+_annotate = None
+
+
+def _profiler_annotation(name: str, span_id: int):
+    global _annotate
+    if _annotate is None:
+        if "jax" not in sys.modules:
+            return None
+        from tony_tpu import profiling
+
+        _annotate = profiling.annotate
+    return _annotate(name, span_id=span_id)
 
 # The trace id presented by the current RPC request (server side).
 _rpc_trace: contextvars.ContextVar[str | None] = contextvars.ContextVar(
@@ -60,23 +138,56 @@ def ambient_trace_id() -> str | None:
 
 class Span:
     """One open interval. ``end()`` is idempotent; attributes land in the
-    Chrome event's ``args``."""
+    Chrome event's ``args``. As a context manager (``with
+    tracer.span(...)``) it is also the parent of the spans begun inside
+    it and a ``TraceAnnotation`` in the profiler's trace; a span from
+    ``begin()`` may be ended on another thread, so it is neither."""
+
+    __slots__ = ("_tracer", "name", "attrs", "span_id", "parent_id",
+                 "start_ns", "end_ns", "_token", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
-        self.start_us = int(time.time() * 1e6)
-        self._done = False
+        self.span_id = next(_span_ids)
+        self.parent_id = _current_span.get()
+        self.end_ns: int | None = None
+        self._token = None
+        self._annotation = None
+        self.start_ns = 0          # stamped by begin() or __enter__
+
+    @property
+    def dur_ns(self) -> int:
+        """Start to end, or to now while the span is open."""
+        end = self.end_ns if self.end_ns is not None else now_ns()
+        return end - self.start_ns
 
     def set(self, **attrs: Any) -> None:
         self.attrs.update(attrs)
 
     def end(self) -> None:
-        if self._done:
+        if self.end_ns is not None:
             return
-        self._done = True
-        self._tracer._record(self)
+        self.end_ns = now_ns()
+        self._tracer._record(self.name, self.start_ns, self.end_ns,
+                             self.span_id, self.parent_id, self.attrs)
+
+    def __enter__(self) -> "Span":
+        self._token = _current_span.set(self.span_id)
+        self._annotation = _profiler_annotation(self.name, self.span_id)
+        # Stamped beside the profiler's own stamp, so the two records of
+        # this span start within a call of each other.
+        self.start_ns = now_ns()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        self.end()
+        _current_span.reset(self._token)
 
 
 class Tracer:
@@ -91,37 +202,50 @@ class Tracer:
     ) -> None:
         self.trace_id = trace_id or ambient_trace_id() or new_trace_id()
         self.proc = proc or f"proc-{os.getpid()}"
-        self._events: list[dict[str, Any]] = []
-        self._lock = _sync.make_lock("trace.Tracer._lock")
+        # No lock: one C call appends (and drops the oldest) or copies
+        # the deque, and the interpreter lock makes each atomic.
+        self._ring: deque[tuple] = deque(maxlen=RING_SPANS)
 
     # -- recording ---------------------------------------------------------
     def begin(self, name: str, **attrs: Any) -> Span:
+        """An open span the caller ends with ``end()``, on any thread."""
+        span = self.span(name, **attrs)
+        span.start_ns = now_ns()
+        return span
+
+    def span(self, name: str, **attrs: Any) -> Span:
+        """``with tracer.span("load") as s: ...`` — see ``Span``."""
         return Span(self, name, attrs)
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs: Any):
-        s = self.begin(name, **attrs)
-        try:
-            yield s
-        finally:
-            s.end()
+    def record(self, name: str, start_ns: int, end_ns: int,
+               **attrs: Any) -> int:
+        """A span written after the fact from two ``now_ns()`` stamps (a
+        request's life, known only when it retires). It has no parent;
+        spans of one request share ``request=``. Returns its id."""
+        span_id = next(_span_ids)
+        self._record(name, start_ns, end_ns, span_id, None, attrs)
+        return span_id
 
-    def _record(self, span: Span) -> None:
-        now_us = int(time.time() * 1e6)
-        with self._lock:
-            self._events.append({
-                "name": span.name, "ph": "X",
-                "ts": span.start_us,
-                "dur": max(now_us - span.start_us, 1),
-                "pid": os.getpid(), "tid": threading.get_ident() % 100000,
-                "args": {"trace_id": self.trace_id, "proc": self.proc,
-                         **span.attrs},
-            })
+    def _record(self, name: str, start_ns: int, end_ns: int, span_id: int,
+                parent_id: int | None, attrs: dict[str, Any]) -> None:
+        self._ring.append((name, start_ns, end_ns, span_id, parent_id, _pid,
+                           threading.get_ident() % 100000, attrs))
+
+    def __len__(self) -> int:
+        return len(self._ring)
 
     # -- export ------------------------------------------------------------
     def to_chrome_events(self) -> list[dict[str, Any]]:
-        with self._lock:
-            events = list(self._events)
+        rows = self._ring.copy()
+        events = [{
+            "name": name, "ph": "X",
+            "ts": start_ns // 1000,
+            "dur": max((end_ns - start_ns) // 1000, 1),
+            "pid": pid, "tid": tid,
+            "args": {"trace_id": self.trace_id, "proc": self.proc,
+                     "span_id": span_id, "parent_id": parent_id, **attrs},
+        } for name, start_ns, end_ns, span_id, parent_id, pid, tid, attrs
+            in rows]
         if events:
             events.insert(0, {
                 "name": "process_name", "ph": "M", "pid": os.getpid(),
@@ -200,7 +324,7 @@ def default_tracer() -> Tracer:
                 path = Path(log_dir) / f"trace-user-{suffix}.jsonl"
                 atexit.register(
                     lambda: _default_tracer.write_jsonl(path)
-                    if _default_tracer._events else None
+                    if len(_default_tracer) else None
                 )
         return _default_tracer
 

@@ -99,8 +99,11 @@ class StepProfiler:
             self._active = False
 
 
-def annotate(name: str):
-    """Named span in the device trace (jax.profiler.TraceAnnotation)."""
+def annotate(name: str, **metadata):
+    """Named span in the device trace (jax.profiler.TraceAnnotation);
+    ``metadata`` rides the event as stats. Outside a profiler session
+    entering it costs a flag test. ``observability.trace`` spans enter
+    one each, so the program's spans sit beside the device ops."""
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **metadata)

@@ -21,11 +21,32 @@ wall. Telemetry goes through the PR-3 observability registry —
 ``tony_serving_{queue_depth,active_slots,ttft_ms,inter_token_ms,
 tokens_per_sec}`` plus request/token counters — so a tony-launched
 serving task's numbers ride heartbeats onto the coordinator's
-``/metrics`` and the health detectors see serving load. The two
-dispatches also record sampled ``serving_decode_window`` /
-``serving_prefill_chunks`` trace spans (dense through warmup, then
-decimated), so the serving engine shows up in the job's Chrome trace
-beside the coordinator and training waterfalls.
+``/metrics`` and the health detectors see serving load.
+
+Every working iteration records spans through ``observability.trace``
+(one primitive: the tracer's bounded ring for the job's Chrome trace,
+a ``TraceAnnotation`` for the profiler's, and the stamps the counters
+below are summed from). The names are a contract — benchmark readers
+and trace reductions match on them::
+
+    tony:engine.step               one iteration (attr iteration)
+      tony:engine.admit            queue -> free slots
+      tony:engine.prefill_round    one prefill dispatch (attrs batch, chunk)
+        tony:engine.prefill_assemble   host builds the batch
+        tony:engine.prefill_device     dispatch -> readback returned
+        tony:engine.emit               first tokens, retirements
+      tony:engine.decode_device    dispatch -> readback returned
+                                   (attrs slots, window)
+      tony:engine.emit             the per-token loop, retirements
+      tony:engine.publish          gauges + registry report
+
+and, per request, written when it retires and joined by ``request=``:
+``tony:request.queue`` (submit -> slot), ``tony:request.prefill`` (slot
+-> first token, attr ``rounds``; none for a request injected with
+shipped KV) and ``tony:request.decode`` (first token -> done, attr
+``tokens``; none for a request that never decoded). ``stats()`` serves
+the counters taken at the same boundaries (``phase_ms``, ``kv``,
+``queue_wait_ms`` ...). Idle polls record and count nothing.
 
 Greedy parity contract (pinned by tests/test_serving.py): a request
 decoded through the slot engine yields token-for-token the same output
@@ -36,7 +57,6 @@ step is the same math at per-slot positions.
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import itertools
 import logging
@@ -65,6 +85,15 @@ _MS_BUCKETS = (0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
 
 # Rolling window for the tony_serving_tokens_per_sec gauge.
 _RATE_WINDOW_S = 5.0
+
+# Host-clock phases of a working iteration, each summed from the span of
+# the same name (stats()["phase_ms"]); what is left of the iteration's
+# wall is host time between spans.
+_PHASES = ("admit", "prefill_assemble", "prefill_device", "decode_device",
+           "emit", "publish")
+
+# Retired requests whose queue wait / prefill span stats() summarises.
+_LATENCY_RING = 512
 
 # Declared metric names — the tony_serving_* family (TONY-M001/M002
 # lint these module-scope constants).
@@ -106,6 +135,7 @@ class ServingRequest:
         self.tokens: list[int] = []
         self.error: str | None = None
         self.t_submit = time.perf_counter()
+        self.t_admit: float | None = None      # slot assigned
         self.t_first_token: float | None = None
         self.t_done: float | None = None
         self._done = threading.Event()
@@ -139,6 +169,19 @@ class ServingRequest:
                 ((self.t_done or self.t_submit) - self.t_submit) * 1000.0, 3
             ),
         }
+
+
+def _summary(samples_ms: list[float]) -> dict:
+    """{n, mean, p50, p90, max} of a latency ring; a percentile is the
+    value at rank ceil(q * n) (nearest rank)."""
+    n = len(samples_ms)
+    if not n:
+        return {"n": 0, "mean": None, "p50": None, "p90": None, "max": None}
+    ordered = sorted(samples_ms)
+    return {"n": n, "mean": sum(ordered) / n,
+            "p50": ordered[(n + 1) // 2 - 1],
+            "p90": ordered[(9 * n + 9) // 10 - 1],
+            "max": ordered[-1]}
 
 
 def _chunk_plan(prompt_len: int, chunk: int) -> list[tuple[int, int]]:
@@ -270,13 +313,33 @@ class ServingEngine:
         self._iter = 0
         self._decode_calls = 0
         self._pf_draws = 0
-        self._spans_taken: dict[str, int] = {}
+        self._tracer = obs_trace.default_tracer()
         # Engine-local tallies: the registry counters below may be the
         # process-wide default registry (shared by every engine in the
         # process), so stats()/tokens_generated must not read them back.
         self._n_requests = 0
         self._n_retired = 0
         self._n_tokens = 0
+        # Counters at the span boundaries (stats()), cumulative over the
+        # engine's life and untouched by close(). ``_it_ns`` is the
+        # iteration in progress; it is committed only if the iteration
+        # did work, so idle polls and model swaps count nowhere.
+        self._it_ns = dict.fromkeys(_PHASES, 0)
+        self._phase_ns = dict.fromkeys(_PHASES, 0)
+        self._working_iters = 0
+        self._working_wall_ns = 0
+        self._decode_iters = 0
+        self._decode_slots_sum = 0
+        self._prefill_rounds = 0
+        self._prefill_tokens_valid = 0
+        self._prefill_rows_padded = 0
+        self._live_position_ns = 0
+        self._kv_bytes_per_position = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(
+                (self._k, self._v))
+        ) // (self.slots * max_len)
+        self._queue_wait_ms: deque[float] = deque(maxlen=_LATENCY_RING)
+        self._prefill_span_ms: deque[float] = deque(maxlen=_LATENCY_RING)
         self._ids = itertools.count()
         self._base_key = jax.random.key(seed)
         self._rate_window: deque[tuple[float, int]] = deque()
@@ -539,8 +602,11 @@ class ServingEngine:
         return self._n_tokens
 
     def stats(self) -> dict:
+        """Load and the counters taken at the span boundaries (module
+        docstring). Called at 20 Hz by health checks: copies under the
+        engine condition, reduces the latency rings outside it."""
         with self._cond:
-            return {
+            out = {
                 "slots": self.slots,
                 "active_slots": int(self._active.sum()),
                 "queue_depth": len(self._queue),
@@ -553,7 +619,25 @@ class ServingEngine:
                 "model": self._model,
                 "models": sorted(set(self._resident)
                                  | set(self._model_loaders)),
+                "working_iterations": self._working_iters,
+                "working_wall_ms": self._working_wall_ns / 1e6,
+                "phase_ms": {k: v / 1e6 for k, v in self._phase_ns.items()},
+                "decode_iterations": self._decode_iters,
+                "decode_slots_sum": self._decode_slots_sum,
+                "prefill_rounds": self._prefill_rounds,
+                "prefill_tokens_valid": self._prefill_tokens_valid,
+                "prefill_rows_padded": self._prefill_rows_padded,
+                "kv": {
+                    "reserved_positions": self.slots * self.max_len,
+                    "bytes_per_position": self._kv_bytes_per_position,
+                    "live_position_ms": self._live_position_ns / 1e6,
+                },
             }
+            queue_wait = list(self._queue_wait_ms)
+            prefill_span = list(self._prefill_span_ms)
+        out["queue_wait_ms"] = _summary(queue_wait)
+        out["prefill_span_ms"] = _summary(prefill_span)
+        return out
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> "ServingEngine":
@@ -649,66 +733,84 @@ class ServingEngine:
                     req._done.set()
             self._zero_gauges()
 
-    # Trace sampling for the engine's dispatch spans: the serving loop
-    # is the hottest dispatch path in the framework and the Tracer
-    # buffers spans in memory for the job-trace merge, so the first
-    # iterations record densely (compile + ramp — the part a waterfall
-    # reader wants) and the steady state is decimated; a week-long
-    # engine cannot grow the trace without bound.
-    _SPAN_DENSE = 64
-    _SPAN_EVERY = 256
-
-    def _dispatch_span(self, name: str, **attrs):
-        n = self._spans_taken.get(name, 0)
-        self._spans_taken[name] = n + 1
-        if n < self._SPAN_DENSE or n % self._SPAN_EVERY == 0:
-            return obs_trace.default_tracer().span(name, iteration=n,
-                                                   **attrs)
-        return contextlib.nullcontext()
-
     # -- the iteration -----------------------------------------------------
     def step(self) -> bool:
         """One engine iteration (admit -> prefill chunk(s) -> decode
         window for all slots -> retire). Public so tests and the bench
         can drive the loop without threads. Returns False when fully
         idle."""
-        t0 = time.perf_counter()
-        self._admit()
-        did_prefill = self._prefill_some()
-        decoded = False
-        if self._active.any():
-            w = self.decode_window
-            # Inactive lanes park their write at Tmax-1 (engine.py's
-            # wpos contract): writing at their stale pos would clobber
-            # a concurrent prefill into the same slot.
-            wpos = np.where(self._active, self._pos,
-                            np.int32(self.max_len - 1)).astype(np.int32)
-            # Decode draws live in [0, 2**30), prefill draws in
-            # [2**30, 2**31): modular so a long-lived engine can neither
-            # overflow int32 nor cross domains (keys repeat only after
-            # 2**30 draws of the same kind — billions of tokens).
-            # Span covers dispatch AND the readback sync — the wall the
-            # chip actually spent on this window, visible in the job's
-            # Chrome trace beside the training/coordinator spans.
-            with self._dispatch_span("serving_decode_window",
-                                     slots=int(self._active.sum()),
-                                     window=w), \
-                    jit_sanitizer.step_region("serving_decode_window"):
-                self._k, self._v, window = self._decode(
-                    self.params, self._k, self._v, self._pos, wpos,
-                    self._last, self._temp, self._base_key,
-                    np.int32((self._decode_calls * w) % 2**30),
-                )
-                self._decode_calls += 1
-                # Iteration fence: EXPLICIT readback, so the armed
-                # transfer guard (jit sanitizer) lets it through.
-                toks = np.asarray(jax.device_get(window))  # tony: noqa[TONY-X002] — intended per-window fence
-            wall_ms = (time.perf_counter() - t0) * 1000.0
-            # Recorded PER TOKEN (wall / window): with a deep window the
-            # client sees bursts, but the sustained per-stream gap is
-            # what capacity planning reads.
-            self._h_inter.observe(wall_ms / w)
-            self.inter_token_ms_samples.append(wall_ms / w)
+        with self._cond:
+            waiting = bool(self._queue) or bool(self._pf)
+        if not waiting and not self._active.any():
+            # An idle poll: decay the rate gauge and publish, with no
+            # span and no counter.
+            self._publish(decoded=False)
+            return False
+        tr = self._tracer
+        it = self._it_ns = dict.fromkeys(_PHASES, 0)
+        with tr.span("tony:engine.step", iteration=self._iter) as step_span:
+            with tr.span("tony:engine.admit") as sp:
+                self._admit()
+            it["admit"] = sp.dur_ns
+            did_prefill = self._prefill_some()
+            decoded = False
+            if self._active.any():
+                self._decode_some(step_span.start_ns)
+                decoded = True
+            with tr.span("tony:engine.publish") as sp:
+                live_positions = self._publish(decoded)
+            it["publish"] = sp.dur_ns
+        working = did_prefill or decoded
+        if working:
+            wall_ns = step_span.dur_ns
+            with self._cond:
+                for phase, ns in it.items():
+                    self._phase_ns[phase] += ns
+                self._working_iters += 1
+                self._working_wall_ns += wall_ns
+                # Positions held at the iteration's end, for its wall.
+                self._live_position_ns += live_positions * wall_ns
+        return working
+
+    def _decode_some(self, step_start_ns: int) -> None:
+        """One ``decode_window`` for every slot, then the tokens to
+        their requests."""
+        tr, it = self._tracer, self._it_ns
+        w = self.decode_window
+        n_active = int(self._active.sum())
+        # Inactive lanes park their write at Tmax-1 (engine.py's
+        # wpos contract): writing at their stale pos would clobber
+        # a concurrent prefill into the same slot.
+        wpos = np.where(self._active, self._pos,
+                        np.int32(self.max_len - 1)).astype(np.int32)
+        # Decode draws live in [0, 2**30), prefill draws in
+        # [2**30, 2**31): modular so a long-lived engine can neither
+        # overflow int32 nor cross domains (keys repeat only after
+        # 2**30 draws of the same kind — billions of tokens).
+        # Span covers dispatch AND the readback sync — the wall the
+        # chip actually spent on this window.
+        with tr.span("tony:engine.decode_device", slots=n_active,
+                     window=w) as sp, \
+                jit_sanitizer.step_region("serving_decode_window"):
+            self._k, self._v, window = self._decode(
+                self.params, self._k, self._v, self._pos, wpos,
+                self._last, self._temp, self._base_key,
+                np.int32((self._decode_calls * w) % 2**30),
+            )
+            self._decode_calls += 1
+            # Iteration fence: EXPLICIT readback, so the armed
+            # transfer guard (jit sanitizer) lets it through.
+            toks = np.asarray(jax.device_get(window))  # tony: noqa[TONY-X002] — intended per-window fence
+        it["decode_device"] = sp.dur_ns
+        self._decode_iters += 1
+        self._decode_slots_sum += n_active
+        wall_ms = (sp.end_ns - step_start_ns) / 1e6
+        # Recorded PER TOKEN (wall / window): with a deep window the
+        # client sees bursts, but the sustained per-stream gap is
+        # what capacity planning reads.
+        self._h_inter.observe(wall_ms / w)
+        self.inter_token_ms_samples.append(wall_ms / w)
+        with tr.span("tony:engine.emit") as sp:
             n_new = 0
             for s in np.flatnonzero(self._active):
                 req = self._slot_req[s]
@@ -729,7 +831,12 @@ class ServingEngine:
             self._c_tokens.inc(n_new)
             self._n_tokens += n_new
             self._note_rate(n_new)
-            decoded = True
+        it["emit"] += sp.dur_ns
+
+    def _publish(self, decoded: bool) -> int:
+        """End of an iteration: gauges and the registry report. Returns
+        the KV positions written so far in occupied slots (``_pos`` of
+        the decoding ones plus the chunks done of the prefilling)."""
         if not decoded:
             # Idle decay: the rolling-rate gauge must fall to zero when
             # generation stops, or the autoscaler reads phantom load.
@@ -739,14 +846,19 @@ class ServingEngine:
                 self._rate_window.clear()
                 self._g_rate.set(0.0)
         self._iter += 1
+        live_positions = int(self._pos[self._active].sum())
         with self._cond:
             self._g_queue.set(len(self._queue))
+            for req, _slot in self._pf:
+                if req._chunk_i:
+                    start, n_valid = req._chunks[req._chunk_i - 1]
+                    live_positions += start + n_valid
         self._g_active.set(int(self._active.sum()))
         # Publish (throttled inside the registry): serving metrics only
         # reach the executor heartbeat via the $TONY_METRICS_FILE
         # snapshot, and nothing else in a serving loop calls report().
         self._reg.report()
-        return did_prefill or decoded
+        return live_positions
 
     def _next_admissible_locked(self) -> ServingRequest | None:
         """First queued request served by the CURRENT weights. Requests
@@ -762,6 +874,9 @@ class ServingEngine:
         injects: list[tuple[ServingRequest, int]] = []
         switch_to: str | None = None
         with self._cond:
+            # Stamped under the condition: every queued request was
+            # submitted before it was taken, so t_submit <= t_admit.
+            now = time.perf_counter()
             for s in range(self.slots):
                 if not self._queue:
                     break
@@ -770,6 +885,7 @@ class ServingEngine:
                 req = self._next_admissible_locked()
                 if req is None:
                     break
+                req.t_admit = now
                 self._slot_req[s] = req
                 self._pos[s] = 0
                 self._active[s] = False
@@ -813,9 +929,9 @@ class ServingEngine:
         self._active[slot] = True
 
     def _prefill_some(self) -> bool:
-        """Run one prefill ROUND: one chunk for every pending slot (the
-        auto budget — prefill work only exists while slots sit idle),
-        batched ``prefill_batch`` slots per dispatch and padded by
+        """One chunk for every pending slot (the auto budget — prefill
+        work only exists while slots sit idle), in ROUNDS of
+        ``prefill_batch`` slots per dispatch, a short round padded by
         duplicating entry 0 (idempotent rewrite), so the executable
         count stays at one whatever the pending population."""
         # The pending-prefill deque is shared with _admit and the
@@ -834,7 +950,19 @@ class ServingEngine:
             if not entries:
                 break
             budget -= n
-            pb = self.prefill_batch
+            with self._tracer.span("tony:engine.prefill_round", batch=n,
+                                   chunk=self.prefill_chunk):
+                self._prefill_round(entries)
+        return True
+
+    def _prefill_round(
+        self, entries: list[tuple[ServingRequest, int]],
+    ) -> None:
+        """One ``prefill_chunks`` dispatch: the next chunk of each
+        entry's prompt; a prompt's last chunk yields its first token."""
+        tr, it = self._tracer, self._it_ns
+        n, pb = len(entries), self.prefill_batch
+        with tr.span("tony:engine.prefill_assemble") as sp:
             toks = np.zeros((pb, self.prefill_chunk), np.int32)
             slots_a = np.zeros(pb, np.int32)
             starts = np.zeros(pb, np.int32)
@@ -860,15 +988,20 @@ class ServingEngine:
             # offset) so no prefill sample can ever share a decode
             # step's key.
             self._pf_draws += 1
-            with self._dispatch_span("serving_prefill_chunks", batch=n,
-                                     chunk=self.prefill_chunk), \
-                    jit_sanitizer.step_region("serving_prefill_chunks"):
-                self._k, self._v, first_toks, _ = self._prefill(
-                    self.params, self._k, self._v, toks, slots_a, starts,
-                    n_valids, temps, self._base_key,
-                    np.int32(2**30 + self._pf_draws % 2**30),
-                )
-                firsts = np.asarray(jax.device_get(first_toks))  # tony: noqa[TONY-X002] — intended per-round fence
+        it["prefill_assemble"] += sp.dur_ns
+        self._prefill_rounds += 1
+        self._prefill_tokens_valid += int(n_valids[:n].sum())
+        self._prefill_rows_padded += pb - n
+        with tr.span("tony:engine.prefill_device") as sp, \
+                jit_sanitizer.step_region("serving_prefill_chunks"):
+            self._k, self._v, first_toks, _ = self._prefill(
+                self.params, self._k, self._v, toks, slots_a, starts,
+                n_valids, temps, self._base_key,
+                np.int32(2**30 + self._pf_draws % 2**30),
+            )
+            firsts = np.asarray(jax.device_get(first_toks))  # tony: noqa[TONY-X002] — intended per-round fence
+        it["prefill_device"] += sp.dur_ns
+        with tr.span("tony:engine.emit") as sp:
             now = time.perf_counter()
             requeue: list[tuple[ServingRequest, int]] = []
             for i, (req, slot) in enumerate(entries):
@@ -909,10 +1042,11 @@ class ServingEngine:
             if requeue:
                 with self._cond:
                     self._pf.extend(requeue)
-        return True
+        it["emit"] += sp.dur_ns
 
     def _retire(self, slot: int) -> None:
         req = self._slot_req[slot]
+        decoded = bool(self._active[slot])   # else it ends at prefill
         self._active[slot] = False
         self._slot_req[slot] = None
         # Reset the lane temperature: a stale hot value would keep the
@@ -923,6 +1057,27 @@ class ServingEngine:
         self._n_retired += 1
         req.t_done = time.perf_counter()
         req._done.set()
+        # The request's life as spans, and the two waits stats() serves.
+        # A request injected with shipped KV never prefilled here: it has
+        # no prefill span, and decodes from its admission.
+        tr, to_ns = self._tracer, obs_trace.perf_counter_to_ns
+        admit_ns = to_ns(req.t_admit)
+        tr.record("tony:request.queue", to_ns(req.t_submit), admit_ns,
+                  request=req.id)
+        first_ns = admit_ns
+        if req.t_first_token is not None:
+            first_ns = to_ns(req.t_first_token)
+            tr.record("tony:request.prefill", admit_ns, first_ns,
+                      request=req.id, rounds=len(req._chunks))
+        if decoded:
+            tr.record("tony:request.decode", first_ns, to_ns(req.t_done),
+                      request=req.id, tokens=len(req.tokens))
+        with self._cond:
+            self._queue_wait_ms.append(
+                (req.t_admit - req.t_submit) * 1000.0)
+            if req.t_first_token is not None:
+                self._prefill_span_ms.append(
+                    (req.t_first_token - req.t_admit) * 1000.0)
 
     def _note_rate(self, n_tokens: int) -> None:
         now = time.perf_counter()
