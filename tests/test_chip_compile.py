@@ -86,6 +86,13 @@ def _flash_bwd(bh, t, d, block):
     return fn, dtypes, shapes
 
 
+def _cache_decode(layers, slots, t, h_kv, group, d):
+    cache = (layers, slots, t, h_kv, d)
+    shapes = [(slots, h_kv * group, d), cache, cache, (), (slots,)]
+    dtypes = [jnp.bfloat16] * 3 + [jnp.int32] * 2
+    return attention.cache_decode_attention, dtypes, shapes
+
+
 def _rms(rows, d):
     fn = functools.partial(norms._rms_norm_pallas, eps=1e-6, block_rows=256)
     return fn, [jnp.bfloat16, jnp.float32], [(rows, d), (d,)]
@@ -93,7 +100,10 @@ def _rms(rows, d):
 
 # (B·H, T, D, block) as bench/chip_smoke run them: 200M 16x64 and 8x128 at
 # 2k, the 1B 16x128 at 2k, both head dims at 8k; decode is one query row
-# over a 2048-key cache; RMSNorm at a train (8x2048 rows) and a decode
+# over a 2048-key cache; the engine's decode read at the serving cells'
+# size (16 layers x 32 slots x 2,048 positions of 8 KV heads x 128, 4
+# query heads a group) and with 32 KV heads (the block must shrink to
+# fit VMEM); RMSNorm at a train (8x2048 rows) and a decode
 # (8 rows) row count of the 1B width.
 KERNELS = {
     "flash_fwd_2k_d128": (_flash_fwd, (64, 2048, 2048, 128, 512), 1),
@@ -105,6 +115,10 @@ KERNELS = {
     "flash_bwd_8k_d128": (_flash_bwd, (16, 8192, 128, 1024), 2),
     "flash_bwd_8k_d64": (_flash_bwd, (16, 8192, 64, 1024), 2),
     "flash_decode_tq1": (_flash_fwd, (64, 1, 2048, 128, 512), 1),
+    "cache_decode_mistral7b": (_cache_decode, (16, 32, 2048, 8, 4, 128), 1),
+    "cache_decode_mha32": (_cache_decode, (2, 8, 2048, 32, 1, 128), 1),
+    # Dh 64 (the 200M flagship): the cache's rows do not merge, plain path
+    "cache_decode_d64_plain": (_cache_decode, (8, 8, 512, 4, 4, 64), 0),
     "rms_norm_train_rows": (_rms, (16384, 2048), 1),
     "rms_norm_decode_rows": (_rms, (8, 2048), 1),
 }
@@ -203,3 +217,54 @@ def test_sharded_decode_step_compiles_on_four_chips(v5e):
         compiled = jax.jit(prefill_then_step).lower(fused, tokens).compile()
     # Prefill: flash + 2 layer norms; step: 2 layer norms; 2 final norms.
     assert _mosaic_calls(compiled) >= 5
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunks"])
+def test_serving_programs_hold_no_layer_slab(v5e, program):
+    """The engine's two programs at the serving cells' widths (Mistral-7B,
+    32 slots x 2,048 positions, depth cut to 2): the compiler's temporaries
+    stay under ONE layer's K or V slab. The jaxpr cannot show this side —
+    XLA copies a layer out of the stacked cache even for a read-only slice
+    that feeds the attention contraction, which is why decode reads
+    through ``cache_decode_attention`` and prefill takes only its own
+    slots' rows (the slab path held three slabs here)."""
+    from tony_tpu.serving import engine
+
+    cfg = TransformerConfig(
+        vocab_size=32_000, d_model=4096, n_layers=2, n_heads=32,
+        head_dim=128, d_ff=14336, max_seq=2048, n_kv_heads=8,
+        dtype="bfloat16", remat=False,
+    )
+    slots, t_max, p, c = 32, 2048, 4, 32
+    one_chip = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree
+        )
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fused = on_chip(jax.eval_shape(
+        lambda: decode_lib.decode_weights(
+            init_params(jax.random.key(0), cfg), cfg
+        )
+    ))
+    kv = arr((cfg.n_layers, slots, t_max, cfg.kv_heads, cfg.head_dim),
+             jnp.bfloat16)
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    if program == "decode_window":
+        lowered = engine.decode_window.lower(
+            fused, kv, kv, arr((slots,)), arr((slots,)), arr((slots,)),
+            arr((slots,), jnp.float32), key, arr(()), cfg=cfg, steps=1,
+        )
+    else:
+        lowered = engine.prefill_chunks.lower(
+            fused, kv, kv, arr((p, c)), arr((p,)), arr((p,)), arr((p,)),
+            arr((p,), jnp.float32), key, arr(()), cfg=cfg,
+        )
+    compiled = lowered.compile()
+    slab_bytes = slots * t_max * cfg.kv_heads * cfg.head_dim * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes

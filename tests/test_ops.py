@@ -9,6 +9,7 @@ import pytest
 
 from tony_tpu.ops import (
     apply_rope,
+    cache_decode_attention,
     flash_attention,
     rms_norm,
     rope_frequencies,
@@ -145,6 +146,54 @@ class TestFlashAttention:
         q, k, v = (x.astype(jnp.bfloat16) for x in qkv)
         out = flash_attention(q, k, v, force_jax=True)
         assert out.dtype == jnp.bfloat16
+
+
+class TestCacheDecodeAttention:
+    """The serving engine's decode read: one query per slot against one
+    layer of the stacked cache. The kernel (interpret mode) is pinned to
+    the plain-JAX path, which slices the layer out first."""
+
+    @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                            (jnp.bfloat16, 2e-2)])
+    def test_kernel_interpret_matches_jax(self, dtype, atol):
+        n_l, n_s, t, h_kv, group, d = 3, 4, 64, 2, 4, 16
+        keys = jax.random.split(jax.random.key(3), 3)
+        q = jax.random.normal(keys[0], (n_s, h_kv * group, d), dtype)
+        k_all = jax.random.normal(keys[1], (n_l, n_s, t, h_kv, d), dtype)
+        v_all = jax.random.normal(keys[2], (n_l, n_s, t, h_kv, d), dtype)
+        # first key only, mid-block, the last key, and an inactive lane
+        # whose position ran past the cache
+        pos = jnp.asarray([0, 21, t - 1, t + 5], jnp.int32)
+        for layer in (0, n_l - 1):
+            got, want = (
+                cache_decode_attention(q, k_all, v_all, jnp.int32(layer),
+                                       pos, block_rows=32, mode=mode)
+                for mode in ("interpret", "jax")
+            )
+            assert got.dtype == dtype and got.shape == q.shape
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32), np.asarray(want, np.float32),
+                atol=atol,
+            )
+
+    def test_jax_path_is_grouped_softmax_over_the_prefix(self):
+        rng = np.random.default_rng(5)
+        n_s, t, h_kv, group, d = 2, 8, 2, 2, 4
+        q = jnp.asarray(rng.normal(size=(n_s, h_kv * group, d)), jnp.float32)
+        k_all = jnp.asarray(rng.normal(size=(2, n_s, t, h_kv, d)), jnp.float32)
+        v_all = jnp.asarray(rng.normal(size=(2, n_s, t, h_kv, d)), jnp.float32)
+        pos = jnp.asarray([3, 7], jnp.int32)
+        out = cache_decode_attention(q, k_all, v_all, jnp.int32(1), pos,
+                                     mode="jax")
+        for s in range(n_s):
+            n = int(pos[s]) + 1
+            for h in range(h_kv * group):
+                k = np.asarray(k_all[1, s, :n, h // group])
+                v = np.asarray(v_all[1, s, :n, h // group])
+                w = np.exp(k @ np.asarray(q[s, h]) * d ** -0.5)
+                np.testing.assert_allclose(
+                    np.asarray(out[s, h]), (w / w.sum()) @ v, atol=2e-5
+                )
 
 
 class TestRmsNorm:

@@ -23,9 +23,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tony_tpu.models import TransformerConfig, generate, init_params
+from tony_tpu.models import (
+    TransformerConfig,
+    decode_weights,
+    generate,
+    init_params,
+)
+from tony_tpu.models import decode as decode_lib
 from tony_tpu.observability.metrics import MetricsRegistry
 from tony_tpu.serving import ServingEngine, ServingQueueFull
+from tony_tpu.serving import engine as engine_lib
 from tony_tpu.serving.scheduler import _chunk_plan
 
 
@@ -158,6 +165,57 @@ class TestEngineParity:
         assert "tony_serving_active_slots" in snap["gauges"]
         assert "tony_serving_tokens_per_sec" in snap["gauges"]
 
+    def test_padded_batch_parked_reuse_and_full_row(self):
+        """The cache's edges in one run: a prefill batch padded with
+        duplicates of its row 0 (fewer pending prompts than
+        ``prefill_batch``), a slot taken again right after a retirement
+        while its decode lane is parked at ``Tmax - 1``, and a prompt of
+        ``Tmax - 1 - C`` tokens whose stream then fills its row to the
+        end. Tokens match the single-request reference and every
+        prompt's exported K/V rows match a plain whole-prompt forward."""
+        cfg, params = _tiny_setup()
+        t_max, chunk = 32, 4
+        rng = np.random.default_rng(11)
+        lens = (t_max - 1 - chunk, 5, 9, 6)
+        budgets = (5, 3, 6, 4)
+        prompts = [rng.integers(0, 64, n).astype(np.int32) for n in lens]
+        eng = ServingEngine(params, cfg, slots=2, max_len=t_max,
+                            prefill_chunk=chunk, prefill_batch=3)
+        reqs = [eng.submit(p, n) for p, n in zip(prompts, budgets)]
+        rows: dict[int, tuple] = {}
+        for _ in range(500):
+            if all(r.done() for r in reqs):
+                break
+            eng.step()
+            for i, r in enumerate(reqs):
+                if (i not in rows and r.t_first_token is not None
+                        and r in eng._slot_req):
+                    slot = eng._slot_req.index(r)
+                    rows[i] = tuple(
+                        np.asarray(engine_lib.cache_export_rows(c, slot,
+                                                                lens[i]))
+                        for c in (eng._k, eng._v)
+                    )
+        stats = eng.stats()
+        assert stats["prefill_rows_padded"] > 0
+        assert stats["retired"] == len(prompts) > eng.slots
+        fused = decode_weights(params, cfg)
+        for i, (p, n, r) in enumerate(zip(prompts, budgets, reqs)):
+            want = np.asarray(
+                generate(params, jnp.asarray(p)[None], cfg, n)
+            )[0]
+            np.testing.assert_array_equal(
+                np.asarray(r.result(1)["tokens"]), want
+            )
+            _, cache = decode_lib.advance(
+                fused, decode_lib.init_cache(cfg, 1, len(p)),
+                jnp.asarray(p)[None], cfg, prefill=True,
+            )
+            for got, ref in zip(rows[i], (cache["k"], cache["v"])):
+                np.testing.assert_allclose(
+                    got, np.asarray(ref)[:, 0], rtol=1e-5, atol=1e-5
+                )
+
     def test_moe_trunk_parity(self):
         cfg, params = _tiny_setup(n_experts=2)
         rng = np.random.default_rng(3)
@@ -221,6 +279,62 @@ class TestEngineParity:
         # Exactly two instrumented first-compiles: the prefill batch and
         # the decode window.
         assert totals() == before + 2
+
+
+def _cache_writes(jaxpr):
+    """(primitive, operand shape, update shape) of every
+    ``dynamic_update_slice`` / ``scatter*`` in ``jaxpr``, the bodies of
+    scan / while / cond / pjit included."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "dynamic_update_slice":
+            yield name, eqn.invars[0].aval.shape, eqn.invars[1].aval.shape
+        elif name.startswith("scatter"):
+            yield name, eqn.invars[0].aval.shape, eqn.invars[2].aval.shape
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _cache_writes(sub)
+
+
+class TestCacheWritesAreRows:
+    """Structure, not speed: no write into a cache buffer may carry an
+    update as large as one layer's slab — the programs append rows to
+    the stacked, donated buffer where they lie. (The slab path this
+    replaced copied [S, Tmax, Hkv, Dh] out of the buffer and back, per
+    buffer, per layer, per dispatch: over half the device time of both
+    serving cells.)"""
+
+    @pytest.mark.parametrize("kv_quant", ["none", "int8"])
+    @pytest.mark.parametrize("program", ["decode_window", "prefill_chunks"])
+    def test_no_write_is_slab_sized(self, program, kv_quant):
+        cfg, params = _tiny_setup()
+        fused = decode_weights(params, cfg)
+        slots, t_max, p, c = 3, 24, 2, 4
+        k, v = engine_lib.init_slot_cache(cfg, slots, t_max, kv_quant)
+        key = jax.random.key(0)
+        if program == "decode_window":
+            lane = jnp.zeros((slots,), jnp.int32)
+            jaxpr = jax.make_jaxpr(
+                lambda *a: engine_lib.decode_window(*a, cfg=cfg, steps=2)
+            )(fused, k, v, lane, lane, lane,
+              jnp.zeros((slots,), jnp.float32), key, jnp.int32(0))
+        else:
+            row = jnp.zeros((p,), jnp.int32)
+            jaxpr = jax.make_jaxpr(
+                lambda *a: engine_lib.prefill_chunks(*a, cfg=cfg)
+            )(fused, k, v, jnp.zeros((p, c), jnp.int32), row, row, row + c,
+              jnp.zeros((p,), jnp.float32), key, jnp.int32(0))
+        rows = max(slots, p * c) * cfg.kv_heads * cfg.head_dim
+        slab = slots * t_max * cfg.kv_heads
+        writes = [
+            w for w in _cache_writes(jaxpr.jaxpr)
+            if int(np.prod(w[1])) >= slab  # a layer's slab or the buffer
+        ]
+        assert writes, "the program no longer writes its cache"
+        too_big = [w for w in writes if int(np.prod(w[2])) > rows]
+        assert not too_big, f"slab-sized cache writes: {too_big}"
 
 
 class TestServingHTTP:

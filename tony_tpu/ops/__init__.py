@@ -8,12 +8,19 @@ and a numerically identical blockwise-JAX path elsewhere (which is also the
 recompute used for the backward pass).
 """
 
-from tony_tpu.ops.attention import flash_attention, flash_attention_lse
+from tony_tpu.ops.attention import (
+    cache_decode_attention,
+    flash_attention,
+    flash_attention_lse,
+    grouped_cache_attention,
+)
 from tony_tpu.ops.norms import rms_norm
 from tony_tpu.ops.rope import apply_rope, rope_frequencies
 from tony_tpu.ops.losses import softmax_cross_entropy
 
 __all__ = [
+    "cache_decode_attention",
+    "grouped_cache_attention",
     "flash_attention",
     "flash_attention_lse",
     "rms_norm",

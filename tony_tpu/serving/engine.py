@@ -9,24 +9,34 @@ orchestration is the tax — not re-measured on the current chip). This module i
 executables total, compiled once per engine lifetime, shared by every
 request that ever passes through —
 
-* ``decode_step`` — ONE token for ALL slots. The slot batch is a fixed
-  [S] lane array; each slot owns a row of the stacked KV cache
-  [L, S, Tmax, Hkv, Dh], its own position, and its own sampling
-  temperature, so requests of different lengths share every decode
-  iteration (Orca-style iteration-level scheduling). Per-slot cache
-  writes are a vmapped ``dynamic_update_slice`` at each slot's own
-  offset; attention masks per row with ``key_index <= pos[slot]``.
-* ``prefill_chunk`` — a bounded chunk of ONE request's prompt into its
-  slot's cache row. Chunking bounds how long a new prompt can stall the
-  in-flight decode streams: the host interleaves one chunk per engine
-  iteration, so time-to-first-token for the new request trades off
-  against inter-token latency for everyone else at a fixed, configured
-  granularity (``tony.serving.prefill-chunk``).
+* ``decode_window`` — ``steps`` tokens for ALL slots in one dispatch.
+  The slot batch is a fixed [S] lane array; each slot owns a row of the
+  stacked KV cache [L, S, Tmax, Hkv, Dh], its own position, and its own
+  sampling temperature, so requests of different lengths share every
+  decode iteration (Orca-style iteration-level scheduling). Each layer
+  scatters the slots' new K/V rows into the stacked buffer at
+  (layer, slot, wpos[slot]) and attention reads that layer where it
+  lies (``ops.cache_decode_attention``), masked per slot with
+  ``key_index <= pos[slot]``.
+* ``prefill_chunks`` — one bounded chunk of the prompt of EACH of up to
+  P pending slots into those slots' cache rows. Chunking bounds how long
+  a new prompt can stall the in-flight decode streams: the host
+  interleaves one round per engine iteration, so time-to-first-token for
+  the new requests trades off against inter-token latency for everyone
+  else at a fixed, configured granularity
+  (``tony.serving.prefill-chunk``). Each layer writes the P chunks by
+  ``dynamic_update_slice`` at (layer, slot, start) and attends over
+  those P slots' rows only.
 
 Both run over the fused ``decode_weights`` layout (weights fuse once per
 engine, exactly like ``DecodeSession``) and carry the stacked caches as
 scan CARRY (the xs/ys re-stack cost decode.py's docstring documents).
-KV buffers are donated, so the two big cache arrays update in place.
+KV buffers are donated and no program ever holds a layer's slab apart
+from them: per dispatch the cache takes S · Hkv · Dh elements per layer
+per buffer in decode and P · C · Hkv · Dh in prefill (2 MB and 8 MB
+over 16 layers of 8 × 128 bf16 heads at 32 slots, 4 × 32-token chunks),
+and gives one pass over the layer's S · Tmax rows in decode (4.3 GB),
+over P · Tmax in prefill (0.5 GB).
 
 Overwrite-before-read invariant: slot reuse never zeroes a cache row.
 A freed slot's stale K/V rows are only ever unmasked after the new
@@ -44,9 +54,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tony_tpu.models.decode import NEG_INF, _moe_mlp_decode
+from tony_tpu.models.decode import _moe_mlp_decode
 from tony_tpu.models.transformer import TransformerConfig
-from tony_tpu.ops import apply_rope, rms_norm, rope_frequencies
+from tony_tpu.ops import (
+    apply_rope,
+    cache_decode_attention,
+    grouped_cache_attention,
+    rms_norm,
+    rope_frequencies,
+)
 
 
 class QuantizedKV(NamedTuple):
@@ -90,63 +106,69 @@ def _cache_tmax(cache) -> int:
     return (cache.data if isinstance(cache, QuantizedKV) else cache).shape[2]
 
 
-def _cache_layer(cache, layer):
-    """One layer's rows [S, Tmax, Hkv, Dh] out of the stacked buffer."""
+# The cache interface of decode_window / prefill_chunks: rows are written
+# into the stacked [L, S, Tmax, Hkv, ·] buffer where they lie, and a
+# layer is read out of that same buffer. ``jax.tree.map`` over the cache
+# runs each plane of a QuantizedKV and a plain array through one path.
+
+
+def _encode(cache, x):
+    """``x`` in the cache's storage form (the cache's own pytree)."""
     if isinstance(cache, QuantizedKV):
-        return QuantizedKV(
-            lax.dynamic_index_in_dim(cache.data, layer, 0, keepdims=False),
-            lax.dynamic_index_in_dim(cache.scale, layer, 0, keepdims=False),
-        )
-    return lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
+        return _quantize(x)
+    return x.astype(cache.dtype)
 
 
-def _cache_store_layer(cache, layer_cache, layer):
+def _write_rows(cache, layer, rows, wpos):
+    """Decode's one-token append: ``rows`` [S, Hkv, Dh] land at
+    (layer, slot, wpos[slot]) by one scatter per plane. The indices are
+    sorted and unique (every slot is its own row of the buffer) and the
+    host keeps ``wpos`` inside [0, Tmax)."""
+    slot = jnp.arange(wpos.shape[0])
+    return jax.tree.map(
+        lambda buf, val: buf.at[layer, slot, wpos].set(
+            val, indices_are_sorted=True, unique_indices=True,
+            mode="promise_in_bounds",
+        ),
+        cache, _encode(cache, rows),
+    )
+
+
+def _write_chunk(cache, layer, slot, start, chunk):
+    """One prefill chunk [C, Hkv, Dh] at (layer, slot, start)."""
+    return jax.tree.map(
+        lambda buf, val: lax.dynamic_update_slice(
+            buf, val[None, None], (layer, slot, start, 0, 0)
+        ),
+        cache, _encode(cache, chunk),
+    )
+
+
+def _layer_view(cache, layer, dt):
+    """(stack, index) under which decode attention reads one layer in
+    compute dtype: a plain cache is the stack, read where it lies; an
+    int8 cache dequantizes the layer into a one-layer stack."""
     if isinstance(cache, QuantizedKV):
-        return QuantizedKV(
-            lax.dynamic_update_slice(
-                cache.data, layer_cache.data[None], (layer, 0, 0, 0, 0)
-            ),
-            lax.dynamic_update_slice(
-                cache.scale, layer_cache.scale[None], (layer, 0, 0, 0, 0)
-            ),
+        one = jax.tree.map(
+            lambda buf: lax.dynamic_slice_in_dim(buf, layer, 1, 0), cache
         )
-    return lax.dynamic_update_slice(
-        cache, layer_cache[None], (layer, 0, 0, 0, 0)
-    )
+        return _materialize(one, dt), jnp.int32(0)
+    return cache, layer
 
 
-def _cache_gather(layer_cache, slots):
-    if isinstance(layer_cache, QuantizedKV):
-        return QuantizedKV(layer_cache.data[slots], layer_cache.scale[slots])
-    return layer_cache[slots]
+def _read_slots(cache, layer, slots, dt):
+    """The rows [P, Tmax, Hkv, Dh] of ``slots`` [P] in one layer, in
+    compute dtype: P small dynamic slices (on the TPU a gather over the
+    stacked buffer lowers to slices of the WHOLE buffer)."""
+    def take(buf):
+        return jnp.concatenate([
+            lax.dynamic_slice(
+                buf, (layer, slots[i], 0, 0, 0), (1, 1) + buf.shape[2:]
+            )[0]
+            for i in range(slots.shape[0])
+        ])
 
-
-def _write_rows(layer_cache, new, wpos):
-    """Per-slot vmapped write of ``new`` [S, 1, Hkv, Dh] at each slot's
-    own offset (decode's one-token append)."""
-    write = jax.vmap(
-        lambda row, val, p: lax.dynamic_update_slice(row, val, (p, 0, 0))
-    )
-    if isinstance(layer_cache, QuantizedKV):
-        q = _quantize(new)
-        return QuantizedKV(
-            write(layer_cache.data, q.data, wpos),
-            write(layer_cache.scale, q.scale, wpos),
-        )
-    return write(layer_cache, new.astype(layer_cache.dtype), wpos)
-
-
-def _write_chunk(layer_cache, chunk, at):
-    """One prefill chunk [1, C, Hkv, Dh] at (slot, start, 0, 0)."""
-    if isinstance(layer_cache, QuantizedKV):
-        q = _quantize(chunk)
-        return QuantizedKV(
-            lax.dynamic_update_slice(layer_cache.data, q.data, at),
-            lax.dynamic_update_slice(layer_cache.scale, q.scale, at),
-        )
-    return lax.dynamic_update_slice(
-        layer_cache, chunk.astype(layer_cache.dtype), at
-    )
+    return _materialize(jax.tree.map(take, cache), dt)
 
 
 def init_slot_cache(
@@ -154,11 +176,12 @@ def init_slot_cache(
     kv_quant: str = "none",
 ):
     """Zeroed stacked KV cache pair [L, S, Tmax, Hkv, Dh] — one row per
-    slot, sized once for the engine's lifetime. Serving HBM budget is
-    2 · L · S · Tmax · Hkv · Dh · dtype bytes (``kv_quant="int8"``:
-    1 + 4/Dh bytes per element instead of the compute dtype's 2); see
-    docs/DEPLOY.md "Serving" for the sizing table and "Autotuning" for
-    the quantization contract."""
+    slot, sized once for the engine's lifetime, donated to every dispatch
+    and rewritten in place a few rows at a time (module docstring).
+    Serving HBM budget is 2 · L · S · Tmax · Hkv · Dh · dtype bytes
+    (``kv_quant="int8"``: 1 + 4/Dh bytes per element instead of the
+    compute dtype's 2); see docs/DEPLOY.md "Serving" for the sizing table
+    and "Autotuning" for the quantization contract."""
     shape = (cfg.n_layers, slots, max_len, cfg.kv_heads, cfg.head_dim)
     if kv_quant == "int8":
         def one():
@@ -218,29 +241,6 @@ def _mlp(x, lp, cfg):
         * gu[..., f:]
     )
     return x + jnp.einsum("btf,fd->btd", act, lp["w_down"])
-
-
-def _attend_cache(q, k_cache, v_cache, mask, cfg):
-    """Grouped attention against cache rows — q regrouped
-    [B, S, Hkv, G, Dh] so GQA never head-repeats the cache, stored-dtype
-    reads with fp32 MXU accumulation and fp32 softmax (the decode.py
-    recipe). mask: [B, S_q, T] True where the key is visible."""
-    dt = cfg.compute_dtype
-    b, s, n_h, _ = q.shape
-    h_kv = k_cache.shape[2]
-    g = n_h // h_kv
-    scale = cfg.head_dim ** -0.5
-    qg = q.reshape(b, s, h_kv, g, cfg.head_dim)
-    scores = jnp.einsum(
-        "bqhgd,bkhd->bhgqk", qg, k_cache,
-        preferred_element_type=jnp.float32,
-    ) * scale
-    scores = jnp.where(mask[:, None, None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum(
-        "bhgqk,bkhd->bqhgd", probs.astype(dt), v_cache,
-        preferred_element_type=jnp.float32,
-    ).astype(dt).reshape(b, s, n_h, cfg.head_dim)
 
 
 def _sample_slots(logits, temp, key):
@@ -313,7 +313,6 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
         # run past the table mid-window — clamp the RoPE gather (their
         # output is discarded; the mask itself cannot overflow).
         rp = jnp.minimum(pos, cfg.max_seq - 1)[:, None]
-        mask = jnp.arange(t_max)[None, :] <= pos[:, None]   # [S, T]
 
         def body(carry, layer_in):
             x, k_all, v_all = carry
@@ -325,17 +324,14 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
             v_new = qkv[:, :, n_h + h_kv:]
             q = apply_rope(q, cos, sin, positions=rp)
             k_new = apply_rope(k_new, cos, sin, positions=rp)
-            k_layer = _cache_layer(k_all, layer)
-            v_layer = _cache_layer(v_all, layer)
-            k_layer = _write_rows(k_layer, k_new, wpos)
-            v_layer = _write_rows(v_layer, v_new, wpos)
-            k_all = _cache_store_layer(k_all, k_layer, layer)
-            v_all = _cache_store_layer(v_all, v_layer, layer)
-            o = _attend_cache(
-                q, _materialize(k_layer, dt), _materialize(v_layer, dt),
-                mask[:, None, :], cfg,
-            )
-            x = x + jnp.einsum("bthk,hkd->btd", o.astype(dt), lp["wo"])
+            k_all = _write_rows(k_all, layer, k_new[:, 0], wpos)
+            v_all = _write_rows(v_all, layer, v_new[:, 0], wpos)
+            k_stack, at = _layer_view(k_all, layer, dt)
+            v_stack, _ = _layer_view(v_all, layer, dt)
+            o = cache_decode_attention(
+                q[:, 0], k_stack, v_stack, at, pos
+            )[:, None]
+            x = x + jnp.einsum("bthk,hkd->btd", o, lp["wo"])
             x = _mlp(x, lp, cfg)
             return (x, k_all, v_all), None
 
@@ -409,27 +405,20 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
         v_new = qkv[:, :, n_h + h_kv:]
         q = apply_rope(q, cos, sin, positions=rope_pos)
         k_new = apply_rope(k_new, cos, sin, positions=rope_pos)
-        k_layer = _cache_layer(k_all, layer)
-        v_layer = _cache_layer(v_all, layer)
 
         def write_one(i, kv):
-            k_l, v_l = kv
-            kc = lax.dynamic_index_in_dim(k_new, i, 0)   # [1, C, Hkv, Dh]
-            vc = lax.dynamic_index_in_dim(v_new, i, 0)
-            at = (slots[i], starts[i], 0, 0)
-            return _write_chunk(k_l, kc, at), _write_chunk(v_l, vc, at)
+            at = (layer, slots[i], starts[i])
+            return (_write_chunk(kv[0], *at, k_new[i]),
+                    _write_chunk(kv[1], *at, v_new[i]))
 
         # Sequential writes, not a vmap-scatter: P is small and
         # duplicate (padding) rows must overwrite cleanly in order.
-        k_layer, v_layer = lax.fori_loop(0, p, write_one,
-                                         (k_layer, v_layer))
-        k_all = _cache_store_layer(k_all, k_layer, layer)
-        v_all = _cache_store_layer(v_all, v_layer, layer)
-        o = _attend_cache(
-            q, _materialize(_cache_gather(k_layer, slots), dt),
-            _materialize(_cache_gather(v_layer, slots), dt), mask, cfg,
+        k_all, v_all = lax.fori_loop(0, p, write_one, (k_all, v_all))
+        o = grouped_cache_attention(
+            q, _read_slots(k_all, layer, slots, dt),
+            _read_slots(v_all, layer, slots, dt), mask,
         )
-        x = x + jnp.einsum("bthk,hkd->btd", o.astype(dt), lp["wo"])
+        x = x + jnp.einsum("bthk,hkd->btd", o, lp["wo"])
         x = _mlp(x, lp, cfg)
         return (x, k_all, v_all), None
 
