@@ -21,7 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from yardstick import weights
+from yardstick import spec, weights
 from yardstick.precision import OPERAND
 
 
@@ -71,15 +71,15 @@ def decoder_layer(x, p, cfg, op):
 @functools.partial(jax.jit, static_argnames=("cfg_key", "dtype", "lowp"))
 def _layer_step(x, key, layer, cfg_key, dtype, lowp):
     cfg = dict(cfg_key)
-    p = jax.tree.map(lambda w: w.astype(jnp.float32),
-                     weights.layer_tree(key, cfg, layer, jnp.dtype(dtype)))
+    p = jax.tree.map(lambda w: w.astype(jnp.float32), weights.layer_tree(
+        key, leaf_table(cfg), layer, jnp.dtype(dtype), like=0))
     return decoder_layer(x, p, cfg, OPERAND[lowp])
 
 
 @functools.partial(jax.jit, static_argnames=("cfg_key", "dtype"))
 def _embed(tokens, key, cfg_key, dtype):
     cfg = dict(cfg_key)
-    e = weights.leaf(key, cfg, "embed", 0, jnp.dtype(dtype))
+    e = weights.leaf(key, leaf_table(cfg), "embed", 0, jnp.dtype(dtype))
     return e[tokens].astype(jnp.float32)
 
 
@@ -87,7 +87,7 @@ def _embed(tokens, key, cfg_key, dtype):
 def _head(x, key, cfg_key, dtype, lowp):
     cfg = dict(cfg_key)
     op = OPERAND[lowp]
-    top = weights.top_tree(key, cfg, jnp.dtype(dtype))
+    top = weights.top_tree(key, leaf_table(cfg), jnp.dtype(dtype))
     x = rms_norm(x, top["final_norm"].astype(jnp.float32),
                  cfg["rms_norm_eps"])
     return jnp.einsum("btd,dv->btv", op(x),
@@ -96,10 +96,15 @@ def _head(x, key, cfg_key, dtype, lowp):
 
 def model_key(cfg: dict) -> tuple:
     """The sizes the forward pass needs, hashable for jit."""
-    names = ("hidden_size", "intermediate_size", "num_attention_heads",
-             "num_key_value_heads", "head_dim", "vocab_size",
-             "num_hidden_layers", "rms_norm_eps", "rope_theta")
+    names = ("model", "hidden_size", "intermediate_size",
+             "num_attention_heads", "num_key_value_heads", "head_dim",
+             "vocab_size", "num_hidden_layers", "rms_norm_eps", "rope_theta")
     return tuple((n, cfg[n]) for n in names)
+
+
+def leaf_table(cfg: dict) -> dict:
+    """The leaves as the configuration's model module states them."""
+    return spec.load_model(cfg["model"]).leaf_table(cfg)
 
 
 def logits(cfg: dict, seed: int, tokens, *, dtype: str = "bfloat16",

@@ -20,7 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from yardstick import weights
+from yardstick import spec, weights
 from yardstick.precision import OPERAND
 
 B1, B2, EPS = 0.9, 0.999, 1e-8
@@ -86,10 +86,15 @@ def loss_fn(params, tokens, cfg, op):
 
 
 def model_key(cfg: dict) -> tuple:
-    names = ("hidden_size", "intermediate_size", "num_attention_heads",
-             "num_key_value_heads", "head_dim", "vocab_size",
-             "num_hidden_layers", "rms_norm_eps", "rope_theta")
+    names = ("model", "hidden_size", "intermediate_size",
+             "num_attention_heads", "num_key_value_heads", "head_dim",
+             "vocab_size", "num_hidden_layers", "rms_norm_eps", "rope_theta")
     return tuple((n, cfg[n]) for n in names)
+
+
+def leaf_table(cfg: dict) -> dict:
+    """The leaves as the configuration's model module states them."""
+    return spec.load_model(cfg["model"]).leaf_table(cfg)
 
 
 def _leaves(tree: dict) -> dict:
@@ -107,8 +112,9 @@ def _norms(tree: dict) -> dict:
 @functools.partial(jax.jit, static_argnames=("cfg_key",))
 def init_params(key, cfg_key):
     cfg = dict(cfg_key)
-    params = weights.top_tree(key, cfg, jnp.float32)
-    params["layers"] = weights.stacked_layers(key, cfg, jnp.float32)
+    table = leaf_table(cfg)
+    params = weights.top_tree(key, table, jnp.float32)
+    params["layers"] = weights.stacked_layers(key, table, jnp.float32)
     return params
 
 

@@ -6,11 +6,16 @@ address atomically and serves until ``POST /shutdown``. The harness drives
 it over HTTP. After shutdown it frees the engine and runs the plain
 reference over the sample of served requests the harness left for it.
 
+What the model is, the configuration's model module says
+(``models/<model>.py``): the program's model configuration and the seeded
+weights in the program's tree come from it, and this file reads no size
+of the model.
+
 Names of the program this file depends on: ``rt.initialize``,
-``rt.build_job_mesh``, ``TransformerConfig``, ``decode_weights`` (the
-fused serving layout), ``ServingEngine`` (constructor, ``start``,
-``step``, ``submit``, ``stats``, ``close``), ``ServingServer`` (``start``,
-``wait_shutdown``, ``stop``), and the executor's ``TB_PORT`` and
+``rt.build_job_mesh``, ``decode_weights`` (the fused serving layout),
+``ServingEngine`` (constructor, ``start``, ``step``, ``submit``,
+``stats``, ``close``), ``ServingServer`` (``start``, ``wait_shutdown``,
+``stop``), and the executor's ``TB_PORT`` and
 ``TONY_SERVING_PREFILL_CHUNK`` / ``TONY_SERVING_DECODE_WINDOW``."""
 
 from __future__ import annotations
@@ -22,9 +27,10 @@ import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from yardstick import spec  # noqa: E402
 from yardstick.jobside import (CompileLog, Tracer, Work, device_report,  # noqa: E402
                            memory_peak_bytes, program_compile_s)
-from _shared import load_reference, program_params  # noqa: E402
+from _shared import load_reference  # noqa: E402
 
 
 def main() -> int:
@@ -33,13 +39,14 @@ def main() -> int:
     p = work.params
     cfg, run = p["config"], p["config"]["run"]
     seed = int(p["seed"])
+    model = spec.load_model(cfg["model"])
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     import tony_tpu.runtime as rt
-    from tony_tpu.models import TransformerConfig, decode_weights
+    from tony_tpu.models import decode_weights
     from tony_tpu.serving import ServingEngine
     from tony_tpu.serving.http import ServingServer
 
@@ -54,18 +61,13 @@ def main() -> int:
     print(f"bench serve job: mesh {dict(mesh.shape)} {rt.describe_devices()}",
           flush=True)
 
-    tcfg = TransformerConfig(
-        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-        n_layers=cfg["num_hidden_layers"], n_heads=cfg["num_attention_heads"],
-        head_dim=cfg["head_dim"], d_ff=cfg["intermediate_size"],
-        max_seq=int(run["max_seq"]), rope_theta=float(cfg["rope_theta"]),
-        n_kv_heads=cfg["num_key_value_heads"], dtype=run["weights_dtype"],
-    )
+    tcfg = model.program_config(cfg, run, max_seq=int(run["max_seq"]),
+                                dtype=run["weights_dtype"])
     # One jitted call from the seed, in the served dtype, straight into
     # the engine's fused layout: no float32 copy of the model ever exists.
     dtype = jnp.dtype(run["weights_dtype"])
     fused = jax.jit(lambda k: decode_weights(
-        program_params(k, cfg, dtype), tcfg))(weights.seed_key(seed))
+        model.program_params(k, cfg, dtype), tcfg))(weights.seed_key(seed))
     jax.block_until_ready(fused)
     work.stage("weights_on_device")
 
@@ -154,12 +156,13 @@ def main() -> int:
     }
     work.publish("window.json", result)
 
-    # Free the program's state, then the reference over the sample.
-    engine.params = None
-    engine._resident = None
-    engine._k = engine._v = None
+    # Free what the job holds on the device, then the reference over the
+    # sample. The engine is closed and nothing of it is used again, so
+    # every array still alive goes, whatever name the engine keeps it under.
     del engine, server
     gc.collect()
+    for array in jax.live_arrays():
+        array.delete()
     check = work.read("check.json")
     if check:
         reference = load_reference(p["config_path"])

@@ -6,10 +6,15 @@ batches, and hands that same object to the timed window. Everything it
 reads comes from the parameters file the harness wrote; everything it
 reports goes to the run's work directory.
 
+What the model is, the configuration's model module says
+(``models/<model>.py``): the program's model configuration, the seeded
+weights in the program's tree and the leaves' norms under the benchmark's
+names come from it, and this file reads no size of the model.
+
 Names of the program this file depends on: ``rt.initialize``,
 ``rt.build_job_mesh``, ``rt.sharded_reader(fmt="tokens")``,
-``TransformerConfig``, ``make_train_step`` (its ``TrainState`` with
-``params`` / ``opt_state``, the adam state's ``mu``), ``MeshSpec``."""
+``make_train_step`` (its ``TrainState`` with ``params`` / ``opt_state``,
+the adam state's ``mu``), ``MeshSpec``."""
 
 from __future__ import annotations
 
@@ -20,7 +25,8 @@ import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-from _shared import leaf_norms, load_reference, program_params  # noqa: E402
+from _shared import load_reference  # noqa: E402
+from yardstick import spec  # noqa: E402
 from yardstick.jobside import (CompileLog, Tracer, Work, device_report,  # noqa: E402
                                memory_peak_bytes, program_compile_s)
 
@@ -44,13 +50,15 @@ def main() -> int:
     p = work.params
     cfg, run, traffic = p["config"], p["config"]["run"], p["traffic"]
     seed, seconds = int(p["seed"]), float(p["seconds"])
+    model = spec.load_model(cfg["model"])
+    program_params, leaf_norms = model.program_params, model.leaf_norms
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     import tony_tpu.runtime as rt
-    from tony_tpu.models import TransformerConfig, make_train_step
+    from tony_tpu.models import make_train_step
     from tony_tpu.parallel.mesh import MeshSpec
 
     from yardstick import compare, traffic as traffic_gen, weights
@@ -70,14 +78,9 @@ def main() -> int:
     records.tofile(corpus)
     work.stage("corpus_written")
 
-    tcfg = TransformerConfig(
-        vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-        n_layers=cfg["num_hidden_layers"], n_heads=cfg["num_attention_heads"],
-        head_dim=cfg["head_dim"], d_ff=cfg["intermediate_size"], max_seq=seq,
-        rope_theta=float(cfg["rope_theta"]),
-        n_kv_heads=cfg["num_key_value_heads"], dtype=run["compute_dtype"],
-        remat=True, remat_policy=run["remat"],
-    )
+    tcfg = model.program_config(cfg, run, max_seq=seq,
+                                dtype=run["compute_dtype"], remat=True,
+                                remat_policy=run["remat"])
     hp = run["optimizer"]
     init_fn, step_fn = make_train_step(
         tcfg, mesh, learning_rate=hp["learning_rate"],

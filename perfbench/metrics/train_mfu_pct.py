@@ -1,6 +1,6 @@
-"""The benchmark's own FLOPs a token x tokens/s/chip over the chip's bf16
-peak. Recomputed operations are not counted."""
-from yardstick import counts
+"""The FLOPs a trained token needs, by the cell's model module, x
+tokens/s/chip over the chip's bf16 peak. Recomputed operations are not
+counted."""
 from yardstick.readers import peaks_of, train_tokens_per_s_per_chip
 
 
@@ -8,5 +8,6 @@ def read(run):
     rate = train_tokens_per_s_per_chip(run)
     if rate <= 0:
         return None
-    flops = counts.train_flops_per_token(run["config"], run["job"]["seq"])
+    flops = run["cell"].model.train_flops_per_token(run["config"],
+                                                    run["job"]["seq"])
     return 100.0 * flops * rate / peaks_of(run)["flops_bf16"]

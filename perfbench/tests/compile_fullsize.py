@@ -7,7 +7,6 @@ TPU compiler's own memory count. Nothing runs; no time comes from this.
 
 from __future__ import annotations
 
-import importlib.util
 import json
 import os
 import sys
@@ -24,6 +23,8 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
+from yardstick import spec  # noqa: E402
+
 GB = 1e9
 
 
@@ -36,13 +37,6 @@ def report(name, compiled):
           f"{m.temp_size_in_bytes / GB:.2f}, alias "
           f"{m.alias_size_in_bytes / GB:.2f} -> {total / GB:.2f} GB",
           flush=True)
-
-
-def load(path):
-    spec = importlib.util.spec_from_file_location("ref", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def main():
@@ -69,22 +63,18 @@ def main():
         if len(sys.argv) > 2:
             cfg["num_hidden_layers"] = int(sys.argv[2])
     run = cfg["run"]
+    model = spec.load_model(cfg["model"])
 
     if what == "train":
         from jax.sharding import Mesh
         import numpy as np
-        from tony_tpu.models import TransformerConfig, make_train_step
+        from tony_tpu.models import make_train_step
         from tony_tpu.parallel.mesh import AXES
-        import _shared as train_job
 
         mesh = Mesh(np.array([dev]).reshape((1,) * len(AXES)), AXES)
-        tcfg = TransformerConfig(
-            vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-            n_layers=cfg["num_hidden_layers"],
-            n_heads=cfg["num_attention_heads"], head_dim=cfg["head_dim"],
-            d_ff=cfg["intermediate_size"], max_seq=seq,
-            n_kv_heads=cfg["num_key_value_heads"], dtype=run["compute_dtype"],
-            remat=True, remat_policy=run["remat"])
+        tcfg = model.program_config(cfg, run, max_seq=seq,
+                                    dtype=run["compute_dtype"], remat=True,
+                                    remat_policy=run["remat"])
         hp = run["optimizer"]
         init_fn, step_fn = make_train_step(
             tcfg, mesh, learning_rate=hp["learning_rate"],
@@ -93,14 +83,15 @@ def main():
         state = on_chip(state)
         tokens = sds((batch, seq + 1), jnp.int32)
         report("train step", step_fn.lower(state, tokens).compile())
-        gen = jax.jit(lambda k: train_job.program_params(k, cfg, jnp.float32))
+        gen = jax.jit(lambda k: model.program_params(k, cfg, jnp.float32))
         report("seeded weights", gen.lower(key_sds).compile())
-        change = jax.jit(lambda p, k: train_job.leaf_norms(jax.tree.map(
+        change = jax.jit(lambda p, k: model.leaf_norms(jax.tree.map(
             lambda a, b: a - b, p,
-            train_job.program_params(k, cfg, jnp.float32))))
+            model.program_params(k, cfg, jnp.float32))))
         report("change norms", change.lower(state.params, key_sds).compile())
     elif what == "train-ref":
-        ref = load(ROOT / "configs/mistral7b-train-1chip.reference.py")
+        ref = spec.load_module(
+            ROOT / "configs/mistral7b-train-1chip.reference.py", "ref")
         ck = ref.model_key(cfg)
         params = on_chip(jax.eval_shape(
             lambda k: ref.init_params.__wrapped__(k, ck), jax.random.key(0)))
@@ -114,23 +105,18 @@ def main():
         report("reference change norms",
                ref.change_norms.lower(params, key_sds, ck).compile())
     elif what == "serve":
-        from tony_tpu.models import TransformerConfig, decode_weights
+        from tony_tpu.models import decode_weights
         from tony_tpu.serving import engine as eng
-        import _shared as train_job
 
-        tcfg = TransformerConfig(
-            vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-            n_layers=cfg["num_hidden_layers"],
-            n_heads=cfg["num_attention_heads"], head_dim=cfg["head_dim"],
-            d_ff=cfg["intermediate_size"], max_seq=run["max_seq"],
-            n_kv_heads=cfg["num_key_value_heads"], dtype=run["weights_dtype"])
+        tcfg = model.program_config(cfg, run, max_seq=run["max_seq"],
+                                    dtype=run["weights_dtype"])
         gen = jax.jit(lambda k: decode_weights(
-            train_job.program_params(k, cfg, jnp.bfloat16), tcfg))
+            model.program_params(k, cfg, jnp.bfloat16), tcfg))
         report("seeded fused weights", gen.lower(key_sds).compile())
         fused = on_chip(jax.eval_shape(gen, jax.random.key(0)))
         S, T = run["slots"], run["max_seq"]
-        kv = sds((cfg["num_hidden_layers"], S, T,
-                  cfg["num_key_value_heads"], cfg["head_dim"]), jnp.bfloat16)
+        kv = sds((tcfg.n_layers, S, T, tcfg.kv_heads, tcfg.head_dim),
+                 jnp.bfloat16)
         i32 = lambda *s: sds(s, jnp.int32)  # noqa: E731
         report("decode_window", eng.decode_window.lower(
             fused, kv, kv, i32(S), i32(S), i32(S), sds((S,), jnp.float32),
@@ -139,7 +125,8 @@ def main():
             fused, kv, kv, i32(4, 32), i32(4), i32(4), i32(4),
             sds((4,), jnp.float32), key_sds, i32(), cfg=tcfg).compile())
     elif what == "serve-ref":
-        ref = load(ROOT / "configs/mistral7b-serve-1chip.reference.py")
+        ref = spec.load_module(
+            ROOT / "configs/mistral7b-serve-1chip.reference.py", "ref")
         ck = ref.model_key(cfg)
         x = sds((4, 1408, cfg["hidden_size"]), jnp.float32)
         for lowp in ("float32", "fp8"):

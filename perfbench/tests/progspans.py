@@ -201,7 +201,8 @@ def analyse(kept: Path, kept_traces: Path, result: dict) -> dict:
               if s[0] == xplane.TRACED_SPAN]
     by_program_span = xplane.reduce(dict(
         extracted, host_spans=window + [s[:3] for s in profiled["spans"]]))
-    by_bench_span = xplane.reduce(extracted)
+    by_bench_span = xplane.reduce(dict(extracted, host_spans=[
+        s for s in extracted["host_spans"] if s[0].startswith("bench:")]))
     ring = ring_spans(kept_traces)
     return {
         "correct": result["correct"], "failed": result["failed"],

@@ -18,6 +18,11 @@ import tinyrepo
     ("tiny.serve", {"fault": "truncate_answer"}, "requests_failed"),
     ("tiny.train", {"control": "fp8"}, "grad_norm_gap"),
     ("tiny.serve", {"control": "fp8"}, "widest_gap"),
+    # another architecture, added as files: the toy mixture of experts
+    ("toymoe.train", {"fault": "half_batch"}, "loss1_rel"),
+    ("toymoe.serve", {"fault": "alter_token"}, "widest_gap"),
+    ("toymoe.train", {"control": "fp8"}, "grad_norm_gap"),
+    ("toymoe.serve", {"control": "fp8"}, "widest_gap"),
 ])
 def test_a_run_with_the_timed_path_broken_or_the_control_in_its_place_is_not_correct(
         tmp_path, workload, broken, number):
@@ -37,6 +42,27 @@ def test_a_run_with_the_timed_path_broken_or_the_control_in_its_place_is_not_cor
         missed = [k for k, v in result["compared"].items() if not v["ok"]]
         assert number in missed and "requests_failed" not in missed
         assert "steps_failed" not in missed
+
+
+@pytest.mark.parametrize("workload,numbers", [
+    ("toymoe.train", ("loss1_rel", "grad_norm_gap", "change_norm_gap")),
+    ("toymoe.serve", ("widest_gap",)),
+])
+def test_a_sound_run_of_an_added_architecture_is_correct(tmp_path, workload,
+                                                         numbers):
+    """The toy mixture of experts through the whole command: the program's
+    expert trunk against a plain reference with no capacity."""
+    import run as harness
+
+    repo = tinyrepo.make(tmp_path / "repo")
+    result = harness.run_cell(repo, workload, 2**31 + 5, 2.0, False,
+                              require_tpu=False)["result"]
+    assert result["correct"] is True and result["failed"] == 0
+    for name in numbers:
+        assert result["compared"][name]["ok"] is True
+    if workload == "toymoe.train":    # the router is a leaf like any other
+        job_numbers = result["compared"]
+        assert job_numbers["grad_norm_gap"]["value"] < 1e-3
 
 
 def test_a_sound_run_at_toy_size_is_correct_and_needs_a_tpu(tmp_path):

@@ -12,6 +12,8 @@ import pytest
 from yardstick import compare, counts, spec, stats, traffic, xplane
 
 DATA = Path(__file__).resolve().parent / "data"
+dense = spec.load_model("dense")
+toymoe = spec.load_module(DATA / "moe" / "models" / "toymoe.py", "toymoe")
 MISTRAL = dict(hidden_size=4096, intermediate_size=14336,
                num_attention_heads=32, num_key_value_heads=8, head_dim=128,
                vocab_size=32000, num_hidden_layers=2)
@@ -50,6 +52,52 @@ def test_idle_gaps_go_to_the_innermost_host_span_open_at_the_time():
     assert gaps["bench:outer"] == pytest.approx(0.0005)
     assert gaps["bench:inner"] == pytest.approx(0.0005)
     assert gaps[xplane.NO_SPAN] == pytest.approx(0.0005)
+
+
+def test_idle_gaps_are_named_after_the_programs_own_spans_too():
+    """A ``tony:`` span inside a ``bench:`` one is the innermost and takes
+    the gap; a span of neither prefix is not extracted at all."""
+    assert xplane.HOST_SPAN_PREFIX == ("bench:", "tony:")
+    assert "tony:engine.step".startswith(xplane.HOST_SPAN_PREFIX)
+    assert not "other:span".startswith(xplane.HOST_SPAN_PREFIX)
+    trace = json.loads((DATA / "synthetic_trace.json").read_text())
+    trace["host_spans"].append(["tony:engine.decode_device", 5200000, 500000])
+    gaps = dict(xplane.reduce(trace)["idle_gaps"])
+    # device 0 idles [5,6): 0.5 ms of it inside the program's span
+    assert gaps["tony:engine.decode_device"] == pytest.approx(0.00025)
+    assert gaps["bench:outer"] == pytest.approx(0.00025)
+    assert gaps["bench:inner"] == pytest.approx(0.0005)
+
+
+def test_the_sweep_over_idle_gaps_agrees_with_asking_every_span():
+    """``_gaps_by_span`` hands ``_innermost`` only the spans that can cover
+    a gap; over many gaps under nested spans it must say what asking all
+    of them says."""
+    import random
+
+    lo, hi = 0.0, 1e9
+    rng = random.Random(3)
+    spans = []
+    for i in range(300):                  # outer spans, each with an inner
+        s = rng.uniform(lo, hi)
+        d = rng.uniform(0, (hi - lo) / 100)
+        spans += [(f"bench:o{i % 7}", s, s + d),
+                  (f"tony:i{i % 5}", s + d / 4, s + d / 2)]
+    spans.sort(key=lambda sp: sp[1])
+    busy = xplane.union([[s, s + rng.uniform(0, 2e6)] for s in
+                         (rng.uniform(lo, hi) for _ in range(1000))])
+    idle = xplane.subtract([[lo, hi]], busy)
+    assert len(idle) > 300
+
+    def totals(pairs):
+        out = {}
+        for name, dur in pairs:
+            out[name] = out.get(name, 0.0) + dur
+        return out
+
+    slow = totals(p for s, e in idle for p in xplane._innermost(spans, s, e))
+    assert totals(xplane._gaps_by_span(spans, idle)) == pytest.approx(slow)
+    assert len(slow) == 13
 
 
 def test_exposed_collective_time_is_what_no_compute_overlaps():
@@ -169,17 +217,21 @@ def test_spread_is_the_quartile_distance_over_the_median():
 # -- operations and bytes ----------------------------------------------------
 def test_counts_agree_with_sums_made_by_hand():
     layer = (4096 * 4096 * 2 + 4096 * 1024 * 2 + 3 * 4096 * 14336)
-    assert counts.layer_matmul_params(MISTRAL) == layer == 218_103_808
-    assert counts.params_total(MISTRAL) == (
+    assert dense.layer_matmul_params(MISTRAL) == layer == 218_103_808
+    assert dense.params_total(MISTRAL) == (
         2 * (layer + 2 * 4096) + 2 * 32000 * 4096 + 4096)
+    assert dense.matmul_params_per_token(MISTRAL) == 2 * layer + 4096 * 32000
     # head yes, lookup no; causal attention 6·L·S·H·Dh a token
     want = 6 * (2 * layer + 4096 * 32000) + 6 * 2 * 2048 * 32 * 128
-    assert counts.train_flops_per_token(MISTRAL, 2048) == want
+    assert dense.train_flops_per_token(MISTRAL, 2048) == want
     assert want == pytest.approx(3.504e9, rel=1e-3)
 
 
 def test_flash_and_decode_bytes():
     fwd = counts.flash_call_cost("fwd", 4, 2048, 32, 8, 128)
+    assert fwd == dense.attention_call_cost(MISTRAL, "fwd", 4, 2048)
+    assert dense.attention_call_cost(MISTRAL, "dkv", 2, 2048, tp=2) == \
+        counts.flash_call_cost("dkv", 2, 2048, 16, 4, 128)
     assert fwd["flops"] == 2.0 * 4 * 32 * 2048 * 2048 * 128
     q = 4 * 2048 * 32 * 128 * 2
     assert fwd["bytes"] == 2 * q + 2 * (q // 4) + 4 * 32 * 2048 * 4
@@ -187,9 +239,9 @@ def test_flash_and_decode_bytes():
            + counts.flash_call_cost("dkv", 4, 2048, 32, 8, 128)["flops"])
     assert bwd == 2.5 * fwd["flops"]
     cfg = dict(MISTRAL, num_hidden_layers=16)
-    w = counts.weight_bytes(cfg)
+    w = dense.weight_bytes(cfg)
     assert w == (16 * (218_103_808 + 8192) + 4096 * 32000 + 4096) * 2
-    need = counts.decode_iter_bytes(cfg, live_positions=10_000,
+    need = dense.decode_iter_bytes(cfg, live_positions=10_000,
                                     active_slots=20)
     row = 8 * 128 * 2
     assert need == w + 2 * 16 * 10_000 * row + 2 * 16 * 20 * row + 20 * 8192
@@ -220,8 +272,9 @@ def test_traffic_is_a_pure_function_of_the_seed():
 
 
 def test_every_seed_gets_the_same_sizes_and_gaps_in_another_order():
-    a = traffic.serving_schedule(chat(), 1, 30, 32000)["requests"]
-    c = traffic.serving_schedule(chat(), 2, 30, 32000)["requests"]
+    turning = dict(chat(), seed_turns_order=True)   # the seed turns the circle
+    a = traffic.serving_schedule(turning, 1, 30, 32000)["requests"]
+    c = traffic.serving_schedule(turning, 2, 30, 32000)["requests"]
     for key in (lambda r: len(r["prompt"]), lambda r: r["max_new_tokens"]):
         assert sorted(map(key, a)) == sorted(map(key, c))
     assert [key(r) for r in a] != [key(r) for r in c]
@@ -254,3 +307,153 @@ def test_judge_needs_every_number_and_keeps_each_limit():
     assert not compare.judge({"x": 0.3, "y": 0}, {"x": 0.2, "y": 0})[0]
     assert not compare.judge({"y": 0}, {"x": 0.2, "y": 0})[0]
     assert not compare.judge({"x": float("nan")}, {"x": 0.2})[0]
+
+
+# -- seeded weights and the model modules -------------------------------------
+def digest(array) -> str:
+    import hashlib
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    return hashlib.sha256(
+        np.asarray(array.astype(jnp.float32)).tobytes()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("seed", [1, 2**31 + 11])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_every_weight_is_bit_for_bit_what_the_parent_made(seed, dtype):
+    """The digests were recorded from the parent's ``weights.leaf`` (one
+    closed table, ids by place) before the table moved to ``models/dense``
+    and ids became a function of the name."""
+    import jax.numpy as jnp
+
+    from yardstick import weights
+
+    recorded = json.loads((DATA / "parent_weight_digests.json").read_text())
+    cfg, want = recorded["config"], recorded["digests"][f"{seed}:{dtype}"]
+    table, key = dense.leaf_table(cfg), weights.seed_key(seed)
+    stacked = weights.stacked_layers(key, table, jnp.dtype(dtype))
+    got = {**stacked, **weights.top_tree(key, table, jnp.dtype(dtype))}
+    assert {n: digest(a) for n, a in got.items()} == want
+    assert len(want) == 12
+    # a layer made alone is that layer of the stack, and the program's tree
+    # holds the same arrays under the program's names
+    for layer in range(cfg["num_hidden_layers"]):
+        alone = weights.layer_tree(key, table, layer, jnp.dtype(dtype))
+        assert set(alone) == set(stacked)
+        for name, array in alone.items():
+            assert digest(array) == digest(stacked[name][layer])
+    tree = dense.program_params(key, cfg, jnp.dtype(dtype))
+    assert digest(tree["layers"]["wq"]) == want["q_proj"]
+    assert digest(tree["unembed"]) == want["lm_head"]
+    assert set(dense.leaf_norms(tree)) == set(want)
+
+
+def test_a_leafs_id_follows_from_its_name_alone():
+    from yardstick import weights
+
+    pinned = json.loads(weights.PINNED_IDS.read_text())
+    assert sorted(pinned.values()) == list(range(12))
+    assert [weights.leaf_id(n) for n in pinned] == list(pinned.values())
+    # a new name is hashed, above every pinned id and inside 32 bits; the
+    # same in every table it appears in, whatever stands beside it
+    new = weights.leaf_id("router")
+    assert new == weights.leaf_id("router") and 2**16 <= new < 2**31
+    assert new != weights.leaf_id("experts_gate")
+    ids = {weights.leaf_id(n) for n in toymoe.leaf_table(MOE)}
+    assert len(ids) == 13 and set(range(6)) | {9, 10, 11} < ids
+
+
+def test_two_names_that_share_an_id_are_an_error_when_the_table_is_built(
+        monkeypatch):
+    from yardstick import weights
+
+    leaf = weights.Leaf((4,))
+    assert weights.check_table({"a": leaf, "b": leaf})
+    monkeypatch.setattr(weights, "leaf_id", lambda name: 7)
+    with pytest.raises(ValueError, match="share the id"):
+        weights.check_table({"a": leaf, "b": leaf})
+
+
+def test_a_leaf_may_live_on_some_layers_only():
+    import jax.numpy as jnp
+
+    from yardstick import weights
+
+    table = {"first_only": weights.Leaf((3,), layers=range(0, 1)),
+             "all_but_first": weights.Leaf((3,), 0.5, layers=range(1, 4)),
+             "everywhere": weights.Leaf((3,), norm=True, layers=range(4)),
+             "top": weights.Leaf((2, 3))}
+    key = weights.seed_key(5)
+    stacked = weights.stacked_layers(key, table, jnp.float32)
+    assert {n: a.shape for n, a in stacked.items()} == {
+        "first_only": (1, 3), "all_but_first": (3, 3), "everywhere": (4, 3)}
+    assert set(weights.layer_tree(key, table, 0, jnp.float32)) == {
+        "first_only", "everywhere"}
+    assert set(weights.layer_tree(key, table, 2, jnp.float32, like=1)) == {
+        "all_but_first", "everywhere"}
+    # a stack's row is the layer's own draw, not the row's index
+    assert digest(stacked["all_but_first"][0]) == digest(
+        weights.leaf(key, table, "all_but_first", 1, jnp.float32))
+    assert set(weights.top_tree(key, table, jnp.float32)) == {"top"}
+
+
+MOE = dict(hidden_size=64, intermediate_size=96, num_attention_heads=4,
+           num_key_value_heads=2, head_dim=16, vocab_size=512,
+           num_hidden_layers=2, num_local_experts=4, num_experts_per_tok=2,
+           router_aux_loss_coef=0.01, router_z_loss_coef=0.001,
+           rope_theta=10000.0)
+
+
+def test_a_mixture_of_experts_counts_stored_and_active_parameters_apart():
+    attn = 2 * 64 * 16 * (4 + 2)
+    expert, router = 3 * 64 * 96, 64 * 4
+    assert toymoe.params_total(MOE) == (
+        2 * (attn + router + 2 * 64 + 4 * expert) + 2 * 512 * 64 + 64)
+    active = 2 * (attn + router + 2 * expert) + 64 * 512
+    assert toymoe.matmul_params_per_token(MOE) == active
+    assert toymoe.train_flops_per_token(MOE, 64) == (
+        6 * active + 6 * 2 * 64 * 4 * 16)
+    # one slot reaches two experts, three or more reach all four at most
+    one = toymoe.weight_bytes(MOE, active_slots=1)
+    assert one == 2 * ((attn + 128 + 2 * expert) * 2 + router * 4) + (
+        64 * 512 + 64) * 2
+    assert toymoe.weight_bytes(MOE, 3) - one == 2 * 2 * expert * 2
+    assert toymoe.weight_bytes(MOE, 3) == toymoe.weight_bytes(MOE, 30)
+    row = 2 * 16 * 2
+    assert toymoe.decode_iter_bytes(MOE, 100, 3) == (
+        toymoe.weight_bytes(MOE, 3) + 2 * 2 * 103 * row + 3 * 64 * 2)
+    cfg = toymoe.program_config(MOE, {}, max_seq=64, dtype="float32")
+    assert (cfg.n_experts, cfg.expert_top_k) == (4, 2)
+    # room for every token at every expert: the program drops none
+    assert int(cfg.capacity_factor * 4 * 64 * 2 / 4) >= 4 * 64
+    assert (cfg.moe_balance_coef, cfg.moe_zloss_coef) == (0.01, 0.001)
+
+
+@pytest.mark.parametrize("model,cfg", [(dense, dict(MISTRAL, num_hidden_layers=16)),
+                                       (toymoe, MOE)])
+def test_the_roofline_readers_count_with_the_cells_model_module(model, cfg):
+    """The same trace and the same load read against the bytes and FLOPs
+    of whichever architecture the cell's configuration names."""
+    from types import SimpleNamespace
+
+    from yardstick import readers
+
+    cell = SimpleNamespace(model=model, root=spec.ROOT, chips=1)
+    requests = [req(i, 1.0, 9.0, 100.0, 8000.0, 200) for i in range(3)]
+    run = {"cell": cell, "config": dict(cfg, run={}),
+           "device": {"kind": "TPU v5 lite"}, "requests": requests,
+           "traced_span_client": (4.0, 6.0), "job": {"decode_window": 1},
+           "trace": {"programs": {"jit_decode_window": {
+               "calls": 10, "total_s": 0.2, "median_s": 0.02}}}}
+    slots, positions = stats.live_load(requests, 4.0, 6.0)
+    need = model.decode_iter_bytes(run["config"], positions, slots)
+    assert readers.decode_hbm_roofline(run) == pytest.approx(
+        100.0 * need / 819e9 / 0.02)
+    run["job"] = {"step_ends": [1.0, 2.0], "tokens_per_step": 8192,
+                  "window_s": 2.0, "seq": 2048}
+    mfu = spec.Cell(spec.load_benchmark(), "mistral7b.train.b4x2048").reader(
+        "train_mfu_pct")
+    assert mfu(run) == pytest.approx(
+        100.0 * model.train_flops_per_token(cfg, 2048) * 8192 / 197e12)

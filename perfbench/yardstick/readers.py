@@ -3,8 +3,11 @@ under ``metrics/`` named after its metric, with ``read(run)``; ``run``
 holds what one run left: ``job`` (the job script's result), ``trace`` (the
 reduced device trace, traced runs only), ``serving`` (the load generator's
 numbers), ``stages``, ``config``, ``traffic``, ``seconds``, ``device``,
-``cell``. A reader that finds nothing to read returns None, and the metric
-is left out of the line; it never returns 0 for a share of a peak."""
+``cell``. What follows from the architecture (a decode iteration's bytes, a
+trained token's FLOPs, the attention call's cost) is counted by the cell's
+model module, ``run["cell"].model``. A reader that finds nothing to read
+returns None, and the metric is left out of the line; it never returns 0
+for a share of a peak."""
 
 from __future__ import annotations
 
@@ -44,18 +47,16 @@ def flash_roofline(run, patterns: dict):
     t = run["trace"]
     if not t:
         return None
-    cfg, job = run["config"], run["job"]
+    cfg, job, model = run["config"], run["job"], run["cell"].model
     peaks = peaks_of(run)
     mesh = cfg["run"].get("mesh", {})
     # One call sees one device's shard: batch over dp, heads over tp.
     batch = job["batch"] // int(mesh.get("dp", 1))
-    heads = cfg["num_attention_heads"] // int(mesh.get("tp", 1))
-    kv = max(cfg["num_key_value_heads"] // int(mesh.get("tp", 1)), 1)
     least, took = 0.0, 0.0
     for kind, pattern in patterns.items():
         rx = re.compile(pattern)
-        cost = counts.flash_call_cost(kind, batch, job["seq"], heads, kv,
-                                      cfg["head_dim"])
+        cost = model.attention_call_cost(cfg, kind, batch, job["seq"],
+                                         tp=int(mesh.get("tp", 1)))
         floor, _ = counts.roofline_seconds(cost["flops"], cost["bytes"],
                                            peaks)
         for name, seconds in t["device_ops"]:
@@ -77,7 +78,8 @@ def decode_hbm_roofline(run):
     slots, positions = stats.live_load(run["requests"], lo, hi)
     if slots <= 0:
         return None
-    need = counts.decode_iter_bytes(run["config"], positions, slots)
+    need = run["cell"].model.decode_iter_bytes(run["config"], positions,
+                                               slots)
     floor = need / peaks_of(run)["hbm_bytes_per_s"]
     steps = int(run["job"]["decode_window"])
     return 100.0 * floor * steps / prog["median_s"]

@@ -16,7 +16,10 @@ import statistics
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-HOST_SPAN_PREFIX = "bench:"
+# Host spans that idle gaps are named after: the job scripts' own and the
+# program's (``observability/trace.py`` enters a profiler annotation per
+# span, so they sit in the trace on the device operations' clock).
+HOST_SPAN_PREFIX = ("bench:", "tony:")
 TRACED_SPAN = "bench:traced"
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute"
@@ -159,12 +162,26 @@ def _innermost(spans, lo, hi):
         yield (max(covering)[1] if covering else NO_SPAN), b - a
 
 
+def _gaps_by_span(spans, idle):
+    """``_innermost`` over every idle interval (sorted, disjoint), given
+    only the spans that can cover it: a span a program writes per
+    iteration comes by the thousand, the idle intervals by the ten
+    thousand, and all but a few of their pairs are far apart."""
+    open_spans, nxt = [], 0
+    for s, e in idle:
+        while nxt < len(spans) and spans[nxt][1] < e:
+            open_spans.append(spans[nxt])
+            nxt += 1
+        open_spans = [sp for sp in open_spans if sp[2] > s]
+        yield from _innermost(open_spans, s, e)
+
+
 def reduce(trace: dict) -> dict:
     """Seconds, not nanoseconds, in everything returned. Times by
     operation and by span are means over the devices."""
     lo, hi = window_of(trace)
-    spans = [(n, s, s + d) for n, s, d in trace["host_spans"]
-             if n != TRACED_SPAN]
+    spans = sorted(((n, s, s + d) for n, s, d in trace["host_spans"]
+                    if n != TRACED_SPAN), key=lambda sp: sp[1])
     devices = {}
     op_time: dict[str, float] = {}
     op_calls: dict[str, int] = {}
@@ -187,9 +204,8 @@ def reduce(trace: dict) -> dict:
         for n, self_ns in self_times(ops):
             op_time[n] = op_time.get(n, 0.0) + self_ns / 1e9
             op_calls[n] = op_calls.get(n, 0) + 1
-        for s, e in idle:
-            for name, dur in _innermost(spans, s, e):
-                gaps_by_span[name] = gaps_by_span.get(name, 0.0) + dur / 1e9
+        for name, dur in _gaps_by_span(spans, idle):
+            gaps_by_span[name] = gaps_by_span.get(name, 0.0) + dur / 1e9
         for n, s, d in dev["modules"]:
             if lo <= s < hi:      # a program belongs where it started
                 modules.setdefault(re.sub(r"\(\d+\)$", "", n), []
