@@ -306,22 +306,19 @@ def bench_moe(batch: int = 4, seq: int = 2048, measure: int = 8):
 
 
 def bench_moe_decode(batch: int = 8, windows: int = 3):
-    """MoE decode at E=4 vs E=16: routed (top-k gather) step times plus
-    the dense-mixture comparison at E=16. The measured verdict on v5e is
-    that DENSE wins (XLA streams stacked expert weights near roofline;
-    per-token gathers do not) — these numbers are the evidence for why
-    moe_decode_mode=auto resolves to dense. Long differencing horizons
-    (256 vs 896 steps) so per-call wall noise divides out."""
+    """MoE decode step times at E=4 and E=16 through the one expert path
+    decode has (dropless, grouped by expert: ``_moe_mlp_decode``). Long
+    differencing horizons (256 vs 896 steps) so per-call wall noise
+    divides out."""
     from tony_tpu.models import DecodeSession, TransformerConfig, init_params
 
     out = {"batch": batch, "top_k": 2}
     steps = {}
-    for n_experts, mode in ((4, "routed"), (16, "routed"), (16, "dense")):
+    for n_experts in (4, 16):
         cfg = TransformerConfig(
             vocab_size=32_000, d_model=512, n_layers=4, n_heads=8,
             head_dim=64, d_ff=1024, max_seq=1024, dtype="bfloat16",
             remat=False, n_experts=n_experts, expert_top_k=2,
-            moe_decode_mode=mode,
         )
         params = jax.jit(lambda k, c=cfg: init_params(k, c))(  # tony: noqa[TONY-X001] — one-shot init compile, not a step path
             jax.random.key(0)
@@ -338,16 +335,9 @@ def bench_moe_decode(batch: int = 8, windows: int = 3):
                 windows,
             )
 
-        step_s = max(timed(896) - timed(256), 1e-9) / 640
-        steps[(n_experts, mode)] = step_s
-        key = f"step_ms_e{n_experts}" + ("_dense" if mode == "dense" else "")
-        out[key] = round(step_s * 1000, 3)
-    out["e16_over_e4_step_ratio"] = round(
-        steps[(16, "routed")] / steps[(4, "routed")], 2
-    )
-    out["dense_over_routed_e16"] = round(
-        steps[(16, "dense")] / steps[(16, "routed")], 2
-    )
+        steps[n_experts] = max(timed(896) - timed(256), 1e-9) / 640
+        out[f"step_ms_e{n_experts}"] = round(steps[n_experts] * 1000, 3)
+    out["e16_over_e4_step_ratio"] = round(steps[16] / steps[4], 2)
     return out
 
 
@@ -1870,7 +1860,7 @@ def run_benches() -> dict:
             "serving": _safe(bench_serving),
             "serving_fleet": _safe(bench_serving_fleet),
             "moe": _safe(bench_moe),
-            "moe_decode_routed": _safe(bench_moe_decode),
+            "moe_decode": _safe(bench_moe_decode),
             "input_pipeline": _safe(bench_input_pipeline),
             "scheduler": _safe(bench_scheduler),
             "checkpoint": _safe(bench_checkpoint),

@@ -268,3 +268,69 @@ def test_serving_programs_hold_no_layer_slab(v5e, program):
     compiled = lowered.compile()
     slab_bytes = slots * t_max * cfg.kv_heads * cfg.head_dim * 2
     assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunks"])
+def test_layered_serving_programs_copy_no_cache(v5e, program):
+    """The engine's two programs over a LAYERED model at published widths
+    (q/k 192, v 128, 4 KV heads in the full layer and 8 in the window
+    layer, a sink, 16 of 256 experts held; 64 slots x 8,192 positions,
+    depth cut to one layer of each attention kind): the decode kernel of
+    both kinds, the grouped expert products (``ops.grouped_matmul``: the
+    megablox kernel at weight-streaming tiles) and the norms compile, and decode's temporaries stay far
+    under one K buffer of the full layers (1.07 GB) — a K row of 192
+    kept as ONE [Tmax, 4, 256] buffer does not merge to rows without a
+    copy of the whole cache per dispatch (2.15 GB of temporaries here);
+    as two 128-lane tiles it does."""
+    from tony_tpu.serving import engine
+
+    cfg = TransformerConfig(
+        vocab_size=19_072, d_model=4096, n_layers=2, n_heads=64,
+        head_dim=192, v_head_dim=128, rotary_dim=64, v_scale=0.707,
+        rms_eps=1e-5, n_kv_heads=4, rope_theta=5e6, max_seq=8192,
+        attn_kinds=("full", "window"), window=128, window_kv_heads=8,
+        window_rope_theta=1e4, window_sink=True, n_dense_layers=1,
+        dense_d_ff=16384, d_ff=2048, n_experts=256, expert_top_k=8,
+        router_scoring="sigmoid", router_bias=True, experts_held=(0, 16),
+        dtype="bfloat16", remat=False,
+    )
+    slots, t_max, p, c = 64, 8192, 4, 128
+    one_chip = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree
+        )
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fused = on_chip(jax.eval_shape(
+        lambda: decode_lib.decode_weights(
+            init_params(jax.random.key(0), cfg), cfg
+        )
+    ))
+    k, v = on_chip(jax.eval_shape(
+        lambda: engine.init_slot_cache(cfg, slots, t_max, prefill_chunk=c)
+    ))
+    assert [x.shape for x in k["full"]] == [(1, 64, 8192, 4, 128)] * 2
+    assert v["window"].shape == (1, 64, 257, 8, 128)
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    if program == "decode_window":
+        compiled = engine.decode_window.lower(
+            fused, k, v, arr((slots,)), arr((slots,)), arr((slots,)),
+            arr((slots,), jnp.float32), key, arr(()), cfg=cfg, steps=1,
+        ).compile()
+        # 2 attention calls, 2 + 2 + 1 norms, the expert layer's 2 products
+        assert _mosaic_calls(compiled) == 9
+        assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+    else:
+        compiled = engine.prefill_chunks.lower(
+            fused, k, v, arr((p, c)), arr((p,)), arr((p,)), arr((p,)),
+            arr((p,), jnp.float32), key, arr(()), cfg=cfg,
+        ).compile()
+        assert _mosaic_calls(compiled) == 7
+        # the float32 scores of one row of the chunk against all Tmax
+        # keys, and the four slots' rows: under 1.5 GB
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

@@ -195,6 +195,98 @@ def test_close_leaves_the_counters_as_they_were(served):
         assert after[key] == before_close[key], key
 
 
+def _layered_engine(**kw) -> ServingEngine:
+    """Two attention kinds (layer 0 full and dense, then window layers
+    with experts of which a share is held): the model whose dispatches
+    carry expert counts and whose cache has two kinds of rows."""
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=32, n_layers=3, n_heads=4, head_dim=12,
+        v_head_dim=8, rotary_dim=4, n_kv_heads=1, max_seq=96,
+        dtype="float32", remat=False,
+        attn_kinds=("full", "window", "window"), window=8,
+        window_kv_heads=2, window_rope_theta=1e4, window_sink=True,
+        n_dense_layers=1, dense_d_ff=48, d_ff=16, n_experts=8,
+        expert_top_k=3, router_scoring="sigmoid", router_bias=True,
+        experts_held=(2, 4),
+    )
+    params = init_params(jax.random.key(0), cfg)
+    eng = ServingEngine(params, cfg, registry=MetricsRegistry(), **kw)
+    eng._tracer = obs_trace.Tracer(proc="test-engine")
+    return eng
+
+
+@pytest.fixture(scope="module")
+def served_layered():
+    eng = _layered_engine(slots=2, prefill_chunk=4, prefill_batch=2,
+                          max_len=64, kv_quant="none")
+    rng = np.random.default_rng(1)
+    reqs = [eng.submit(rng.integers(0, 64, n).astype(np.int32), 6)
+            for n in (5, 13, 22)]
+    _drive(eng, reqs)
+    eng.close()
+    return eng
+
+
+def test_device_spans_carry_the_pairs_on_held_experts(served_layered):
+    """``expert_pairs`` on every ``decode_device`` / ``prefill_device``
+    span is that dispatch's (token, choice) pairs on the held experts;
+    over all dispatches they are ``stats()["experts"]``."""
+    eng = served_layered
+    ex = eng.stats()["experts"]
+    device = [e for e in _spans(eng) if e["name"] in
+              ("tony:engine.decode_device", "tony:engine.prefill_device")]
+    assert device and all("expert_pairs" in e["args"] for e in device)
+    assert sum(e["args"]["expert_pairs"] for e in device) == \
+        ex["pairs_held"] == sum(ex["pairs_per_expert"])
+    assert ex["held"] == [2, 4] and len(ex["pairs_per_expert"]) == 4
+    assert ex["dispatches"] == len(device)
+    # tokens through the layers: every chunk's valid ones (a prompt's
+    # last chunk overlaps) and every served token but a request's last;
+    # 2 expert layers, 3 choices a token
+    tokens = sum(sum(n for _, n in _chunk_plan(p, 4)) + 6 - 1
+                 for p in (5, 13, 22))
+    assert ex["pairs_total"] == tokens * 2 * 3
+    assert 0 < ex["pairs_held"] < ex["pairs_total"]
+
+
+def test_kv_counters_by_cache_kind(served_layered):
+    eng = served_layered
+    st = eng.stats()
+    kv, kinds = st["kv"], st["kv"]["kinds"]
+    assert set(kinds) == {"full", "window"}
+    # the top-level keys keep their meaning: the full kind's
+    for key in ("reserved_positions", "bytes_per_position",
+                "live_position_ms"):
+        assert kv[key] == kinds["full"][key], key
+    assert kinds["full"]["reserved_positions"] == 2 * 64
+    # K 12 + V 8 wide, one full layer of 1 head, float32
+    assert kinds["full"]["bytes_per_position"] == (12 + 8) * 4
+    # a ring of 4 * ceil((8 + 4) / 4) = 12 positions and the parking row;
+    # two window layers of 2 heads
+    assert kinds["window"]["reserved_positions"] == 2 * 13
+    assert kinds["window"]["bytes_per_position"] == 2 * 2 * (12 + 8) * 4
+    for row in kinds.values():
+        assert row["bytes_reserved"] == (row["reserved_positions"]
+                                         * row["bytes_per_position"])
+    # a window layer keeps at most its window of each slot
+    assert 0 < kinds["window"]["live_position_ms"] <= \
+        2 * 8 * st["working_wall_ms"]
+    assert kinds["window"]["live_position_ms"] < \
+        kinds["full"]["live_position_ms"]
+
+
+def test_a_uniform_model_has_one_cache_kind_and_no_expert_block(served):
+    eng, _, _ = served
+    st = eng.stats()
+    assert "experts" not in st
+    assert list(st["kv"]["kinds"]) == ["full"]
+    assert st["kv"]["kinds"]["full"]["reserved_positions"] == \
+        st["kv"]["reserved_positions"]
+    device = [e for e in _spans(eng) if e["name"] in
+              ("tony:engine.decode_device", "tony:engine.prefill_device")]
+    assert not any("expert_pairs" in e["args"] for e in device)
+
+
 @pytest.mark.parametrize("prompt_len,chunk", [(3, 8), (16, 8), (20, 8),
                                               (33, 4)])
 def test_prefill_rounds_of_one_prompt_are_its_chunk_plan(prompt_len, chunk):

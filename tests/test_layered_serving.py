@@ -1,0 +1,414 @@
+"""A model whose layers are not all alike, served: full and window
+attention layers with their own KV head counts, rope bases and caches, q/k
+wider than v, partial rotary, a value scale, sinks, a leading dense layer,
+then a sigmoid top-k router with a selection bias over experts of which a
+share is held. The serving engine against the benchmark's PLAIN reference
+(``perfbench/configs/mimo-v2-flash-serve-1chip.reference.py``: float32, no
+cache, no kernels, the weights again from the seed through the model
+module's leaf table) at tiny widths that keep every ratio."""
+
+from __future__ import annotations
+
+import dataclasses
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+PERFBENCH = Path(__file__).resolve().parents[1] / "perfbench"
+sys.path.insert(0, str(PERFBENCH))
+
+from yardstick import spec, weights  # noqa: E402
+
+from tony_tpu.models import decode_weights, init_params  # noqa: E402
+from tony_tpu.models.decode import _moe_mlp_decode  # noqa: E402
+from tony_tpu.ops import cache_decode_attention  # noqa: E402
+from tony_tpu.serving import ServingEngine  # noqa: E402
+from tony_tpu.serving import engine as engine_lib  # noqa: E402
+
+SEED = 2 ** 31 + 77
+
+# MiMo-V2-Flash's keys at toy sizes: qk 12 / v 8, 4 of 12 dims rotate, a
+# window of 8 against sequences of 40-70, KV heads 1 (full) and 2 (window),
+# F W W W F W, layer 0 dense, 8 experts top-3 of which 4 are held.
+TINY = {
+    "model": "mimo_v2_flash", "hidden_size": 32, "intermediate_size": 48,
+    "moe_intermediate_size": 16, "num_attention_heads": 4, "head_dim": 12,
+    "v_head_dim": 8, "num_key_value_heads": 1, "swa_num_key_value_heads": 2,
+    "num_hidden_layers": 6, "vocab_size": 96, "layernorm_epsilon": 1e-5,
+    "rope_theta": 5_000_000, "swa_rope_theta": 10_000,
+    "partial_rotary_factor": 0.334, "attention_value_scale": 0.707,
+    "sliding_window": 8, "hybrid_layer_pattern": [0, 1, 1, 1, 0, 1, 1, 0],
+    "moe_layer_freq": [0, 1, 1, 1, 1, 1, 1, 1],
+    "add_swa_attention_sink_bias": True,
+    "add_full_attention_sink_bias": False, "n_routed_experts": 4,
+    "n_shared_experts": None, "num_experts_per_tok": 3,
+    "norm_topk_prob": True, "scoring_func": "sigmoid", "n_group": 1,
+    "topk_group": 1, "topk_method": "noaux_tc",
+    "routed_scaling_factor": None,
+    "published": {"n_routed_experts": 8},
+    "deployment": {"experts_first": 2},
+}
+
+
+@pytest.fixture(scope="module")
+def model():
+    return spec.load_model("mimo_v2_flash")
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return spec.load_module(
+        PERFBENCH / "configs" / "mimo-v2-flash-serve-1chip.reference.py",
+        "mimo_reference")
+
+
+# The same with the PUBLISHED head widths (q/k 192 of which 64 rotate, v
+# 128): a K row is then kept as two 128-lane tiles in both caches.
+WIDE = dict(TINY, head_dim=192, v_head_dim=128)
+
+
+def program(model, dtype="float32", max_seq=96, cfg=TINY):
+    tcfg = model.program_config(cfg, {}, max_seq=max_seq, dtype=dtype)
+    params = model.program_params(weights.seed_key(SEED), cfg,
+                                  jnp.dtype(dtype))
+    return tcfg, decode_weights(params, tcfg)
+
+
+def prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, TINY["vocab_size"], size=n).astype(np.int32)
+            for n in lengths]
+
+
+def reference_logits(reference, rows, cfg=TINY):
+    """The reference's full forward pass over each row (its own length)."""
+    return [np.asarray(reference.logits(
+        cfg, SEED, jnp.asarray(r)[None], dtype="float32"))[0] for r in rows]
+
+
+def widest_gap(reference, rows, n_prompt, cfg=TINY):
+    """How far a served token's reference logit lies below the
+    reference's best, at worst, over the served positions."""
+    worst = 0.0
+    for row, n, ref in zip(rows, n_prompt,
+                           reference_logits(reference, rows, cfg)):
+        at = np.arange(n - 1, len(row) - 1)
+        worst = max(worst, float(np.max(ref[at].max(-1) - ref[at, row[at + 1]])))
+    return worst
+
+
+def test_the_program_takes_the_configuration(model):
+    tcfg, fused = program(model)
+    assert tcfg.layered and tcfg.layer_groups == {
+        "full_dense": (0,), "window_moe": (1, 2, 3, 5), "full_moe": (4,)}
+    assert tcfg.rot_dim == 4 and tcfg.held == (2, 4)
+    assert isinstance(fused["layers"], tuple) and len(fused["layers"]) == 6
+    assert "sink" in fused["layers"][1] and "sink" not in fused["layers"][4]
+    assert fused["layers"][0]["gate_up"].shape == (32, 96)
+    assert fused["layers"][1]["gate_up"].shape == (4, 32, 32)
+    assert fused["layers"][1]["router"].dtype == jnp.float32
+    k, v = engine_lib.init_slot_cache(tcfg, 3, 64, prefill_chunk=5)
+    # full: Tmax rows of 1 head; window: a ring of 5 * ceil(13 / 5) = 15
+    # positions and the parking row, 2 heads
+    assert k["full"].shape == (2, 3, 64, 1, 12)
+    assert v["window"].shape == (4, 3, 16, 2, 8)
+    # the published widths: K 192 wide is kept as two 128-lane tiles
+    big = dataclasses.replace(tcfg, head_dim=192, v_head_dim=128,
+                              rotary_dim=64)
+    k, v = jax.eval_shape(
+        lambda: engine_lib.init_slot_cache(big, 3, 64, prefill_chunk=5))
+    assert [t.shape for t in k["full"]] == [(2, 3, 64, 1, 128)] * 2
+    assert v["window"].shape == (4, 3, 16, 2, 128)
+    with pytest.raises(ValueError, match="int8"):
+        engine_lib.init_slot_cache(tcfg, 3, 64, kv_quant="int8")
+
+
+def prefill_logit_gap(model, reference, chunk, dtype):
+    """Prompts several windows long, of mixed lengths in one batch, through
+    ``prefill_chunks`` chunk by chunk (the last chunk overlapping, short
+    batches padded with a duplicate of row 0 as the host pads them): the
+    largest distance of any chunk's last-position logits from the
+    reference's full forward pass over float32 weights."""
+    from tony_tpu.serving.scheduler import _chunk_plan
+
+    tcfg, fused = program(model, dtype=dtype)
+    rows = prompts([37, 9, 64, 23])
+    want = reference_logits(reference, rows)
+    k, v = engine_lib.init_slot_cache(tcfg, 4, 96, prefill_chunk=chunk)
+    key = jax.random.key(0)
+    worst = 0.0
+    plans = [_chunk_plan(len(r), chunk) for r in rows]
+    for step in range(max(len(p) for p in plans)):
+        live = [i for i, p in enumerate(plans) if step < len(p)]
+        live += [live[0]] * (4 - len(live))          # pad like the host
+        toks = np.zeros((4, chunk), np.int32)
+        starts, valids = np.zeros(4, np.int32), np.zeros(4, np.int32)
+        for j, i in enumerate(live):
+            starts[j], valids[j] = plans[i][step]
+            toks[j, :valids[j]] = rows[i][starts[j]:starts[j] + valids[j]]
+        k, v, _, logits, pairs = engine_lib.prefill_chunks(
+            fused, k, v, toks, np.asarray(live, np.int32), starts, valids,
+            np.zeros(4, np.float32), key, np.int32(0), cfg=tcfg)
+        assert pairs.shape == (4,)
+        for j, i in enumerate(live):
+            at = starts[j] + valids[j] - 1
+            worst = max(worst, float(np.max(np.abs(
+                np.asarray(logits[j]) - want[i][at]))))
+    return worst
+
+
+@pytest.mark.parametrize("chunk", [5, 16])
+def test_chunked_prefill_logits_equal_the_references(model, reference, chunk):
+    """Float32 on both sides: 2e-4 is ten times the largest gap seen
+    (summation order on logits of order 1), a hundredth of what bfloat16
+    gives (next test)."""
+    worst = prefill_logit_gap(model, reference, chunk, "float32")
+    assert worst < 2e-4, worst
+
+
+def test_bfloat16_in_float32s_place_fails_the_same_tolerance(model,
+                                                             reference):
+    """The same comparison with the program in bfloat16 (weights, matmuls
+    and both caches) where float32 is stated: far outside 2e-4."""
+    worst = prefill_logit_gap(model, reference, 5, "bfloat16")
+    assert worst > 2e-3, worst
+
+
+def serve(tcfg, fused, requests, **kw):
+    eng = ServingEngine(fused, tcfg, slots=2, max_len=96, prefill_chunk=5,
+                        kv_quant="none", **kw)
+    handles = [eng.submit(p, n) for p, n in requests]
+    while eng.step():
+        pass
+    rows = [np.concatenate([p, np.asarray(h.result()["tokens"], np.int32)])
+            for (p, _), h in zip(requests, handles)]
+    return eng, rows
+
+
+@pytest.mark.parametrize("window,cfg", [(1, TINY), (3, TINY), (1, WIDE)],
+                         ids=["window1", "window3", "published-head-widths"])
+def test_served_tokens_are_the_references_first_choice(model, reference,
+                                                       window, cfg):
+    """Two slots, five requests of mixed lengths: prefill in chunks, then
+    decode through both caches, a slot reused by a SHORTER request after a
+    longer one (its ring and its full rows still hold the last tenant's).
+    Every served token's reference logit lies within 1e-3 of the
+    reference's best over its own prefix: float32 rounding moves a logit
+    by 1e-5, so a token served is the reference's first choice or ties
+    with it to that rounding (the logits themselves are held to 2e-4
+    above, where bfloat16 fails)."""
+    requests = list(zip(prompts([41, 12, 30, 7, 19], seed=3),
+                        [30, 25, 12, 40, 9]))
+    tcfg, fused = program(model, cfg=cfg)
+    eng, rows = serve(tcfg, fused, requests, decode_window=window)
+    gap = widest_gap(reference, rows, [len(p) for p, _ in requests], cfg)
+    assert gap < 1e-3, gap
+    stats = eng.stats()
+    # tokens that went through the layers: every chunk's (the last chunk
+    # of a prompt overlaps the one before), and every served token but a
+    # request's last, which is never fed
+    from tony_tpu.serving.scheduler import _chunk_plan
+    n_tokens = sum(sum(n for _, n in _chunk_plan(len(p), 5)) + n_new - 1
+                   for p, n_new in requests)
+    assert stats["experts"]["held"] == [2, 4]
+    if window == 1:       # a deeper window decodes past a retirement
+        assert stats["experts"]["pairs_total"] == n_tokens * 5 * 3
+    assert 0 < stats["experts"]["pairs_held"] < stats["experts"]["pairs_total"]
+    assert sum(stats["experts"]["pairs_per_expert"]) == \
+        stats["experts"]["pairs_held"]
+    kinds = stats["kv"]["kinds"]
+    assert kinds["full"]["reserved_positions"] == 2 * 96 == \
+        stats["kv"]["reserved_positions"]
+    assert kinds["window"]["reserved_positions"] == 2 * 16
+    assert 0 < kinds["window"]["live_position_ms"] < \
+        kinds["full"]["live_position_ms"] == stats["kv"]["live_position_ms"]
+
+
+@pytest.mark.parametrize("cfg", [TINY, WIDE],
+                         ids=["tiny", "published-head-widths"])
+def test_shipped_rows_continue_where_prefill_left(model, reference, cfg):
+    """Prefill on one engine, decode on another from the exported rows of
+    both cache kinds: the same tokens as one engine serving the request."""
+    tcfg, fused = program(model, cfg=cfg)
+    prompt = prompts([33], seed=5)[0]
+    a = ServingEngine(fused, tcfg, slots=2, max_len=96, prefill_chunk=5,
+                      kv_quant="none")
+    first = a.prefill_only(prompt, 12)
+    while a.step():
+        pass
+    kv_k, kv_v = first.kv
+    assert kv_k["full"].shape == (2, 33, 1, cfg["head_dim"])
+    assert kv_v["window"].shape == (4, 15, 2, cfg["v_head_dim"])
+    b = ServingEngine(fused, tcfg, slots=2, max_len=96, prefill_chunk=5,
+                      kv_quant="none")
+    rest = b.submit_with_kv(kv_k, kv_v, first.result()["tokens"][0], 33, 11)
+    while b.step():
+        pass
+    _, rows = serve(tcfg, fused, [(prompt, 12)])
+    got = first.result()["tokens"] + rest.result()["tokens"]
+    np.testing.assert_array_equal(rows[0][33:], got)
+
+
+# -- the expert layer ----------------------------------------------------------
+def expert_layer(model, reference, first, count, *, bias=None, tokens=24):
+    """One expert layer of the program (held = first .. first + count of
+    8) and the UNCUT reference layer's weights, the same experts."""
+    cfg = dict(TINY, n_routed_experts=8, deployment={"experts_first": 0})
+    table = model.leaf_table(cfg)
+    p = jax.tree.map(lambda w: w.astype(jnp.float32), weights.layer_tree(
+        weights.seed_key(SEED), table, 1, jnp.float32))
+    if bias is not None:
+        p["router_bias"] = jnp.asarray(bias, jnp.float32)
+    x = jax.random.normal(jax.random.key(4), (2, tokens // 2, 32))
+    tcfg = dataclasses.replace(
+        model.program_config(cfg, {}, max_seq=64, dtype="float32"),
+        experts_held=(first, count))
+    held = slice(first, first + count)
+    lp = {"ln2": p["post_norm"], "router": p["router"],
+          "router_bias": p["router_bias"],
+          "gate_up": jnp.concatenate([p["experts_gate"][held],
+                                      p["experts_up"][held]], -1),
+          "w_down": p["experts_down"][held]}
+    out, pairs = jax.jit(lambda x: _moe_mlp_decode(x, lp, tcfg))(x)
+    return cfg, p, x, out, pairs
+
+
+def test_the_shares_of_one_layer_add_up_to_the_uncut_reference(
+        model, reference):
+    """Four chips hold 2 of 8 experts each: the parts they give add up to
+    the reference's layer with all 8 (float32: 1e-5 is summation order)."""
+    total, all_pairs = 0.0, 0
+    for first in (0, 2, 4, 6):
+        cfg, p, x, out, pairs = expert_layer(model, reference, first, 2)
+        total, all_pairs = total + out, all_pairs + int(pairs.sum())
+    want = reference.experts(x, p, cfg, lambda a: a, first=0) - x
+    assert all_pairs == x.shape[0] * x.shape[1] * 3
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                               atol=1e-5, rtol=0)
+    # and each share alone is the reference's share
+    cfg, p, x, out, _ = expert_layer(model, reference, 4, 2)
+    held = {k: v[4:6] if k.startswith("experts_") else v
+            for k, v in p.items()}
+    want = reference.experts(x, held, cfg, lambda a: a, first=4) - x
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-5, rtol=0)
+
+
+@pytest.mark.parametrize("held,landing", [((5, 1), 24), ((0, 3), 0)])
+def test_no_pair_is_dropped_under_any_routing(model, reference, held,
+                                              landing):
+    """A selection bias sends EVERY token to experts 5, 6 and 7. A chip
+    that holds expert 5 alone gets all 24 tokens on its one expert (no
+    capacity drops one); a chip that holds 0-2 gets none and adds
+    nothing. Both equal the reference."""
+    bias = [0, 0, 0, 0, 0, 50, 50, 50]
+    cfg, p, x, out, pairs = expert_layer(model, reference, *held, bias=bias)
+    assert int(pairs.sum()) == landing
+    part = {k: v[held[0]:held[0] + held[1]] if k.startswith("experts_")
+            else v for k, v in p.items()}
+    want = reference.experts(x, part, cfg, lambda a: a, first=held[0]) - x
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-5, rtol=0)
+    if not landing:
+        assert not np.asarray(out).any()
+
+
+@pytest.mark.parametrize("sizes", [[3, 0, 100, 17, 40], [0, 0, 0, 0, 256],
+                                   [0, 0, 0, 0, 0]],
+                         ids=["uneven", "all-on-one", "none"])
+def test_grouped_matmul_kernel_equals_ragged_dot(sizes):
+    """The grouped product's Pallas kernel (interpret mode) against
+    ``lax.ragged_dot`` on the rows that belong to a group: groups of
+    uneven size, an empty group, every row on one group, and no row at
+    all; rows past the groups' sum are undefined and not compared.
+    Float32: 1e-4 is summation order over k = 256."""
+    from tony_tpu.ops import grouped_matmul
+
+    k1, k2 = jax.random.split(jax.random.key(0))
+    lhs = jax.random.normal(k1, (256, 256), jnp.float32)
+    rhs = jax.random.normal(k2, (5, 256, 384), jnp.float32)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    want = grouped_matmul(lhs, rhs, sizes, mode="jax")
+    got = grouped_matmul(lhs, rhs, sizes, mode="interpret")
+    n = int(sizes.sum())
+    assert got.shape == want.shape == (256, 384)
+    np.testing.assert_allclose(np.asarray(got[:n]), np.asarray(want[:n]),
+                               atol=1e-4, rtol=0)
+
+
+# -- the decode kernel, interpret mode against plain jnp ------------------------
+@pytest.mark.parametrize("name,h_kv,window,sink,block_rows", [
+    ("full", 4, 0, False, 64),
+    ("ring", 8, 16, True, 4096),
+    ("ring-blocks", 2, 16, True, 16),      # blocks that hold no visible row
+    ("ring-no-sink", 8, 16, False, 4096),
+])
+def test_cache_decode_kernel_equals_the_plain_path(name, h_kv, window, sink,
+                                                   block_rows):
+    """K rows 192 wide kept as two 128-lane tiles (the second zero-filled
+    past 64), V rows 128: a full cache read up to pos, and a ring of 32
+    positions + parking row read through its window of 16 with the sinks
+    in the denominator. Slots at position 0, before the first wrap and
+    past it. The kernel (interpret mode) sums the tiles' products; the
+    plain path lays the tiles side by side. bfloat16 data, float32
+    accumulation in both: 2e-2 on values of order 1."""
+    slots, n_h, t = 4, 16, 33 if window else 64
+    keys = jax.random.split(jax.random.key(7), 4)
+    q = jax.random.normal(keys[0], (slots, n_h, 192), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (2, slots, t, h_kv, 192), jnp.bfloat16)
+    k_tiles = engine_lib._lane_tiles(k, 2)
+    assert [x.shape[-1] for x in k_tiles] == [128, 128]
+    v_all = jax.random.normal(keys[2], (2, slots, t, h_kv, 128), jnp.bfloat16)
+    b = jax.random.normal(keys[3], (n_h,)) * 2 if sink else None
+    pos = jnp.asarray([0, 9, 40, 63], jnp.int32)
+    kw = dict(window=window, sink=b)
+    want = cache_decode_attention(q, k, v_all, jnp.int32(1), pos,
+                                  mode="jax", **kw)
+    tiled = cache_decode_attention(q, k_tiles, v_all, jnp.int32(1), pos,
+                                   mode="jax", **kw)
+    np.testing.assert_array_equal(np.asarray(tiled, np.float32),
+                                  np.asarray(want, np.float32))
+    got = cache_decode_attention(q, k_tiles, v_all, jnp.int32(1), pos,
+                                 mode="interpret", block_rows=block_rows,
+                                 **kw)
+    assert got.shape == (slots, n_h, 128)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+
+
+def test_a_ring_is_masked_by_position_not_by_row():
+    """A slot at position 5 of a ring full of a last tenant's rows sees
+    rows 0..5 only: the answer does not move when every other row does."""
+    keys = jax.random.split(jax.random.key(1), 3)
+    q = jax.random.normal(keys[0], (1, 4, 16))
+    k = jax.random.normal(keys[1], (1, 1, 33, 2, 16))
+    v = jax.random.normal(keys[2], (1, 1, 33, 2, 8))
+    pos = jnp.asarray([5], jnp.int32)
+    a = cache_decode_attention(q, k, v, jnp.int32(0), pos, window=16,
+                               mode="jax")
+    b = cache_decode_attention(q, k.at[:, :, 6:].mul(-3.0),
+                               v.at[:, :, 6:].add(9.0), jnp.int32(0), pos,
+                               window=16, mode="jax")
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- forward and generate refuse, never a silently uniform model ---------------
+def test_forward_and_generate_refuse_a_layered_configuration(model):
+    from tony_tpu.models import forward, generate
+    from tony_tpu.models.decode import advance, init_cache
+
+    tcfg, fused = program(model)
+    raw = init_params(jax.random.key(0), tcfg)
+    assert set(raw["layers"]) == {"full_dense", "window_moe", "full_moe"}
+    assert raw["layers"]["window_moe"]["wk"].shape == (4, 32, 2, 12)
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    with pytest.raises(ValueError, match="uniform layers only"):
+        forward(raw, tokens, tcfg)
+    with pytest.raises(ValueError, match="uniform layers only"):
+        generate(fused, tokens, tcfg, max_new_tokens=4)
+    with pytest.raises(ValueError, match="uniform layers only"):
+        advance(fused, init_cache(tcfg, 1, 16), tokens, tcfg)

@@ -660,7 +660,7 @@ class TestDecode:
     def test_moe_decode_matches_training_forward(self):
         """MoE trunk (with GQA): cached greedy decode emits the same tokens
         as full-recompute argmax. capacity_factor is sized so training's
-        dispatch drops nothing — decode's dense-mixture evaluation never
+        dispatch drops nothing — decode's grouped expert evaluation never
         drops (inference serves whatever the router picks), so parity
         requires a non-dropping training config."""
         from tony_tpu.models import (
@@ -693,32 +693,35 @@ class TestDecode:
         )
 
     @pytest.mark.parametrize("n_experts", [4, 16])
-    def test_routed_moe_decode_token_exact_vs_dense(self, n_experts):
-        """Top-k-only (gathered) expert evaluation vs the dense mixture:
-        identical greedy tokens at E=4 and E=16. On
-        v5e the dense mixture measured FASTER at every tested (B, E) so
-        it stays the default; this parity pin is what lets either mode be
-        chosen on perf grounds alone."""
-        import dataclasses
+    def test_grouped_expert_layer_equals_the_dense_mixture(self, n_experts):
+        """Decode's expert layer (pairs grouped by expert through
+        ``lax.ragged_dot``, no capacity) against the mixture written out
+        in plain jnp: every expert on every token, weighed by the
+        router's normalised top-k weights, at E=4 and E=16. Float32:
+        1e-5 is summation order."""
+        from tony_tpu.models import TransformerConfig, decode_weights, init_params
+        from tony_tpu.models.decode import _moe_mlp_decode
+        from tony_tpu.models.transformer import _route_tokens
+        from tony_tpu.ops import rms_norm
 
-        from tony_tpu.models import TransformerConfig, generate, init_params
-
-        base = TransformerConfig(
+        cfg = TransformerConfig(
             vocab_size=64, d_model=32, n_layers=2, n_heads=4, head_dim=8,
             d_ff=64, max_seq=64, dtype="float32", remat=False,
             n_experts=n_experts, expert_top_k=2, capacity_factor=4.0,
         )
-        params = init_params(jax.random.key(11), base)
-        prompt = jnp.asarray(
-            np.random.default_rng(5).integers(0, 64, (3, 7)), jnp.int32
-        )
-        out = {}
-        for mode in ("routed", "dense"):
-            cfg = dataclasses.replace(base, moe_decode_mode=mode)
-            out[mode] = np.asarray(
-                generate(params, prompt, cfg, max_new_tokens=6)
-            )
-        np.testing.assert_array_equal(out["routed"], out["dense"])
+        fused = decode_weights(init_params(jax.random.key(11), cfg), cfg)
+        lp = jax.tree.map(lambda w: w[1], fused["layers"])
+        x = jax.random.normal(jax.random.key(5), (3, 7, 32))
+        got, pairs = jax.jit(lambda x: _moe_mlp_decode(x, lp, cfg))(x)
+        assert int(pairs.sum()) == 3 * 7 * 2
+        hn = rms_norm(x, lp["ln2"], eps=cfg.rms_eps)
+        _, _, gvals, gidx = _route_tokens(hn, lp["router"], 2)
+        weight = (jax.nn.one_hot(gidx, n_experts) * gvals[..., None]).sum(2)
+        gu = jnp.einsum("btd,edf->btef", hn, lp["gate_up"])
+        act = jax.nn.silu(gu[..., :64]) * gu[..., 64:]
+        want = jnp.einsum("btef,efd,bte->btd", act, lp["w_down"], weight)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=0)
 
     def test_decode_session_matches_generate_and_refreshes(self):
         from tony_tpu.models import DecodeSession, generate

@@ -22,13 +22,15 @@ choices:
   holds the fused pack so repeated ``generate`` calls pay fusion once
   (module-level ``generate`` on raw params re-fuses per call).
 
-MoE trunks decode via the dense mixture by default (every expert runs,
-unselected get exact weight 0): measured on v5e, streaming the stacked
-expert weights beats per-token top-k weight gathers at every tested
-(B, E) — the gathers are the bandwidth-inefficient path, not the
-streaming. A ``routed`` top-k-only evaluation
-(``_moe_mlp_decode_routed``) stays selectable via
-``cfg.moe_decode_mode`` and is token-exact vs dense. Sampling: greedy at
+Expert layers decode through ``_moe_mlp_decode``: one dropless grouped
+path for prefill and decode alike — the (token, choice) pairs that land
+on the experts held here, sorted by expert, through two grouped matrix
+products (``ops.grouped_matmul``: on a TPU a Pallas kernel whose work
+follows the rows that are there and whose tiles are sized to stream each
+visited expert's weights once) and added back under their router
+weights. No capacity, static shapes, no pair dropped under any routing;
+running every held expert on every token would be 32x the needed FLOPs
+where a token uses 0.5 of 16 held experts. Sampling: greedy at
 ``temperature=0``, else temperature sampling with a caller-provided key.
 """
 
@@ -45,11 +47,19 @@ from tony_tpu.models.transformer import TransformerConfig
 from tony_tpu.ops import (
     apply_rope,
     flash_attention,
+    grouped_matmul,
     rms_norm,
     rope_frequencies,
 )
 
 NEG_INF = -1e30
+
+
+def is_fused(params: dict) -> bool:
+    """Whether ``params`` is the ``decode_weights`` layout: a stacked
+    dict with ``qkv``, or (a layered configuration) a tuple of layers."""
+    layers = params["layers"]
+    return isinstance(layers, tuple) or "qkv" in layers
 
 
 def decode_weights(params: dict, cfg: TransformerConfig) -> dict:
@@ -63,14 +73,33 @@ def decode_weights(params: dict, cfg: TransformerConfig) -> dict:
     MoE configs keep the router and fuse gate|up per expert
     ([L, E, d, 2F]); see ``_layer_decode``'s mixture evaluation.
 
+    A layered configuration (groups of stacks by kind) comes back as a
+    TUPLE of layers in model order, each an array set of its own: the
+    serving programs walk it in a static loop, so no layer is ever
+    sliced out of a stack on the device. Its q|k|v fuse on the FEATURE
+    axis ([d, H*Dk + Hkv*Dk + Hkv*Dv]: the widths differ, the head axis
+    cannot hold them), per kind at that kind's KV head count.
+
     ``advance`` accepts either this fused layout or raw training params
     (fusing on the fly), so eager chat-style callers need not care."""
     dt = cfg.compute_dtype
-    lp = params["layers"]
 
     def c(x):
         return x.astype(dt)
 
+    top = {
+        "embed": c(params["embed"]),
+        "final_norm": c(params["final_norm"]),
+        "unembed": c(params["unembed"]),
+    }
+    if cfg.layered:
+        layers: list = [None] * cfg.n_layers
+        for name, members in cfg.layer_groups.items():
+            for i, layer in enumerate(members):
+                layers[layer] = _fuse_layer(
+                    jax.tree.map(lambda p: p[i], params["layers"][name]), dt)
+        return {**top, "layers": tuple(layers)}
+    lp = params["layers"]
     layers = {
         "ln1": c(lp["ln1"]),
         "ln2": c(lp["ln2"]),
@@ -91,12 +120,29 @@ def decode_weights(params: dict, cfg: TransformerConfig) -> dict:
         # decode — the token-exact-parity guarantee would silently narrow
         # to fp32 configs (ADVICE r3).
         layers["router"] = lp["router"].astype(jnp.float32)
-    return {
-        "embed": c(params["embed"]),
-        "final_norm": c(params["final_norm"]),
-        "unembed": c(params["unembed"]),
-        "layers": layers,
+    return {**top, "layers": layers}
+
+
+def _fuse_layer(lp: dict, dt) -> dict:
+    """One layer of a layered configuration in the serving layout."""
+    d = lp["wq"].shape[0]
+    out = {
+        "ln1": lp["ln1"].astype(dt),
+        "ln2": lp["ln2"].astype(dt),
+        "qkv": jnp.concatenate(
+            [lp[n].astype(dt).reshape(d, -1) for n in ("wq", "wk", "wv")],
+            axis=1),
+        "wo": lp["wo"].astype(dt),
+        "gate_up": jnp.concatenate(
+            [lp["w_gate"].astype(dt), lp["w_up"].astype(dt)], axis=-1),
+        "w_down": lp["w_down"].astype(dt),
     }
+    # Router, its selection bias and the sinks stay float32 (tiny, and a
+    # near tie must not flip on a rounding the model never had).
+    for name in ("router", "router_bias", "sink"):
+        if name in lp:
+            out[name] = lp[name].astype(jnp.float32)
+    return out
 
 
 def decode_param_specs(cfg: TransformerConfig) -> dict:
@@ -108,9 +154,28 @@ def decode_param_specs(cfg: TransformerConfig) -> dict:
     replicate. ``DecodeSession(mesh=...)`` places weights with these; a
     dim a mesh axis doesn't divide falls back to replicated at placement
     time (sharding is an optimization, never a correctness requirement —
-    same rule as train._sharding_for_tree)."""
+    same rule as train._sharding_for_tree). A layered configuration gets
+    one spec set per layer, by its kind: its feature-fused qkv
+    replicates (q, k and v columns of one head do not lie together)."""
     from jax.sharding import PartitionSpec as P
 
+    top = {"embed": P(), "final_norm": P(), "unembed": P(None, "tp")}
+    if cfg.layered:
+        def one(attn, mlp):
+            spec = {"ln1": P(), "ln2": P(), "qkv": P(),
+                    "wo": P("tp", None, None)}
+            if mlp == "moe":
+                spec.update(gate_up=P("ep", None, "tp"),
+                            w_down=P("ep", "tp", None), router=P())
+                if cfg.router_bias:
+                    spec["router_bias"] = P()
+            else:
+                spec.update(gate_up=P(None, "tp"), w_down=P("tp", None))
+            if attn == "window" and cfg.window_sink:
+                spec["sink"] = P()
+            return spec
+
+        return {**top, "layers": tuple(one(a, m) for a, m in cfg.layer_kinds)}
     layers = {
         "ln1": P(),
         "ln2": P(),
@@ -127,12 +192,7 @@ def decode_param_specs(cfg: TransformerConfig) -> dict:
     }
     if cfg.n_experts:
         layers["router"] = P()
-    return {
-        "embed": P(),
-        "final_norm": P(),
-        "unembed": P(None, "tp"),
-        "layers": layers,
-    }
+    return {**top, "layers": layers}
 
 
 def _cache_spec(abstract_mesh, batch: int, kv_heads: int):
@@ -199,7 +259,7 @@ def _layer_decode(x, lp, k_all, v_all, layer, length, cfg, cos, sin,
     t_max = k_all.shape[2]
     n_h, h_kv = cfg.n_heads, k_all.shape[3]
 
-    h = rms_norm(x, lp["ln1"]).astype(dt)
+    h = rms_norm(x, lp["ln1"], eps=cfg.rms_eps).astype(dt)
     qkv = jnp.einsum("btd,dhk->bthk", h, lp["qkv"])
     q = qkv[:, :, :n_h]
     k_new = qkv[:, :, n_h:n_h + h_kv]
@@ -257,23 +317,11 @@ def _layer_decode(x, lp, k_all, v_all, layer, length, cfg, cos, sin,
     x = x + jnp.einsum("bthk,hkd->btd", o.astype(dt), lp["wo"])
 
     if "router" in lp:
-        mode = cfg.moe_decode_mode
-        if mode not in ("auto", "routed", "dense"):
-            raise ValueError(f"unknown moe_decode_mode {mode!r}")
-        # auto -> dense: measured on v5e, streaming all experts beats
-        # per-token top-k weight gathers at every tested (B, E) — see
-        # TransformerConfig.moe_decode_mode. Routed
-        # applies only to single-token steps even when selected: its
-        # gathered [B, T, K, d, 2f] weight copy scales with T — a
-        # 1024-token prefill would materialize hundreds of GB.
-        if mode == "routed" and s == 1:
-            x = x + _moe_mlp_decode_routed(x, lp, cfg)
-        else:
-            x = x + _moe_mlp_decode(x, lp, cfg)
+        x = x + _moe_mlp_decode(x, lp, cfg)[0]
     else:
         # SwiGLU with the fused gate|up projection — the same math as
         # training's _dense_mlp, one matmul instead of two.
-        hn = rms_norm(x, lp["ln2"]).astype(dt)
+        hn = rms_norm(x, lp["ln2"], eps=cfg.rms_eps).astype(dt)
         gu = jnp.einsum("btd,df->btf", hn, lp["gate_up"])
         f = gu.shape[-1] // 2
         act = (
@@ -284,76 +332,62 @@ def _layer_decode(x, lp, k_all, v_all, layer, length, cfg, cos, sin,
     return x, k_all, v_all
 
 
-def _moe_mlp_decode(x, lp, cfg):
-    """MoE layer at decode time: dense-mixture evaluation — run every
-    expert on the new token(s) and combine with the normalized top-k
-    router weights (non-selected experts get exact weight 0). Equivalent
-    to training's dispatch/combine WITHOUT capacity dropping: inference
-    serves whatever the router picks — token dropping is a training-time
-    throughput trade, not a serving semantic (and a per-step capacity over
-    1..S tokens would diverge from the full-sequence forward anyway).
-    Cost: all E experts' weights stream per step; fine for the modest
-    expert counts a single host serves — sharded expert decode belongs on
-    an ep mesh.
-    """
+def _moe_mlp_decode(x, lp, cfg, token_mask=None, count_mask=None):
+    """An expert layer for prefill and decode alike: dropless, grouped.
+
+    x [b, t, d]. The router scores all ``n_experts`` and picks top-k
+    (``_route_tokens``: softmax or sigmoid, a selection bias where the
+    model has one); the (token, choice) pairs that land on the experts
+    HELD here (``cfg.held``; all of them by default) are sorted by
+    expert and go through two grouped matrix products over ``gate_up``
+    [held, d, 2F] and ``w_down`` [held, F, d] (``ops.grouped_matmul``),
+    then back to their tokens under the router's weights — normalised
+    over all k choices, so a share of the experts gives its own part of
+    the layer's result and nothing stands in for the rest. Shapes are
+    static (b*t*k pair rows, the worst case), no pair is dropped under
+    any routing, and the work follows the rows really there.
+
+    ``token_mask`` [b, t]: tokens whose pairs take no part (idle lanes
+    of a decode batch). ``count_mask`` [b, t]: tokens that are COMPUTED
+    but not counted (a prefill batch's padding rows, which must write
+    the K/V of the row they duplicate). Returns (out [b, t, d], pairs
+    [held] int32: the counted pairs each held expert received)."""
     from tony_tpu.models.transformer import _route_tokens
 
     dt = cfg.compute_dtype
-    e = cfg.n_experts
-    hn = rms_norm(x, lp["ln2"])
-    # Same router gating as training (_route_tokens — shared so parity
-    # cannot drift); [b,t,E] combine weights sum the normalized gvals over
-    # the top-k slots.
-    _, _, gvals, gidx = _route_tokens(hn, lp["router"], cfg.expert_top_k)
-    weights = (jax.nn.one_hot(gidx, e, dtype=jnp.float32)
-               * gvals[..., None]).sum(2)
-
-    hd = hn.astype(dt)
-    gu = jnp.einsum("btd,edf->btef", hd, lp["gate_up"])
+    b, t, d = x.shape
+    k = cfg.expert_top_k
+    first, held = cfg.held
+    hn32 = rms_norm(x.astype(jnp.float32), lp["ln2"], eps=cfg.rms_eps)
+    _, _, gvals, gidx = _route_tokens(
+        hn32, lp["router"], k, scoring=cfg.router_scoring,
+        bias=lp.get("router_bias"))
+    local = gidx.reshape(-1) - first                      # [n*k]
+    here = (local >= 0) & (local < held)
+    if token_mask is not None:
+        here &= jnp.repeat(token_mask.reshape(-1), k)
+    # Pairs sorted by held expert; the rest sort last, into no group.
+    key = jnp.where(here, local, held)
+    order = jnp.argsort(key, stable=True)
+    on_expert = key[:, None] == jnp.arange(held)           # [n*k, held]
+    sizes = on_expert.sum(0, dtype=jnp.int32)
+    pairs = sizes
+    if count_mask is not None:
+        counted = jnp.repeat(count_mask.reshape(-1), k)
+        pairs = (on_expert & counted[:, None]).sum(0, dtype=jnp.int32)
+    rows = hn32.astype(dt).reshape(b * t, d)[order // k]   # [n*k, d]
+    gu = grouped_matmul(rows, lp["gate_up"], sizes)
     f = gu.shape[-1] // 2
     act = (
-        jax.nn.silu(gu[..., :f].astype(jnp.float32)).astype(dt)
-        * gu[..., f:]
+        jax.nn.silu(gu[:, :f].astype(jnp.float32)).astype(dt) * gu[:, f:]
     )
-    per_expert = jnp.einsum("btef,efd->bted", act, lp["w_down"])
-    return jnp.einsum(
-        "bted,bte->btd", per_expert, weights.astype(dt)
-    )
-
-
-def _moe_mlp_decode_routed(x, lp, cfg):
-    """Top-k-only MoE evaluation: gather each token's K selected experts'
-    weights and run just those — per-step cost is B·K expert matmuls.
-    Same router, same normalized gate weights, no capacity dropping —
-    token-exact vs the dense path up to summation order (distinct top-k
-    indices make the zero-weight terms the dense path adds EXACT zeros,
-    so the two sums agree to fp rounding).
-
-    Measured on v5e (r4) this path LOSES to the dense mixture at every
-    tested point (E=16/B=8: 1.52 vs 1.27 ms/step; E=64/B=4: 3.94 vs
-    1.71): decode MoE is bandwidth-bound, XLA streams the stacked expert
-    weights near roofline, and per-token weight gathers do not — so
-    "auto" resolves to dense and this stays an explicit option for
-    B·K ≪ E regimes on hardware with efficient gathers."""
-    from tony_tpu.models.transformer import _route_tokens
-
-    dt = cfg.compute_dtype
-    hn = rms_norm(x, lp["ln2"])
-    # Same router gating as training/dense decode (_route_tokens — shared
-    # so parity cannot drift). gidx/gvals: [b, t, k].
-    _, _, gvals, gidx = _route_tokens(hn, lp["router"], cfg.expert_top_k)
-
-    hd = hn.astype(dt)
-    w_gu = lp["gate_up"][gidx]          # [b, t, k, d, 2f] gathered
-    w_dn = lp["w_down"][gidx]           # [b, t, k, f, d]
-    gu = jnp.einsum("btd,btkdf->btkf", hd, w_gu)
-    f = gu.shape[-1] // 2
-    act = (
-        jax.nn.silu(gu[..., :f].astype(jnp.float32)).astype(dt)
-        * gu[..., f:]
-    )
-    per_slot = jnp.einsum("btkf,btkfd->btkd", act, w_dn)
-    return jnp.einsum("btkd,btk->btd", per_slot, gvals.astype(dt))
+    y = grouped_matmul(act, lp["w_down"], sizes)           # [n*k, d]
+    # Back in (token, choice) order; rows of no group carry whatever the
+    # grouped product left there, so they are selected out, not weighed.
+    y = y[jnp.argsort(order)]
+    w = jnp.where(here, gvals.reshape(-1), 0.0)
+    out = jnp.where(here[:, None], y.astype(jnp.float32) * w[:, None], 0.0)
+    return out.reshape(b, t, k, d).sum(2).astype(dt), pairs
 
 
 def advance(params: dict, cache: dict, tokens: jax.Array,
@@ -376,6 +410,7 @@ def advance(params: dict, cache: dict, tokens: jax.Array,
     would silently ignore all cached context. Checked eagerly for
     concrete lengths, via checkify with ``checked=True`` for traced
     ones."""
+    cfg.refuse_layered("advance / generate")
     capacity = cache["k"].shape[2]
     if tokens.shape[1] > capacity:
         # RoPE tables and the cache are both static; overflow would clamp
@@ -415,7 +450,7 @@ def advance(params: dict, cache: dict, tokens: jax.Array,
                 "prefill=True on a non-empty cache (length {l})",
                 l=cache["length"],
             )
-    if "qkv" not in params["layers"]:
+    if not is_fused(params):
         # Raw training params from an eager caller: fuse per call (generate
         # fuses once, outside its token loop).
         params = decode_weights(params, cfg)
@@ -445,7 +480,7 @@ def advance(params: dict, cache: dict, tokens: jax.Array,
     )
     # Only the last position is ever sampled — slice BEFORE the unembed so
     # prefill never materializes [B, S, V] logits.
-    x = rms_norm(x[:, -1:], params["final_norm"]).astype(dt)
+    x = rms_norm(x[:, -1:], params["final_norm"], eps=cfg.rms_eps).astype(dt)
     logits = jnp.einsum(
         "btd,dv->btv", x, params["unembed"]
     )[:, 0].astype(jnp.float32)
@@ -556,7 +591,8 @@ def generate(
         raise ValueError(f"top_p must be in (0, 1], got {top_p}")
     if key is None:
         key = jax.random.key(0)  # unused in greedy mode
-    if "qkv" not in params["layers"]:
+    cfg.refuse_layered("generate")
+    if not is_fused(params):
         params = _decode_weights_jit(params, cfg)
     if eos_id is not None:
         toks, lengths = _generate_loop_eos(
@@ -634,7 +670,7 @@ class DecodeSession:
         """Re-fuse from (possibly updated) training params; accepts
         already-fused layouts as-is. Under a mesh, (re-)place the fused
         weights to their serving shardings."""
-        if "qkv" in params["layers"]:
+        if is_fused(params):
             fused = params
         elif self.mesh is not None:
             with jax.sharding.set_mesh(self.mesh):

@@ -70,16 +70,6 @@ class TransformerConfig:
     # added to the LM loss by lm_loss(); 0 disables.
     moe_balance_coef: float = 0.01
     moe_zloss_coef: float = 1e-3
-    # MoE decode-time expert evaluation (models/decode.py): "dense"
-    # streams every expert and zero-weights the unselected; "routed" runs
-    # only the top-k experts per token via weight gathers. Measured on
-    # v5e (r4): dense WINS at every tested point — E=16/B=8 1.27 vs 1.52
-    # ms/step, E=64/B=4 1.71 vs 3.94 — because decode MoE is
-    # bandwidth-bound and XLA streams the stacked expert weights near
-    # roofline while per-token weight gathers do not; "auto" therefore
-    # resolves to dense. "routed" stays available for regimes where
-    # B·K ≪ E AND expert weights exceed what a step can stream.
-    moe_decode_mode: str = "auto"
     dtype: str = "bfloat16"
     remat: bool = True
     # "full": recompute the whole layer in backward (min memory);
@@ -97,6 +87,60 @@ class TransformerConfig:
     # use scan's own unroll. 1 = rolled (default; dryruns/tests compile
     # fast).
     layer_scan_unroll: int = 1
+    # RMSNorm epsilon, every norm of the model.
+    rms_eps: float = 1e-6
+    # -- Facts of a model whose layers are not all alike. Any of them set
+    # makes the configuration ``layered``: its parameters are groups of
+    # stacks by layer kind (``layer_groups``), the serving engine runs it
+    # through its one layer definition with a cache per attention kind,
+    # and ``forward`` / ``generate`` refuse it (never a silently uniform
+    # model). ``head_dim`` is the q/k width.
+    v_head_dim: int = 0          # value width; 0 = head_dim
+    rotary_dim: int = 0          # leading dims of q/k that rotate; 0 = all
+    v_scale: float = 1.0         # v = v_scale * (h @ Wv)
+    # Per layer "full" | "window"; () = every layer full. A window layer
+    # sees the last ``window`` positions (its own included), has its own
+    # KV head count and rope base, and with ``window_sink`` a learnt
+    # per-head bias in the softmax denominator.
+    attn_kinds: tuple = ()
+    window: int = 0
+    window_kv_heads: int = 0     # 0 = n_kv_heads
+    window_rope_theta: float = 0.0   # 0 = rope_theta
+    window_sink: bool = False
+    # Leading layers with a dense SwiGLU of width ``dense_d_ff`` before
+    # the expert layers (``d_ff`` is then an expert's width).
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
+    # Router: "softmax" scores as above, or "sigmoid" scores with the
+    # chosen ones normalised; ``router_bias`` adds a learnt per-expert
+    # bias to the scores for the CHOICE only (it never weighs).
+    router_scoring: str = "softmax"
+    router_bias: bool = False
+    # The experts this device holds of each layer's ``n_experts``:
+    # (first, count). The router stays ``n_experts`` wide and the layer
+    # computes its own experts' part of the result; None = all.
+    experts_held: tuple | None = None
+
+    def __post_init__(self):
+        if self.attn_kinds and len(self.attn_kinds) != self.n_layers:
+            raise ValueError(
+                f"attn_kinds names {len(self.attn_kinds)} layers, the "
+                f"model has {self.n_layers}")
+        if set(self.attn_kinds) - {"full", "window"}:
+            raise ValueError(f"unknown attention kind in {self.attn_kinds}")
+        if "window" in self.attn_kinds and self.window < 1:
+            raise ValueError("window layers need window >= 1")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown router_scoring {self.router_scoring!r}")
+        if self.n_dense_layers and not (self.n_experts and self.dense_d_ff):
+            raise ValueError(
+                "n_dense_layers needs n_experts and dense_d_ff")
+        first, count = self.held
+        if not 0 <= first <= first + count <= max(self.n_experts, 0):
+            raise ValueError(
+                f"experts_held {self.experts_held} outside 0.."
+                f"{self.n_experts}")
 
     @property
     def compute_dtype(self):
@@ -104,12 +148,69 @@ class TransformerConfig:
 
     @property
     def kv_heads(self) -> int:
+        return self.kv_heads_of("full")
+
+    def kv_heads_of(self, attn_kind: str) -> int:
         kv = self.n_kv_heads or self.n_heads
+        if attn_kind == "window":
+            kv = self.window_kv_heads or kv
         if self.n_heads % kv:
             raise ValueError(
                 f"n_kv_heads {kv} must divide n_heads {self.n_heads}"
             )
         return kv
+
+    def rope_theta_of(self, attn_kind: str) -> float:
+        if attn_kind == "window" and self.window_rope_theta:
+            return self.window_rope_theta
+        return self.rope_theta
+
+    @property
+    def v_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def rot_dim(self) -> int:
+        return self.rotary_dim or self.head_dim
+
+    @property
+    def held(self) -> tuple:
+        """(first, count) of the experts held here."""
+        return tuple(self.experts_held or (0, self.n_experts))
+
+    @property
+    def layered(self) -> bool:
+        return bool(
+            self.attn_kinds or self.v_head_dim or self.rotary_dim
+            or self.v_scale != 1.0 or self.n_dense_layers
+            or self.router_scoring != "softmax" or self.router_bias
+            or self.experts_held)
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """(attention kind, MLP kind) of every layer."""
+        attn = self.attn_kinds or ("full",) * self.n_layers
+        return tuple(
+            (a, "moe" if self.n_experts and i >= self.n_dense_layers
+             else "dense")
+            for i, a in enumerate(attn))
+
+    @property
+    def layer_groups(self) -> dict:
+        """Kind name ("window_moe") -> its layers in order: the groups
+        of stacks a layered configuration's parameters come in."""
+        groups: dict = {}
+        for i, (a, m) in enumerate(self.layer_kinds):
+            groups.setdefault(f"{a}_{m}", []).append(i)
+        return {k: tuple(v) for k, v in groups.items()}
+
+    def refuse_layered(self, what: str) -> None:
+        if self.layered:
+            raise ValueError(
+                f"{what} runs uniform layers only; this configuration "
+                f"has layer kinds, its own v/rotary widths, or a share "
+                f"of its experts (TransformerConfig.layered): serve it "
+                f"through ServingEngine")
 
 
 # ---------------------------------------------------------------------------
@@ -128,6 +229,19 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
 
     def norm(k, shape, scale):
         return (jax.random.normal(k, shape, jnp.float32) * scale)
+
+    if cfg.layered:
+        groups = {
+            name: _init_group(jax.random.fold_in(keys[1], i), cfg, name,
+                              len(layers))
+            for i, (name, layers) in enumerate(cfg.layer_groups.items())
+        }
+        return {
+            "embed": norm(keys[0], (cfg.vocab_size, d), 1.0),
+            "layers": groups,
+            "final_norm": jnp.ones((d,), jnp.float32),
+            "unembed": norm(keys[9], (d, cfg.vocab_size), d ** -0.5),
+        }
 
     layer = {
         "ln1": jnp.ones((l, d), jnp.float32),
@@ -156,10 +270,49 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
     }
 
 
+def _init_group(key, cfg: TransformerConfig, name: str, n: int) -> dict:
+    """One group of a layered configuration: ``n`` layers of one
+    (attention kind, MLP kind), each leaf stacked [n, ...]."""
+    attn, mlp = name.split("_")
+    d, h, dk, dv = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.v_dim
+    hkv = cfg.kv_heads_of(attn)
+    keys = jax.random.split(key, 10)
+
+    def norm(k, shape, scale):
+        return jax.random.normal(k, (n,) + shape, jnp.float32) * scale
+
+    group = {
+        "ln1": jnp.ones((n, d), jnp.float32),
+        "wq": norm(keys[0], (d, h, dk), d ** -0.5),
+        "wk": norm(keys[1], (d, hkv, dk), d ** -0.5),
+        "wv": norm(keys[2], (d, hkv, dv), d ** -0.5),
+        "wo": norm(keys[3], (h, dv, d), (h * dv) ** -0.5),
+        "ln2": jnp.ones((n, d), jnp.float32),
+    }
+    if attn == "window" and cfg.window_sink:
+        group["sink"] = norm(keys[4], (h,), 1.0)
+    if mlp == "moe":
+        e, f = cfg.n_experts, cfg.d_ff
+        held = cfg.held[1]
+        group["router"] = norm(keys[5], (d, e), d ** -0.5)
+        if cfg.router_bias:
+            group["router_bias"] = norm(keys[9], (e,), 0.1)
+        group["w_gate"] = norm(keys[6], (held, d, f), d ** -0.5)
+        group["w_up"] = norm(keys[7], (held, d, f), d ** -0.5)
+        group["w_down"] = norm(keys[8], (held, f, d), f ** -0.5)
+    else:
+        f = cfg.dense_d_ff if cfg.n_dense_layers else cfg.d_ff
+        group["w_gate"] = norm(keys[6], (d, f), d ** -0.5)
+        group["w_up"] = norm(keys[7], (d, f), d ** -0.5)
+        group["w_down"] = norm(keys[8], (f, d), f ** -0.5)
+    return group
+
+
 def param_roles(cfg: TransformerConfig) -> dict:
     """Logical-axis roles per leaf (sharding.py LOGICAL_RULES maps roles to
     mesh axes): tp splits heads/mlp/vocab, fsdp splits the embed dim, pp
     stages the stacked layers axis, ep splits experts."""
+    cfg.refuse_layered("param_roles (the training layout)")
     layer = {
         "ln1": ("layers", None),
         "wq": ("layers", "embed_fsdp", "heads", None),
@@ -199,7 +352,7 @@ def _attention(x, lp, cfg, cos, sin, *, manual: bool, mesh: Mesh | None):
     RoPE positions offset by the shard's global start.
     """
     dt = cfg.compute_dtype
-    h = rms_norm(x, lp["ln1"], mesh=mesh).astype(dt)
+    h = rms_norm(x, lp["ln1"], eps=cfg.rms_eps, mesh=mesh).astype(dt)
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"].astype(dt))
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"].astype(dt))
     v = jnp.einsum("btd,dhk->bthk", h, lp["wv"].astype(dt))
@@ -250,7 +403,7 @@ def _dense_mlp(
     ``constrain=False`` skips the sharding constraint for mesh-free callers
     (the KV-cache decode path reuses this exact math)."""
     dt = cfg.compute_dtype
-    h = rms_norm(x, lp["ln2"], mesh=mesh).astype(dt)
+    h = rms_norm(x, lp["ln2"], eps=cfg.rms_eps, mesh=mesh).astype(dt)
     g = jnp.einsum("btd,df->btf", h, lp["w_gate"].astype(dt))
     u = jnp.einsum("btd,df->btf", h, lp["w_up"].astype(dt))
     act = jax.nn.silu(g.astype(jnp.float32)).astype(dt) * u
@@ -262,18 +415,30 @@ def _dense_mlp(
     return with_logical_constraint(out, "batch", "seq", "embed", mesh=mesh)
 
 
-def _route_tokens(hn, router, top_k: int):
+def _route_tokens(hn, router, top_k: int, *, scoring: str = "softmax",
+                  bias=None):
     """Shared router gating for training AND decode (models/decode.py):
-    fp32 logits + softmax, top-k over probabilities, epsilon-guarded
-    renormalization of the selected weights. One implementation so the
-    decode-vs-training token-exact parity cannot drift. Returns
-    (gate_logits [.., E] f32, probs [.., E], gvals [.., k] normalized,
-    gidx [.., k])."""
+    fp32 logits (at ``highest`` matmul precision: on a TPU a float32
+    product is otherwise rounded to bfloat16 first, and a near tie then
+    flips), scores by softmax or sigmoid, top-k over the scores — plus
+    ``bias`` [E] where the model has one, which chooses and never weighs
+    — and epsilon-guarded renormalization of the selected scores. One
+    implementation so the decode-vs-training token-exact parity cannot
+    drift. Returns (gate_logits [.., E] f32, probs [.., E], gvals
+    [.., k] normalized, gidx [.., k])."""
     gate_logits = jnp.einsum(
-        "btd,de->bte", hn.astype(jnp.float32), router.astype(jnp.float32)
+        "btd,de->bte", hn.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
     )
-    probs = jax.nn.softmax(gate_logits, axis=-1)
-    gvals, gidx = lax.top_k(probs, top_k)
+    if scoring == "sigmoid":
+        probs = jax.nn.sigmoid(gate_logits)
+    else:
+        probs = jax.nn.softmax(gate_logits, axis=-1)
+    if bias is None:
+        gvals, gidx = lax.top_k(probs, top_k)
+    else:
+        _, gidx = lax.top_k(probs + bias.astype(jnp.float32), top_k)
+        gvals = jnp.take_along_axis(probs, gidx, axis=-1)
     gvals = gvals / jnp.maximum(gvals.sum(-1, keepdims=True), 1e-9)
     return gate_logits, probs, gvals, gidx
 
@@ -297,7 +462,7 @@ def _moe_mlp(x, lp, cfg, mesh: Mesh):
     e, kk = cfg.n_experts, cfg.expert_top_k
     cap = max(1, int(cfg.capacity_factor * b * t * kk / e))
 
-    hn = rms_norm(x, lp["ln2"], mesh=mesh)
+    hn = rms_norm(x, lp["ln2"], eps=cfg.rms_eps, mesh=mesh)
     gate_logits, probs, gvals, gidx = _route_tokens(hn, lp["router"], kk)
     onehot_e = jax.nn.one_hot(gidx, e, dtype=jnp.float32)  # [b,t,k,E]
 
@@ -373,7 +538,7 @@ def _moe_mlp_manual(x, lp, cfg):
     e_local = lp["w_gate"].shape[0]  # E / ep resident experts
     cap = max(1, int(cfg.capacity_factor * b * t * kk / e))
 
-    hn = rms_norm(x, lp["ln2"])
+    hn = rms_norm(x, lp["ln2"], eps=cfg.rms_eps)
     gate_logits, probs, gvals, gidx = _route_tokens(hn, lp["router"], kk)
     onehot_e = jax.nn.one_hot(gidx, e, dtype=jnp.float32)  # [b,t,k,E]
 
@@ -467,6 +632,7 @@ def forward(
     ``return_aux=True`` additionally returns the layer-averaged MoE router
     aux dict (balance/z losses + diagnostics; empty dict for dense
     configs) — the train loss needs it, inference callers don't."""
+    cfg.refuse_layered("forward")
     dt = cfg.compute_dtype
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, theta=cfg.rope_theta)
     x = params["embed"][tokens].astype(dt)
@@ -496,7 +662,7 @@ def forward(
         x, aux_layers = lax.scan(
             layer_fn, x, params["layers"], unroll=cfg.layer_scan_unroll
         )
-    x = rms_norm(x, params["final_norm"], mesh=mesh).astype(dt)
+    x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps, mesh=mesh).astype(dt)
     logits = jnp.einsum("btd,dv->btv", x, params["unembed"].astype(dt))
     logits = with_logical_constraint(logits, "batch", "seq", "vocab", mesh=mesh)
     if not return_aux:
@@ -565,6 +731,7 @@ def forward_pipeline(
     device v round-robin chunks of n_layers/(v·pp) layers (Megatron
     virtual stages) — the bubble shrinks ~v-fold; see
     ``parallel.pipeline.schedule_info``."""
+    cfg.refuse_layered("forward_pipeline")
     pp = mesh.shape["pp"]
     if cfg.n_experts and cfg.n_experts % mesh.shape.get("ep", 1):
         raise ValueError(
@@ -662,7 +829,7 @@ def forward_pipeline(
     else:
         x, aux = out, {}
     x = with_logical_constraint(x, "batch", "seq", "embed", mesh=mesh)
-    x = rms_norm(x, params["final_norm"], mesh=mesh).astype(dt)
+    x = rms_norm(x, params["final_norm"], eps=cfg.rms_eps, mesh=mesh).astype(dt)
     logits = jnp.einsum("btd,dv->btv", x, params["unembed"].astype(dt))
     logits = with_logical_constraint(logits, "batch", "seq", "vocab", mesh=mesh)
     if not return_aux:

@@ -14,6 +14,7 @@ from tony_tpu.ops.attention import (
     flash_attention_lse,
     grouped_cache_attention,
 )
+from tony_tpu.ops.grouped import grouped_matmul
 from tony_tpu.ops.norms import rms_norm
 from tony_tpu.ops.rope import apply_rope, rope_frequencies
 from tony_tpu.ops.losses import softmax_cross_entropy
@@ -23,6 +24,7 @@ __all__ = [
     "grouped_cache_attention",
     "flash_attention",
     "flash_attention_lse",
+    "grouped_matmul",
     "rms_norm",
     "apply_rope",
     "rope_frequencies",

@@ -831,12 +831,27 @@ def flash_attention_lse(
 # straight out of the stacked buffer and nothing slab-shaped exists.
 
 
-def grouped_cache_attention(q, k, v, mask, *, scale=None):
+def ring_positions(cur, n_rows: int, ring: int):
+    """Position held by each row of a ring cache whose newest entry is
+    position ``cur`` (any shape; a trailing axis of ``n_rows`` is added):
+    row r holds the largest position q <= cur with q % ring == r. Rows
+    at or past ``ring`` (the parking row) and rows never written since
+    position 0 read negative, which every mask refuses — so a ring is
+    masked by POSITION and a reused slot cannot show its last tenant."""
+    r = jnp.arange(n_rows)
+    held = cur[..., None] - (cur[..., None] - r) % ring
+    return jnp.where(r < ring, held, -1)
+
+
+def grouped_cache_attention(q, k, v, mask, *, scale=None, sink=None):
     """Grouped attention against cache rows in plain JAX — q
-    [B, Sq, Hq, Dh] regrouped [B, Sq, Hkv, G, Dh] so GQA never
-    head-repeats the cache k/v [B, T, Hkv, Dh]; stored-dtype reads with
-    fp32 MXU accumulation and fp32 softmax (the decode.py recipe).
-    mask: [B, Sq, T] True where the key is visible."""
+    [B, Sq, Hq, Dk] regrouped [B, Sq, Hkv, G, Dk] so GQA never
+    head-repeats the cache k [B, T, Hkv, Dk] / v [B, T, Hkv, Dv]
+    (Dv may differ from Dk); stored-dtype reads with fp32 MXU
+    accumulation and fp32 softmax (the decode.py recipe).
+    mask: [B, Sq, T] True where the key is visible. ``sink`` [Hq]: one
+    more softmax column per query head that takes mass and adds no
+    value. -> [B, Sq, Hq, Dv]."""
     b, s, n_h, d = q.shape
     h_kv = k.shape[2]
     if scale is None:
@@ -846,26 +861,43 @@ def grouped_cache_attention(q, k, v, mask, *, scale=None):
         "bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32,
     ) * scale
     scores = jnp.where(mask[:, None, None], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1)
+    else:
+        b_g = sink.astype(jnp.float32).reshape(1, h_kv, -1, 1, 1)
+        m = jnp.maximum(scores.max(-1, keepdims=True), b_g)
+        e = jnp.exp(scores - m)
+        probs = e / (e.sum(-1, keepdims=True) + jnp.exp(b_g - m))
     return jnp.einsum(
         "bhgqk,bkhd->bqhgd", probs.astype(q.dtype), v,
         preferred_element_type=jnp.float32,
-    ).astype(q.dtype).reshape(b, s, n_h, d)
+    ).astype(q.dtype).reshape(b, s, n_h, v.shape[-1])
 
 
 def _cache_decode_kernel(
-    layer_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, scale, h_kv,
+    layer_ref, pos_ref, *refs, scale, h_kv, window, ring, has_sink, k_parts,
 ):
-    """One program = one (slot, key block). Refs: q_ref/o_ref [Hq, Dh];
-    k_ref/v_ref [block, Dh], the rows (t, kv-head) of ``block / Hkv``
-    positions exactly as they lie in the cache — so both matmuls run on
-    the stored layout, every query head against every kv-head's rows,
-    and the mask keeps a head's own group (all but 1/Hkv of the MXU
-    work is discarded; the MXU is otherwise idle in decode and the
-    relayout it saves is not free). Key block 0 holds position 0, which
-    every slot sees, so m is finite from the first block on and masked
-    scores underflow to p = 0 with no guard."""
+    """One program = one (slot, key block). Refs: q_ref [Hq, Dk], o_ref
+    [Hq, Dv]; k_ref [block, Dk] / v_ref [block, Dv], the rows (t,
+    kv-head) of ``block / Hkv`` positions exactly as they lie in the
+    cache — so both matmuls run on the stored layout, every query head
+    against every kv-head's rows, and the mask keeps a head's own group
+    (all but 1/Hkv of the MXU work is discarded; the MXU is otherwise
+    idle in decode and the relayout it saves is not free).
+
+    Full cache (``window`` 0): key block 0 holds position 0, which every
+    slot sees, so m is finite from the first block on and masked scores
+    underflow to p = 0 with no guard. Ring cache: row r holds position
+    pos - (pos - r) % ring, visible inside the last ``window`` positions
+    and not before position 0; a block may hold no visible row, so p is
+    zeroed under the mask. ``sink_ref`` [Hq, 1]: folded into the
+    denominator when the last block has been seen."""
+    q_ref, *k_refs = refs[:1 + k_parts]
+    rest = refs[1 + k_parts:]
+    if has_sink:
+        v_ref, sink_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        v_ref, o_ref, m_ref, l_ref, acc_ref = rest
     slot, kb = pl.program_id(0), pl.program_id(1)
 
     @pl.when(kb == 0)
@@ -874,19 +906,31 @@ def _cache_decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    s = lax.dot_general(
-        q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    # K in lane tiles (one, or several for a head wider than a tile):
+    # the score is the sum of the tiles' products.
+    lanes = k_refs[0].shape[-1]
+    s = sum(
+        lax.dot_general(
+            q_ref[:, i * lanes:(i + 1) * lanes], k_ref[...],
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        ) for i, k_ref in enumerate(k_refs)
     ) * scale                                              # [Hq, block]
     group = s.shape[0] // h_kv
     head = lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
     row = lax.broadcasted_iota(jnp.int32, s.shape, 1) + kb * s.shape[1]
-    visible = (row % h_kv == head) & (row // h_kv <= pos_ref[slot])
+    cur = pos_ref[slot]
+    if window:
+        back = (cur - row // h_kv) % ring        # how far behind ``cur``
+        visible = (row % h_kv == head) & (back < window) & (back <= cur)
+    else:
+        visible = (row % h_kv == head) & (row // h_kv <= cur)
     s = jnp.where(visible, s, NEG_INF)
     m = m_ref[...]
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m - m_new)
     p = jnp.exp(s - m_new)
+    if window:
+        p = jnp.where(visible, p, 0.0)
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
     acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
         p.astype(v_ref.dtype), v_ref[...],
@@ -896,44 +940,77 @@ def _cache_decode_kernel(
 
     @pl.when(kb == pl.num_programs(1) - 1)
     def _finalize():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        l = l_ref[...]
+        if has_sink:
+            # The sink as one more column: exp(b - m) joins the sum. m
+            # is a real score here (the newest position is always
+            # visible); a sink above it is rescaled like any late max.
+            b = sink_ref[...]
+            m_all = jnp.maximum(m_ref[...], b)
+            beta = jnp.exp(m_ref[...] - m_all)
+            o_ref[...] = (acc_ref[...] * beta
+                          / (l * beta + jnp.exp(b - m_all))
+                          ).astype(o_ref.dtype)
+        else:
+            o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _cache_decode_pallas(q, k_all, v_all, layer, pos, *, scale, block_rows,
-                         interpret=False):
+def _cache_decode_pallas(q, *operands, n_k, has_sink, scale, block_rows,
+                         window=0, ring=0, interpret=False):
+    """``operands``: the ``n_k`` lane tiles of K, V, layer, pos and, with
+    ``has_sink``, the sinks."""
     from jax.experimental.pallas import tpu as pltpu
 
-    n_l, n_s, t, h_kv, d = k_all.shape
+    k_parts, (v_all, layer, pos, *sink) = operands[:n_k], operands[n_k:]
+    n_l, n_s, t, h_kv, lanes = k_parts[0].shape
+    d_v = v_all.shape[-1]
     n_h = q.shape[1]
-    # [.., Tmax, Hkv, Dh] -> [.., Tmax * Hkv, Dh]: the same bytes under the
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, n_k * lanes - q.shape[-1])))
+    # [.., Tmax, Hkv, D] -> [.., Tmax * Hkv, D]: the same bytes under the
     # TPU's tiling of the two minor dims (a bitcast, no copy).
-    k_all = k_all.reshape(n_l, n_s, t * h_kv, d)
-    v_all = v_all.reshape(n_l, n_s, t * h_kv, d)
-    block = math.gcd(t, max(1, block_rows // h_kv)) * h_kv
-    q_spec = pl.BlockSpec((None, n_h, d), lambda s, j, layer, pos: (s, 0, 0))
-    kv_spec = pl.BlockSpec(
-        (None, None, block, d), lambda s, j, layer, pos: (layer[0], s, j, 0)
-    )
+    k_parts = [k.reshape(n_l, n_s, t * h_kv, lanes) for k in k_parts]
+    v_all = v_all.reshape(n_l, n_s, t * h_kv, d_v)
+    # A ring is read over its ``ring`` positions only: the parking row
+    # past them never enters a block.
+    t_read = ring if window else t
+    block = math.gcd(t_read, max(1, block_rows // h_kv)) * h_kv
+    q_spec = pl.BlockSpec((None, n_h, n_k * lanes),
+                          lambda s, j, layer, pos: (s, 0, 0))
+    o_spec = pl.BlockSpec((None, n_h, d_v),
+                          lambda s, j, layer, pos: (s, 0, 0))
+
+    def kv_spec(d):
+        return pl.BlockSpec(
+            (None, None, block, d),
+            lambda s, j, layer, pos: (layer[0], s, j, 0))
+
+    in_specs = [q_spec] + [kv_spec(lanes)] * n_k + [kv_spec(d_v)]
+    operands = [q, *k_parts, v_all]
+    if has_sink:
+        in_specs.append(pl.BlockSpec((n_h, 1),
+                                     lambda s, j, layer, pos: (0, 0)))
+        operands.append(sink[0].astype(jnp.float32).reshape(n_h, 1))
     return pl.pallas_call(
-        functools.partial(_cache_decode_kernel, scale=scale, h_kv=h_kv),
+        functools.partial(_cache_decode_kernel, scale=scale, h_kv=h_kv,
+                          window=window, ring=ring, has_sink=has_sink,
+                          k_parts=n_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(n_s, t * h_kv // block),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=q_spec,
+            grid=(n_s, t_read * h_kv // block),
+            in_specs=in_specs,
+            out_specs=o_spec,
             scratch_shapes=[
                 pltpu.VMEM((n_h, 1), jnp.float32),
                 pltpu.VMEM((n_h, 1), jnp.float32),
-                pltpu.VMEM((n_h, d), jnp.float32),
+                pltpu.VMEM((n_h, d_v), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_s, n_h, d_v), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(layer.reshape(1).astype(jnp.int32), pos.astype(jnp.int32),
-      q, k_all, v_all)
+    )(layer.reshape(1).astype(jnp.int32), pos.astype(jnp.int32), *operands)
 
 
 # Dim roles of the decode query [S, Hq, Dh] and the stacked cache.
@@ -952,52 +1029,84 @@ def cache_decode_attention(
     block_rows: int = 4096,
     mode: str = "auto",
     mesh: Mesh | None = None,
+    window: int = 0,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """One decode query per slot against ONE layer of a stacked cache.
 
-    q: [S, Hq, Dh]; k_all, v_all: [L, S, Tmax, Hkv, Dh] (Hq % Hkv == 0);
-    ``layer`` a traced scalar; slot s sees keys 0..pos[s] inclusive
-    (pos >= 0). -> [S, Hq, Dh]. The bytes read are the layer's own K/V
-    (all Tmax positions), once; about ``block_rows`` rows (position,
-    kv-head) of each stream through VMEM per grid step (1 MB of bf16 at
-    Dh 128; 2,048 to 8,192 rows measured alike on a v5e). ``mode`` as in
-    ``flash_attention_lse``; "auto" takes the kernel on a TPU where the
-    cache's rows merge without a copy, the plain path elsewhere. Under a
-    multi-device mesh the kernel runs per shard — slots over dp/ep, heads
-    over tp — and refuses a tp that splits the query heads but not the
-    KV heads.
+    q: [S, Hq, Dk]; k_all: [L, S, Tmax, Hkv, Dk], v_all: [L, S, Tmax,
+    Hkv, Dv] (Hq % Hkv == 0; Dv may differ from Dk); ``layer`` a traced
+    scalar; slot s sees keys 0..pos[s] inclusive (pos >= 0).
+    -> [S, Hq, Dv]. The bytes read are the layer's own K/V (all Tmax
+    positions), once; about ``block_rows`` rows (position, kv-head) of
+    each stream through VMEM per grid step (1 MB of bf16 at Dh 128;
+    2,048 to 8,192 rows measured alike on a v5e).
+
+    ``k_all`` may also be a TUPLE of 128-lane tiles [L, S, Tmax, Hkv,
+    128] of a K wider than one tile and no multiple of it, the last
+    zero-filled past Dk (Dk 192: two tiles): under the TPU's tiling a
+    [Tmax, 4, 256] buffer does not merge to rows without a copy of the
+    whole cache and a [Tmax, 4, 128] one does (compiled for a v5e), so
+    such a cache is kept in tiles and the score is the sum of the tiles'
+    products.
+
+    ``window`` > 0 reads the cache as a RING: its third axis holds
+    ``ring`` = Tmax - 1 positions and one parking row; position p lies
+    at row p % ring, and slot s sees the last ``window`` positions up to
+    pos[s] (``ring_positions``). ``sink`` [Hq]: a per-head bias that
+    joins the softmax denominator and adds no value.
+
+    ``mode`` as in ``flash_attention_lse``; "auto" takes the kernel on a
+    TPU where the cache's rows merge without a copy, the plain path
+    elsewhere. Under a multi-device mesh the kernel runs per shard —
+    slots over dp/ep, heads over tp — and refuses a tp that splits the
+    query heads but not the KV heads.
     """
-    h_kv, d = k_all.shape[3:]
+    k_parts = k_all if isinstance(k_all, tuple) else (k_all,)
+    h_kv, d = k_parts[0].shape[3:]
+    ring = k_parts[0].shape[2] - 1 if window else 0
     if scale is None:
-        scale = d ** -0.5
+        scale = q.shape[-1] ** -0.5
     if mode == "auto":
         # [Tmax, Hkv, Dh] is the same bytes as [Tmax * Hkv, Dh] only
         # where the TPU tiles the two minor dims as they stand: Dh in
         # whole 128-lane rows, Hkv a sublane tile (compiled for a v5e:
         # 1, 2, 4, 8, 16, 24 merge; 12, and Dh 64, get another layout
-        # and the reshape would copy the whole cache per call).
-        merges = d % 128 == 0 and (h_kv % 8 == 0 or h_kv in (1, 2, 4))
+        # and the reshape would copy the whole cache per call; so does
+        # Hkv 4 at Dh 256, which is why a wide K comes in tiles).
+        merges = (d % 128 == 0 and v_all.shape[-1] % 128 == 0
+                  and (h_kv % 8 == 0
+                       or (h_kv in (1, 2, 4) and d == 128)))
         mode = "pallas" if merges and _on_tpu(mesh) else "jax"
     if mode == "jax":
-        k, v = (lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
-                for c in (k_all, v_all))
-        mask = jnp.arange(k.shape[1])[None, :] <= pos[:, None]
+        k = jnp.concatenate(
+            [lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+             for c in k_parts], axis=-1)[..., :q.shape[-1]]
+        v = lax.dynamic_index_in_dim(v_all, layer, 0, keepdims=False)
+        if window:
+            held = ring_positions(pos, k.shape[1], ring)
+            mask = (held >= 0) & (held > pos[:, None] - window)
+        else:
+            mask = jnp.arange(k.shape[1])[None, :] <= pos[:, None]
         return grouped_cache_attention(
-            q[:, None], k, v, mask[:, None], scale=scale
+            q[:, None], k, v, mask[:, None], scale=scale, sink=sink
         )[:, 0]
     axes = auto_axes(mesh)
     if (local_spec(q.shape, _DECODE_Q_ROLES, axes)[1]
-            != local_spec(k_all.shape, _CACHE_ROLES, axes)[3]):
+            != local_spec(k_parts[0].shape, _CACHE_ROLES, axes)[3]):
         raise ValueError(
             f"{h_kv} KV heads do not split over the mesh as the "
             f"{q.shape[1]} query heads do"
         )
     local = functools.partial(
-        _cache_decode_pallas, scale=scale, block_rows=block_rows,
+        _cache_decode_pallas, n_k=len(k_parts), has_sink=sink is not None,
+        scale=scale, block_rows=block_rows, window=window, ring=ring,
         interpret=(mode == "interpret"),
     )
-    return per_shard(
-        local, mesh,
-        (_DECODE_Q_ROLES, _CACHE_ROLES, _CACHE_ROLES, (), ("batch",)),
-        q, k_all, v_all, layer, pos,
-    )
+    roles = ([_DECODE_Q_ROLES] + [_CACHE_ROLES] * (len(k_parts) + 1)
+             + [(), ("batch",)])
+    operands = [q, *k_parts, v_all, layer, pos]
+    if sink is not None:
+        roles.append(("heads",))
+        operands.append(sink)
+    return per_shard(local, mesh, tuple(roles), *operands)

@@ -34,9 +34,10 @@ and trace reductions match on them::
       tony:engine.prefill_round    one prefill dispatch (attrs batch, chunk)
         tony:engine.prefill_assemble   host builds the batch
         tony:engine.prefill_device     dispatch -> readback returned
+                                       (attr expert_pairs*)
         tony:engine.emit               first tokens, retirements
       tony:engine.decode_device    dispatch -> readback returned
-                                   (attrs slots, window)
+                                   (attrs slots, window, expert_pairs*)
       tony:engine.emit             the per-token loop, retirements
       tony:engine.publish          gauges + registry report
 
@@ -47,6 +48,12 @@ shipped KV) and ``tony:request.decode`` (first token -> done, attr
 ``tokens``; none for a request that never decoded). ``stats()`` serves
 the counters taken at the same boundaries (``phase_ms``, ``kv``,
 ``queue_wait_ms`` ...). Idle polls record and count nothing.
+(*) A model with experts only: the dispatch's (token, choice) pairs on
+the experts held here, which come back with the tokens in the one
+readback; ``stats()["experts"]`` sums them per held expert. A model with
+window layers has a cache stack per attention kind, and
+``stats()["kv"]["kinds"]`` counts each (the top-level ``kv`` keys stay
+the full kind's).
 
 Greedy parity contract (pinned by tests/test_serving.py): a request
 decoded through the slot engine yields token-for-token the same output
@@ -71,7 +78,7 @@ import numpy as np
 log = logging.getLogger(__name__)
 
 from tony_tpu.analysis import jit_sanitizer
-from tony_tpu.models.decode import _decode_weights_jit
+from tony_tpu.models.decode import _decode_weights_jit, is_fused
 from tony_tpu.models.transformer import TransformerConfig
 from tony_tpu.observability import metrics as obs_metrics
 from tony_tpu.observability import trace as obs_trace
@@ -280,7 +287,7 @@ class ServingEngine:
         self.decode_window = int(decode_window)
         self.prefill_batch = max(1, int(prefill_batch))
         self.max_queue = int(max_queue)
-        if "qkv" in params["layers"]:
+        if is_fused(params):
             self.params = params
         else:
             self.params = _decode_weights_jit(params, cfg)
@@ -297,7 +304,8 @@ class ServingEngine:
         )
         self._model_loaders: dict[str, Callable[[], dict]] = {}
         self._k, self._v = _engine.init_slot_cache(
-            cfg, self.slots, max_len, kv_quant=self.kv_quant
+            cfg, self.slots, max_len, kv_quant=self.kv_quant,
+            prefill_chunk=prefill_chunk,
         )
         self._pos = np.zeros(self.slots, np.int32)
         self._active = np.zeros(self.slots, bool)
@@ -334,10 +342,30 @@ class ServingEngine:
         self._prefill_tokens_valid = 0
         self._prefill_rows_padded = 0
         self._live_position_ns = 0
-        self._kv_bytes_per_position = sum(
-            leaf.nbytes for leaf in jax.tree_util.tree_leaves(
-                (self._k, self._v))
-        ) // (self.slots * max_len)
+        # The cache by attention kind (a uniform model has the one kind,
+        # "full"): positions a slot reserves, bytes of one position over
+        # the kind's layers (K and V as stored), bytes reserved. A window
+        # kind's live positions are min(position, window) a slot.
+        self._kv_kinds: dict[str, dict] = {}
+        self._live_window_position_ns = 0
+        by_kind = [c if isinstance(c, dict) else {"full": c}
+                   for c in (self._k, self._v)]
+        for kind in by_kind[0]:
+            stacks = [c[kind] for c in by_kind]
+            nbytes = sum(leaf.nbytes for leaf in
+                         jax.tree_util.tree_leaves(stacks))
+            rows = _engine._cache_tmax(stacks[0])
+            self._kv_kinds[kind] = {
+                "reserved_positions": self.slots * rows,
+                "bytes_per_position": nbytes // (self.slots * rows),
+                "bytes_reserved": nbytes,
+            }
+        self._kv_bytes_per_position = (
+            self._kv_kinds["full"]["bytes_per_position"])
+        # Pairs on the held experts, as the programs count them.
+        self._expert_pairs = np.zeros(cfg.held[1], np.int64)
+        self._expert_tokens = 0
+        self._expert_dispatches = 0
         self._queue_wait_ms: deque[float] = deque(maxlen=_LATENCY_RING)
         self._prefill_span_ms: deque[float] = deque(maxlen=_LATENCY_RING)
         self._ids = itertools.count()
@@ -476,7 +504,7 @@ class ServingEngine:
             raise ValueError("add_model needs exactly one of "
                              "params/loader")
         if params is not None:
-            if "qkv" not in params["layers"]:
+            if not is_fused(params):
                 params = _decode_weights_jit(params, self.cfg)
             with self._cond:
                 self._resident[name] = params
@@ -505,7 +533,7 @@ class ServingEngine:
             params = self._resident.get(name)
         if params is None:
             raw = self._model_loaders[name]()
-            params = (raw if "qkv" in raw["layers"]
+            params = (raw if is_fused(raw)
                       else _decode_weights_jit(raw, self.cfg))
         with self._cond:
             self._resident[name] = params
@@ -554,13 +582,14 @@ class ServingEngine:
         token; the slot's KV rows are written at admission and decode
         proceeds exactly as if prefill had run here — the per-slot KV
         layout makes the injection one targeted write."""
-        kv_k = np.asarray(kv_k)
-        kv_v = np.asarray(kv_v)
+        kv_k = jax.tree.map(np.asarray, kv_k)
+        kv_v = jax.tree.map(np.asarray, kv_v)
         pos = int(pos)
-        if pos < 1 or kv_k.shape[1] != pos or kv_v.shape[1] != pos:
+        full_k, full_v = (_engine._kind(c, "full") for c in (kv_k, kv_v))
+        if pos < 1 or full_k.shape[1] != pos or full_v.shape[1] != pos:
             raise ValueError(
                 f"kv rows must be [L, pos={pos}, Hkv, Dh]; got "
-                f"{kv_k.shape} / {kv_v.shape}"
+                f"{full_k.shape} / {full_v.shape}"
             )
         if max_new_tokens < 1:
             raise ValueError(f"max_new_tokens must be >= 1, got "
@@ -631,8 +660,24 @@ class ServingEngine:
                     "reserved_positions": self.slots * self.max_len,
                     "bytes_per_position": self._kv_bytes_per_position,
                     "live_position_ms": self._live_position_ns / 1e6,
+                    "kinds": {
+                        kind: dict(row, live_position_ms=(
+                            self._live_position_ns if kind == "full"
+                            else self._live_window_position_ns) / 1e6)
+                        for kind, row in self._kv_kinds.items()
+                    },
                 },
             }
+            if self.cfg.n_experts:
+                n_moe = sum(m == "moe" for _, m in self.cfg.layer_kinds)
+                out["experts"] = {
+                    "held": list(self.cfg.held),
+                    "pairs_per_expert": self._expert_pairs.tolist(),
+                    "pairs_held": int(self._expert_pairs.sum()),
+                    "pairs_total": (self._expert_tokens * n_moe
+                                    * self.cfg.expert_top_k),
+                    "dispatches": self._expert_dispatches,
+                }
             queue_wait = list(self._queue_wait_ms)
             prefill_span = list(self._prefill_span_ms)
         out["queue_wait_ms"] = _summary(queue_wait)
@@ -769,7 +814,8 @@ class ServingEngine:
                 self._working_iters += 1
                 self._working_wall_ns += wall_ns
                 # Positions held at the iteration's end, for its wall.
-                self._live_position_ns += live_positions * wall_ns
+                self._live_position_ns += live_positions[0] * wall_ns
+                self._live_window_position_ns += live_positions[1] * wall_ns
         return working
 
     def _decode_some(self, step_start_ns: int) -> None:
@@ -792,15 +838,19 @@ class ServingEngine:
         with tr.span("tony:engine.decode_device", slots=n_active,
                      window=w) as sp, \
                 jit_sanitizer.step_region("serving_decode_window"):
-            self._k, self._v, window = self._decode(
+            self._k, self._v, window, pair_counts = self._decode(
                 self.params, self._k, self._v, self._pos, wpos,
                 self._last, self._temp, self._base_key,
                 np.int32((self._decode_calls * w) % 2**30),
             )
             self._decode_calls += 1
             # Iteration fence: EXPLICIT readback, so the armed
-            # transfer guard (jit sanitizer) lets it through.
-            toks = np.asarray(jax.device_get(window))  # tony: noqa[TONY-X002] — intended per-window fence
+            # transfer guard (jit sanitizer) lets it through. The
+            # experts' pair counts come back in the same readback.
+            toks, pairs = jax.device_get((window, pair_counts))  # tony: noqa[TONY-X002] — intended per-window fence
+            toks = np.asarray(toks)
+            if pairs is not None:
+                sp.set(expert_pairs=self._note_pairs(pairs, n_active * w))
         it["decode_device"] = sp.dur_ns
         self._decode_iters += 1
         self._decode_slots_sum += n_active
@@ -833,10 +883,12 @@ class ServingEngine:
             self._note_rate(n_new)
         it["emit"] += sp.dur_ns
 
-    def _publish(self, decoded: bool) -> int:
+    def _publish(self, decoded: bool) -> tuple[int, int]:
         """End of an iteration: gauges and the registry report. Returns
         the KV positions written so far in occupied slots (``_pos`` of
-        the decoding ones plus the chunks done of the prefilling)."""
+        the decoding ones plus the chunks done of the prefilling): all
+        of them, which is what a full layer keeps, and the last
+        ``window`` of each slot, which is what a window layer keeps."""
         if not decoded:
             # Idle decay: the rolling-rate gauge must fall to zero when
             # generation stops, or the autoscaler reads phantom load.
@@ -846,13 +898,17 @@ class ServingEngine:
                 self._rate_window.clear()
                 self._g_rate.set(0.0)
         self._iter += 1
-        live_positions = int(self._pos[self._active].sum())
+        written = [self._pos[self._active]]
         with self._cond:
             self._g_queue.set(len(self._queue))
-            for req, _slot in self._pf:
-                if req._chunk_i:
-                    start, n_valid = req._chunks[req._chunk_i - 1]
-                    live_positions += start + n_valid
+            written.append(np.asarray(
+                [sum(req._chunks[req._chunk_i - 1])
+                 for req, _slot in self._pf if req._chunk_i], np.int64))
+        written = np.concatenate(written)
+        live_positions = (
+            int(written.sum()),
+            int(np.minimum(written, self.cfg.window).sum())
+            if "window" in self._kv_kinds else 0)
         self._g_active.set(int(self._active.sum()))
         # Publish (throttled inside the registry): serving metrics only
         # reach the executor heartbeat via the $TONY_METRICS_FILE
@@ -919,10 +975,10 @@ class ServingEngine:
 
         kv_k, kv_v, pos, last = req._inject
         self._k = _engine.cache_inject_rows(
-            self._k, slot, jnp.asarray(kv_k)
+            self._k, slot, jax.tree.map(jnp.asarray, kv_k)
         )
         self._v = _engine.cache_inject_rows(
-            self._v, slot, jnp.asarray(kv_v)
+            self._v, slot, jax.tree.map(jnp.asarray, kv_v)
         )
         self._pos[slot] = pos
         self._last[slot] = last
@@ -994,12 +1050,16 @@ class ServingEngine:
         self._prefill_rows_padded += pb - n
         with tr.span("tony:engine.prefill_device") as sp, \
                 jit_sanitizer.step_region("serving_prefill_chunks"):
-            self._k, self._v, first_toks, _ = self._prefill(
+            self._k, self._v, first_toks, _, pair_counts = self._prefill(
                 self.params, self._k, self._v, toks, slots_a, starts,
                 n_valids, temps, self._base_key,
                 np.int32(2**30 + self._pf_draws % 2**30),
             )
-            firsts = np.asarray(jax.device_get(first_toks))  # tony: noqa[TONY-X002] — intended per-round fence
+            firsts, pairs = jax.device_get((first_toks, pair_counts))  # tony: noqa[TONY-X002] — intended per-round fence
+            firsts = np.asarray(firsts)
+            if pairs is not None:
+                sp.set(expert_pairs=self._note_pairs(
+                    pairs, int(n_valids[:n].sum())))
         it["prefill_device"] += sp.dur_ns
         with tr.span("tony:engine.emit") as sp:
             now = time.perf_counter()
@@ -1029,10 +1089,12 @@ class ServingEngine:
                     P = int(req.prompt.size)
                     with jit_sanitizer.step_region(
                             "serving_prefill_extract"):
-                        req.kv = (
-                            np.asarray(jax.device_get(_engine.cache_export_rows(self._k, slot, P))),  # tony: noqa[TONY-X002] — intended KV export fence
-                            np.asarray(jax.device_get(_engine.cache_export_rows(self._v, slot, P))),  # tony: noqa[TONY-X002] — intended KV export fence
-                        )
+                        req.kv = jax.tree.map(np.asarray, jax.device_get((  # tony: noqa[TONY-X002] — intended KV export fence
+                            _engine.cache_export_rows(
+                                self._k, slot, P, self.cfg.head_dim),
+                            _engine.cache_export_rows(
+                                self._v, slot, P, self.cfg.v_dim),
+                        )))
                     self._retire(slot)
                 elif ((req.eos_id is not None and first == req.eos_id)
                         or req.max_new_tokens <= 1):
@@ -1043,6 +1105,17 @@ class ServingEngine:
                 with self._cond:
                     self._pf.extend(requeue)
         it["emit"] += sp.dur_ns
+
+    def _note_pairs(self, pairs, tokens: int) -> int:
+        """One dispatch's (token, choice) pairs per held expert (a host
+        array: it came back in the dispatch's fenced readback), summed
+        over its expert layers, into stats()["experts"]; returns the
+        dispatch's pairs on held experts (the device span's attr)."""
+        with self._cond:
+            self._expert_pairs += pairs
+            self._expert_tokens += tokens
+            self._expert_dispatches += 1
+        return int(pairs.sum())
 
     def _retire(self, slot: int) -> None:
         req = self._slot_req[slot]
