@@ -19,6 +19,7 @@ import os
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,6 +94,24 @@ def _cache_decode(layers, slots, t, h_kv, group, d):
     return attention.cache_decode_attention, dtypes, shapes
 
 
+def _cache_prefill(layers, slots, t, h_kv, group, tiles, p, c):
+    """A round of ``p`` chunks of ``c`` tokens; K as ``tiles`` 128-lane
+    tiles of a 192-wide row where ``tiles`` is 2."""
+    cache = (layers, slots, t, h_kv, 128)
+    d_k = 192 if tiles == 2 else 128
+    shapes = [(p, c, h_kv * group, d_k)] + [cache] * (tiles + 1) + [
+        (), (p,), (p,), (h_kv * group,)]
+    dtypes = [jnp.bfloat16] * (tiles + 2) + [jnp.int32] * 3 + [jnp.float32]
+
+    def fn(q, *rest):
+        k, (v, layer, row_slots, ends, sink) = rest[:tiles], rest[tiles:]
+        return attention.cache_prefill_attention(
+            q, k if tiles > 1 else k[0], v, layer, row_slots, ends,
+            sink=sink)
+
+    return fn, dtypes, shapes
+
+
 def _rms(rows, d):
     fn = functools.partial(norms._rms_norm_pallas, eps=1e-6, block_rows=256)
     return fn, [jnp.bfloat16, jnp.float32], [(rows, d), (d,)]
@@ -103,7 +122,10 @@ def _rms(rows, d):
 # over a 2048-key cache; the engine's decode read at the serving cells'
 # size (16 layers x 32 slots x 2,048 positions of 8 KV heads x 128, 4
 # query heads a group) and with 32 KV heads (the block must shrink to
-# fit VMEM); RMSNorm at a train (8x2048 rows) and a decode
+# fit VMEM); a prefill round's read at the MiMo cell's size (4 chunks of
+# 128 tokens, 64 query heads over 4 KV heads, K in two lane tiles, 64
+# slots x 8,192 positions) and at Mistral's widths over a long
+# reservation; RMSNorm at a train (8x2048 rows) and a decode
 # (8 rows) row count of the 1B width.
 KERNELS = {
     "flash_fwd_2k_d128": (_flash_fwd, (64, 2048, 2048, 128, 512), 1),
@@ -117,6 +139,8 @@ KERNELS = {
     "flash_decode_tq1": (_flash_fwd, (64, 1, 2048, 128, 512), 1),
     "cache_decode_mistral7b": (_cache_decode, (16, 32, 2048, 8, 4, 128), 1),
     "cache_decode_mha32": (_cache_decode, (2, 8, 2048, 32, 1, 128), 1),
+    "cache_prefill_mimo": (_cache_prefill, (2, 64, 8192, 4, 16, 2, 4, 128), 1),
+    "cache_prefill_gqa8": (_cache_prefill, (2, 8, 32768, 8, 4, 1, 4, 128), 1),
     # Dh 64 (the 200M flagship): the cache's rows do not merge, plain path
     "cache_decode_d64_plain": (_cache_decode, (8, 8, 512, 4, 4, 64), 0),
     "rms_norm_train_rows": (_rms, (16384, 2048), 1),
@@ -268,6 +292,9 @@ def test_serving_programs_hold_no_layer_slab(v5e, program):
     compiled = lowered.compile()
     slab_bytes = slots * t_max * cfg.kv_heads * cfg.head_dim * 2
     assert compiled.memory_analysis().temp_size_in_bytes < slab_bytes
+    # decode: a layer's attention and 3 norms; prefill: the norms alone
+    # (its scores are 33 MB: the batched path, no kernel of its own)
+    assert _mosaic_calls(compiled) == (4 if program == "decode_window" else 3)
 
 
 @pytest.mark.parametrize("program", ["decode_window", "prefill_chunks"])
@@ -330,7 +357,15 @@ def test_layered_serving_programs_copy_no_cache(v5e, program):
             fused, k, v, arr((p, c)), arr((p,)), arr((p,)), arr((p,)),
             arr((p,), jnp.float32), key, arr(()), cfg=cfg,
         ).compile()
-        assert _mosaic_calls(compiled) == 7
-        # the float32 scores of one row of the chunk against all Tmax
-        # keys, and the four slots' rows: under 1.5 GB
-        assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+        # 2 + 2 + 1 norms, the expert layer's 2 products and the full
+        # layer's chunk attention (``cache_prefill_attention``: a round's
+        # scores against 8,192 keys are 1 GiB)
+        assert _mosaic_calls(compiled) == 8
+        # no scores through HBM and no slot's rows read out: nothing of
+        # 2^28 bytes in float32, and far under the 0.33 GB the plain
+        # path held (one row's scores and the four slots' rows)
+        sizes = [int(np.prod([int(n) for n in dims.split(",")])) * 4
+                 for dims in re.findall(r"f32\[([\d,]+)\]",
+                                        compiled.as_text())]
+        assert max(sizes) < 2 ** 28
+        assert compiled.memory_analysis().temp_size_in_bytes < 64e6

@@ -190,7 +190,8 @@ def test_close_leaves_the_counters_as_they_were(served):
     after = eng.stats()
     for key in ("working_iterations", "working_wall_ms", "phase_ms",
                 "decode_iterations", "decode_slots_sum", "prefill_rounds",
-                "prefill_tokens_valid", "prefill_rows_padded", "kv",
+                "prefill_tokens_valid", "prefill_rows_padded",
+                "prefill_keys", "kv",
                 "queue_wait_ms", "prefill_span_ms", "retired"):
         assert after[key] == before_close[key], key
 
@@ -273,6 +274,47 @@ def test_kv_counters_by_cache_kind(served_layered):
         2 * 8 * st["working_wall_ms"]
     assert kinds["window"]["live_position_ms"] < \
         kinds["full"]["live_position_ms"]
+
+
+@pytest.mark.parametrize("layered,limit,read", [
+    # one full layer of 1 KV head, key blocks of gcd(96, 2048) = 32
+    # positions; chunk ends 8 | 8, 13 | 8, 16, 24, 32, 40, 45 read
+    # 32 | 32, 32 | 32, 32, 32, 32, 64, 64
+    (True, 2 ** 13, 32 + 64 + (4 * 32 + 2 * 64)),
+    # the same model with the limit as shipped, and a dense model of two
+    # layers: every row reads its slot's whole reservation
+    (True, None, 9 * 96),
+    (False, None, 9 * 96 * 2),
+], ids=["scores-over-the-limit", "layered", "dense"])
+def test_prefill_keys_count_what_a_chunk_is_given_to_read(monkeypatch,
+                                                          layered, limit,
+                                                          read):
+    """Prompts of 5, 13 and 45 tokens at a chunk of 8 and 96 positions a
+    slot: 9 rows. With the limit on a round's float32 scores lowered to
+    8 KiB the full layer's 24 KiB (2 rows x 8 queries x 4 heads x 96
+    keys) attend through ``cache_prefill_attention`` and a row reads
+    whole key blocks up to its chunk's end; the rings' 4 KiB do not."""
+    from tony_tpu.serving import engine as engine_lib
+
+    if limit:
+        monkeypatch.setattr(engine_lib, "SCORES_LIMIT", limit)
+    engine_lib.prefill_chunks.clear_cache()
+    try:
+        make = _layered_engine if layered else _engine
+        eng = make(slots=2, prefill_chunk=8, prefill_batch=2, max_len=96,
+                   kv_quant="none")
+        reqs = [eng.submit(np.arange(n, dtype=np.int32) % 64, 6)
+                for n in (5, 13, 45)]
+        _drive(eng, reqs)
+        eng.close()
+    finally:
+        engine_lib.prefill_chunks.clear_cache()
+    keys = eng.stats()["prefill_keys"]
+    assert keys["read_positions"] == read
+    assert keys["reserved_positions"] == 9 * 96 * (1 if layered else 2)
+    device = [e for e in _spans(eng)
+              if e["name"] == "tony:engine.prefill_device"]
+    assert sum(e["args"]["keys_read"] for e in device) == read
 
 
 def test_a_uniform_model_has_one_cache_kind_and_no_expert_block(served):

@@ -25,7 +25,10 @@ from yardstick import spec, weights  # noqa: E402
 
 from tony_tpu.models import decode_weights, init_params  # noqa: E402
 from tony_tpu.models.decode import _moe_mlp_decode  # noqa: E402
-from tony_tpu.ops import cache_decode_attention  # noqa: E402
+from tony_tpu.ops import (  # noqa: E402
+    cache_decode_attention,
+    cache_prefill_attention,
+)
 from tony_tpu.serving import ServingEngine  # noqa: E402
 from tony_tpu.serving import engine as engine_lib  # noqa: E402
 
@@ -161,11 +164,34 @@ def prefill_logit_gap(model, reference, chunk, dtype):
     return worst
 
 
-@pytest.mark.parametrize("chunk", [5, 16])
-def test_chunked_prefill_logits_equal_the_references(model, reference, chunk):
+@pytest.fixture
+def scores_limit(monkeypatch):
+    """Lower ``engine.SCORES_LIMIT`` for a test (the programs read it
+    while they trace, so their caches are dropped around the change)."""
+    def lower(limit: int) -> None:
+        monkeypatch.setattr(engine_lib, "SCORES_LIMIT", limit)
+        engine_lib.prefill_chunks.clear_cache()
+
+    yield lower
+    engine_lib.prefill_chunks.clear_cache()
+
+
+@pytest.mark.parametrize("chunk,limit", [(5, None), (16, None), (16, 2 ** 16)],
+                         ids=["5", "16", "16-scores-over-the-limit"])
+def test_chunked_prefill_logits_equal_the_references(model, reference, chunk,
+                                                     limit, scores_limit):
     """Float32 on both sides: 2e-4 is ten times the largest gap seen
     (summation order on logits of order 1), a hundredth of what bfloat16
-    gives (next test)."""
+    gives (next test). The last size with the limit on a round's scores
+    lowered to 64 KiB: the full layers' 96 KiB (4 rows x 16 queries x 4
+    heads x 96 keys in float32) go through ``cache_prefill_attention``,
+    the rings' 33 KiB stay on the batched path."""
+    if limit:
+        scores_limit(limit)
+        tcfg, _ = program(model)
+        k, _ = jax.eval_shape(lambda: engine_lib.init_slot_cache(
+            tcfg, 4, 96, prefill_chunk=chunk))
+        assert engine_lib.prefill_read_block(tcfg, k, 4, chunk) == 32
     worst = prefill_logit_gap(model, reference, chunk, "float32")
     assert worst < 2e-4, worst
 
@@ -394,6 +420,96 @@ def test_a_ring_is_masked_by_position_not_by_row():
                                v.at[:, :, 6:].add(9.0), jnp.int32(0), pos,
                                window=16, mode="jax")
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# -- the prefill kernel, interpret mode against the plain path -------------------
+def prefill_case(h_kv, d_k, starts, slots, chunk=8, t=64, n_h=16,
+                 dtype=jnp.bfloat16):
+    """A stacked cache of 2 layers x 5 slots x ``t`` positions and a
+    round of chunks at ``starts`` in ``slots``; K 192 wide comes as two
+    128-lane tiles."""
+    keys = jax.random.split(jax.random.key(3), 4)
+    q = jax.random.normal(keys[0], (len(starts), chunk, n_h, d_k), dtype)
+    k = jax.random.normal(keys[1], (2, 5, t, h_kv, d_k), dtype)
+    v = jax.random.normal(keys[2], (2, 5, t, h_kv, 128), dtype)
+    sink = jax.random.normal(keys[3], (n_h,)) * 2
+    ends = jnp.asarray(starts, jnp.int32) + chunk
+    return q, k, v, sink, jnp.asarray(slots, jnp.int32), ends
+
+
+@pytest.mark.parametrize("name,h_kv,d_k,sink,starts,slots,block_rows", [
+    # starts: 0, one inside a key block (16 positions), the last chunk
+    # before Tmax; the fourth row duplicates the first as the host pads
+    ("gqa4-k192", 4, 192, False, [0, 21, 56, 0], [2, 0, 4, 2], 64),
+    ("gqa4-k192-sink", 4, 192, True, [0, 21, 56, 0], [2, 0, 4, 2], 64),
+    ("gqa8-k128", 8, 128, False, [0, 21, 56, 0], [2, 0, 4, 2], 64),
+    ("gqa8-k128-sink", 8, 128, True, [40, 40, 40, 40], [1, 1, 1, 1], 64),
+    ("mha-one-block", 16, 128, True, [3, 50], [4, 3], 4096),
+    ("one-kv-head", 1, 128, False, [0, 21, 56], [2, 0, 4], 16),
+    # a float32 cache: a head's rows are read strided where they lie
+    ("gqa4-float32", 4, 128, True, [0, 21, 56], [2, 0, 4], 64),
+])
+def test_cache_prefill_kernel_equals_the_plain_path(name, h_kv, d_k, sink,
+                                                    starts, slots,
+                                                    block_rows):
+    """The kernel (interpret mode) reads the slots' blocks out of the
+    stack up to each chunk's end and gives each KV head's queries their
+    own keys; the plain path reads the slots' rows whole and masks.
+    bfloat16 data, float32 scores and accumulation in both: 2e-2 on
+    values of order 1."""
+    dtype = jnp.float32 if "float32" in name else jnp.bfloat16
+    q, k, v, b, slots, ends = prefill_case(h_kv, d_k, starts, slots,
+                                           dtype=dtype)
+    padded = starts[-1] == starts[0] and int(slots[-1]) == int(slots[0])
+    if padded:
+        q = q.at[-1].set(q[0])
+    k_in = engine_lib._lane_tiles(k, 2) if d_k == 192 else k
+    kw = dict(sink=b if sink else None)
+    want = cache_prefill_attention(q, k_in, v, jnp.int32(1), slots, ends,
+                                   mode="jax", **kw)
+    got = cache_prefill_attention(q, k_in, v, jnp.int32(1), slots, ends,
+                                  mode="interpret", block_rows=block_rows,
+                                  **kw)
+    assert got.shape == want.shape == q.shape[:3] + (128,)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+    # a duplicated row reads what its first reads
+    if padded:
+        np.testing.assert_array_equal(np.asarray(got[-1], np.float32),
+                                      np.asarray(got[0], np.float32))
+
+
+def test_a_short_chunk_is_not_moved_by_its_padded_tail():
+    """A prompt of 5 tokens under a chunk of 8: the chunk wrote garbage
+    at positions 5, 6, 7 (here: rows of 1e4). The 5 valid queries read
+    the same from the kernel as from the plain path."""
+    q, k, v, _, slots, ends = prefill_case(4, 192, [0], [3])
+    k = k.at[:, 3, 5:8].set(1e4)
+    v = v.at[:, 3, 5:8].set(1e4)
+    args = (q, engine_lib._lane_tiles(k, 2), v, jnp.int32(0), slots, ends)
+    want = cache_prefill_attention(*args, mode="jax")
+    got = cache_prefill_attention(*args, mode="interpret", block_rows=64)
+    np.testing.assert_allclose(np.asarray(got[:, :5], np.float32),
+                               np.asarray(want[:, :5], np.float32),
+                               atol=2e-2)
+
+
+@pytest.mark.parametrize("mode", ["jax", "interpret"])
+def test_a_chunk_reads_nothing_past_its_end(mode):
+    """Rows at and past ``ends[r]`` of every slot multiplied by -3 in K
+    and V (a last tenant's, or positions decode will write): the output
+    stays bit for bit, in blocks the kernel skips and in the block the
+    chunk ends inside."""
+    q, k, v, b, slots, ends = prefill_case(4, 128, [0, 21, 40], [2, 0, 4])
+    past = (jnp.arange(64)[None, :] >= jnp.zeros(5, jnp.int32).at[
+        slots].set(ends)[:, None])[None, :, :, None, None]
+    a, c = (cache_prefill_attention(
+        q, kk, vv, jnp.int32(1), slots, ends, sink=b, mode=mode,
+        block_rows=64)
+        for kk, vv in ((k, v), (jnp.where(past, k * -3, k),
+                                jnp.where(past, v * -3, v))))
+    np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                  np.asarray(c, np.float32))
 
 
 # -- forward and generate refuse, never a silently uniform model ---------------

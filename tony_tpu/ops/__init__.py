@@ -10,6 +10,7 @@ recompute used for the backward pass).
 
 from tony_tpu.ops.attention import (
     cache_decode_attention,
+    cache_prefill_attention,
     flash_attention,
     flash_attention_lse,
     grouped_cache_attention,
@@ -21,6 +22,7 @@ from tony_tpu.ops.losses import softmax_cross_entropy
 
 __all__ = [
     "cache_decode_attention",
+    "cache_prefill_attention",
     "grouped_cache_attention",
     "flash_attention",
     "flash_attention_lse",

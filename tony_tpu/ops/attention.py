@@ -843,6 +843,51 @@ def ring_positions(cur, n_rows: int, ring: int):
     return jnp.where(r < ring, held, -1)
 
 
+def cache_rows_merge(h_kv: int, d_k: int, d_v: int) -> bool:
+    """Whether [Tmax, Hkv, D] is the same bytes as [Tmax * Hkv, D] under
+    the TPU's tiling of the two minor dims, so a kernel may read the
+    stacked cache as rows with no copy: D in whole 128-lane rows, Hkv a
+    sublane tile (compiled for a v5e: 1, 2, 4, 8, 16, 24 merge; 12, and
+    Dh 64, get another layout and the reshape would copy the whole
+    cache per call; so does Hkv 4 at Dh 256, which is why a wide K
+    comes in tiles)."""
+    return (d_k % 128 == 0 and d_v % 128 == 0
+            and (h_kv % 8 == 0 or (h_kv in (1, 2, 4) and d_k == 128)))
+
+
+def cache_rows_view(buf):
+    """``buf`` [L, S, T, Hkv, D] as rows [L, S, T * Hkv, D] where that is
+    the same bytes and the TPU would otherwise re-lay the whole buffer
+    around a chunk's write or a slot's read: D whole 128-lane tiles and
+    fewer KV heads than a sublane tile (compiled for a v5e: at 4 heads
+    prefill copied every full-layer buffer to a head-major layout and
+    back, 1 GB each way, per dispatch). Else ``buf`` as it is."""
+    n_l, n_s, t, h_kv, d = buf.shape
+    if d % 128 == 0 and h_kv in (1, 2, 4):
+        return buf.reshape(n_l, n_s, t * h_kv, d)
+    return buf
+
+
+def cache_take(buf, layer, slot, start, n: int):
+    """[n, Hkv, D] of one buffer from (layer, slot, start)."""
+    rows = cache_rows_view(buf)
+    if rows.ndim == buf.ndim:
+        return lax.dynamic_slice(
+            buf, (layer, slot, start, 0, 0), (1, 1, n) + buf.shape[3:])[0, 0]
+    h_kv = buf.shape[3]
+    return lax.dynamic_slice(
+        rows, (layer, slot, start * h_kv, 0), (1, 1, n * h_kv, buf.shape[4])
+    ).reshape((n,) + buf.shape[3:])
+
+
+def cache_slot_rows(buf, layer, slots):
+    """The rows [P, Tmax, Hkv, D] of ``slots`` [P] in one layer of one
+    buffer: P small dynamic slices (on the TPU a gather over the stacked
+    buffer lowers to slices of the WHOLE buffer)."""
+    return jnp.stack([cache_take(buf, layer, slots[i], 0, buf.shape[2])
+                      for i in range(slots.shape[0])])
+
+
 def grouped_cache_attention(q, k, v, mask, *, scale=None, sink=None):
     """Grouped attention against cache rows in plain JAX — q
     [B, Sq, Hq, Dk] regrouped [B, Sq, Hkv, G, Dk] so GQA never
@@ -874,6 +919,40 @@ def grouped_cache_attention(q, k, v, mask, *, scale=None, sink=None):
     ).astype(q.dtype).reshape(b, s, n_h, v.shape[-1])
 
 
+def _online_softmax_step(s, v, m_ref, l_ref, acc_ref, rows=...,
+                         visible=None):
+    """One key block of the running softmax over ``rows`` of the
+    statistics: ``s`` the masked float32 scores [rows, block], ``v``
+    [block, Dv], a ref or a value. ``visible``: zero p under it (a block
+    that may hold no visible key, where m is not yet a real score)."""
+    m = m_ref[rows]
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    if visible is not None:
+        p = jnp.where(visible, p, 0.0)
+    l_ref[rows] = alpha * l_ref[rows] + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[rows] = alpha * acc_ref[rows] + jnp.dot(
+        p.astype(v.dtype), v[...], preferred_element_type=jnp.float32,
+    )
+    m_ref[rows] = m_new
+
+
+def _softmax_result(m_ref, l_ref, acc_ref, sink_ref, dtype):
+    """acc / l when the last block has been seen. ``sink_ref`` [rows,
+    1] or None: the sink as one more column, exp(b - m) joins the sum.
+    m is a real score here (the newest position is always visible); a
+    sink above it is rescaled like any late max."""
+    l = l_ref[...]
+    if sink_ref is None:
+        return (acc_ref[...] / l).astype(dtype)
+    b = sink_ref[...]
+    m_all = jnp.maximum(m_ref[...], b)
+    beta = jnp.exp(m_ref[...] - m_all)
+    return (acc_ref[...] * beta / (l * beta + jnp.exp(b - m_all))
+            ).astype(dtype)
+
+
 def _cache_decode_kernel(
     layer_ref, pos_ref, *refs, scale, h_kv, window, ring, has_sink, k_parts,
 ):
@@ -897,7 +976,7 @@ def _cache_decode_kernel(
     if has_sink:
         v_ref, sink_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
-        v_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        (v_ref, o_ref, m_ref, l_ref, acc_ref), sink_ref = rest, None
     slot, kb = pl.program_id(0), pl.program_id(1)
 
     @pl.when(kb == 0)
@@ -925,34 +1004,13 @@ def _cache_decode_kernel(
     else:
         visible = (row % h_kv == head) & (row // h_kv <= cur)
     s = jnp.where(visible, s, NEG_INF)
-    m = m_ref[...]
-    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m - m_new)
-    p = jnp.exp(s - m_new)
-    if window:
-        p = jnp.where(visible, p, 0.0)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
-        p.astype(v_ref.dtype), v_ref[...],
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
+    _online_softmax_step(s, v_ref, m_ref, l_ref, acc_ref,
+                         visible=visible if window else None)
 
     @pl.when(kb == pl.num_programs(1) - 1)
     def _finalize():
-        l = l_ref[...]
-        if has_sink:
-            # The sink as one more column: exp(b - m) joins the sum. m
-            # is a real score here (the newest position is always
-            # visible); a sink above it is rescaled like any late max.
-            b = sink_ref[...]
-            m_all = jnp.maximum(m_ref[...], b)
-            beta = jnp.exp(m_ref[...] - m_all)
-            o_ref[...] = (acc_ref[...] * beta
-                          / (l * beta + jnp.exp(b - m_all))
-                          ).astype(o_ref.dtype)
-        else:
-            o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[...] = _softmax_result(m_ref, l_ref, acc_ref, sink_ref,
+                                     o_ref.dtype)
 
 
 def _cache_decode_pallas(q, *operands, n_k, has_sink, scale, block_rows,
@@ -1068,15 +1126,7 @@ def cache_decode_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if mode == "auto":
-        # [Tmax, Hkv, Dh] is the same bytes as [Tmax * Hkv, Dh] only
-        # where the TPU tiles the two minor dims as they stand: Dh in
-        # whole 128-lane rows, Hkv a sublane tile (compiled for a v5e:
-        # 1, 2, 4, 8, 16, 24 merge; 12, and Dh 64, get another layout
-        # and the reshape would copy the whole cache per call; so does
-        # Hkv 4 at Dh 256, which is why a wide K comes in tiles).
-        merges = (d % 128 == 0 and v_all.shape[-1] % 128 == 0
-                  and (h_kv % 8 == 0
-                       or (h_kv in (1, 2, 4) and d == 128)))
+        merges = cache_rows_merge(h_kv, d, v_all.shape[-1])
         mode = "pallas" if merges and _on_tpu(mesh) else "jax"
     if mode == "jax":
         k = jnp.concatenate(
@@ -1110,3 +1160,235 @@ def cache_decode_attention(
         roles.append(("heads",))
         operands.append(sink)
     return per_shard(local, mesh, tuple(roles), *operands)
+
+
+# ---------------------------------------------------------------------------
+# A prefill chunk against its slots' rows of the stacked cache
+# ---------------------------------------------------------------------------
+
+
+def rowwise_cache_attention(q, k, v, mask, *, scale=None, sink=None):
+    """``grouped_cache_attention`` one batch row at a time (``lax.map``):
+    for a batch whose float32 scores [B, Hq, Sq, T] would not fit at
+    once."""
+    return lax.map(
+        lambda row: grouped_cache_attention(
+            row[0][None], row[1][None], row[2][None], row[3][None],
+            scale=scale, sink=sink)[0],
+        (q, k, v, mask))
+
+
+def prefill_key_block(t_max: int, h_kv: int, block_rows: int = 2048) -> int:
+    """Positions in one key block of ``cache_prefill_attention``'s kernel:
+    about ``block_rows`` rows (position, kv-head), a divisor of Tmax. A
+    row whose chunk ends at ``end`` reads ``ceil(end / block)`` blocks."""
+    return math.gcd(t_max, max(1, block_rows // h_kv))
+
+
+def _cache_prefill_kernel(
+    layer_ref, slots_ref, ends_ref, *refs, scale, h_kv, group, chunk,
+    block_q, has_sink, k_parts,
+):
+    """One program = one (row, kv-head, key block). Refs: q_ref [C * G,
+    Dk], the head's G query heads of every position of the chunk, rows
+    (position, head in group); k_ref [block * Hkv, 128] per lane tile /
+    v_ref [block * Hkv, Dv], the rows (t, kv-head) of ``block`` positions
+    as they lie in the cache; o_ref [C * G, Dv]. The head's own rows are
+    taken out of a block by a strided read of its float32 copy (a
+    bfloat16 row shares its sublane with the next head's, and Mosaic
+    reads strided only in 32 bits), so each head's queries meet their
+    own keys only: no product is computed to be masked away.
+
+    Query row i of the chunk sees keys 0 .. ends - C + i. Key block 0
+    holds position 0, which every query sees, so m is finite from the
+    first block on and masked scores underflow to p = 0 with no guard;
+    a block past the row's last visible position is not computed (and
+    not fetched: the index map stays on the last live block)."""
+    q_ref, *k_refs = refs[:1 + k_parts]
+    rest = refs[1 + k_parts:]
+    if has_sink:
+        v_ref, sink_ref, o_ref, m_ref, l_ref, acc_ref, *x_refs = rest
+    else:
+        (v_ref, o_ref, m_ref, l_ref, acc_ref, *x_refs), sink_ref = rest, None
+    x_refs = x_refs or [None] * (k_parts + 1)
+    row, head, kb = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    block = v_ref.shape[0] // h_kv
+    end = ends_ref[row]
+
+    @pl.when(kb == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def own_rows(ref, x_ref):
+        """[block, D] of this kv-head out of the block's rows (t, h)."""
+        if h_kv == 1:
+            return ref[...]
+        if ref.dtype.itemsize == 4:
+            return ref[pl.ds(head, block, stride=h_kv), :]
+        x_ref[...] = ref[...].astype(jnp.float32)
+        return x_ref[pl.ds(head, block, stride=h_kv), :].astype(ref.dtype)
+
+    @pl.when(kb * block < end)
+    def _block():
+        ks = [own_rows(k_ref, x_ref) for k_ref, x_ref in zip(k_refs, x_refs)]
+        v = own_rows(v_ref, x_refs[-1])
+        lanes = ks[0].shape[-1]
+        n_q = q_ref.shape[0]
+        for lo in range(0, n_q, block_q):
+            rows = slice(lo, min(lo + block_q, n_q))
+            s = sum(
+                lax.dot_general(
+                    q_ref[rows, i * lanes:(i + 1) * lanes], k,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) for i, k in enumerate(ks)
+            ) * scale                                      # [rows, block]
+            at = lax.broadcasted_iota(jnp.int32, s.shape, 0) + lo
+            key = lax.broadcasted_iota(jnp.int32, s.shape, 1) + kb * block
+            s = jnp.where(key <= end - chunk + at // group, s, NEG_INF)
+            _online_softmax_step(s, v, m_ref, l_ref, acc_ref, rows)
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _finalize():
+        o_ref[...] = _softmax_result(m_ref, l_ref, acc_ref, sink_ref,
+                                     o_ref.dtype)
+
+
+def _cache_prefill_pallas(q, *operands, n_k, has_sink, scale, block_rows,
+                          block_q=512, interpret=False):
+    """``operands``: the ``n_k`` lane tiles of K, V, layer, slots, ends
+    and, with ``has_sink``, the sinks. A head's queries meet a key block
+    ``block_q`` rows at a time (256 and 512 measured alike on a v5e at
+    4 x 128 x 64 queries, all 2,048 at once a fifth slower)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    k_parts, (v_all, layer, slots, ends, *sink) = operands[:n_k], operands[n_k:]
+    n_l, n_s, t, h_kv, lanes = k_parts[0].shape
+    d_v = v_all.shape[-1]
+    p, c, n_h, _ = q.shape
+    group = n_h // h_kv
+    n_q, d_k = c * group, n_k * lanes
+    # [P, C, Hq, Dk] -> [P, Hkv, C * G, Dk]: a kv-head's queries together
+    q = jnp.pad(q, ((0, 0),) * 3 + ((0, d_k - q.shape[-1]),))
+    q = q.reshape(p, c, h_kv, group, d_k).transpose(0, 2, 1, 3, 4)
+    q = q.reshape(p, h_kv, n_q, d_k)
+    # [.., Tmax, Hkv, D] -> [.., Tmax * Hkv, D]: the same bytes under the
+    # TPU's tiling of the two minor dims (a bitcast, no copy).
+    k_parts = [k.reshape(n_l, n_s, t * h_kv, lanes) for k in k_parts]
+    v_all = v_all.reshape(n_l, n_s, t * h_kv, d_v)
+    block = prefill_key_block(t, h_kv, block_rows)
+
+    def q_map(r, h, j, layer, slots, ends):
+        return (r, h, 0, 0)
+
+    def kv_map(r, h, j, layer, slots, ends):
+        # past the row's last visible block: stay there, so the pipeline
+        # issues no new copy
+        return (layer[0], slots[r], jnp.minimum(j, (ends[r] - 1) // block), 0)
+
+    def kv_spec(d):
+        return pl.BlockSpec((None, None, block * h_kv, d), kv_map)
+
+    in_specs = ([pl.BlockSpec((None, None, n_q, d_k), q_map)]
+                + [kv_spec(lanes)] * n_k + [kv_spec(d_v)])
+    operands = [q, *k_parts, v_all]
+    if has_sink:
+        in_specs.append(pl.BlockSpec(
+            (None, n_q, 1), lambda r, h, j, layer, slots, ends: (h, 0, 0)))
+        operands.append(jnp.broadcast_to(
+            sink[0].astype(jnp.float32).reshape(h_kv, 1, group),
+            (h_kv, c, group)).reshape(h_kv, n_q, 1))
+    scratch = [
+        pltpu.VMEM((n_q, 1), jnp.float32),
+        pltpu.VMEM((n_q, 1), jnp.float32),
+        pltpu.VMEM((n_q, d_v), jnp.float32),
+    ]
+    if h_kv > 1 and v_all.dtype.itemsize < 4:
+        # float32 copies of a block, one per operand (own_rows)
+        scratch += [pltpu.VMEM((block * h_kv, d), jnp.float32)
+                    for d in [lanes] * n_k + [d_v]]
+    out = pl.pallas_call(
+        functools.partial(_cache_prefill_kernel, scale=scale, h_kv=h_kv,
+                          group=group, chunk=c, block_q=block_q,
+                          has_sink=has_sink, k_parts=n_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(p, h_kv, t // block),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, None, n_q, d_v), q_map),
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct((p, h_kv, n_q, d_v), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(layer.reshape(1).astype(jnp.int32), slots.astype(jnp.int32),
+      ends.astype(jnp.int32), *operands)
+    return out.reshape(p, h_kv, c, group, d_v).transpose(
+        0, 2, 1, 3, 4).reshape(p, c, n_h, d_v)
+
+
+def cache_prefill_attention(
+    q: jax.Array,
+    k_all,
+    v_all: jax.Array,
+    layer: jax.Array,
+    slots: jax.Array,
+    ends: jax.Array,
+    *,
+    scale: float | None = None,
+    sink: jax.Array | None = None,
+    mode: str = "auto",
+    block_rows: int = 2048,
+) -> jax.Array:
+    """One prefill chunk per row against ONE layer of a stacked cache,
+    the chunk's own K/V already written: the twin of
+    ``cache_decode_attention`` for a chunk whose float32 scores against
+    the whole reservation are too large to hold.
+
+    q: [P, C, Hq, Dk]; k_all: [L, S, Tmax, Hkv, Dk] (or the tuple of
+    128-lane tiles a wide K is kept in), v_all: [L, S, Tmax, Hkv, Dv];
+    ``layer`` a traced scalar; row r reads slot ``slots[r]`` and its
+    query i sees keys 0 .. ends[r] - C + i (``ends`` = the chunks' starts
+    + C, in [C, Tmax]). ``sink`` [Hq] as in the decode kernel.
+    -> [P, C, Hq, Dv].
+
+    The kernel reads each slot's K/V blocks straight out of the stacked
+    buffer, only up to the block that holds position ``ends[r] - 1``
+    (``prefill_key_block`` positions a block; 2,048 rows a block
+    measured 1.6 times as fast as 1,024 on a v5e, and 4,096 pass the
+    16 MB of VMEM a kernel is given), once per KV head, and keeps scores, running maximum, denominator and accumulator on the
+    chip: bfloat16 operands, float32 scores and softmax, probabilities
+    in the compute dtype into the second product with float32
+    accumulation (the plain path's recipe).
+
+    ``mode`` "jax" is the plain path: the slots' rows read out whole and
+    ``grouped_cache_attention`` row by row. "auto" takes the kernel on a
+    TPU where the cache's rows merge without a copy
+    (``cache_rows_merge``) and no mesh axis is left to partition (the
+    slots index the cache's batch axis, which a mesh shards)."""
+    k_parts = k_all if isinstance(k_all, tuple) else (k_all,)
+    t, h_kv, d = k_parts[0].shape[2:]
+    c = q.shape[1]
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    if mode == "auto":
+        takes = (cache_rows_merge(h_kv, d, v_all.shape[-1]) and _on_tpu()
+                 and math.prod(auto_axes().values()) == 1)
+        mode = "pallas" if takes else "jax"
+    if mode == "jax":
+        k = jnp.concatenate([cache_slot_rows(tile, layer, slots)
+                             for tile in k_parts], axis=-1)[..., :q.shape[-1]]
+        v = cache_slot_rows(v_all, layer, slots)
+        sees = ends[:, None] - c + jnp.arange(c)[None, :]        # [P, C]
+        mask = sees[:, :, None] >= jnp.arange(t)[None, None, :]
+        return rowwise_cache_attention(q, k, v, mask, scale=scale, sink=sink)
+    operands = [q, *k_parts, v_all, layer, slots, ends]
+    if sink is not None:
+        operands.append(sink)
+    return _cache_prefill_pallas(
+        *operands, n_k=len(k_parts), has_sink=sink is not None, scale=scale,
+        block_rows=block_rows, interpret=(mode == "interpret"))

@@ -26,7 +26,13 @@ request that ever passes through —
   else at a fixed, configured granularity
   (``tony.serving.prefill-chunk``). Each layer writes the P chunks by
   ``dynamic_update_slice`` at (layer, slot, start) and attends over
-  those P slots' rows only.
+  those P slots only: where the round's float32 scores against the
+  whole reservation fit at once (``SCORES_LIMIT``), over the slots'
+  rows read out, in one batched product; where they do not (a full
+  layer at 8,192 positions under a 128-token chunk), through
+  ``ops.cache_prefill_attention``, a kernel that reads each slot's K/V
+  blocks out of the stacked buffer up to the chunk's last position and
+  keeps the scores on the chip.
 
 Both programs run ONE layer definition (``_serve_layer``), parameterised
 by the layer's attention kind and MLP kind, over one cache interface:
@@ -53,7 +59,8 @@ from them: per dispatch the cache takes S · Hkv · Dh elements per layer
 per buffer in decode and P · C · Hkv · Dh in prefill (2 MB and 8 MB
 over 16 layers of 8 × 128 bf16 heads at 32 slots, 4 × 32-token chunks),
 and gives one pass over the layer's S · Tmax rows in decode (4.3 GB),
-over P · Tmax in prefill (0.5 GB).
+over P · Tmax in prefill (0.5 GB; through the kernel, over the P slots'
+positions before each chunk's end, once per KV head).
 
 Overwrite-before-read invariant: slot reuse never zeroes a cache row.
 A freed slot's stale K/V rows are only ever unmasked after the new
@@ -76,11 +83,19 @@ from tony_tpu.models.transformer import TransformerConfig
 from tony_tpu.ops import (
     apply_rope,
     cache_decode_attention,
+    cache_prefill_attention,
     grouped_cache_attention,
     rms_norm,
     rope_frequencies,
 )
-from tony_tpu.ops.attention import ring_positions
+from tony_tpu.ops.attention import (
+    cache_rows_view,
+    cache_slot_rows,
+    cache_take,
+    prefill_key_block,
+    ring_positions,
+    rowwise_cache_attention,
+)
 
 
 class QuantizedKV(NamedTuple):
@@ -178,40 +193,15 @@ def _write_rows(cache, layer, rows, wpos):
     )
 
 
-def _rows_view(buf):
-    """``buf`` [L, S, T, Hkv, D] as rows [L, S, T * Hkv, D] where that is
-    the same bytes and the TPU would otherwise re-lay the whole buffer
-    around a chunk's write or a slot's read: D whole 128-lane tiles and
-    fewer KV heads than a sublane tile (compiled for a v5e: at 4 heads
-    prefill copied every full-layer buffer to a head-major layout and
-    back, 1 GB each way, per dispatch). Else ``buf`` as it is."""
-    n_l, n_s, t, h_kv, d = buf.shape
-    if d % LANES == 0 and h_kv in (1, 2, 4):
-        return buf.reshape(n_l, n_s, t * h_kv, d)
-    return buf
-
-
 def _put_chunk(buf, layer, slot, start, val):
     """``val`` [C, Hkv, D] into one buffer at (layer, slot, start)."""
-    rows = _rows_view(buf)
+    rows = cache_rows_view(buf)
     if rows.ndim == buf.ndim:
         return lax.dynamic_update_slice(
             buf, val[None, None], (layer, slot, start, 0, 0))
     return lax.dynamic_update_slice(
         rows, val.reshape(1, 1, -1, val.shape[-1]),
         (layer, slot, start * buf.shape[3], 0)).reshape(buf.shape)
-
-
-def _take_chunk(buf, layer, slot, start, n: int):
-    """[n, Hkv, D] of one buffer from (layer, slot, start)."""
-    rows = _rows_view(buf)
-    if rows.ndim == buf.ndim:
-        return lax.dynamic_slice(
-            buf, (layer, slot, start, 0, 0), (1, 1, n) + buf.shape[3:])[0, 0]
-    h_kv = buf.shape[3]
-    return lax.dynamic_slice(
-        rows, (layer, slot, start * h_kv, 0), (1, 1, n * h_kv, buf.shape[4])
-    ).reshape((n,) + buf.shape[3:])
 
 
 def _write_chunk(cache, layer, slot, start, chunk):
@@ -237,13 +227,8 @@ def _read_slots(cache, layer, slots, dt):
     """The rows [P, Tmax, Hkv, Dh] of ``slots`` [P] in one layer, in
     compute dtype: P small dynamic slices (on the TPU a gather over the
     stacked buffer lowers to slices of the WHOLE buffer)."""
-    def take(buf):
-        return jnp.stack([
-            _take_chunk(buf, layer, slots[i], 0, buf.shape[2])
-            for i in range(slots.shape[0])
-        ])
-
-    return _materialize(jax.tree.map(take, cache), dt)
+    return _materialize(jax.tree.map(
+        lambda buf: cache_slot_rows(buf, layer, slots), cache), dt)
 
 
 def ring_rows(cfg: TransformerConfig, prefill_chunk: int) -> int:
@@ -396,26 +381,56 @@ def _write_chunk_ring(cache, layer, slot, start, chunk):
         rolled = jnp.roll(val, off, axis=0)
         for block, mine in ((0, row >= off), (1, row < off)):
             at = (layer, slot, (start - off + block * c) % ring)
-            old = _take_chunk(buf, *at, c)
+            old = cache_take(buf, *at, c)
             buf = _put_chunk(buf, *at, jnp.where(mine, rolled, old))
         return buf
 
     return jax.tree.map(write, cache, _encode(cache, chunk))
 
 
-def _attend_rows(q, k, v, mask, scale, sink):
-    """``grouped_cache_attention``, row by row where the whole batch's
-    float32 scores would pass 256 MiB (a full layer at 8,192 positions
-    under a 128-token chunk), at once where they fit."""
-    b, s, n_h, _ = q.shape
-    if b * s * n_h * k.shape[1] * 4 <= 2 ** 28:
-        return grouped_cache_attention(q, k, v, mask, scale=scale,
-                                       sink=sink)
-    return lax.map(
-        lambda row: grouped_cache_attention(
-            row[0][None], row[1][None], row[2][None], row[3][None],
-            scale=scale, sink=sink)[0],
-        (q, k, v, mask))
+# Float32 scores [P, Hq, C, T] of one layer a prefill round may hold at
+# once, in bytes: under it the round attends in one batched product,
+# over it chunk by chunk through ``cache_prefill_attention``.
+SCORES_LIMIT = 2 ** 28
+
+
+def _scores_fit(p: int, c: int, n_h: int, t: int) -> bool:
+    return p * c * n_h * t * 4 <= SCORES_LIMIT
+
+
+def prefill_read_block(cfg: TransformerConfig, k_all, p: int, c: int) -> int:
+    """Positions in one key block where a round of ``p`` chunks of ``c``
+    tokens attends its FULL layers through ``cache_prefill_attention``
+    (a row then reads whole blocks up to its chunk's end); 0 where it
+    reads every slot's whole reservation: scores that fit at once, or an
+    int8 cache, whose rows are read out to be dequantized."""
+    kc = _kind(k_all, "full")
+    t = _cache_tmax(kc)
+    if isinstance(kc, QuantizedKV) or _scores_fit(p, c, cfg.n_heads, t):
+        return 0
+    return prefill_key_block(t, cfg.kv_heads_of("full"))
+
+
+def _attend_rows(q, kc, vc, at, slots, starts, mask, scale, sink, cfg, attn):
+    """A round's chunks [P, C, Hq, Dk] against layer ``at`` of their
+    slots' cache, the chunks already written. Where the whole batch's
+    float32 scores fit (``SCORES_LIMIT``): the slots' rows read out and
+    one ``grouped_cache_attention`` under ``mask``. Where they do not (a
+    full layer at 8,192 positions under a 128-token chunk): through
+    ``cache_prefill_attention``, which reads the stack where it lies and
+    only up to each chunk's end; a ring or an int8 cache of that size
+    keeps the plain path, row by row."""
+    p, c, n_h, d_k = q.shape
+    dt = cfg.compute_dtype
+    if attn == "full" and prefill_read_block(cfg, kc, p, c):
+        return cache_prefill_attention(q, kc, vc, at, slots, starts + c,
+                                       scale=scale, sink=sink)
+    k = _read_slots(kc, at, slots, dt)[..., :d_k]
+    v = _read_slots(vc, at, slots, dt)
+    attention = (grouped_cache_attention
+                 if _scores_fit(p, c, n_h, k.shape[1])
+                 else rowwise_cache_attention)
+    return attention(q, k, v, mask, scale=scale, sink=sink)
 
 
 def _rope(x, tables, positions, rot: int):
@@ -430,11 +445,13 @@ def _rope(x, tables, positions, rot: int):
 
 
 def _rope_tables(cfg: TransformerConfig) -> dict:
-    """(cos, sin) per attention kind the model has."""
+    """(cos, sin) per attention kind the model has, in the layers' order
+    (a set's order follows the process's hash seed, and with it the
+    program's text and its key in the compile cache)."""
     return {
         kind: rope_frequencies(cfg.rot_dim, cfg.max_seq,
                                theta=cfg.rope_theta_of(kind))
-        for kind in {a for a, _ in cfg.layer_kinds}
+        for kind in dict.fromkeys(a for a, _ in cfg.layer_kinds)
     }
 
 
@@ -668,6 +685,12 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
     its masks are by position, and a position past the prompt is
     written by decode before any query reaches it).
 
+    A layer's chunks attend after all P were written, by
+    ``_attend_rows``: the slots' rows read out and one batched product
+    where the round's float32 scores fit ``SCORES_LIMIT``, else (full
+    layers) ``ops.cache_prefill_attention`` over the stack where it
+    lies, which reads no key past ``starts[i] + C``.
+
     Returns (k_all, v_all, first_tokens [P], logits [P, V] fp32, pairs):
     row i's token samples from position ``n_valids[i] - 1`` — meaningful
     only on a request's FINAL chunk (earlier chunks' sample is
@@ -715,9 +738,8 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
             # Sequential writes, not a vmap-scatter: P is small and
             # duplicate (padding) rows must overwrite cleanly in order.
             kc, vc = lax.fori_loop(0, p, write_one, (kc, vc))
-            o = _attend_rows(
-                q, _read_slots(kc, at, slots, dt)[..., :cfg.head_dim],
-                _read_slots(vc, at, slots, dt), masks[attn], scale, sink)
+            o = _attend_rows(q, kc, vc, at, slots, starts, masks[attn],
+                             scale, sink, cfg, attn)
             k_all = _with_kind(k_all, attn, kc)
             v_all = _with_kind(v_all, attn, vc)
             return o

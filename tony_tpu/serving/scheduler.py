@@ -34,7 +34,7 @@ and trace reductions match on them::
       tony:engine.prefill_round    one prefill dispatch (attrs batch, chunk)
         tony:engine.prefill_assemble   host builds the batch
         tony:engine.prefill_device     dispatch -> readback returned
-                                       (attr expert_pairs*)
+                                       (attrs keys_read, expert_pairs*)
         tony:engine.emit               first tokens, retirements
       tony:engine.decode_device    dispatch -> readback returned
                                    (attrs slots, window, expert_pairs*)
@@ -341,6 +341,15 @@ class ServingEngine:
         self._prefill_rounds = 0
         self._prefill_tokens_valid = 0
         self._prefill_rows_padded = 0
+        # Key positions a round's rows (padding apart) read in the full
+        # layers against what their slots reserve there: whole key
+        # blocks up to the chunk's end where the chunk attends through
+        # ``cache_prefill_attention``, the reservation elsewhere.
+        self._pf_read_block = _engine.prefill_read_block(
+            cfg, self._k, self.prefill_batch, self.prefill_chunk)
+        self._full_layers = sum(a == "full" for a, _ in cfg.layer_kinds)
+        self._prefill_keys_read = 0
+        self._prefill_keys_reserved = 0
         self._live_position_ns = 0
         # The cache by attention kind (a uniform model has the one kind,
         # "full"): positions a slot reserves, bytes of one position over
@@ -656,6 +665,10 @@ class ServingEngine:
                 "prefill_rounds": self._prefill_rounds,
                 "prefill_tokens_valid": self._prefill_tokens_valid,
                 "prefill_rows_padded": self._prefill_rows_padded,
+                "prefill_keys": {
+                    "read_positions": self._prefill_keys_read,
+                    "reserved_positions": self._prefill_keys_reserved,
+                },
                 "kv": {
                     "reserved_positions": self.slots * self.max_len,
                     "bytes_per_position": self._kv_bytes_per_position,
@@ -1048,7 +1061,15 @@ class ServingEngine:
         self._prefill_rounds += 1
         self._prefill_tokens_valid += int(n_valids[:n].sum())
         self._prefill_rows_padded += pb - n
-        with tr.span("tony:engine.prefill_device") as sp, \
+        keys_read = n * self.max_len
+        if self._pf_read_block:      # whole blocks up to each chunk's end
+            blocks = -(-(starts[:n] + self.prefill_chunk)
+                       // self._pf_read_block)
+            keys_read = int(blocks.sum()) * self._pf_read_block
+        keys_read *= self._full_layers
+        self._prefill_keys_read += keys_read
+        self._prefill_keys_reserved += n * self.max_len * self._full_layers
+        with tr.span("tony:engine.prefill_device", keys_read=keys_read) as sp, \
                 jit_sanitizer.step_region("serving_prefill_chunks"):
             self._k, self._v, first_toks, _, pair_counts = self._prefill(
                 self.params, self._k, self._v, toks, slots_a, starts,
