@@ -12,6 +12,7 @@ mixed-length concurrent load rather than in isolation.
 
 from __future__ import annotations
 
+import ast
 import json
 import threading
 import time
@@ -281,6 +282,134 @@ class TestEngineParity:
         assert totals() == before + 2
 
 
+class TestCachePoliciesOverOneLayer:
+    """``advance``, ``decode_window`` and ``prefill_chunks`` run ONE layer
+    (``models.decode.serve_layer``) and differ by cache policy alone, so
+    the same tokens give the same LOGITS whichever of them reads them
+    (the token parity above passes on any argmax that survives)."""
+
+    @pytest.mark.parametrize("chunks", [1, 4])
+    @pytest.mark.parametrize("n_experts", [0, 2])
+    def test_prefill_chunks_logits_match_advance(self, n_experts, chunks):
+        cfg, params = _tiny_setup(n_experts=n_experts)
+        fused = decode_weights(params, cfg)
+        c, t_max = 6, 40
+        n = c * chunks
+        prompts = jnp.asarray(np.random.default_rng(5).integers(
+            0, cfg.vocab_size, (2, n)), jnp.int32)
+        want, _ = decode_lib.advance(
+            fused, decode_lib.init_cache(cfg, 2, n), prompts, cfg,
+            prefill=True)
+        k, v = engine_lib.init_slot_cache(cfg, 3, t_max, prefill_chunk=c)
+        slots = jnp.asarray([2, 0], jnp.int32)
+        for i in range(chunks):
+            k, v, _, got, _ = engine_lib.prefill_chunks(
+                fused, k, v, prompts[:, i * c:(i + 1) * c], slots,
+                jnp.full((2,), i * c, jnp.int32), jnp.full((2,), c, jnp.int32),
+                jnp.zeros((2,), jnp.float32), jax.random.key(0),
+                jnp.int32(0), cfg=cfg)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+    def test_decode_window_tokens_match_advance_beside_parked_lane(self):
+        """GQA, one live slot and one parked lane: eight single-token
+        ``advance`` steps against eight ``decode_window(steps=1)``
+        dispatches from the same prefilled rows."""
+        cfg = TransformerConfig(
+            vocab_size=64, d_model=32, n_layers=2, n_heads=4, head_dim=8,
+            d_ff=64, max_seq=96, dtype="float32", remat=False, n_kv_heads=2,
+        )
+        fused = decode_weights(init_params(jax.random.key(1), cfg), cfg)
+        t_max, n, live = 32, 7, 1
+        prompt = jnp.asarray(np.random.default_rng(9).integers(
+            0, cfg.vocab_size, (1, n)), jnp.int32)
+        logits, cache = decode_lib.advance(
+            fused, decode_lib.init_cache(cfg, 1, t_max), prompt, cfg,
+            prefill=True)
+        k, v = engine_lib.init_slot_cache(cfg, 2, t_max)
+        k = engine_lib.cache_inject_rows(k, live, cache["k"][:, 0, :n])
+        v = engine_lib.cache_inject_rows(v, live, cache["v"][:, 0, :n])
+        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        pos = np.array([0, n], np.int32)
+        wpos = np.array([t_max - 1, n], np.int32)   # lane 0 is parked
+        for step in range(8):
+            logits, cache = decode_lib.advance(fused, cache, tok[:, None], cfg)
+            k, v, got, _ = engine_lib.decode_window(
+                fused, k, v, jnp.asarray(pos), jnp.asarray(wpos),
+                jnp.asarray([0, int(tok[0])], jnp.int32),
+                jnp.zeros((2,), jnp.float32), jax.random.key(0),
+                jnp.int32(step), cfg=cfg, steps=1)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            assert int(got[live, 0]) == int(tok[0]), step
+            pos[live] += 1
+            wpos[live] += 1
+
+
+def _module_ast(relpath: str):
+    path = Path(__file__).resolve().parents[1] / "tony_tpu" / relpath
+    return ast.parse(path.read_text())
+
+
+class TestOneInferenceLayer:
+    """Static: the decoder layer of inference has ONE definition, in
+    ``models/decode.py``, and the cache one storage decision."""
+
+    LAYER_WEIGHTS = {"qkv", "gate_up", "w_down", "ln1", "ln2", "unembed",
+                     "final_norm"}
+
+    def test_engine_holds_no_layer_arithmetic(self):
+        tree = _module_ast("serving/engine.py")
+        subscripts = {
+            node.slice.value for node in ast.walk(tree)
+            if isinstance(node, ast.Subscript)
+            and isinstance(node.slice, ast.Constant)
+        }
+        assert not subscripts & self.LAYER_WEIGHTS
+        called = {
+            getattr(node.func, "id", getattr(node.func, "attr", None))
+            for node in ast.walk(tree) if isinstance(node, ast.Call)
+        }
+        assert "rms_norm" not in called
+        # of models/ it takes the configuration and the layer's public
+        # functions, nothing private
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.ImportFrom)
+                    and (node.module or "").startswith("tony_tpu.models")):
+                names = [a.name for a in node.names]
+                assert not [n for n in names if n.startswith("_")], names
+
+    def test_decode_defines_the_layer_and_the_head_once(self):
+        tree = _module_ast("models/decode.py")
+        defined = [n.name for n in ast.walk(tree)
+                   if isinstance(n, ast.FunctionDef)]
+        assert "_layer_decode" not in defined
+        for name in ("serve_layer", "run_layers", "lm_head"):
+            assert defined.count(name) == 1
+        # the head's weight is read where the logits are made (lm_head)
+        # and where the training layout is re-packed (decode_weights),
+        # and by no other function of the module
+        readers = {
+            fn.name for fn in ast.walk(tree)
+            if isinstance(fn, ast.FunctionDef)
+            for node in ast.walk(fn)
+            if isinstance(node, ast.Subscript)
+            and isinstance(node.slice, ast.Constant)
+            and node.slice.value == "unembed"
+        }
+        assert readers == {"lm_head", "decode_weights"}
+
+    def test_no_module_names_a_quantized_cache(self):
+        root = Path(__file__).resolve().parents[1] / "tony_tpu"
+        files = [root / "serving/engine.py", root / "models/decode.py",
+                 *sorted((root / "conf").iterdir())]
+        named = [
+            str(f) for f in files if f.is_file()
+            and any(word in f.read_text()
+                    for word in ("int8", "QuantizedKV"))
+        ]
+        assert not named
+
+
 def _cache_writes(jaxpr):
     """(primitive, operand shape, update shape) of every
     ``dynamic_update_slice`` / ``scatter*`` in ``jaxpr``, the bodies of
@@ -306,13 +435,12 @@ class TestCacheWritesAreRows:
     buffer, per layer, per dispatch: over half the device time of both
     serving cells.)"""
 
-    @pytest.mark.parametrize("kv_quant", ["none", "int8"])
     @pytest.mark.parametrize("program", ["decode_window", "prefill_chunks"])
-    def test_no_write_is_slab_sized(self, program, kv_quant):
+    def test_no_write_is_slab_sized(self, program):
         cfg, params = _tiny_setup()
         fused = decode_weights(params, cfg)
         slots, t_max, p, c = 3, 24, 2, 4
-        k, v = engine_lib.init_slot_cache(cfg, slots, t_max, kv_quant)
+        k, v = engine_lib.init_slot_cache(cfg, slots, t_max)
         key = jax.random.key(0)
         if program == "decode_window":
             lane = jnp.zeros((slots,), jnp.int32)

@@ -51,7 +51,6 @@ _PREFLIGHT_MODES = (
 _ENUM_KEYS: dict[str, tuple[str, ...]] = {
     keys.K_FRAMEWORK: _FRAMEWORKS,
     keys.K_PREFLIGHT_MODE: _PREFLIGHT_MODES,
-    keys.K_TUNE_KV_QUANT: ("none", "int8"),
 }
 
 # Integer keys where 0 is not a legal value (the generic int rule only

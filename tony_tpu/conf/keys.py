@@ -276,9 +276,6 @@ K_TUNE_TRIAL_BUDGET = TUNE_PREFIX + "trial-budget"
 # get the plan-measurements local sidecar mirror). A /tmp dir is
 # silently cold every reboot — lint rule TONY-C011, like TONY-C010.
 K_TUNE_RECORD_DIR = TUNE_PREFIX + "record-dir"
-# Serving KV-cache storage: "none" (compute dtype) or "int8"
-# (per-position absmax quantization — half the decode bandwidth).
-K_TUNE_KV_QUANT = TUNE_PREFIX + "kv-quant"
 
 # --- on-demand profiling (observability/profiling.py) -----------------------
 PROFILE_PREFIX = TONY_PREFIX + "profile."
@@ -611,7 +608,6 @@ DEFAULTS: dict[str, object] = {
     K_TUNE_ENABLED: True,
     K_TUNE_TRIAL_BUDGET: 12,
     K_TUNE_RECORD_DIR: "",
-    K_TUNE_KV_QUANT: "none",
     K_PROFILE_DURATION_MS: 2000,
     K_PROFILE_HBM_INTERVAL_MS: 5000,
     K_PROXY_CONNECT_TIMEOUT_MS: 5000,

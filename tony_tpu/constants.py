@@ -108,13 +108,11 @@ TONY_STEPSTATS_CALIBRATE = "TONY_STEPSTATS_CALIBRATE"
 TONY_STEPSTATS_WINDOW = "TONY_STEPSTATS_WINDOW"
 # Measured program autotuner (tony.tune.* conf → user-process env →
 # parallel/autotune.py): persisted per-(model, topology, jax version)
-# tune records — consumption switch, search trial budget, the record
-# dir (empty = beside the compile cache), and the serving engine's
-# KV-cache storage mode ("none" | "int8").
+# tune records — consumption switch, search trial budget, and the
+# record dir (empty = beside the compile cache).
 TONY_TUNE_ENABLED = "TONY_TUNE_ENABLED"
 TONY_TUNE_TRIAL_BUDGET = "TONY_TUNE_TRIAL_BUDGET"
 TONY_TUNE_RECORD_DIR = "TONY_TUNE_RECORD_DIR"
-TONY_TUNE_KV_QUANT = "TONY_TUNE_KV_QUANT"
 # Self-healing actuation (coordinator/healing.py): the incarnation of a
 # task instance — 0 at first launch, bumped each time the coordinator
 # evicts and replaces the task mid-job so stale executors/registrations/
@@ -162,7 +160,6 @@ DOCKER_FORWARD_ENV = (
     TONY_SERVING_DECODE_WINDOW, TONY_SERVING_MAX_QUEUE, TONY_SERVING_PORT,
     TONY_STEPSTATS_ENABLED, TONY_STEPSTATS_CALIBRATE, TONY_STEPSTATS_WINDOW,
     TONY_TUNE_ENABLED, TONY_TUNE_TRIAL_BUDGET, TONY_TUNE_RECORD_DIR,
-    TONY_TUNE_KV_QUANT,
     TONY_TASK_INCARNATION, TONY_RESHARD_PLAN, TONY_GANG_GENERATION,
     TONY_CKPT_PIPELINE_DEPTH, TONY_CKPT_PERSIST_WORKERS,
     TONY_CKPT_DIFFERENTIAL, TONY_CKPT_FULL_EVERY, TONY_CKPT_BG_SNAPSHOT,

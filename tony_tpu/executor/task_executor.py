@@ -772,9 +772,8 @@ class TaskExecutor:
         )
         # Measured autotuner (tony.tune.* conf → user-process env →
         # parallel/autotune.py): consumption switch, search trial
-        # budget, the record dir (empty = beside the compile cache, so
-        # retries/resumes land warm), and the serving engine's KV-cache
-        # storage mode.
+        # budget and the record dir (empty = beside the compile cache,
+        # so retries/resumes land warm).
         env[constants.TONY_TUNE_ENABLED] = str(
             self.conf.get_bool(keys.K_TUNE_ENABLED, True)
         ).lower()
@@ -783,9 +782,6 @@ class TaskExecutor:
         )
         env[constants.TONY_TUNE_RECORD_DIR] = self.conf.get_str(
             keys.K_TUNE_RECORD_DIR, ""
-        )
-        env[constants.TONY_TUNE_KV_QUANT] = self.conf.get_str(
-            keys.K_TUNE_KV_QUANT, "none"
         )
         env[constants.TONY_SERVING_MAX_QUEUE] = str(
             self.conf.get_int(keys.K_SERVING_MAX_QUEUE, 1024)

@@ -1,37 +1,52 @@
-"""KV-cache decoding / generation for the flagship transformer.
+"""Inference for the flagship transformer: the weights layout, THE
+decoder layer, and single-shot generation over a KV cache.
 
 The reference is a training orchestrator with no model code at all; this
-inference path completes the model family the rebuild adds. TPU-first
-choices:
+inference path completes the model family the rebuild adds. What lives
+here, and why here:
 
-* One jittable ``advance`` handles both prefill (S = prompt length) and
-  single-token steps (S = 1): static shapes per call site, so XLA compiles
-  exactly two executables for a whole generation loop.
-* The cache is a stacked [L, B, Tmax, Hkv, Dh] pair updated with
-  ``dynamic_update_slice`` at a traced offset; Hkv < H under GQA — the
-  n_heads/n_kv_heads cache shrink is the main decode-bandwidth lever. The
-  layer loop stays one ``lax.scan`` over the stacked layer params (same
-  trunk layout as training, so trained checkpoints drop in).
-* Decode attention is a grouped dense matvec against the cache (q regrouped
-  [B, S, Hkv, G, Dh] so the cache is never head-repeated), read in the
-  stored dtype with fp32 MXU accumulation and fp32 softmax (t_q is 1 or
-  the prompt length — flash blocking buys nothing there).
 * ``decode_weights`` re-packs the fp32 training masters: downcast to the
   compute dtype, qkv and gate|up fused — decode at small batch is
-  bandwidth/op-count-bound, so fewer, wider matmuls win. ``DecodeSession``
+  bandwidth/op-count-bound, so fewer, wider matmuls win. Same trunk
+  layout as training, so trained checkpoints drop in.
+* The decoder layer of inference is defined ONCE, beside the layout it
+  reads: ``serve_layer`` (pre-norm attention of the layer's kind, then
+  dense SwiGLU or the experts), ``run_layers`` (one ``lax.scan`` over a
+  uniform model's stacked layers with the caches as carry, a static loop
+  over a layered model's tuple), ``lm_head`` (final norm, unembed,
+  float32). A layer is one decision: ``advance`` here and the serving
+  engine's ``decode_window`` / ``prefill_chunks``
+  (``serving/engine.py``) all run it, and each brings its CACHE POLICY
+  as an ``attend(q, k_new, v_new, attn, sink) -> o`` closure: how the
+  new K/V rows are written and how the cache is read is all they do
+  differently. Training's ``models/transformer.py::_decoder_layer`` is
+  deliberately another definition (unfused fp32 masters, a backward,
+  remat, partitioning): the independent reference the parity tests hold
+  this one to.
+* Expert layers go through ``_moe_mlp_decode``: one dropless grouped
+  path for prefill and decode alike — the (token, choice) pairs that
+  land on the experts held here, sorted by expert, through two grouped
+  matrix products (``ops.grouped_matmul``: on a TPU a Pallas kernel
+  whose work follows the rows that are there and whose tiles are sized
+  to stream each visited expert's weights once) and added back under
+  their router weights. No capacity, static shapes, no pair dropped
+  under any routing; running every held expert on every token would be
+  32x the needed FLOPs where a token uses 0.5 of 16 held experts.
+* ``advance``'s cache policy: one jittable call handles both prefill
+  (S = prompt length) and single-token steps (S = 1), static shapes per
+  call site, so XLA compiles exactly two executables for a whole
+  generation loop. The cache is a stacked [L, B, Tmax, Hkv, Dh] pair
+  updated with ``dynamic_update_slice`` at a traced offset; Hkv < H
+  under GQA — the n_heads/n_kv_heads cache shrink is the main
+  decode-bandwidth lever. A prompt into an empty cache attends through
+  the flash kernel over its own tokens; a step attends the cache by
+  ``ops.grouped_cache_attention`` (q regrouped [B, S, Hkv, G, Dh] so the
+  cache is never head-repeated, read in the stored dtype with fp32 MXU
+  accumulation and fp32 softmax).
+* ``generate`` / ``DecodeSession``: greedy at ``temperature=0``, else
+  temperature sampling with a caller-provided key. ``DecodeSession``
   holds the fused pack so repeated ``generate`` calls pay fusion once
   (module-level ``generate`` on raw params re-fuses per call).
-
-Expert layers decode through ``_moe_mlp_decode``: one dropless grouped
-path for prefill and decode alike — the (token, choice) pairs that land
-on the experts held here, sorted by expert, through two grouped matrix
-products (``ops.grouped_matmul``: on a TPU a Pallas kernel whose work
-follows the rows that are there and whose tiles are sized to stream each
-visited expert's weights once) and added back under their router
-weights. No capacity, static shapes, no pair dropped under any routing;
-running every held expert on every token would be 32x the needed FLOPs
-where a token uses 0.5 of 16 held experts. Sampling: greedy at
-``temperature=0``, else temperature sampling with a caller-provided key.
 """
 
 from __future__ import annotations
@@ -47,6 +62,7 @@ from tony_tpu.models.transformer import TransformerConfig
 from tony_tpu.ops import (
     apply_rope,
     flash_attention,
+    grouped_cache_attention,
     grouped_matmul,
     rms_norm,
     rope_frequencies,
@@ -71,7 +87,7 @@ def decode_weights(params: dict, cfg: TransformerConfig) -> dict:
     per ``generate`` call (XLA hoists it out of the token loop).
 
     MoE configs keep the router and fuse gate|up per expert
-    ([L, E, d, 2F]); see ``_layer_decode``'s mixture evaluation.
+    ([L, E, d, 2F]); see ``_moe_mlp_decode``.
 
     A layered configuration (groups of stacks by kind) comes back as a
     TUPLE of layers in model order, each an array set of its own: the
@@ -237,99 +253,30 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
     }
 
 
-def _layer_decode(x, lp, k_all, v_all, layer, length, cfg, cos, sin,
-                  prefill=False):
-    """One decoder layer over S new tokens at positions [length, length+S).
-    x: [B, S, d]; ``k_all``/``v_all`` are the FULL stacked caches
-    [L, B, Tmax, Hkv, Dh] carried through the layer scan — the new K/V
-    rows are written at (layer, :, length) with a small
-    ``dynamic_update_slice`` that XLA aliases in place. Scanning with the
-    caches as scan xs/ys instead re-stacks them every step: a measured
-    0.8+ ms/step of pure ``copy`` (the whole cache, every token) in the
-    device trace. lp is in the fused ``decode_weights`` layout. Returns
-    (x, k_all, v_all).
+# ---------------------------------------------------------------------------
+# The inference layer (module docstring): one definition, three callers
+# ---------------------------------------------------------------------------
 
-    ``prefill=True`` (static) promises the cache is empty (length == 0):
-    attention then runs the flash kernel over just the S new tokens
-    instead of the masked dense scan of the full T_max cache — the dense
-    path's [S, T_max] fp32 score tensor is fine for single-token steps
-    but quadratic-memory for long prompts."""
-    dt = cfg.compute_dtype
-    b, s, _ = x.shape
-    t_max = k_all.shape[2]
-    n_h, h_kv = cfg.n_heads, k_all.shape[3]
+def _rope(x, tables, positions, rot: int):
+    """Rotary embedding on the first ``rot`` dims of the head; the rest
+    pass."""
+    cos, sin = tables
+    if rot == x.shape[-1]:
+        return apply_rope(x, cos, sin, positions=positions)
+    return jnp.concatenate(
+        [apply_rope(x[..., :rot], cos, sin, positions=positions),
+         x[..., rot:]], axis=-1)
 
-    h = rms_norm(x, lp["ln1"], eps=cfg.rms_eps).astype(dt)
-    qkv = jnp.einsum("btd,dhk->bthk", h, lp["qkv"])
-    q = qkv[:, :, :n_h]
-    k_new = qkv[:, :, n_h:n_h + h_kv]
-    v_new = qkv[:, :, n_h + h_kv:]
-    positions = length + jnp.arange(s)
-    q = apply_rope(q, cos, sin, positions=positions)
-    k_new = apply_rope(k_new, cos, sin, positions=positions)
 
-    k_all = lax.dynamic_update_slice(
-        k_all, k_new.astype(k_all.dtype)[None], (layer, 0, length, 0, 0)
-    )
-    v_all = lax.dynamic_update_slice(
-        v_all, v_new.astype(v_all.dtype)[None], (layer, 0, length, 0, 0)
-    )
-    k_cache = lax.dynamic_index_in_dim(k_all, layer, 0, keepdims=False)
-    v_cache = lax.dynamic_index_in_dim(v_all, layer, 0, keepdims=False)
-
-    if prefill and s > 1:
-        # Empty cache: self-attention over the prompt only (flash handles
-        # the GQA head grouping internally).
-        o = flash_attention(q, k_new.astype(dt), v_new.astype(dt),
-                            causal=True)
-    else:
-        # Grouped attention against the cache: q regrouped as
-        # [B, S, Hkv, G, Dh] so each K/V head serves its G query heads
-        # without materializing a repeated cache. The einsums read the
-        # cache in its stored dtype (bfloat16) with fp32 MXU accumulation
-        # — no fp32 upcast copy of the full T_max cache per step — and
-        # softmax stays fp32.
-        #
-        # Measured dead end (r4): a flash-decoding-style blocked loop
-        # (dynamic trip count over CACHE_BLOCK chunks, online softmax)
-        # is SLOWER here — 0.99 vs 0.82 ms/step at T_max=2048 — because
-        # generate() sizes the cache to exactly t0+max_new_tokens, so
-        # there is no allocated-but-unfilled slack to skip, and the
-        # while-loop costs ~10us/iteration; at a 7.4k-token context the
-        # two paths tie (~4.4 ms). Revisit only if a serving path with
-        # large preallocated caches at low fill appears.
-        g = n_h // h_kv
-        scale = cfg.head_dim ** -0.5
-        qg = q.reshape(b, s, h_kv, g, cfg.head_dim)
-        scores = jnp.einsum(
-            "bqhgd,bkhd->bhgqk", qg, k_cache,
-            preferred_element_type=jnp.float32,
-        ) * scale
-        # Global causal mask; it also hides the cache tail past length+S
-        # (those positions are > every query position). mask: [S, Tmax].
-        mask = positions[:, None] >= jnp.arange(t_max)[None, :]
-        scores = jnp.where(mask[None, None, None], scores, NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum(
-            "bhgqk,bkhd->bqhgd", probs.astype(dt), v_cache,
-            preferred_element_type=jnp.float32,
-        ).astype(dt).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    x = x + jnp.einsum("bthk,hkd->btd", o.astype(dt), lp["wo"])
-
-    if "router" in lp:
-        x = x + _moe_mlp_decode(x, lp, cfg)[0]
-    else:
-        # SwiGLU with the fused gate|up projection — the same math as
-        # training's _dense_mlp, one matmul instead of two.
-        hn = rms_norm(x, lp["ln2"], eps=cfg.rms_eps).astype(dt)
-        gu = jnp.einsum("btd,df->btf", hn, lp["gate_up"])
-        f = gu.shape[-1] // 2
-        act = (
-            jax.nn.silu(gu[..., :f].astype(jnp.float32)).astype(dt)
-            * gu[..., f:]
-        )
-        x = x + jnp.einsum("btf,fd->btd", act, lp["w_down"])
-    return x, k_all, v_all
+def rope_tables(cfg: TransformerConfig) -> dict:
+    """(cos, sin) per attention kind the model has, in the layers' order
+    (a set's order follows the process's hash seed, and with it the
+    program's text and its key in the compile cache)."""
+    return {
+        kind: rope_frequencies(cfg.rot_dim, cfg.max_seq,
+                               theta=cfg.rope_theta_of(kind))
+        for kind in dict.fromkeys(a for a, _ in cfg.layer_kinds)
+    }
 
 
 def _moe_mlp_decode(x, lp, cfg, token_mask=None, count_mask=None):
@@ -388,6 +335,108 @@ def _moe_mlp_decode(x, lp, cfg, token_mask=None, count_mask=None):
     w = jnp.where(here, gvals.reshape(-1), 0.0)
     out = jnp.where(here[:, None], y.astype(jnp.float32) * w[:, None], 0.0)
     return out.reshape(b, t, k, d).sum(2).astype(dt), pairs
+
+
+def _mlp(x, lp, cfg, token_mask=None, count_mask=None):
+    """SwiGLU over the fused gate|up projection (training's
+    ``_dense_mlp`` in one matmul instead of two), or the grouped expert
+    layer (``_moe_mlp_decode``: dropless, the held experts' part).
+    Returns (x, pairs): the (token, choice) pairs each held expert
+    received, None for a dense layer."""
+    dt = cfg.compute_dtype
+    if "router" in lp:
+        out, pairs = _moe_mlp_decode(x, lp, cfg, token_mask, count_mask)
+        return x + out, pairs
+    hn = rms_norm(x, lp["ln2"], eps=cfg.rms_eps).astype(dt)
+    gu = jnp.einsum("btd,df->btf", hn, lp["gate_up"])
+    f = gu.shape[-1] // 2
+    act = (
+        jax.nn.silu(gu[..., :f].astype(jnp.float32)).astype(dt)
+        * gu[..., f:]
+    )
+    return x + jnp.einsum("btf,fd->btd", act, lp["w_down"]), None
+
+
+def serve_layer(x, lp, attn, cfg, ropes, positions, attend, *,
+                token_mask=None, count_mask=None):
+    """THE decoder layer of inference: pre-norm attention of kind
+    ``attn`` (its own KV head count and rope base; q/k width
+    ``head_dim`` of which ``rot_dim`` rotate, v width ``v_dim`` scaled
+    by ``v_scale``), then the layer's MLP by what ``lp`` holds (dense
+    SwiGLU or experts). ``attend(q, k_new, v_new, attn, sink) -> o``
+    writes the new rows into the caller's cache and reads it: the one
+    thing ``advance``, decode and prefill do differently. Returns
+    (x, pairs)."""
+    dt = cfg.compute_dtype
+    b, t, _ = x.shape
+    n_h, h_kv = cfg.n_heads, cfg.kv_heads_of(attn)
+    h = rms_norm(x, lp["ln1"], eps=cfg.rms_eps).astype(dt)
+    if lp["qkv"].ndim == 2:
+        # layered: q|k|v fused on the feature axis, widths of their own
+        flat = jnp.einsum("btd,df->btf", h, lp["qkv"])
+        n_q, n_k = n_h * cfg.head_dim, h_kv * cfg.head_dim
+        q = flat[..., :n_q].reshape(b, t, n_h, cfg.head_dim)
+        k_new = flat[..., n_q:n_q + n_k].reshape(b, t, h_kv, cfg.head_dim)
+        v_new = flat[..., n_q + n_k:].reshape(b, t, h_kv, cfg.v_dim)
+        if cfg.v_scale != 1.0:
+            v_new = (v_new.astype(jnp.float32) * cfg.v_scale).astype(dt)
+    else:
+        qkv = jnp.einsum("btd,dhk->bthk", h, lp["qkv"])
+        q = qkv[:, :, :n_h]
+        k_new = qkv[:, :, n_h:n_h + h_kv]
+        v_new = qkv[:, :, n_h + h_kv:]
+    q = _rope(q, ropes[attn], positions, cfg.rot_dim)
+    k_new = _rope(k_new, ropes[attn], positions, cfg.rot_dim)
+    o = attend(q, k_new, v_new, attn, lp.get("sink"))
+    x = x + jnp.einsum("bthk,hkd->btd", o, lp["wo"])
+    return _mlp(x, lp, cfg, token_mask, count_mask)
+
+
+def run_layers(x, params, k_all, v_all, cfg, layer):
+    """Every layer in model order. ``layer(x, lp, attn, at, k_all,
+    v_all) -> (x, k_all, v_all, pairs)`` with ``at`` the layer's index
+    in its attention kind's cache stack. A uniform model: one
+    ``lax.scan`` over the stacked layers, the caches as CARRY (as xs/ys
+    the scan slices every layer's cache out and re-stacks it each call,
+    the whole cache re-written per token; as carry a layer's update is
+    one small aliased write). A layered model: a static loop over its
+    tuple of layers. Returns (x, k_all, v_all, pairs summed over the
+    expert layers or None)."""
+    if isinstance(params["layers"], tuple):
+        seen: dict = {}
+        total = None
+        for lp, (attn, _) in zip(params["layers"], cfg.layer_kinds):
+            at = seen.get(attn, 0)
+            seen[attn] = at + 1
+            x, k_all, v_all, pairs = layer(x, lp, attn, jnp.int32(at),
+                                           k_all, v_all)
+            if pairs is not None:
+                total = pairs if total is None else total + pairs
+        return x, k_all, v_all, total
+    attn = cfg.layer_kinds[0][0]
+
+    def body(carry, layer_in):
+        x, k_all, v_all = carry
+        lp, at = layer_in
+        x, k_all, v_all, pairs = layer(x, lp, attn, at, k_all, v_all)
+        return (x, k_all, v_all), pairs
+
+    (x, k_all, v_all), pairs = lax.scan(
+        body, (x, k_all, v_all),
+        (params["layers"], jnp.arange(cfg.n_layers)),
+    )
+    return x, k_all, v_all, None if pairs is None else pairs.sum(0)
+
+
+def lm_head(x, params, cfg):
+    """Final norm -> unembed -> float32 logits [B, V] of ``x`` [B, 1, d].
+    Only one position per row is ever sampled: callers slice it out
+    BEFORE this, so no [B, S, V] logits are ever materialized."""
+    x = rms_norm(x, params["final_norm"],
+                 eps=cfg.rms_eps).astype(cfg.compute_dtype)
+    return jnp.einsum(
+        "btd,dv->btv", x, params["unembed"]
+    )[:, 0].astype(jnp.float32)
 
 
 def advance(params: dict, cache: dict, tokens: jax.Array,
@@ -455,39 +504,43 @@ def advance(params: dict, cache: dict, tokens: jax.Array,
         # fuses once, outside its token loop).
         params = decode_weights(params, cfg)
     dt = cfg.compute_dtype
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq,
-                                theta=cfg.rope_theta)
+    s = tokens.shape[1]
     length = cache["length"]
+    positions = length + jnp.arange(s)
+    # Global causal mask [1, S, Tmax]; it also hides the cache tail past
+    # length + S (those positions are > every query position).
+    mask = (positions[:, None] >= jnp.arange(capacity)[None, :])[None]
+    ropes = rope_tables(cfg)
     x = params["embed"][tokens].astype(dt)
 
-    # The caches ride the scan CARRY (not xs/ys): as xs/ys the layer scan
-    # slices every layer's cache out and re-stacks it each call — the
-    # device trace showed ~0.8 ms/step of pure copy at modest cache sizes
-    # (the whole cache re-written per token). As carry, the per-layer
-    # update is one small aliased dynamic_update_slice.
-    def body(carry, layer_in):
-        x, k_all, v_all = carry
-        lp, layer = layer_in
-        x, k_all, v_all = _layer_decode(
-            x, lp, k_all, v_all, layer, length, cfg, cos, sin,
-            prefill=prefill,
-        )
-        return (x, k_all, v_all), None
+    def layer(x, lp, attn, at, k_all, v_all):
+        # This caller's cache policy: the S new rows go in at
+        # (layer, :, length) by a small ``dynamic_update_slice`` that XLA
+        # aliases in place, and attention reads that layer's cache.
+        def attend(q, k_new, v_new, attn, sink):
+            nonlocal k_all, v_all
+            k_all = lax.dynamic_update_slice(
+                k_all, k_new.astype(k_all.dtype)[None], (at, 0, length, 0, 0))
+            v_all = lax.dynamic_update_slice(
+                v_all, v_new.astype(v_all.dtype)[None], (at, 0, length, 0, 0))
+            if prefill and s > 1:
+                # Empty cache: self-attention over the prompt only (flash
+                # handles the GQA head grouping internally); the dense
+                # path's [S, Tmax] fp32 scores are quadratic-memory for
+                # long prompts.
+                return flash_attention(q, k_new, v_new, causal=True)
+            return grouped_cache_attention(
+                q, lax.dynamic_index_in_dim(k_all, at, 0, keepdims=False),
+                lax.dynamic_index_in_dim(v_all, at, 0, keepdims=False),
+                mask)
 
-    (x, k_all, v_all), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers)),
-    )
-    # Only the last position is ever sampled — slice BEFORE the unembed so
-    # prefill never materializes [B, S, V] logits.
-    x = rms_norm(x[:, -1:], params["final_norm"], eps=cfg.rms_eps).astype(dt)
-    logits = jnp.einsum(
-        "btd,dv->btv", x, params["unembed"]
-    )[:, 0].astype(jnp.float32)
-    new_cache = {
-        "k": k_all, "v": v_all,
-        "length": length + tokens.shape[1],
-    }
+        x, _ = serve_layer(x, lp, attn, cfg, ropes, positions, attend)
+        return x, k_all, v_all, None
+
+    x, k_all, v_all, _ = run_layers(x, params, cache["k"], cache["v"], cfg,
+                                    layer)
+    logits = lm_head(x[:, -1:], params, cfg)
+    new_cache = {"k": k_all, "v": v_all, "length": length + s}
     return logits, new_cache
 
 

@@ -14,9 +14,6 @@ the decode loop's wall vs its marginal step). The knobs:
   unchanged;
 * pipeline microbatch count and schedule;
 * buffer donation;
-* the serving-side axis: int8 KV-cache quantization
-  (``serving/engine.py`` — decode is bandwidth-bound, halving KV bytes
-  is the biggest serving lever);
 * an XLA flag set, stored per record and applied before backend init.
 
 Results persist as one JSON record per tune key in a
@@ -69,10 +66,6 @@ _SEARCH_BUCKETS = (
 _TUNE_DIR = "tony-tune-records"
 _RECORD_VERSION = 1
 
-# KV-cache quantization modes the serving engine accepts
-# (tony.tune.kv-quant / TONY_TUNE_KV_QUANT).
-KV_QUANT_MODES = ("none", "int8")
-
 
 def _env_int(name: str, default: int) -> int:
     try:
@@ -101,17 +94,6 @@ def default_trial_budget() -> int:
     return max(1, _env_int(constants.TONY_TUNE_TRIAL_BUDGET, 12))
 
 
-def default_kv_quant() -> str:
-    """The serving engine's KV storage mode when the caller passes none
-    (``tony.tune.kv-quant``). Unknown values degrade to ``none`` — a
-    typo'd conf must not crash a serving fleet at engine construction
-    (config_check flags it preflight)."""
-    from tony_tpu import constants
-
-    mode = _env_str(constants.TONY_TUNE_KV_QUANT, "none").strip().lower()
-    return mode if mode in KV_QUANT_MODES else "none"
-
-
 # ---------------------------------------------------------------------------
 # Knobs
 # ---------------------------------------------------------------------------
@@ -131,7 +113,6 @@ class Knobs:
     microbatches: int | None = None
     pipeline_schedule: str | None = None
     donate_state: bool | None = None
-    kv_quant: str | None = None
     xla_flags: tuple = ()
 
     def describe(self) -> dict[str, Any]:

@@ -1,22 +1,26 @@
-"""Device half of the continuous-batching serving engine.
+"""Device half of the continuous-batching serving engine: the KV cache
+and the two programs that read and write it.
 
-The single-shot ``generate`` path compiles one executable per (batch,
-prompt width, horizon) signature and runs every row to the full static
-horizon — fine for eval generation, a throughput wall for serving
-(on the earlier platform a ``generate`` call's wall rate sat at under
-half of what its marginal decode step sustained; the kernel is fine, the
-orchestration is the tax — not re-measured on the current chip). This module is the orchestration fix: TWO
-executables total, compiled once per engine lifetime, shared by every
-request that ever passes through —
+The decoder layer itself is not here: ``models/decode.py`` defines it
+once (``serve_layer``, walked by ``run_layers``, ended by ``lm_head``)
+for every inference program, and a program brings its cache policy as an
+``attend(q, k_new, v_new, attn, sink)`` closure. This module owns what
+is the engine's: the cache's storage forms and its interface (write
+rows, write a chunk, read a layer, read some slots' rows, inject and
+export), the attention of a prefill round, per-slot sampling, and TWO
+executables, compiled once per engine lifetime and shared by every
+request that ever passes through (``generate`` compiles one per (batch,
+prompt width, horizon) and runs every row to the static horizon: fine
+for eval generation, a wall for serving) —
 
 * ``decode_window`` — ``steps`` tokens for ALL slots in one dispatch.
   The slot batch is a fixed [S] lane array; each slot owns a row of the
   stacked KV cache [L, S, Tmax, Hkv, Dh], its own position, and its own
   sampling temperature, so requests of different lengths share every
-  decode iteration (Orca-style iteration-level scheduling). Each layer
-  scatters the slots' new K/V rows into the stacked buffer at
-  (layer, slot, wpos[slot]) and attention reads that layer where it
-  lies (``ops.cache_decode_attention``), masked per slot with
+  decode iteration (Orca-style iteration-level scheduling). Its
+  ``attend`` scatters the slots' new K/V rows into the stacked buffer at
+  (layer, slot, wpos[slot]) and reads that layer where it lies
+  (``ops.cache_decode_attention``), masked per slot with
   ``key_index <= pos[slot]``.
 * ``prefill_chunks`` — one bounded chunk of the prompt of EACH of up to
   P pending slots into those slots' cache rows. Chunking bounds how long
@@ -24,43 +28,40 @@ request that ever passes through —
   interleaves one round per engine iteration, so time-to-first-token for
   the new requests trades off against inter-token latency for everyone
   else at a fixed, configured granularity
-  (``tony.serving.prefill-chunk``). Each layer writes the P chunks by
-  ``dynamic_update_slice`` at (layer, slot, start) and attends over
-  those P slots only: where the round's float32 scores against the
-  whole reservation fit at once (``SCORES_LIMIT``), over the slots'
-  rows read out, in one batched product; where they do not (a full
-  layer at 8,192 positions under a 128-token chunk), through
+  (``tony.serving.prefill-chunk``). Its ``attend`` writes the P chunks
+  by ``dynamic_update_slice`` at (layer, slot, start) and attends over
+  those P slots only (``_attend_rows``): where the round's float32
+  scores against the whole reservation fit at once (``SCORES_LIMIT``),
+  over the slots' rows read out, in one batched product; where they do
+  not (a full layer at 8,192 positions under a 128-token chunk), through
   ``ops.cache_prefill_attention``, a kernel that reads each slot's K/V
   blocks out of the stacked buffer up to the chunk's last position and
   keeps the scores on the chip.
 
-Both programs run ONE layer definition (``_serve_layer``), parameterised
-by the layer's attention kind and MLP kind, over one cache interface:
-what differs between them is how a layer's new rows are written and its
-cache is read (``attend``). A uniform model's layers are one stacked
-pytree walked by ``lax.scan`` with one stacked cache pair. A LAYERED
-model (``TransformerConfig.layered``) comes as a tuple of layers walked
-by a static loop, with one cache stack per ATTENTION KIND: full layers
-keep ``Tmax`` positions, window layers a ring of the last positions
-(``ring_rows``: window + one prefill chunk, so that a chunk can be
-written before it is read) plus one parking row; position p of a slot
-lies at ring row ``p % ring`` and every mask is by position, so slot
-reuse never shows the last tenant's rows. K rows wider than 128 lanes
-and no whole number of them are kept as a tuple of 128-lane tiles, the
-last zero-filled (``lane_tiles``: a width of 192 lies in 256 lanes on
-the device anyway, and as tiles the rows of 4 KV heads merge without a
-copy of the cache; the decode kernel sums the tiles' products).
+The cache. A uniform model has one stacked pair [L, S, Tmax, Hkv, Dh]
+in the compute dtype. A LAYERED model (``TransformerConfig.layered``)
+has one stack per ATTENTION KIND: full layers keep ``Tmax`` positions,
+window layers a ring of the last positions (``ring_rows``: window + one
+prefill chunk, so that a chunk can be written before it is read) plus
+one parking row; position p of a slot lies at ring row ``p % ring`` and
+every mask is by position, so slot reuse never shows the last tenant's
+rows. A buffer has one of two storage forms, and the row's WIDTH decides
+which, not a caller: a plain array, or — K rows wider than 128 lanes and
+no whole number of them — a tuple of 128-lane tiles, the last
+zero-filled (``lane_tiles``: a width of 192 lies in 256 lanes on the
+device anyway, and as tiles the rows of 4 KV heads merge without a copy
+of the cache; the decode kernel sums the tiles' products).
 
-Both run over the fused ``decode_weights`` layout (weights fuse once per
-engine, exactly like ``DecodeSession``) and carry the stacked caches as
-scan CARRY (the xs/ys re-stack cost decode.py's docstring documents).
-KV buffers are donated and no program ever holds a layer's slab apart
-from them: per dispatch the cache takes S · Hkv · Dh elements per layer
-per buffer in decode and P · C · Hkv · Dh in prefill (2 MB and 8 MB
-over 16 layers of 8 × 128 bf16 heads at 32 slots, 4 × 32-token chunks),
-and gives one pass over the layer's S · Tmax rows in decode (4.3 GB),
-over P · Tmax in prefill (0.5 GB; through the kernel, over the P slots'
-positions before each chunk's end, once per KV head).
+Both programs run over the fused ``decode_weights`` layout (weights fuse
+once per engine, exactly like ``DecodeSession``) and carry the stacked
+caches through the layers as scan CARRY (``run_layers``). KV buffers are
+donated and no program ever holds a layer's slab apart from them: per
+dispatch the cache takes S · Hkv · Dh elements per layer per buffer in
+decode and P · C · Hkv · Dh in prefill (2 MB and 8 MB over 16 layers of
+8 × 128 bf16 heads at 32 slots, 4 × 32-token chunks), and gives one pass
+over the layer's S · Tmax rows in decode (4.3 GB), over P · Tmax in
+prefill (0.5 GB; through the kernel, over the P slots' positions before
+each chunk's end, once per KV head).
 
 Overwrite-before-read invariant: slot reuse never zeroes a cache row.
 A freed slot's stale K/V rows are only ever unmasked after the new
@@ -72,21 +73,22 @@ reads it), so stale data is structurally unreadable.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tony_tpu.models.decode import _moe_mlp_decode
+from tony_tpu.models.decode import (
+    lm_head,
+    rope_tables,
+    run_layers,
+    serve_layer,
+)
 from tony_tpu.models.transformer import TransformerConfig
 from tony_tpu.ops import (
-    apply_rope,
     cache_decode_attention,
     cache_prefill_attention,
     grouped_cache_attention,
-    rms_norm,
-    rope_frequencies,
 )
 from tony_tpu.ops.attention import (
     cache_rows_view,
@@ -98,41 +100,11 @@ from tony_tpu.ops.attention import (
 )
 
 
-class QuantizedKV(NamedTuple):
-    """An int8-quantized KV cache buffer (``tony.tune.kv-quant=int8``):
-    per-(position, kv-head) symmetric absmax quantization over the head
-    dim — ``data * scale`` reconstructs the stored vectors. Decode is
-    bandwidth-bound, so halving (vs bf16) the KV bytes read per step is
-    the biggest serving-throughput lever; the scale plane adds
-    1/head_dim overhead. A NamedTuple so the pair rides jit/donation as
-    an ordinary pytree — the cache TYPE is part of the executable's
-    trace, never a runtime branch."""
-
-    data: jax.Array   # int8  [..., Dh]
-    scale: jax.Array  # f32   [..., 1]
-
-
-# One cache buffer is either a plain array (kv_quant="none") or a
-# QuantizedKV. These helpers keep decode_window/prefill_chunks agnostic.
-
-
-def _quantize(x: jax.Array) -> QuantizedKV:
-    absmax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1,
-                     keepdims=True)
-    scale = jnp.maximum(absmax, 1e-8) / 127.0
-    data = jnp.clip(
-        jnp.round(x.astype(jnp.float32) / scale), -127, 127
-    ).astype(jnp.int8)
-    return QuantizedKV(data, scale)
-
-
-def _materialize(cache, dt) -> jax.Array:
-    """Cache rows in compute dtype: identity for a plain buffer (the
-    stored-dtype einsum path keeps its fp32 MXU accumulation), dequant
-    for int8, the lane tiles side by side for a tiled one (the caller
-    cuts the zero fill off)."""
-    if isinstance(cache, QuantizedKV):
-        return (cache.data.astype(jnp.float32) * cache.scale).astype(dt)
+def _materialize(cache) -> jax.Array:
+    """Cache rows as one array: a plain buffer as it is (the
+    stored-dtype einsum path keeps its fp32 MXU accumulation), the lane
+    tiles of a tiled one side by side (the caller cuts the zero fill
+    off)."""
     if isinstance(cache, tuple):
         return jnp.concatenate(cache, axis=-1)
     return cache
@@ -165,14 +137,13 @@ def _lane_tiles(x, n: int) -> tuple:
 
 # The cache interface of decode_window / prefill_chunks: rows are written
 # into the stacked [L, S, Tmax, Hkv, ·] buffer where they lie, and a
-# layer is read out of that same buffer. ``jax.tree.map`` over the cache
-# runs each plane of a QuantizedKV and a plain array through one path.
+# layer is read out of that same buffer. A cache is a plain buffer or,
+# where the row's width asks for it, a tuple of 128-lane tiles
+# (``lane_tiles``): ``jax.tree.map`` runs both through one path.
 
 
 def _encode(cache, x):
     """``x`` in the cache's storage form (the cache's own pytree)."""
-    if isinstance(cache, QuantizedKV):
-        return _quantize(x)
     if isinstance(cache, tuple):
         return _lane_tiles(x.astype(cache[0].dtype), len(cache))
     return x.astype(cache.dtype)
@@ -211,24 +182,12 @@ def _write_chunk(cache, layer, slot, start, chunk):
         cache, _encode(cache, chunk))
 
 
-def _layer_view(cache, layer, dt):
-    """(stack, index) under which decode attention reads one layer in
-    compute dtype: a plain cache is the stack, read where it lies; an
-    int8 cache dequantizes the layer into a one-layer stack."""
-    if isinstance(cache, QuantizedKV):
-        one = jax.tree.map(
-            lambda buf: lax.dynamic_slice_in_dim(buf, layer, 1, 0), cache
-        )
-        return _materialize(one, dt), jnp.int32(0)
-    return cache, layer
-
-
-def _read_slots(cache, layer, slots, dt):
-    """The rows [P, Tmax, Hkv, Dh] of ``slots`` [P] in one layer, in
-    compute dtype: P small dynamic slices (on the TPU a gather over the
-    stacked buffer lowers to slices of the WHOLE buffer)."""
+def _read_slots(cache, layer, slots):
+    """The rows [P, Tmax, Hkv, Dh] of ``slots`` [P] in one layer: P
+    small dynamic slices (on the TPU a gather over the stacked buffer
+    lowers to slices of the WHOLE buffer)."""
     return _materialize(jax.tree.map(
-        lambda buf: cache_slot_rows(buf, layer, slots), cache), dt)
+        lambda buf: cache_slot_rows(buf, layer, slots), cache))
 
 
 def ring_rows(cfg: TransformerConfig, prefill_chunk: int) -> int:
@@ -252,30 +211,22 @@ def _with_kind(cache, attn: str, stack):
 
 def init_slot_cache(
     cfg: TransformerConfig, slots: int, max_len: int,
-    kv_quant: str = "none", prefill_chunk: int = 32,
+    prefill_chunk: int = 32,
 ):
-    """Zeroed stacked KV cache pair [L, S, Tmax, Hkv, Dh] — one row per
-    slot, sized once for the engine's lifetime, donated to every dispatch
-    and rewritten in place a few rows at a time (module docstring).
-    Serving HBM budget is 2 · L · S · Tmax · Hkv · Dh · dtype bytes
-    (``kv_quant="int8"``: 1 + 4/Dh bytes per element instead of the
-    compute dtype's 2); see docs/DEPLOY.md "Serving" for the sizing table
-    and "Autotuning" for the quantization contract.
+    """Zeroed stacked KV cache pair [L, S, Tmax, Hkv, Dh] in the compute
+    dtype — one row per slot, sized once for the engine's lifetime,
+    donated to every dispatch and rewritten in place a few rows at a
+    time (module docstring). Serving HBM budget is 2 · L · S · Tmax ·
+    Hkv · Dh · dtype bytes; see docs/DEPLOY.md "Serving" for the sizing
+    table.
 
     A layered model gets a dict pair, one stack per attention kind it
     has: ``full`` [Lf, S, Tmax, Hkv, ·] and ``window`` [Lw, S, ring + 1,
     Hkv_w, ·] (``ring_rows(cfg, prefill_chunk)`` positions and the
     parking row); a width that is more than one 128-lane tile and no
-    whole number of them comes as a tuple of tiles (``lane_tiles``).
-    The int8 cache refuses a layered model."""
-    if kv_quant not in ("none", "", None, "int8"):
-        raise ValueError(f"unknown kv_quant mode {kv_quant!r}")
+    whole number of them comes as a tuple of tiles (``lane_tiles``)."""
     dt = cfg.compute_dtype
     if cfg.layered:
-        if kv_quant == "int8":
-            raise ValueError(
-                "the int8 KV cache serves uniform layers only; this "
-                "configuration has layer kinds")
         attn = [a for a, _ in cfg.layer_kinds]
         if "full" not in attn:
             raise ValueError(
@@ -299,24 +250,16 @@ def init_slot_cache(
 
         return stacks(cfg.head_dim), stacks(cfg.v_dim)
     shape = (cfg.n_layers, slots, max_len, cfg.kv_heads, cfg.head_dim)
-    if kv_quant == "int8":
-        def one():
-            return QuantizedKV(
-                jnp.zeros(shape, jnp.int8),
-                jnp.zeros(shape[:-1] + (1,), jnp.float32),
-            )
-        return one(), one()
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
 
 
-def cache_inject_rows(cache, slot: int, rows) -> "jax.Array | QuantizedKV":
-    """Host-side write of FLOAT rows [L, P, Hkv, Dh] into one slot's
-    prefix (the inject half of prefill/decode disaggregation). The
-    cross-replica exchange format is always float — quantization is a
-    per-engine storage decision, so a bf16 prefill replica can feed an
-    int8 decode replica and vice versa. A layered cache takes what
-    ``cache_export_rows`` gave of one: a dict by attention kind, the
-    window kind's rows being the LAST positions of the prefix."""
+def cache_inject_rows(cache, slot: int, rows):
+    """Host-side write of float rows [L, P, Hkv, Dh] into one slot's
+    prefix (the inject half of prefill/decode disaggregation; the
+    exchange format is rows at their logical width, whatever the
+    storage form). A layered cache takes what ``cache_export_rows``
+    gave of one: a dict by attention kind, the window kind's rows being
+    the LAST positions of the prefix."""
     if isinstance(cache, dict):
         length = rows["full"].shape[1]
         out = {}
@@ -330,22 +273,16 @@ def cache_inject_rows(cache, slot: int, rows) -> "jax.Array | QuantizedKV":
                 stack, _encode(stack, part))
         return out
     p = rows.shape[1]
-    if isinstance(cache, QuantizedKV):
-        q = _quantize(jnp.asarray(rows, jnp.float32))
-        return QuantizedKV(
-            cache.data.at[:, slot, :p].set(q.data),
-            cache.scale.at[:, slot, :p].set(q.scale),
-        )
     return cache.at[:, slot, :p].set(jnp.asarray(rows, cache.dtype))
 
 
 def cache_export_rows(cache, slot: int, length: int, width: int = 0):
     """One slot's KV prefix as float rows [L, length, Hkv, Dh] — the
     export half of the exchange contract ``cache_inject_rows``
-    documents (int8 storage dequantizes on the way out). A layered
-    cache exports a dict by attention kind at the logical ``width``:
-    all ``length`` positions of its full layers, and of its window
-    layers the last ``ring`` (or fewer), in position order."""
+    documents. A layered cache exports a dict by attention kind at the
+    logical ``width``: all ``length`` positions of its full layers, and
+    of its window layers the last ``ring`` (or fewer), in position
+    order."""
     if isinstance(cache, dict):
         out = {}
         for kind, stack in cache.items():
@@ -354,15 +291,9 @@ def cache_export_rows(cache, slot: int, length: int, width: int = 0):
                 ring = _cache_tmax(stack) - 1
                 at = jnp.arange(max(0, length - ring), length) % ring
             out[kind] = _materialize(
-                jax.tree.map(lambda buf: buf[:, slot, at], stack),
-                None)[..., :width or None]
+                jax.tree.map(lambda buf: buf[:, slot, at], stack)
+            )[..., :width or None]
         return out
-    if isinstance(cache, QuantizedKV):
-        return _materialize(
-            QuantizedKV(cache.data[:, slot, :length],
-                        cache.scale[:, slot, :length]),
-            jnp.float32,
-        )
     return cache[:, slot, :length]
 
 
@@ -402,11 +333,9 @@ def prefill_read_block(cfg: TransformerConfig, k_all, p: int, c: int) -> int:
     """Positions in one key block where a round of ``p`` chunks of ``c``
     tokens attends its FULL layers through ``cache_prefill_attention``
     (a row then reads whole blocks up to its chunk's end); 0 where it
-    reads every slot's whole reservation: scores that fit at once, or an
-    int8 cache, whose rows are read out to be dequantized."""
-    kc = _kind(k_all, "full")
-    t = _cache_tmax(kc)
-    if isinstance(kc, QuantizedKV) or _scores_fit(p, c, cfg.n_heads, t):
+    reads every slot's whole reservation: scores that fit at once."""
+    t = _cache_tmax(_kind(k_all, "full"))
+    if _scores_fit(p, c, cfg.n_heads, t):
         return 0
     return prefill_key_block(t, cfg.kv_heads_of("full"))
 
@@ -418,127 +347,18 @@ def _attend_rows(q, kc, vc, at, slots, starts, mask, scale, sink, cfg, attn):
     one ``grouped_cache_attention`` under ``mask``. Where they do not (a
     full layer at 8,192 positions under a 128-token chunk): through
     ``cache_prefill_attention``, which reads the stack where it lies and
-    only up to each chunk's end; a ring or an int8 cache of that size
-    keeps the plain path, row by row."""
+    only up to each chunk's end; a ring of that size keeps the plain
+    path, row by row."""
     p, c, n_h, d_k = q.shape
-    dt = cfg.compute_dtype
     if attn == "full" and prefill_read_block(cfg, kc, p, c):
         return cache_prefill_attention(q, kc, vc, at, slots, starts + c,
                                        scale=scale, sink=sink)
-    k = _read_slots(kc, at, slots, dt)[..., :d_k]
-    v = _read_slots(vc, at, slots, dt)
+    k = _read_slots(kc, at, slots)[..., :d_k]
+    v = _read_slots(vc, at, slots)
     attention = (grouped_cache_attention
                  if _scores_fit(p, c, n_h, k.shape[1])
                  else rowwise_cache_attention)
     return attention(q, k, v, mask, scale=scale, sink=sink)
-
-
-def _rope(x, tables, positions, rot: int):
-    """Rotary embedding on the first ``rot`` dims of the head; the rest
-    pass."""
-    cos, sin = tables
-    if rot == x.shape[-1]:
-        return apply_rope(x, cos, sin, positions=positions)
-    return jnp.concatenate(
-        [apply_rope(x[..., :rot], cos, sin, positions=positions),
-         x[..., rot:]], axis=-1)
-
-
-def _rope_tables(cfg: TransformerConfig) -> dict:
-    """(cos, sin) per attention kind the model has, in the layers' order
-    (a set's order follows the process's hash seed, and with it the
-    program's text and its key in the compile cache)."""
-    return {
-        kind: rope_frequencies(cfg.rot_dim, cfg.max_seq,
-                               theta=cfg.rope_theta_of(kind))
-        for kind in dict.fromkeys(a for a, _ in cfg.layer_kinds)
-    }
-
-
-def _mlp(x, lp, cfg, token_mask=None, count_mask=None):
-    """SwiGLU over the fused gate|up projection, or the grouped expert
-    layer (``models.decode._moe_mlp_decode``: dropless, the held
-    experts' part). Returns (x, pairs): the (token, choice) pairs each
-    held expert received, None for a dense layer."""
-    dt = cfg.compute_dtype
-    if "router" in lp:
-        out, pairs = _moe_mlp_decode(x, lp, cfg, token_mask, count_mask)
-        return x + out, pairs
-    hn = rms_norm(x, lp["ln2"], eps=cfg.rms_eps).astype(dt)
-    gu = jnp.einsum("btd,df->btf", hn, lp["gate_up"])
-    f = gu.shape[-1] // 2
-    act = (
-        jax.nn.silu(gu[..., :f].astype(jnp.float32)).astype(dt)
-        * gu[..., f:]
-    )
-    return x + jnp.einsum("btf,fd->btd", act, lp["w_down"]), None
-
-
-def _serve_layer(x, lp, attn, cfg, ropes, positions, attend, *,
-                 token_mask=None, count_mask=None):
-    """THE decoder layer of the serving programs: pre-norm attention of
-    kind ``attn`` (its own KV head count and rope base; q/k width
-    ``head_dim`` of which ``rot_dim`` rotate, v width ``v_dim`` scaled
-    by ``v_scale``), then the layer's MLP by what ``lp`` holds (dense
-    SwiGLU or experts). ``attend(q, k_new, v_new, attn, sink) -> o``
-    writes the new rows into the caller's cache and reads it: the one
-    thing decode and prefill do differently. Returns (x, pairs)."""
-    dt = cfg.compute_dtype
-    b, t, _ = x.shape
-    n_h, h_kv = cfg.n_heads, cfg.kv_heads_of(attn)
-    h = rms_norm(x, lp["ln1"], eps=cfg.rms_eps).astype(dt)
-    if lp["qkv"].ndim == 2:
-        # layered: q|k|v fused on the feature axis, widths of their own
-        flat = jnp.einsum("btd,df->btf", h, lp["qkv"])
-        n_q, n_k = n_h * cfg.head_dim, h_kv * cfg.head_dim
-        q = flat[..., :n_q].reshape(b, t, n_h, cfg.head_dim)
-        k_new = flat[..., n_q:n_q + n_k].reshape(b, t, h_kv, cfg.head_dim)
-        v_new = flat[..., n_q + n_k:].reshape(b, t, h_kv, cfg.v_dim)
-        if cfg.v_scale != 1.0:
-            v_new = (v_new.astype(jnp.float32) * cfg.v_scale).astype(dt)
-    else:
-        qkv = jnp.einsum("btd,dhk->bthk", h, lp["qkv"])
-        q = qkv[:, :, :n_h]
-        k_new = qkv[:, :, n_h:n_h + h_kv]
-        v_new = qkv[:, :, n_h + h_kv:]
-    q = _rope(q, ropes[attn], positions, cfg.rot_dim)
-    k_new = _rope(k_new, ropes[attn], positions, cfg.rot_dim)
-    o = attend(q, k_new, v_new, attn, lp.get("sink"))
-    x = x + jnp.einsum("bthk,hkd->btd", o, lp["wo"])
-    return _mlp(x, lp, cfg, token_mask, count_mask)
-
-
-def _run_layers(x, params, k_all, v_all, cfg, layer):
-    """Every layer in model order. ``layer(x, lp, attn, at, k_all,
-    v_all) -> (x, k_all, v_all, pairs)`` with ``at`` the layer's index
-    in its attention kind's cache stack. A uniform model: one
-    ``lax.scan`` over the stacked layers, the caches as carry. A layered
-    model: a static loop over its tuple of layers. Returns (x, k_all,
-    v_all, pairs summed over the expert layers or None)."""
-    if isinstance(params["layers"], tuple):
-        seen: dict = {}
-        total = None
-        for lp, (attn, _) in zip(params["layers"], cfg.layer_kinds):
-            at = seen.get(attn, 0)
-            seen[attn] = at + 1
-            x, k_all, v_all, pairs = layer(x, lp, attn, jnp.int32(at),
-                                           k_all, v_all)
-            if pairs is not None:
-                total = pairs if total is None else total + pairs
-        return x, k_all, v_all, total
-    attn = cfg.layer_kinds[0][0]
-
-    def body(carry, layer_in):
-        x, k_all, v_all = carry
-        lp, at = layer_in
-        x, k_all, v_all, pairs = layer(x, lp, attn, at, k_all, v_all)
-        return (x, k_all, v_all), pairs
-
-    (x, k_all, v_all), pairs = lax.scan(
-        body, (x, k_all, v_all),
-        (params["layers"], jnp.arange(cfg.n_layers)),
-    )
-    return x, k_all, v_all, None if pairs is None else pairs.sum(0)
 
 
 def _sample_slots(logits, temp, key):
@@ -546,9 +366,8 @@ def _sample_slots(logits, temp, key):
     sampling. One key serves the whole slot batch — the Gumbel noise
     tensor is keyed per (row, vocab) position, so each row's draw is
     independent of every other row's logits. The categorical branch
-    hides behind ``lax.cond``: threefry over [S, V] costs ~16% of a
-    micro decode step on CPU, and an all-greedy slot batch (the common
-    serving default) must not pay it."""
+    hides behind ``lax.cond``: an all-greedy slot batch (the common
+    serving default) must not pay for threefry over [S, V]."""
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     def sample(_):
@@ -573,10 +392,9 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
     slot, advance, repeat. ``steps`` is the host-sync window — the
     throughput/latency knob (``tony.serving.decode-window``): 1 keeps
     admission and EOS retirement exactly per-token; a deeper window
-    amortizes the per-dispatch host cost over ``steps`` tokens at the
-    price of up to ``steps - 1`` wasted lane-steps per retiring stream
-    (measured on the CPU micro bench: host dispatch + PRNG fold cost
-    ~2× the model step itself at window 1).
+    amortizes the per-dispatch host cost (launch, fenced readback) over
+    ``steps`` tokens at the price of up to ``steps - 1`` wasted
+    lane-steps per retiring stream.
 
     pos/wpos/temp live on the HOST between windows (tiny [S] arrays;
     the scheduler mutates them freely on admit/retire) and ride in as
@@ -606,7 +424,7 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
     """
     dt = cfg.compute_dtype
     t_max = _cache_tmax(_kind(k_all, "full"))
-    ropes = _rope_tables(cfg)
+    ropes = rope_tables(cfg)
     scale = cfg.head_dim ** -0.5
 
     def one_step(carry, i):
@@ -629,29 +447,23 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
                     w_at = jnp.where(parked, ring, wpos % ring)
                 kc = _write_rows(kc, at, k_new[:, 0], w_at)
                 vc = _write_rows(vc, at, v_new[:, 0], w_at)
-                k_stack, idx = _layer_view(kc, at, dt)
-                v_stack, _ = _layer_view(vc, at, dt)
                 o = cache_decode_attention(
-                    q[:, 0], k_stack, v_stack, idx, pos, scale=scale,
+                    q[:, 0], kc, vc, at, pos, scale=scale,
                     window=window, sink=sink,
                 )[:, None]
                 k_all = _with_kind(k_all, attn, kc)
                 v_all = _with_kind(v_all, attn, vc)
                 return o
 
-            x, pairs = _serve_layer(x, lp, attn, cfg, ropes, rp, attend,
-                                    token_mask=~parked[:, None])
+            x, pairs = serve_layer(x, lp, attn, cfg, ropes, rp, attend,
+                                   token_mask=~parked[:, None])
             return x, k_all, v_all, pairs
 
-        x, k_all, v_all, pairs = _run_layers(x, params, k_all, v_all, cfg,
-                                             layer)
-        x = rms_norm(x[:, -1:], params["final_norm"],
-                     eps=cfg.rms_eps).astype(dt)
-        logits = jnp.einsum(
-            "btd,dv->btv", x, params["unembed"]
-        )[:, 0].astype(jnp.float32)
+        x, k_all, v_all, pairs = run_layers(x, params, k_all, v_all, cfg,
+                                            layer)
         nxt = _sample_slots(
-            logits, temp, jax.random.fold_in(base_key, draw0 + i)
+            lm_head(x[:, -1:], params, cfg), temp,
+            jax.random.fold_in(base_key, draw0 + i)
         )
         pos = pos + 1
         wpos = jnp.minimum(wpos + 1, t_max - 1)
@@ -672,10 +484,9 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
     """Prefill one chunk for EACH of P pending slots in one dispatch:
     ``tokens`` [P, C] row i is written into slot ``slots[i]`` at
     positions [starts[i], starts[i] + C). Batching the pending slots is
-    the prefill twin of the slot-batch decode step — per-chunk batch-1
-    dispatches measured ~3× the comparator's batched-prefill wall on
-    the CPU micro bench (fixed dispatch + op overhead per chunk), and
-    on TPU a [1, C] chunk cannot fill the MXU.
+    the prefill twin of the slot-batch decode step: a dispatch per
+    chunk pays the fixed launch and readback P times over, and on TPU a
+    [1, C] chunk cannot fill the MXU.
 
     The host guarantees distinct slots per batch and ``start + C <=
     Tmax``; it PADS short batches by duplicating row 0 — the duplicate
@@ -701,7 +512,7 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
     dt = cfg.compute_dtype
     p, c = tokens.shape
     t_max = _cache_tmax(_kind(k_all, "full"))
-    ropes = _rope_tables(cfg)
+    ropes = rope_tables(cfg)
     scale = cfg.head_dim ** -0.5
     positions = starts[:, None] + jnp.arange(c)[None, :]       # [P, C]
     # Padded tail positions can run past the RoPE table; clamp the
@@ -744,18 +555,15 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
             v_all = _with_kind(v_all, attn, vc)
             return o
 
-        x, pairs = _serve_layer(x, lp, attn, cfg, ropes, rope_pos, attend,
-                                count_mask=counted)
+        x, pairs = serve_layer(x, lp, attn, cfg, ropes, rope_pos, attend,
+                               count_mask=counted)
         return x, k_all, v_all, pairs
 
-    x, k_all, v_all, pairs = _run_layers(x, params, k_all, v_all, cfg, layer)
+    x, k_all, v_all, pairs = run_layers(x, params, k_all, v_all, cfg, layer)
     last = jnp.take_along_axis(
         x, jnp.maximum(n_valids - 1, 0)[:, None, None], axis=1
     )                                                          # [P, 1, d]
-    last = rms_norm(last, params["final_norm"], eps=cfg.rms_eps).astype(dt)
-    logits = jnp.einsum(
-        "btd,dv->btv", last, params["unembed"]
-    )[:, 0].astype(jnp.float32)
+    logits = lm_head(last, params, cfg)
     toks = _sample_slots(logits, temps,
                          jax.random.fold_in(base_key, draw))
     return k_all, v_all, toks, logits, pairs

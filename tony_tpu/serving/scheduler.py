@@ -242,22 +242,16 @@ class ServingEngine:
     ) -> None:
         import jax
 
-        from tony_tpu.parallel import autotune as autotune_lib
-
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
-        # KV storage mode (the serving-side tuning axis): explicit arg
-        # wins, else tony.tune.kv-quant via the executor env. Decode is
-        # bandwidth-bound, so "int8" halves the bytes every decode step
-        # reads at a bounded sampling-parity cost (pinned by test).
-        if kv_quant is None:
-            kv_quant = autotune_lib.default_kv_quant()
-        if kv_quant not in autotune_lib.KV_QUANT_MODES:
+        # The cache is kept in the compute dtype, the one storage form.
+        # The keyword stays only because perfbench/jobs/serve.py passes
+        # "none" (ROADMAP D-kvarg).
+        if kv_quant not in (None, "none"):
             raise ValueError(
-                f"kv_quant must be one of {autotune_lib.KV_QUANT_MODES}, "
-                f"got {kv_quant!r}"
+                f"kv_quant={kv_quant!r}: the int8 KV cache was removed "
+                f"in PR 32; the cache is kept in the compute dtype"
             )
-        self.kv_quant = kv_quant
         if decode_window < 1:
             raise ValueError(
                 f"decode_window must be >= 1, got {decode_window}"
@@ -304,8 +298,7 @@ class ServingEngine:
         )
         self._model_loaders: dict[str, Callable[[], dict]] = {}
         self._k, self._v = _engine.init_slot_cache(
-            cfg, self.slots, max_len, kv_quant=self.kv_quant,
-            prefill_chunk=prefill_chunk,
+            cfg, self.slots, max_len, prefill_chunk=prefill_chunk,
         )
         self._pos = np.zeros(self.slots, np.int32)
         self._active = np.zeros(self.slots, bool)
@@ -424,8 +417,7 @@ class ServingEngine:
         extra = {"slots": self.slots, "max_len": self.max_len,
                  "chunk": self.prefill_chunk,
                  "window": self.decode_window,
-                 "prefill_batch": self.prefill_batch,
-                 "kv_quant": self.kv_quant}
+                 "prefill_batch": self.prefill_batch}
         self._decode = plan_lib.instrument_jit(
             functools.partial(_engine.decode_window, cfg=cfg,
                               steps=self.decode_window),
@@ -653,7 +645,6 @@ class ServingEngine:
                 "requests": self._n_requests,
                 "retired": self._n_retired,
                 "draining": bool(self._draining),
-                "kv_quant": self.kv_quant,
                 "model": self._model,
                 "models": sorted(set(self._resident)
                                  | set(self._model_loaders)),
