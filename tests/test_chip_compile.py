@@ -205,6 +205,109 @@ def test_train_step_compiles_on_four_chips(v5e, name):
     assert _mosaic_calls(compiled) >= 5
 
 
+def _computations(text: str) -> dict:
+    """Computation name -> its instruction lines, from optimized HLO text."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            out[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def _loop_bodies(text: str) -> tuple:
+    """(instruction lines directly in the ``while`` bodies, those lines
+    and the lines of every computation they call, fusions included)."""
+    comps = _computations(text)
+    bodies = re.findall(r"body=%([\w.\-]+)", text)
+    direct = [ln for b in bodies for ln in comps[b]]
+    seen, todo = set(), list(bodies)
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for ln in comps[name]:
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                               ln)
+    return direct, [ln for name in seen for ln in comps[name]]
+
+
+_RESULT = re.compile(r" = (.*?[\]})]) [a-z][\w\-]*\(")
+_O = r"bf16\[\d+,\d+,\d+\]\S*"
+_FLASH_RESULT = re.compile(     # forward (o, log-sum-exp); dq; (dk, dv)
+    rf"^(?:\({_O}, f32\[\d+,1,\d+\]\S*\)|{_O}|\({_O}, {_O}\))$")
+
+
+def _result(line: str) -> str:
+    """An instruction's result shapes: what stands between ``=`` and the
+    operation's name."""
+    found = _RESULT.search(line)
+    return found.group(1) if found else ""
+
+
+def _elements(shape: str) -> int:
+    return int(np.prod([int(n) for n in shape.split(",") if n]))
+
+
+@pytest.mark.parametrize("policy", ["dots", "full"])
+def test_train_step_moves_q_and_k_through_hbm_once(v5e, policy):
+    """The train cell's own step (Mistral-7B widths, 2 layers, batch 4 x
+    2,049, float32 state) on one chip of the described v5e, read from the
+    optimized HLO of its two layer loops. What ``remat_policy`` keeps and
+    what the rope lowers to are decided at compile time, so the compiled
+    text is where they are counted:
+
+    - ``dots`` keeps the flash forward's result, so the loops hold three
+      flash calls (forward; dq and dkv) and the backward runs no forward;
+      ``full`` keeps nothing and holds four.
+    - Between the projections and the kernels nothing gathers or scatters
+      an array of k's size or more (``apply_rope`` did, for q and k, in
+      both loops), and no float32 copy of a q-sized array is written
+      (its transpose under autodiff did)."""
+    cfg = TransformerConfig(
+        vocab_size=32_000, d_model=4096, n_layers=2, n_heads=32,
+        head_dim=128, d_ff=14336, max_seq=2048, n_kv_heads=8,
+        dtype="bfloat16", remat=True, remat_policy=policy,
+    )
+    batch, seq = 4, 2048
+    mesh = Mesh(np.asarray(v5e[:1]).reshape((1,) * len(AXES)), AXES)
+    one_chip = SingleDeviceSharding(v5e[0])
+    init_fn, step_fn = make_train_step(cfg, mesh)
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(init_fn.__wrapped__, jax.random.key(0)))
+    tokens = jax.ShapeDtypeStruct((batch, seq + 1), jnp.int32,
+                                  sharding=one_chip)
+    text = step_fn.lower(state, tokens).compile().as_text()
+    direct, reachable = _loop_bodies(text)
+
+    flash = [ln for ln in direct if "tpu_custom_call" in ln
+             and _FLASH_RESULT.match(_result(ln))]
+    assert len(flash) == (3 if policy == "dots" else 4), flash
+
+    q_size = batch * seq * cfg.n_heads * cfg.head_dim
+    k_size = batch * seq * cfg.kv_heads * cfg.head_dim
+    # (a line names its operands without their shapes; the half of an
+    # operand's lanes that ``apply_rope`` gathered is half of k's size)
+    moved = [ln.strip()[:160] for ln in reachable
+             if re.search(r" (?:gather|scatter)\(", ln)
+             and max(_elements(dims) for dims in
+                     re.findall(r"[a-z]+\d+\[([\d,]*)\]", ln)) >= k_size // 2]
+    assert not moved, moved
+    # an activation's copy: batch and sequence among its dimensions
+    in_f32 = [ln.strip()[:160] for ln in direct
+              for dims in re.findall(r"f32\[([\d,]*)\]", _result(ln))
+              if _elements(dims) >= q_size
+              and {str(batch), str(seq)} <= set(dims.split(","))]
+    assert not in_f32, in_f32
+
+
 def test_sharded_decode_step_compiles_on_four_chips(v5e):
     """``DecodeSession(mesh=)``'s layout — fused weights megatron-split
     over tp, KV cache batch-over-dp / kv-heads-over-tp — through one

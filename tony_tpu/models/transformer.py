@@ -35,11 +35,12 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tony_tpu.ops import (
-    apply_rope,
     flash_attention,
     rms_norm,
     rope_frequencies,
+    rotate_rope,
 )
+from tony_tpu.ops.attention import FLASH_RESIDUALS
 from tony_tpu.parallel.pipeline import pipeline_apply
 from tony_tpu.parallel.ring import ring_attention, ring_attention_local
 from tony_tpu.parallel.sharding import logical_spec, with_logical_constraint
@@ -72,20 +73,26 @@ class TransformerConfig:
     moe_zloss_coef: float = 1e-3
     dtype: str = "bfloat16"
     remat: bool = True
-    # "full": recompute the whole layer in backward (min memory);
-    # "dots": save matmul outputs, recompute elementwise (XLA's
-    # dots_with_no_batch_dims_saveable) — more memory, fewer recomputed
-    # flops, usually the better MFU point when the model fits.
+    # "full": recompute the whole layer in backward, keep nothing (min
+    # memory);
+    # "dots": keep what is expensive to recompute — matmul outputs (XLA's
+    # dots_with_no_batch_dims_saveable) AND the flash forward's result (o
+    # and the log-sum-exp, named in ops/attention.py: a Pallas call is no
+    # dot to that policy, which alone re-ran the forward kernel in every
+    # layer's backward) — and recompute the elementwise work. More memory
+    # (at Mistral-7B widths and 4 x 2,048 tokens about 1.1 GB a layer, 68
+    # MB of it the flash result), fewer recomputed flops: the better MFU
+    # point when the model fits.
     remat_policy: str = "full"
-    # Layer-loop scheduling. The rolled scan accumulates stacked [L, ...]
-    # gradients with dynamic-update-slices XLA cannot alias (measured 18%
-    # of a 2k train step in dus copies). Values >= n_layers bypass scan
-    # entirely for a static Python loop over static layer slices —
-    # scan-with-unroll STILL lowers stacked-grad updates to unfusable dus,
-    # so the loop is the fused form (measured ~7% then +2% step wins at
-    # L=8) — at the cost of ~L x trunk compile time. Intermediate values
-    # use scan's own unroll. 1 = rolled (default; dryruns/tests compile
-    # fast).
+    # Layer-loop scheduling. 1 = the rolled scan (default; dryruns/tests
+    # compile fast). Values >= n_layers bypass scan for a static Python
+    # loop over static layer slices, at ~L x the trunk's compile time;
+    # intermediate values use scan's own unroll. No chip record favours
+    # either form: on the v5e the rolled scan's dynamic-update-slice
+    # fusions over the stacked gradients ARE the weight-gradient matmuls
+    # writing into the stacked buffer, at 74-82% of the chip's peak
+    # (PERF.md section 5, PR 33's traced step), so there is no copy to
+    # win back by unrolling.
     layer_scan_unroll: int = 1
     # RMSNorm epsilon, every norm of the model.
     rms_eps: float = 1e-6
@@ -370,8 +377,8 @@ def _attention(x, lp, cfg, cos, sin, *, manual: bool, mesh: Mesh | None):
         sp = lax.axis_size("sp")
         t_local = x.shape[1]
         positions = lax.axis_index("sp") * t_local + jnp.arange(t_local)
-        q = apply_rope(q, cos, sin, positions=positions)
-        k = apply_rope(k, cos, sin, positions=positions)
+        q = rotate_rope(q, cos, sin, positions=positions)
+        k = rotate_rope(k, cos, sin, positions=positions)
         if sp > 1:
             o = ring_attention_local(
                 q, expand_kv(k), expand_kv(v), axis_name="sp", causal=True,
@@ -384,8 +391,8 @@ def _attention(x, lp, cfg, cos, sin, *, manual: bool, mesh: Mesh | None):
 
     q = with_logical_constraint(q, "batch", "seq", "heads", None, mesh=mesh)
     k = with_logical_constraint(k, "batch", "seq", "heads", None, mesh=mesh)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    q = rotate_rope(q, cos, sin)
+    k = rotate_rope(k, cos, sin)
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         o = ring_attention(q, expand_kv(k), expand_kv(v), mesh, causal=True)
     else:
@@ -611,12 +618,19 @@ def _decoder_layer(x, lp, cfg, cos, sin, *, manual: bool, mesh: Mesh | None):
 # ---------------------------------------------------------------------------
 
 def _remat_policy(cfg: TransformerConfig):
-    """None = save nothing (full recompute); the "dots" policy keeps matmul
-    outputs resident so the backward re-runs only elementwise work."""
+    """None = save nothing (full recompute); the "dots" policy keeps what
+    is expensive to recompute: matmul outputs and the flash forward's
+    result (a Pallas call is no dot to XLA's policy, which alone would
+    run the kernel twice a layer), so the backward re-runs only
+    elementwise work."""
     if cfg.remat_policy == "full":
         return None
     if cfg.remat_policy == "dots":
-        return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        policies = jax.checkpoint_policies
+        return policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable,
+            policies.save_only_these_names(*FLASH_RESIDUALS),
+        )
     raise ValueError(
         f"unknown remat_policy {cfg.remat_policy!r}; expected full|dots"
     )
@@ -645,10 +659,8 @@ def forward(
         layer_fn = jax.checkpoint(layer_fn, policy=_remat_policy(cfg))
 
     if cfg.layer_scan_unroll >= cfg.n_layers:
-        # Fully unrolled: a static Python loop over static slices beats
-        # scan-with-unroll — even unrolled, scan's stacked-grad updates
-        # lower to dynamic-update-slices XLA cannot fully fuse (measured
-        # +2% step throughput from the static loop at L=8/2k).
+        # Fully unrolled: a static Python loop over static slices (see
+        # ``layer_scan_unroll``; no chip record favours either form).
         aux_list = []
         for layer in range(cfg.n_layers):
             lp = jax.tree.map(lambda p: p[layer], params["layers"])

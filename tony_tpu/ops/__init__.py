@@ -17,7 +17,7 @@ from tony_tpu.ops.attention import (
 )
 from tony_tpu.ops.grouped import grouped_matmul
 from tony_tpu.ops.norms import rms_norm
-from tony_tpu.ops.rope import apply_rope, rope_frequencies
+from tony_tpu.ops.rope import apply_rope, rope_frequencies, rotate_rope
 from tony_tpu.ops.losses import softmax_cross_entropy
 
 __all__ = [
@@ -30,5 +30,6 @@ __all__ = [
     "rms_norm",
     "apply_rope",
     "rope_frequencies",
+    "rotate_rope",
     "softmax_cross_entropy",
 ]

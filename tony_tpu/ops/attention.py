@@ -30,6 +30,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.sharding import Mesh
 
@@ -577,12 +578,21 @@ def _flash_core(q, k, v, causal, scale, block_q, block_k, kernel):
     )
 
 
+# What the kernel's backward needs of its forward, under the names a
+# ``jax.checkpoint`` policy can keep them by: a Pallas call is not a dot,
+# so a policy that saves dots alone throws both away and runs the forward
+# kernel a second time in the backward to have them again.
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
+
+
 def _flash_core_fwd(q, k, v, causal, scale, block_q, block_k, kernel):
     if kernel:
         out, lse = _flash_attention_pallas(
             q, k, v, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, return_lse=True,
         )
+        out = checkpoint_name(out, FLASH_RESIDUALS[0])
+        lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
         return out, (q, k, v, out, lse)
     out = _blockwise_attention_jax(
         q, k, v, causal=causal, scale=scale, block_k=block_k
