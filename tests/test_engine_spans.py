@@ -250,6 +250,18 @@ def test_device_spans_carry_the_pairs_on_held_experts(served_layered):
     assert 0 < ex["pairs_held"] < ex["pairs_total"]
 
 
+def test_stats_count_the_passes_of_the_expert_layers(served_layered):
+    """``stats()["experts"]["passes"]``: the passes the expert layers
+    ran, which come back in each dispatch's one readback beside the
+    pairs. A toy's pair rows are few beside its weights, so each of the
+    2 expert layers runs one pass a dispatch (decode window 1): the
+    ratio a served model reads 1.0 at while no layer streams its
+    weights twice."""
+    ex = served_layered.stats()["experts"]
+    assert ex["dispatches"] > 0
+    assert ex["passes"] == ex["dispatches"] * 2
+
+
 def test_kv_counters_by_cache_kind(served_layered):
     eng = served_layered
     st = eng.stats()

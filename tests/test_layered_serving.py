@@ -24,7 +24,7 @@ sys.path.insert(0, str(PERFBENCH))
 from yardstick import spec, weights  # noqa: E402
 
 from tony_tpu.models import decode_weights, init_params  # noqa: E402
-from tony_tpu.models.decode import _moe_mlp_decode  # noqa: E402
+from tony_tpu.models.decode import _moe_mlp_decode, _pass_rows  # noqa: E402
 from tony_tpu.ops import (  # noqa: E402
     cache_decode_attention,
     cache_prefill_attention,
@@ -151,10 +151,10 @@ def prefill_logit_gap(model, reference, chunk, dtype):
         for j, i in enumerate(live):
             starts[j], valids[j] = plans[i][step]
             toks[j, :valids[j]] = rows[i][starts[j]:starts[j] + valids[j]]
-        k, v, _, logits, pairs = engine_lib.prefill_chunks(
+        k, v, _, logits, counts = engine_lib.prefill_chunks(
             fused, k, v, toks, np.asarray(live, np.int32), starts, valids,
             np.zeros(4, np.float32), key, np.int32(0), cfg=tcfg)
-        assert pairs.shape == (4,)
+        assert counts["pairs"].shape == (4,)
         for j, i in enumerate(live):
             at = starts[j] + valids[j] - 1
             worst = max(worst, float(np.max(np.abs(
@@ -278,10 +278,14 @@ def test_shipped_rows_continue_where_prefill_left(model, reference, cfg):
 
 
 # -- the expert layer ----------------------------------------------------------
-def expert_layer(model, reference, first, count, *, bias=None, tokens=24):
+def expert_layer(model, reference, first, count, *, bias=None, tokens=24,
+                 n_experts=8, token_mask=None, count_mask=None):
     """One expert layer of the program (held = first .. first + count of
-    8) and the UNCUT reference layer's weights, the same experts."""
-    cfg = dict(TINY, n_routed_experts=8, deployment={"experts_first": 0})
+    ``n_experts``) and the UNCUT reference layer's weights, the same
+    experts."""
+    cfg = dict(TINY, n_routed_experts=n_experts,
+               published={"n_routed_experts": n_experts},
+               deployment={"experts_first": 0})
     table = model.leaf_table(cfg)
     p = jax.tree.map(lambda w: w.astype(jnp.float32), weights.layer_tree(
         weights.seed_key(SEED), table, 1, jnp.float32))
@@ -297,8 +301,9 @@ def expert_layer(model, reference, first, count, *, bias=None, tokens=24):
           "gate_up": jnp.concatenate([p["experts_gate"][held],
                                       p["experts_up"][held]], -1),
           "w_down": p["experts_down"][held]}
-    out, pairs = jax.jit(lambda x: _moe_mlp_decode(x, lp, tcfg))(x)
-    return cfg, p, x, out, pairs
+    out, counts = jax.jit(lambda x: _moe_mlp_decode(
+        x, lp, tcfg, token_mask, count_mask))(x)
+    return cfg, p, x, out, counts
 
 
 def test_the_shares_of_one_layer_add_up_to_the_uncut_reference(
@@ -307,8 +312,9 @@ def test_the_shares_of_one_layer_add_up_to_the_uncut_reference(
     the reference's layer with all 8 (float32: 1e-5 is summation order)."""
     total, all_pairs = 0.0, 0
     for first in (0, 2, 4, 6):
-        cfg, p, x, out, pairs = expert_layer(model, reference, first, 2)
-        total, all_pairs = total + out, all_pairs + int(pairs.sum())
+        cfg, p, x, out, counts = expert_layer(model, reference, first, 2)
+        total = total + out
+        all_pairs += int(counts["pairs"].sum())
     want = reference.experts(x, p, cfg, lambda a: a, first=0) - x
     assert all_pairs == x.shape[0] * x.shape[1] * 3
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),
@@ -330,8 +336,8 @@ def test_no_pair_is_dropped_under_any_routing(model, reference, held,
     capacity drops one); a chip that holds 0-2 gets none and adds
     nothing. Both equal the reference."""
     bias = [0, 0, 0, 0, 0, 50, 50, 50]
-    cfg, p, x, out, pairs = expert_layer(model, reference, *held, bias=bias)
-    assert int(pairs.sum()) == landing
+    cfg, p, x, out, counts = expert_layer(model, reference, *held, bias=bias)
+    assert int(counts["pairs"].sum()) == landing
     part = {k: v[held[0]:held[0] + held[1]] if k.startswith("experts_")
             else v for k, v in p.items()}
     want = reference.experts(x, part, cfg, lambda a: a, first=held[0]) - x
@@ -341,30 +347,104 @@ def test_no_pair_is_dropped_under_any_routing(model, reference, held,
         assert not np.asarray(out).any()
 
 
-@pytest.mark.parametrize("sizes", [[3, 0, 100, 17, 40], [0, 0, 0, 0, 256],
-                                   [0, 0, 0, 0, 0]],
-                         ids=["uneven", "all-on-one", "none"])
-def test_grouped_matmul_kernel_equals_ragged_dot(sizes):
+# 512 tokens x top-3 over 32 experts: 1,536 pair rows against 1-3 held
+# experts' weights is a shape that takes its pairs in passes.
+PASS_TOKENS = 512
+TO_5_6_7 = [0.0] * 5 + [50.0] * 3 + [0.0] * 24
+FIRST_257 = np.arange(PASS_TOKENS).reshape(2, -1) < 257
+
+
+@pytest.mark.parametrize("held,bias,token_mask,count_mask,rows,passes", [
+    ((4, 2), None, None, None, 384, 1),
+    ((5, 3), TO_5_6_7, None, None, 640, 3),
+    ((0, 2), TO_5_6_7, None, None, 384, 0),
+    ((5, 1), TO_5_6_7, FIRST_257, None, 256, 2),
+    ((5, 2), TO_5_6_7, ~FIRST_257, FIRST_257, 384, 2),
+], ids=["uniform", "every-pair-here", "no-pair-here", "one-over-a-pass",
+        "token-and-count-masks"])
+def test_passes_drop_no_pair_and_equal_the_reference(
+        model, reference, held, bias, token_mask, count_mask, rows, passes):
+    """The expert layer where it takes its pairs in PASSES of ``rows``
+    (``_pass_rows``), against the plain float32 reference's share: a
+    routing near uniform (one pass); a selection bias that sends every
+    token to experts 5, 6 and 7 onto a chip that holds all three (all
+    1,536 pairs land: three passes of 640, the last padded, none
+    dropped), that holds none (no pass, exactly zero), that holds
+    expert 5 with 257 tokens taking part (one pair over a pass: two),
+    and with both masks set (255 tokens take part on two experts: 510
+    pairs in two passes; none of them counted)."""
+    first, count = held
+    cfg, p, x, out, counts = expert_layer(
+        model, reference, first, count, bias=bias, tokens=PASS_TOKENS,
+        n_experts=32,
+        token_mask=None if token_mask is None else jnp.asarray(token_mask),
+        count_mask=None if count_mask is None else jnp.asarray(count_mask))
+    assert _pass_rows(PASS_TOKENS * 3, 32, count, 32, 4,
+                      count * 3 * 32 * 16 * 4) == rows
+    assert int(counts["passes"]) == passes
+    part = {k: v[first:first + count] if k.startswith("experts_") else v
+            for k, v in p.items()}
+    want = np.asarray(reference.experts(x, part, cfg, lambda a: a,
+                                        first=first) - x)
+    taking_part = np.ones((2, PASS_TOKENS // 2), bool)
+    if token_mask is not None:
+        taking_part = token_mask
+        want = want * token_mask[..., None]
+    np.testing.assert_allclose(np.asarray(out), want, atol=1e-5, rtol=0)
+    if bias is not None:
+        # every token that takes part sends one pair to each of 5, 6, 7
+        landing = count * int(taking_part.sum()) if first == 5 else 0
+        counted = taking_part if count_mask is None else (
+            taking_part & count_mask)
+        assert (landing + rows - 1) // rows == passes
+        assert int(counts["pairs"].sum()) == (
+            count * int(counted.sum()) if first == 5 else 0)
+    if not passes:
+        assert not np.asarray(out).any()
+
+
+@pytest.mark.parametrize("nk,rows", [(4096, 1024), (512, 512)],
+                         ids=["prefill-round", "decode-iteration"])
+def test_pass_rows_at_the_served_widths(nk, rows):
+    """MiMo-V2-Flash's share on one chip (16 of 256 experts, d 4096, F
+    2048, bfloat16: 805 MB of weights): a prefill round's 4,096 pair
+    rows go in passes of 1,024, a decode iteration's 512 in one."""
+    held_bytes = 16 * 3 * 4096 * 2048 * 2
+    assert _pass_rows(nk, 4096, 16, 256, 2, held_bytes) == rows
+    # every expert held: the worst case is the mean, nothing to follow
+    assert _pass_rows(nk, 4096, 256, 256, 2, 16 * held_bytes) == nk
+
+
+@pytest.mark.parametrize("sizes,k", [([3, 0, 100, 17, 40], 256),
+                                     ([0, 0, 0, 0, 256], 256),
+                                     ([0, 0, 0, 0, 0], 256),
+                                     ([70, 0, 1, 57, 100], 8192)],
+                         ids=["uneven", "all-on-one", "none", "two-k-tiles"])
+def test_grouped_matmul_kernel_equals_ragged_dot(sizes, k):
     """The grouped product's Pallas kernel (interpret mode) against
     ``lax.ragged_dot`` on the rows that belong to a group: groups of
     uneven size, an empty group, every row on one group, and no row at
-    all; rows past the groups' sum are undefined and not compared.
-    Float32: 1e-4 is summation order over k = 256."""
+    all; rows past the groups' sum are undefined and not compared. The
+    tiles keep all of k = 256 (one k tile: the rows stay resident); k =
+    8,192 in float32 is past what a [k, 128] tile may hold, and runs as
+    two. Float32: 1e-4 (1e-3 at k = 8,192) is summation order."""
     from tony_tpu.ops import grouped_matmul
+    from tony_tpu.ops.grouped import _tiling
 
+    n = 384 if k == 256 else 128
+    assert _tiling(256, k, n, 4) == (128, min(k, 4096), 128)
     k1, k2 = jax.random.split(jax.random.key(0))
-    lhs = jax.random.normal(k1, (256, 256), jnp.float32)
-    rhs = jax.random.normal(k2, (5, 256, 384), jnp.float32)
+    lhs = jax.random.normal(k1, (256, k), jnp.float32)
+    rhs = jax.random.normal(k2, (5, k, n), jnp.float32)
     sizes = jnp.asarray(sizes, jnp.int32)
     want = grouped_matmul(lhs, rhs, sizes, mode="jax")
     got = grouped_matmul(lhs, rhs, sizes, mode="interpret")
-    n = int(sizes.sum())
-    assert got.shape == want.shape == (256, 384)
-    np.testing.assert_allclose(np.asarray(got[:n]), np.asarray(want[:n]),
-                               atol=1e-4, rtol=0)
+    rows = int(sizes.sum())
+    assert got.shape == want.shape == (256, n)
+    np.testing.assert_allclose(np.asarray(got[:rows]), np.asarray(want[:rows]),
+                               atol=1e-4 if k == 256 else 1e-3, rtol=0)
 
 
-# -- the decode kernel, interpret mode against plain jnp ------------------------
 @pytest.mark.parametrize("name,h_kv,window,sink,block_rows", [
     ("full", 4, 0, False, 64),
     ("ring", 8, 16, True, 4096),

@@ -712,8 +712,8 @@ class TestDecode:
         fused = decode_weights(init_params(jax.random.key(11), cfg), cfg)
         lp = jax.tree.map(lambda w: w[1], fused["layers"])
         x = jax.random.normal(jax.random.key(5), (3, 7, 32))
-        got, pairs = jax.jit(lambda x: _moe_mlp_decode(x, lp, cfg))(x)
-        assert int(pairs.sum()) == 3 * 7 * 2
+        got, counts = jax.jit(lambda x: _moe_mlp_decode(x, lp, cfg))(x)
+        assert int(counts["pairs"].sum()) == 3 * 7 * 2
         hn = rms_norm(x, lp["ln2"], eps=cfg.rms_eps)
         _, _, gvals, gidx = _route_tokens(hn, lp["router"], 2)
         weight = (jax.nn.one_hot(gidx, n_experts) * gvals[..., None]).sum(2)

@@ -28,10 +28,15 @@ here, and why here:
   land on the experts held here, sorted by expert, through two grouped
   matrix products (``ops.grouped_matmul``: on a TPU a Pallas kernel
   whose work follows the rows that are there and whose tiles are sized
-  to stream each visited expert's weights once) and added back under
-  their router weights. No capacity, static shapes, no pair dropped
-  under any routing; running every held expert on every token would be
-  32x the needed FLOPs where a token uses 0.5 of 16 held experts.
+  to stream each visited expert's weights once, the rows resident
+  beside them) and added back under their router weights. Static
+  shapes, no capacity, no pair dropped under any routing; running every
+  held expert on every token would be 32x the needed FLOPs where a token
+  uses 0.5 of 16 held experts. Where the worst case's pair rows would be
+  a real share of the weights' bytes (a prefill round over a small share
+  of the experts) the sorted pairs go in passes of a static number of
+  rows, as many passes as the pairs that landed need: one at any routing
+  near uniform, and the arrays follow the pairs, not the worst case.
 * ``advance``'s cache policy: one jittable call handles both prefill
   (S = prompt length) and single-token steps (S = 1), static shapes per
   call site, so XLA compiles exactly two executables for a whole
@@ -67,6 +72,7 @@ from tony_tpu.ops import (
     rms_norm,
     rope_frequencies,
 )
+from tony_tpu.ops.grouped import ROW_TILE
 
 NEG_INF = -1e30
 
@@ -279,6 +285,30 @@ def rope_tables(cfg: TransformerConfig) -> dict:
     }
 
 
+# The expert layer takes its pairs in passes from where ONE array of the
+# worst case's pair rows is a 48th of the held weights' bytes: the layer
+# reads or writes about six such arrays (the gathered rows, gate|up's
+# result and the activation each written and read, the down product's
+# result and its un-sort), an eighth of what the weights stream.
+_PASSES_FROM = 48
+
+
+def _pass_rows(nk: int, d: int, held: int, n_experts: int, itemsize: int,
+               weight_bytes: int) -> int:
+    """Pair rows one pass of the expert layer takes. All ``nk`` — one
+    pass whatever the routing, arrays of the worst case's size — unless
+    those arrays are a real share of the held weights' bytes AND the
+    experts held here see a small share of the pairs; then four times
+    the mean that lands here, in whole row tiles of the grouped product
+    (1,024 of a 4,096-row prefill round over 16 of 256 experts; a decode
+    iteration's 512 rows, 4 MB arrays against 805 MB of weights, stay
+    one pass)."""
+    rows = -(-4 * nk * held // (n_experts * ROW_TILE)) * ROW_TILE
+    if 2 * rows > nk or _PASSES_FROM * nk * d * itemsize < weight_bytes:
+        return nk
+    return rows
+
+
 def _moe_mlp_decode(x, lp, cfg, token_mask=None, count_mask=None):
     """An expert layer for prefill and decode alike: dropless, grouped.
 
@@ -286,24 +316,39 @@ def _moe_mlp_decode(x, lp, cfg, token_mask=None, count_mask=None):
     (``_route_tokens``: softmax or sigmoid, a selection bias where the
     model has one); the (token, choice) pairs that land on the experts
     HELD here (``cfg.held``; all of them by default) are sorted by
-    expert and go through two grouped matrix products over ``gate_up``
-    [held, d, 2F] and ``w_down`` [held, F, d] (``ops.grouped_matmul``),
-    then back to their tokens under the router's weights — normalised
-    over all k choices, so a share of the experts gives its own part of
-    the layer's result and nothing stands in for the rest. Shapes are
-    static (b*t*k pair rows, the worst case), no pair is dropped under
-    any routing, and the work follows the rows really there.
+    expert, the rest last, and go through two grouped matrix products
+    over ``gate_up`` [held, d, 2F] and ``w_down`` [held, F, d]
+    (``ops.grouped_matmul``), then back to their tokens under the
+    router's weights — normalised over all k choices, so a share of the
+    experts gives its own part of the layer's result and nothing stands
+    in for the rest.
+
+    Shapes are static and no pair is dropped under any routing. Where
+    the b*t*k pair rows of the worst case are small beside the weights
+    (``_pass_rows``: a decode batch, every test-sized model) every array
+    has that many rows and the grouped products' work follows the rows
+    really there. Where they are not — a prefill round's 4,096 rows
+    moved ~180 MB a layer beside 805 MB of weights, for the ~256 pairs
+    that land on 16 of 256 experts — the sorted pairs are taken in
+    PASSES of a static ``C`` rows: gather the pass's ``C`` token rows,
+    the two products with each group's size clipped to the pass, add
+    the results to their tokens. ``ceil(pairs here / C)`` passes run, a
+    traced count: all b*t*k pairs can land here and then all are
+    computed, in ``b*t*k / C`` passes, so there is no capacity; at any
+    routing near uniform ``C`` is four times what lands here, one pass
+    runs and the weights stream once.
 
     ``token_mask`` [b, t]: tokens whose pairs take no part (idle lanes
     of a decode batch). ``count_mask`` [b, t]: tokens that are COMPUTED
     but not counted (a prefill batch's padding rows, which must write
-    the K/V of the row they duplicate). Returns (out [b, t, d], pairs
-    [held] int32: the counted pairs each held expert received)."""
+    the K/V of the row they duplicate). Returns (out [b, t, d], counts:
+    ``pairs`` [held] int32, the counted pairs each held expert received,
+    and ``passes`` int32, the passes the layer ran)."""
     from tony_tpu.models.transformer import _route_tokens
 
     dt = cfg.compute_dtype
     b, t, d = x.shape
-    k = cfg.expert_top_k
+    n, k = b * t, cfg.expert_top_k
     first, held = cfg.held
     hn32 = rms_norm(x.astype(jnp.float32), lp["ln2"], eps=cfg.rms_eps)
     _, _, gvals, gidx = _route_tokens(
@@ -315,38 +360,81 @@ def _moe_mlp_decode(x, lp, cfg, token_mask=None, count_mask=None):
         here &= jnp.repeat(token_mask.reshape(-1), k)
     # Pairs sorted by held expert; the rest sort last, into no group.
     key = jnp.where(here, local, held)
-    order = jnp.argsort(key, stable=True)
     on_expert = key[:, None] == jnp.arange(held)           # [n*k, held]
     sizes = on_expert.sum(0, dtype=jnp.int32)
     pairs = sizes
     if count_mask is not None:
         counted = jnp.repeat(count_mask.reshape(-1), k)
         pairs = (on_expert & counted[:, None]).sum(0, dtype=jnp.int32)
-    rows = hn32.astype(dt).reshape(b * t, d)[order // k]   # [n*k, d]
-    gu = grouped_matmul(rows, lp["gate_up"], sizes)
-    f = gu.shape[-1] // 2
-    act = (
-        jax.nn.silu(gu[:, :f].astype(jnp.float32)).astype(dt) * gu[:, f:]
-    )
-    y = grouped_matmul(act, lp["w_down"], sizes)           # [n*k, d]
-    # Back in (token, choice) order; rows of no group carry whatever the
-    # grouped product left there, so they are selected out, not weighed.
-    y = y[jnp.argsort(order)]
-    w = jnp.where(here, gvals.reshape(-1), 0.0)
-    out = jnp.where(here[:, None], y.astype(jnp.float32) * w[:, None], 0.0)
-    return out.reshape(b, t, k, d).sum(2).astype(dt), pairs
+    rows_of = hn32.astype(dt).reshape(n, d)
+    f = lp["w_down"].shape[1]
+
+    def experts(rows, group):
+        gu = grouped_matmul(rows, lp["gate_up"], group)
+        act = (
+            jax.nn.silu(gu[:, :f].astype(jnp.float32)).astype(dt) * gu[:, f:]
+        )
+        return grouped_matmul(act, lp["w_down"], group)
+
+    c = _pass_rows(n * k, d, held, cfg.n_experts, dt.itemsize,
+                   sum(w.size for w in (lp["gate_up"], lp["w_down"]))
+                   * dt.itemsize)
+    if c == n * k:
+        order = jnp.argsort(key, stable=True)
+        y = experts(rows_of[order // k], sizes)            # [n*k, d]
+        # Back in (token, choice) order; rows of no group carry whatever
+        # the grouped product left there, so they are selected out, not
+        # weighed.
+        y = y[jnp.argsort(order)]
+        w = jnp.where(here, gvals.reshape(-1), 0.0)
+        out = jnp.where(here[:, None], y.astype(jnp.float32) * w[:, None],
+                        0.0).reshape(b, t, k, d).sum(2)
+        passes = jnp.ones((), jnp.int32)
+    else:
+        # One sort carries each pair's index and router weight along.
+        _, order, weight = lax.sort(
+            (key, jnp.arange(n * k, dtype=jnp.int32), gvals.reshape(-1)),
+            num_keys=1, is_stable=True)
+        whole = (0, -(n * k) % c)                 # the last pass, in full
+        order, weight = jnp.pad(order, whole), jnp.pad(weight, whole)
+        ends = jnp.cumsum(sizes)
+
+        def one_pass(i, out):
+            lo = i * c
+            token = lax.dynamic_slice(order, (lo,), (c,)) // k
+            w = lax.dynamic_slice(weight, (lo,), (c,))
+            y = experts(rows_of[token],
+                        jnp.diff(jnp.clip(ends - lo, 0, c), prepend=0))
+            # Back to the tokens in ONE product: row r's router weight
+            # stands in its token's row of a [n, c] matrix (rows past
+            # the pairs that landed: nowhere, and their undefined result
+            # selected out). ``HIGH``: three bfloat16 passes carry the
+            # weight and the result to 16 bits and more, where the
+            # layer's result keeps 8. n*c*d multiply-adds: cheaper than
+            # an un-sort of b*t*k rows while c is a few tokens' worth
+            # (PERF.md section 7).
+            landed = lo + jnp.arange(c) < ends[-1]
+            back = jnp.where(
+                (token == jnp.arange(n)[:, None]) & landed, w, 0.0)
+            y = jnp.where(landed[:, None], y, 0).astype(jnp.float32)
+            return out + jnp.dot(back, y, precision=lax.Precision.HIGH)
+
+        passes = (ends[-1] + c - 1) // c
+        out = lax.fori_loop(0, passes, one_pass,
+                            jnp.zeros((n, d), jnp.float32)).reshape(b, t, d)
+    return out.astype(dt), {"pairs": pairs, "passes": passes}
 
 
 def _mlp(x, lp, cfg, token_mask=None, count_mask=None):
     """SwiGLU over the fused gate|up projection (training's
     ``_dense_mlp`` in one matmul instead of two), or the grouped expert
     layer (``_moe_mlp_decode``: dropless, the held experts' part).
-    Returns (x, pairs): the (token, choice) pairs each held expert
-    received, None for a dense layer."""
+    Returns (x, counts): the expert layer's counters (``pairs`` each
+    held expert received and ``passes``), None for a dense layer."""
     dt = cfg.compute_dtype
     if "router" in lp:
-        out, pairs = _moe_mlp_decode(x, lp, cfg, token_mask, count_mask)
-        return x + out, pairs
+        out, counts = _moe_mlp_decode(x, lp, cfg, token_mask, count_mask)
+        return x + out, counts
     hn = rms_norm(x, lp["ln2"], eps=cfg.rms_eps).astype(dt)
     gu = jnp.einsum("btd,df->btf", hn, lp["gate_up"])
     f = gu.shape[-1] // 2
@@ -366,7 +454,7 @@ def serve_layer(x, lp, attn, cfg, ropes, positions, attend, *,
     SwiGLU or experts). ``attend(q, k_new, v_new, attn, sink) -> o``
     writes the new rows into the caller's cache and reads it: the one
     thing ``advance``, decode and prefill do differently. Returns
-    (x, pairs)."""
+    (x, counts): the expert layer's counters, None for a dense layer."""
     dt = cfg.compute_dtype
     b, t, _ = x.shape
     n_h, h_kv = cfg.n_heads, cfg.kv_heads_of(attn)
@@ -394,38 +482,39 @@ def serve_layer(x, lp, attn, cfg, ropes, positions, attend, *,
 
 def run_layers(x, params, k_all, v_all, cfg, layer):
     """Every layer in model order. ``layer(x, lp, attn, at, k_all,
-    v_all) -> (x, k_all, v_all, pairs)`` with ``at`` the layer's index
+    v_all) -> (x, k_all, v_all, counts)`` with ``at`` the layer's index
     in its attention kind's cache stack. A uniform model: one
     ``lax.scan`` over the stacked layers, the caches as CARRY (as xs/ys
     the scan slices every layer's cache out and re-stacks it each call,
     the whole cache re-written per token; as carry a layer's update is
     one small aliased write). A layered model: a static loop over its
-    tuple of layers. Returns (x, k_all, v_all, pairs summed over the
-    expert layers or None)."""
+    tuple of layers. Returns (x, k_all, v_all, the expert layers'
+    counters summed or None)."""
     if isinstance(params["layers"], tuple):
         seen: dict = {}
         total = None
         for lp, (attn, _) in zip(params["layers"], cfg.layer_kinds):
             at = seen.get(attn, 0)
             seen[attn] = at + 1
-            x, k_all, v_all, pairs = layer(x, lp, attn, jnp.int32(at),
-                                           k_all, v_all)
-            if pairs is not None:
-                total = pairs if total is None else total + pairs
+            x, k_all, v_all, counts = layer(x, lp, attn, jnp.int32(at),
+                                            k_all, v_all)
+            if counts is not None:
+                total = (counts if total is None
+                         else jax.tree.map(jnp.add, total, counts))
         return x, k_all, v_all, total
     attn = cfg.layer_kinds[0][0]
 
     def body(carry, layer_in):
         x, k_all, v_all = carry
         lp, at = layer_in
-        x, k_all, v_all, pairs = layer(x, lp, attn, at, k_all, v_all)
-        return (x, k_all, v_all), pairs
+        x, k_all, v_all, counts = layer(x, lp, attn, at, k_all, v_all)
+        return (x, k_all, v_all), counts
 
-    (x, k_all, v_all), pairs = lax.scan(
+    (x, k_all, v_all), counts = lax.scan(
         body, (x, k_all, v_all),
         (params["layers"], jnp.arange(cfg.n_layers)),
     )
-    return x, k_all, v_all, None if pairs is None else pairs.sum(0)
+    return x, k_all, v_all, jax.tree.map(lambda c: c.sum(0), counts)
 
 
 def lm_head(x, params, cfg):

@@ -418,9 +418,11 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
     token lies at ``Tmax - 2`` at most). Parked lanes send no pair to an
     expert.
 
-    Returns (k_all, v_all, window_tokens [S, steps] int32, pairs): the
-    (token, choice) pairs each held expert received over the window, or
-    None for a model without experts.
+    Returns (k_all, v_all, window_tokens [S, steps] int32, counts): the
+    expert layers' counters summed over the window (``pairs``: the
+    (token, choice) pairs each held expert received; ``passes``: the
+    passes the layers ran, ``_moe_mlp_decode``), or None for a model
+    without experts.
     """
     dt = cfg.compute_dtype
     t_max = _cache_tmax(_kind(k_all, "full"))
@@ -455,25 +457,25 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
                 v_all = _with_kind(v_all, attn, vc)
                 return o
 
-            x, pairs = serve_layer(x, lp, attn, cfg, ropes, rp, attend,
-                                   token_mask=~parked[:, None])
-            return x, k_all, v_all, pairs
+            x, counts = serve_layer(x, lp, attn, cfg, ropes, rp, attend,
+                                    token_mask=~parked[:, None])
+            return x, k_all, v_all, counts
 
-        x, k_all, v_all, pairs = run_layers(x, params, k_all, v_all, cfg,
-                                            layer)
+        x, k_all, v_all, counts = run_layers(x, params, k_all, v_all, cfg,
+                                             layer)
         nxt = _sample_slots(
             lm_head(x[:, -1:], params, cfg), temp,
             jax.random.fold_in(base_key, draw0 + i)
         )
         pos = pos + 1
         wpos = jnp.minimum(wpos + 1, t_max - 1)
-        return (k_all, v_all, pos, wpos, nxt), (nxt, pairs)
+        return (k_all, v_all, pos, wpos, nxt), (nxt, counts)
 
-    (k_all, v_all, _, _, _), (toks, pairs) = lax.scan(
+    (k_all, v_all, _, _, _), (toks, counts) = lax.scan(
         one_step, (k_all, v_all, pos, wpos, tokens), jnp.arange(steps)
     )
     return (k_all, v_all, toks.T,   # [S, steps]
-            None if pairs is None else pairs.sum(0))
+            jax.tree.map(lambda c: c.sum(0), counts))
 
 
 @functools.partial(
@@ -502,13 +504,14 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
     layers) ``ops.cache_prefill_attention`` over the stack where it
     lies, which reads no key past ``starts[i] + C``.
 
-    Returns (k_all, v_all, first_tokens [P], logits [P, V] fp32, pairs):
+    Returns (k_all, v_all, first_tokens [P], logits [P, V] fp32, counts):
     row i's token samples from position ``n_valids[i] - 1`` — meaningful
     only on a request's FINAL chunk (earlier chunks' sample is
     discarded by the scheduler; computing it unconditionally keeps one
-    executable). ``pairs``: the (token, choice) pairs each held expert
-    received from the rows' valid tokens, a duplicated row counted
-    once; None for a model without experts."""
+    executable). ``counts``: the expert layers' counters summed
+    (``pairs``: the (token, choice) pairs each held expert received from
+    the rows' valid tokens, a duplicated row counted once; ``passes``:
+    the passes the layers ran); None for a model without experts."""
     dt = cfg.compute_dtype
     p, c = tokens.shape
     t_max = _cache_tmax(_kind(k_all, "full"))
@@ -555,15 +558,15 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
             v_all = _with_kind(v_all, attn, vc)
             return o
 
-        x, pairs = serve_layer(x, lp, attn, cfg, ropes, rope_pos, attend,
-                               count_mask=counted)
-        return x, k_all, v_all, pairs
+        x, counts = serve_layer(x, lp, attn, cfg, ropes, rope_pos, attend,
+                                count_mask=counted)
+        return x, k_all, v_all, counts
 
-    x, k_all, v_all, pairs = run_layers(x, params, k_all, v_all, cfg, layer)
+    x, k_all, v_all, counts = run_layers(x, params, k_all, v_all, cfg, layer)
     last = jnp.take_along_axis(
         x, jnp.maximum(n_valids - 1, 0)[:, None, None], axis=1
     )                                                          # [P, 1, d]
     logits = lm_head(last, params, cfg)
     toks = _sample_slots(logits, temps,
                          jax.random.fold_in(base_key, draw))
-    return k_all, v_all, toks, logits, pairs
+    return k_all, v_all, toks, logits, counts

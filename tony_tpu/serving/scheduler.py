@@ -50,7 +50,8 @@ the counters taken at the same boundaries (``phase_ms``, ``kv``,
 ``queue_wait_ms`` ...). Idle polls record and count nothing.
 (*) A model with experts only: the dispatch's (token, choice) pairs on
 the experts held here, which come back with the tokens in the one
-readback; ``stats()["experts"]`` sums them per held expert. A model with
+readback; ``stats()["experts"]`` sums them per held expert, and beside
+them the passes the expert layers ran (``passes``). A model with
 window layers has a cache stack per attention kind, and
 ``stats()["kv"]["kinds"]`` counts each (the top-level ``kv`` keys stay
 the full kind's).
@@ -368,6 +369,7 @@ class ServingEngine:
         self._expert_pairs = np.zeros(cfg.held[1], np.int64)
         self._expert_tokens = 0
         self._expert_dispatches = 0
+        self._expert_passes = 0
         self._queue_wait_ms: deque[float] = deque(maxlen=_LATENCY_RING)
         self._prefill_span_ms: deque[float] = deque(maxlen=_LATENCY_RING)
         self._ids = itertools.count()
@@ -681,6 +683,7 @@ class ServingEngine:
                     "pairs_total": (self._expert_tokens * n_moe
                                     * self.cfg.expert_top_k),
                     "dispatches": self._expert_dispatches,
+                    "passes": self._expert_passes,
                 }
             queue_wait = list(self._queue_wait_ms)
             prefill_span = list(self._prefill_span_ms)
@@ -842,7 +845,7 @@ class ServingEngine:
         with tr.span("tony:engine.decode_device", slots=n_active,
                      window=w) as sp, \
                 jit_sanitizer.step_region("serving_decode_window"):
-            self._k, self._v, window, pair_counts = self._decode(
+            self._k, self._v, window, expert_counts = self._decode(
                 self.params, self._k, self._v, self._pos, wpos,
                 self._last, self._temp, self._base_key,
                 np.int32((self._decode_calls * w) % 2**30),
@@ -850,11 +853,11 @@ class ServingEngine:
             self._decode_calls += 1
             # Iteration fence: EXPLICIT readback, so the armed
             # transfer guard (jit sanitizer) lets it through. The
-            # experts' pair counts come back in the same readback.
-            toks, pairs = jax.device_get((window, pair_counts))  # tony: noqa[TONY-X002] — intended per-window fence
+            # experts' counters come back in the same readback.
+            toks, counts = jax.device_get((window, expert_counts))  # tony: noqa[TONY-X002] — intended per-window fence
             toks = np.asarray(toks)
-            if pairs is not None:
-                sp.set(expert_pairs=self._note_pairs(pairs, n_active * w))
+            if counts is not None:
+                sp.set(expert_pairs=self._note_pairs(counts, n_active * w))
         it["decode_device"] = sp.dur_ns
         self._decode_iters += 1
         self._decode_slots_sum += n_active
@@ -1062,16 +1065,16 @@ class ServingEngine:
         self._prefill_keys_reserved += n * self.max_len * self._full_layers
         with tr.span("tony:engine.prefill_device", keys_read=keys_read) as sp, \
                 jit_sanitizer.step_region("serving_prefill_chunks"):
-            self._k, self._v, first_toks, _, pair_counts = self._prefill(
+            self._k, self._v, first_toks, _, expert_counts = self._prefill(
                 self.params, self._k, self._v, toks, slots_a, starts,
                 n_valids, temps, self._base_key,
                 np.int32(2**30 + self._pf_draws % 2**30),
             )
-            firsts, pairs = jax.device_get((first_toks, pair_counts))  # tony: noqa[TONY-X002] — intended per-round fence
+            firsts, counts = jax.device_get((first_toks, expert_counts))  # tony: noqa[TONY-X002] — intended per-round fence
             firsts = np.asarray(firsts)
-            if pairs is not None:
+            if counts is not None:
                 sp.set(expert_pairs=self._note_pairs(
-                    pairs, int(n_valids[:n].sum())))
+                    counts, int(n_valids[:n].sum())))
         it["prefill_device"] += sp.dur_ns
         with tr.span("tony:engine.emit") as sp:
             now = time.perf_counter()
@@ -1118,16 +1121,20 @@ class ServingEngine:
                     self._pf.extend(requeue)
         it["emit"] += sp.dur_ns
 
-    def _note_pairs(self, pairs, tokens: int) -> int:
-        """One dispatch's (token, choice) pairs per held expert (a host
-        array: it came back in the dispatch's fenced readback), summed
-        over its expert layers, into stats()["experts"]; returns the
-        dispatch's pairs on held experts (the device span's attr)."""
+    def _note_pairs(self, counts: dict, tokens: int) -> int:
+        """One dispatch's expert counters (host arrays: they came back
+        in the dispatch's fenced readback), summed over its expert
+        layers, into stats()["experts"]: the (token, choice) pairs per
+        held expert and the passes the layers ran (``passes`` over
+        ``dispatches`` x expert layers = 1.0: no layer streamed its
+        weights twice). Returns the dispatch's pairs on held experts
+        (the device span's attr)."""
         with self._cond:
-            self._expert_pairs += pairs
+            self._expert_pairs += counts["pairs"]
             self._expert_tokens += tokens
             self._expert_dispatches += 1
-        return int(pairs.sum())
+            self._expert_passes += int(counts["passes"])
+        return int(counts["pairs"].sum())
 
     def _retire(self, slot: int) -> None:
         req = self._slot_req[slot]

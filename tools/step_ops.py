@@ -28,8 +28,10 @@ from pathlib import Path
 
 def table(reduced: dict) -> dict:
     """{"program", "calls", "program_median_ms", "window_s", "busy_s",
+    "programs": every traced program's {calls, total_s, median_s},
     "ops": [[name, calls per program call, self ms per program call],
-    ...] by time}, the program being the one with most total time. The
+    ...] by time}, the program being the one with most total time (a
+    serving cell has two: a decode iteration and a prefill round). The
     window cuts its first and last call, so where one program is all that
     ran (a train step) the calls are counted as busy time over the
     program's median, not as the whole calls that started inside."""
@@ -46,7 +48,7 @@ def table(reduced: dict) -> dict:
     return {"program": name, "calls": calls,
             "program_median_ms": 1000.0 * programs[name]["median_s"],
             "window_s": reduced["window_s"], "busy_s": reduced["busy_s"],
-            "ops": ops}
+            "programs": programs, "ops": ops}
 
 
 def main() -> int:
@@ -77,6 +79,10 @@ def main() -> int:
     print(f"{out['program']}: {out['calls']:.2f} calls in the traced window "
           f"of {out['window_s']:.3f} s (busy {out['busy_s']:.3f} s), median "
           f"{out['program_median_ms']:.3f} ms a call")
+    for name, program in sorted(out["programs"].items()):
+        print(f"  {name}: {program['calls']} calls, median "
+              f"{1000.0 * program['median_s']:.3f} ms, "
+              f"{program['total_s']:.3f} s in all")
     for name, calls, ms in out["ops"]:
         print(f"{ms:9.3f} ms {calls:7.2f} x  {name}")
     print(json.dumps({k: result[k] for k in
