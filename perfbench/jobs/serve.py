@@ -13,8 +13,8 @@ of the model.
 
 Names of the program this file depends on: ``rt.initialize``,
 ``rt.build_job_mesh``, ``decode_weights`` (the fused serving layout),
-``ServingEngine`` (constructor, ``start``, ``step``, ``submit``,
-``stats``, ``close``), ``ServingServer`` (``start``, ``wait_shutdown``,
+``ServingEngine`` (constructor, ``start``, ``submit``, ``stats``,
+``tokens_generated``, ``close``), ``ServingServer`` (``start``, ``wait_shutdown``,
 ``stop``), and the executor's ``TB_PORT`` and
 ``TONY_SERVING_PREFILL_CHUNK`` / ``TONY_SERVING_DECODE_WINDOW``."""
 
@@ -83,14 +83,6 @@ def main() -> int:
         kv_quant="none",     # the configuration states a bfloat16 cache
     )
     del fused
-    if p.get("trace"):
-        inner_step = engine.step
-
-        def traced_step():
-            with jax.profiler.TraceAnnotation("bench:engine-step"):
-                return inner_step()
-
-        engine.step = traced_step
     if p.get("fault"):   # test only: an answer altered where it is made
         inner_submit = engine.submit
 
@@ -113,22 +105,37 @@ def main() -> int:
             return req
 
         engine.submit = faulty_submit
+
+    def generated() -> int:
+        """The engine's own count of the tokens it has made so far."""
+        n = engine.tokens_generated
+        if p.get("fault") == "inflate_counter":
+            n += n // 16 + 1     # test only: a counter adrift of the answers
+        return n
+
     engine.start()
     server = ServingServer(engine, port=int(os.environ.get("TB_PORT") or 0))
     port = server.start()
     work.publish("addr.json", {"host": "127.0.0.1", "port": port})
     work.stage("server_listening")
 
-    # Serve until /shutdown, sampling slot occupancy and minding the
-    # harness's request for a trace.
+    # Serve until /shutdown, sampling slot occupancy and the tokens
+    # generated so far (read next to its time, before stats() waits for
+    # the engine's condition), and minding the harness's request for a
+    # trace.
     samples = []
     tracer, traced = None, False
+
+    def sample() -> float:
+        now, made = time.time(), generated()
+        s = engine.stats()
+        samples.append([now, s["active_slots"], s["queue_depth"],
+                        s["prefilling"], made])
+        return now
+
     try:
         while not server.wait_shutdown(timeout=0.05):
-            now = time.time()
-            s = engine.stats()
-            samples.append([now, s["active_slots"], s["queue_depth"],
-                            s["prefilling"]])
+            now = sample()
             if p.get("trace") and not traced:
                 want = work.read("trace_request.json")
                 if want and tracer is None and now >= want["start"]:
@@ -137,6 +144,8 @@ def main() -> int:
                 elif tracer is not None and now >= want["start"] + want["len_s"]:
                     tracer.stop()
                     tracer, traced = None, True
+        sample()    # the shutdown comes after the window: its far edge
+                    # lies between two samples whatever the drain took
     finally:
         if tracer is not None:
             tracer.stop()
@@ -152,7 +161,7 @@ def main() -> int:
         "compile_times": compiles.times, "occupancy": samples,
         "slots": int(run["slots"]), "prefill_chunk": chunk,
         "decode_window": window, "control": p.get("control"),
-        "engine_stats": engine.stats(),
+        "engine_stats": engine.stats(), "tokens_generated": generated(),
     }
     work.publish("window.json", result)
 

@@ -156,8 +156,9 @@ def drive_serving(cell, job, work, stages, seed, seconds, trace) -> dict:
     in_window = lambda r: w0 <= r.due < w1   # noqa: E731
     drained = run.drain(in_window, float(tr["drain_s"]))
     stages.mark("drain_end")
-    return {"run": run, "window": (w0, w1), "wall0": wall0, "addr": (host, port),
-            "drained": drained, "in_window": in_window, "traced": traced}
+    return {"run": run, "warm": warm_run, "window": (w0, w1), "wall0": wall0,
+            "addr": (host, port), "drained": drained, "in_window": in_window,
+            "traced": traced}
 
 
 def pick_check_sample(requests, in_window, seed: int, n: int) -> list:
@@ -303,6 +304,14 @@ def assemble(cell, stages, work, result, serving, seconds, trace) -> dict:
         values.update({k: v for k, v in sm.items()
                        if k.startswith("serve_") and v is not None})
         wall0 = serving["wall0"]
+        # harness and job share the machine's clock: the job's samples of
+        # the engine's counter are read at the window's two edges
+        samples = result["occupancy"]
+        try:
+            values["serve_tokens_per_s"] = stats.generated_rate(
+                samples, wall0 + w0, wall0 + w1)
+        except ValueError as exc:
+            raise BenchFailure(f"tokens generated in the window: {exc}")
         numbers["compiles_in_window"] = sum(
             1 for t in result["compile_times"]
             if wall0 + w0 <= t < wall0 + w1)
@@ -320,10 +329,27 @@ def assemble(cell, stages, work, result, serving, seconds, trace) -> dict:
         attempted = len(due)
         failed = sum(1 for r in due if not r.ok)
         numbers["requests_failed"] = failed
+        # The counter is held to what clients received, over the engine's
+        # life in the job: every token it counted reached a client, but
+        # for those of requests that the shutdown cut.
+        sent = [r for r in serving["warm"].requests + run.requests
+                if r.sent is not None]
+        answered = sum(r.length for r in sent if r.status == 200)
+        allowance = sum(r.max_new for r in sent if r.status != 200) \
+            if cut_ok else 0
+        numbers["tokens_unaccounted"] = stats.tokens_unaccounted(
+            result["tokens_generated"], answered, allowance)
         ctx["serving"].update(
             cut_at_shutdown=len(cut), attempted=attempted,
-            generator_late_max_ms=sm["lateness_max_ms"])
-    limits = cell.config["correct"]["limits"]
+            generator_late_max_ms=sm["lateness_max_ms"],
+            tokens_generated=result["tokens_generated"],
+            tokens_answered=answered, tokens_cut_allowance=allowance,
+            counter_sample_gap_max_ms=1000.0 * max(
+                b[0] - a[0] for a, b in zip(samples, samples[1:])
+                if a[0] < wall0 + w1 and b[0] > wall0 + w0))
+    limits = dict(cell.config["correct"]["limits"])
+    if cell.job == "serve":      # exact, whatever the configuration
+        limits.setdefault("tokens_unaccounted", 0)
     correct, table = compare.judge(numbers, limits)
 
     if trace:
@@ -364,8 +390,7 @@ def info(ctx, result) -> dict:
     """Readings that decide nothing, for whoever reads a run's line."""
     extra = {"reference_s": result.get("reference_s")}
     if ctx["serving"]:
-        extra.update({k: v for k, v in ctx["serving"].items()
-                      if not k.startswith("serve_tokens")})
+        extra.update(ctx["serving"])
         extra.update(prefill_chunk=result["prefill_chunk"],
                      decode_window=result["decode_window"])
     else:

@@ -16,6 +16,7 @@ import tinyrepo
     ("tiny.train", {"fault": "half_batch"}, "loss1_rel"),
     ("tiny.serve", {"fault": "alter_token"}, "widest_gap"),
     ("tiny.serve", {"fault": "truncate_answer"}, "requests_failed"),
+    ("tiny.serve", {"fault": "inflate_counter"}, "tokens_unaccounted"),
     ("tiny.train", {"control": "fp8"}, "grad_norm_gap"),
     ("tiny.serve", {"control": "fp8"}, "widest_gap"),
     # another architecture, added as files: the toy mixture of experts

@@ -65,25 +65,32 @@ def test_reader_gives_none_without_its_counter(name, job):
 
 
 def test_the_five_are_in_the_benchmark_with_their_cells():
+    """What each of the five is (unit, direction, layer, the end-to-end
+    metric it moves) and that the cells PR 25 gave it are IN its list: a
+    later configuration's cell is appended to a list, and a later metric
+    to ``per_layer``, without an edit here."""
     bench = spec.load_benchmark()
     steady, saturated = ("mistral7b.serve.chat-steady",
                          "mistral7b.serve.chat-saturated")
-    got = {m["name"]: (m["unit"], m["better"], m["layer"], m["moves"],
-                       m["workloads"])
-           for m in bench["per_layer"] if m["name"] in WANT}
-    assert got == {
+    want = {
         "queue_wait_p90_ms":
-            ("ms", "lower", "engine", "serve_ttft_p90_ms", [steady]),
+            ("ms", "lower", "engine", "serve_ttft_p90_ms", steady),
         "prefill_span_p90_ms":
-            ("ms", "lower", "engine", "serve_ttft_p90_ms", [steady]),
+            ("ms", "lower", "engine", "serve_ttft_p90_ms", steady),
         "engine_host_share_pct.serve-steady":
-            ("%", "lower", "engine", "serve_tpot_p90_ms", [steady]),
+            ("%", "lower", "engine", "serve_tpot_p90_ms", steady),
         "engine_host_share_pct.serve-saturated":
-            ("%", "lower", "engine", "serve_tokens_per_s", [saturated]),
+            ("%", "lower", "engine", "serve_tokens_per_s", saturated),
         "kv_live_pct":
-            ("%", "higher", "decode", "serve_tokens_per_s", [saturated]),
+            ("%", "higher", "decode", "serve_tokens_per_s", saturated),
     }
-    assert [m["name"] for m in bench["per_layer"]][-5:] == [
-        "queue_wait_p90_ms", "prefill_span_p90_ms",
-        "engine_host_share_pct.serve-steady",
-        "engine_host_share_pct.serve-saturated", "kv_live_pct"]
+    got = {m["name"]: m for m in bench["per_layer"] if m["name"] in want}
+    assert sorted(got) == sorted(want)
+    moved = {m["name"]: m.get("workloads") for m in bench["end_to_end"]}
+    for name, (unit, better, layer, moves, cell) in want.items():
+        m = got[name]
+        assert (m["unit"], m["better"], m["layer"], m["moves"]) == (
+            unit, better, layer, moves), name
+        assert cell in m["workloads"], name
+        # every cell that reads it reports the metric it moves
+        assert set(m["workloads"]) <= set(moved[moves]), name

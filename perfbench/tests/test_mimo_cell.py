@@ -28,6 +28,8 @@ def test_a_sound_run_is_correct_and_counts_its_experts(repo):
     result = done["result"]
     assert result["correct"] is True and result["failed"] == 0
     assert result["compared"]["widest_gap"]["ok"] is True
+    assert result["compared"]["tokens_unaccounted"] == {
+        "value": 0, "limit": 0, "ok": True}
     # the readers of the program's counters read; those of a device trace
     # find no TPU plane on the CPU and leave their metric out
     assert result["metrics"]["expert_load_max_over_mean"]["value"] >= 1.0

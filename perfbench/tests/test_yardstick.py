@@ -171,7 +171,7 @@ def test_a_stall_lowers_the_rate_and_raises_the_tail():
     assert calm["serve_ttft_p90_ms"] == pytest.approx(100.0)
     # timed from when it was DUE: the stall is charged to the request
     assert stalled["serve_ttft_p90_ms"] == pytest.approx(3100.0)
-    assert stalled["serve_tokens_per_s"] < calm["serve_tokens_per_s"]
+    assert stalled["tokens_answered_per_s"] < calm["tokens_answered_per_s"]
     assert calm["serve_tpot_p90_ms"] == pytest.approx(100.0)
 
 
@@ -183,21 +183,70 @@ def test_a_failed_request_misses_every_limit():
     m = stats.serving_metrics(rs, 10.0, 15.0)
     assert m["serve_ttft_p90_ms"] == math.inf
     # eight answers land inside the window; the two failed ones count nothing
-    assert m["serve_tokens_per_s"] == pytest.approx(6 * 10 / 5.0)
+    assert m["tokens_answered_per_s"] == pytest.approx(6 * 10 / 5.0)
     rs = steady(20)
     rs[3].status = 503             # one of twenty is outside the p90
     m = stats.serving_metrics(rs, 10.0, 20.0)
     assert math.isfinite(m["serve_ttft_p90_ms"])
     # answers arrive a second after they were due: 18 inside, one failed
-    assert m["serve_tokens_per_s"] == pytest.approx(17 * 10 / 10.0)
+    assert m["tokens_answered_per_s"] == pytest.approx(17 * 10 / 10.0)
 
 
-def test_tokens_count_where_their_answer_arrives():
+def test_answered_tokens_count_where_their_answer_arrives():
     rs = [req(0, 5.0, 12.0, 50.0, 500.0, 7),     # due in the pre-roll
           req(1, 19.5, 21.0, 50.0, 500.0, 9)]    # answered after the window
     m = stats.serving_metrics(rs, 10.0, 20.0)
-    assert m["serve_tokens_per_s"] == pytest.approx(0.7)
+    assert m["tokens_answered_per_s"] == pytest.approx(0.7)
     assert m["due_in_window"] == 1
+    assert "serve_tokens_per_s" not in m     # that is the counter's
+
+
+# the engine's counter as the job samples it: [time, slots, queue,
+# prefilling, tokens so far]; 40 tokens a second from t = 101
+COUNTER = [[100.0 + 0.5 * i, 3, 0, 0, max(0, 20 * i - 40)] for i in range(13)]
+
+
+@pytest.mark.parametrize("t,want", [
+    (101.0, 0.0),            # on a sample
+    (103.0, 80.0),
+    (102.25, 50.0),          # between two samples: linear
+    (100.3, 0.0),            # between two samples that read the same
+    (100.0, 0.0),            # on the first sample
+    (106.0, 200.0),          # on the last
+], ids=["on-sample", "on-sample-later", "between", "flat", "first", "last"])
+def test_the_counter_is_read_between_its_two_samples(t, want):
+    assert stats.counter_at(COUNTER, t) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("samples,t", [
+    (COUNTER, 99.999), (COUNTER, 106.001), ([], 101.0),
+], ids=["before-the-first", "after-the-last", "no-samples"])
+def test_the_counter_is_never_extrapolated(samples, t):
+    with pytest.raises(ValueError, match="no samples of the counter"):
+        stats.counter_at(samples, t)
+    with pytest.raises(ValueError):
+        stats.generated_rate(samples, min(t, 101.0), max(t, 102.0))
+
+
+def test_the_rate_is_the_counters_difference_over_the_window():
+    # edges between samples, on samples, and a window that starts before
+    # the first token: what was made inside, over all of its length
+    assert stats.generated_rate(COUNTER, 102.25, 104.75) == pytest.approx(40.0)
+    assert stats.generated_rate(COUNTER, 101.0, 106.0) == pytest.approx(40.0)
+    assert stats.generated_rate(COUNTER, 100.0, 102.0) == pytest.approx(20.0)
+
+
+@pytest.mark.parametrize("generated,answered,allowance,want", [
+    (500, 500, 0, 0),        # drained: every token reached a client
+    (501, 500, 0, 1),        # a token counted that no client received
+    (499, 500, 0, 1),        # a token received that was not counted
+    (560, 500, 64, 0),       # cut: inside what the cut requests may hold
+    (565, 500, 64, 1),
+    (499, 500, 64, 1),
+])
+def test_the_counter_is_held_to_the_tokens_clients_received(
+        generated, answered, allowance, want):
+    assert stats.tokens_unaccounted(generated, answered, allowance) == want
 
 
 def test_train_rate_counts_steps_finished_inside_over_the_whole_window():

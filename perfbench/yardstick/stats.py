@@ -4,6 +4,7 @@ of a window, quartile spread. Pure Python, no jax."""
 
 from __future__ import annotations
 
+import bisect
 import math
 import statistics
 
@@ -76,15 +77,17 @@ class Request:
 
 
 def serving_metrics(requests, t0: float, t1: float) -> dict:
-    """End-to-end serving numbers of the window [t0, t1) from the
-    requests' records (those due in the pre-roll included: they are not in
-    the tails, their answers inside the window count to the rate)."""
+    """Serving numbers of the window [t0, t1) from the requests' records.
+    ``tokens_answered_per_s`` counts an answer's tokens at the instant it
+    arrives (the server does not stream; those due in the pre-roll count
+    too): information beside ``serve_tokens_per_s``, which is
+    ``generated_rate`` of the engine's own counter."""
     due = [r for r in requests if t0 <= r.due < t1]
     tokens_in = sum(r.length for r in requests
                     if r.ok and t0 <= r.answered < t1)
     out = {
         "due_in_window": len(due),
-        "serve_tokens_per_s": tokens_in / (t1 - t0),
+        "tokens_answered_per_s": tokens_in / (t1 - t0),
         "lateness_p50_ms": None, "lateness_max_ms": None,
     }
     sent = [r for r in due if r.sent is not None]
@@ -101,6 +104,41 @@ def serving_metrics(requests, t0: float, t1: float) -> dict:
             out["serve_tpot_p50_ms"] = percentile(tpot, 50)
             out["serve_tpot_p90_ms"] = percentile(tpot, 90)
     return out
+
+
+def counter_at(samples, t: float) -> float:
+    """A counter that only grows, read at ``t`` from ``samples`` (rows of
+    [time, ..., count], in time's order): linear between the two samples
+    around ``t``. Before the first or after the last there is nothing to
+    read: an error, never an extrapolation."""
+    if not samples or not samples[0][0] <= t <= samples[-1][0]:
+        raise ValueError(
+            f"no samples of the counter around {t!r}: they span "
+            f"{samples[0][0]!r} to {samples[-1][0]!r}" if samples
+            else "no samples of the counter")
+    hi = bisect.bisect_left(samples, t, key=lambda row: row[0])
+    (ta, a), (tb, b) = ((samples[i][0], samples[i][-1])
+                        for i in (max(hi - 1, 0), hi))
+    return b if tb == ta else a + (b - a) * (t - ta) / (tb - ta)
+
+
+def generated_rate(samples, t0: float, t1: float) -> float:
+    """Tokens the engine generated inside [t0, t1) over its length: the
+    difference of its counter between the window's two edges. A token
+    counts when it is made, so an answer that began before the window or
+    ends after it gives the part of it that falls inside."""
+    return (counter_at(samples, t1) - counter_at(samples, t0)) / (t1 - t0)
+
+
+def tokens_unaccounted(generated: int, answered: int, allowance: int) -> int:
+    """By how many tokens the engine's count at its end, less the tokens
+    of every answer a client received, lies outside [0, ``allowance``]:
+    ``allowance`` is what the requests that were cut or never answered
+    may have had generated for them, 0 where every request was followed
+    to its end. Exact; anything but 0 is a counter that has drifted from
+    the served path."""
+    diff = generated - answered
+    return max(-diff, diff - allowance, 0)
 
 
 def train_rate(step_ends, tokens_per_step: int, window_s: float,
