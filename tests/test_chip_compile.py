@@ -30,7 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from tony_tpu.models import decode as decode_lib
 from tony_tpu.models.train import make_train_step
 from tony_tpu.models.transformer import TransformerConfig, init_params
-from tony_tpu.ops import attention, norms
+from tony_tpu.ops import attention, hybrid, norms
 from tony_tpu.parallel.mesh import AXES, MeshSpec
 
 
@@ -112,6 +112,41 @@ def _cache_prefill(layers, slots, t, h_kv, group, tiles, p, c):
     return fn, dtypes, shapes
 
 
+def _lightning(kind, rows, c, h, d):
+    """The lightning kernels at the MiniCPM-SALA cell's size: a prefill
+    round of ``rows`` chunks of ``c`` positions, a decode step of
+    ``rows`` slots over the state buffer [rows + 1, H, D, D]."""
+    if kind == "prefill":
+        shapes = [(rows, c, h, d)] * 3 + [(rows, h, d, d), (h,), (rows,)]
+        dtypes = [jnp.bfloat16] * 3 + [jnp.float32] * 2 + [jnp.int32]
+        return functools.partial(hybrid.lightning_prefill,
+                                 mode="pallas"), dtypes, shapes
+    shapes = [(rows, h, d)] * 3 + [(rows + 1, h, d, d), (rows,), (h,)]
+    dtypes = [jnp.bfloat16] * 3 + [jnp.float32, jnp.int32, jnp.float32]
+    return functools.partial(hybrid.lightning_decode,
+                             mode="pallas"), dtypes, shapes
+
+
+def _sparse(kind, slots, t, h_kv, group, d, rows, c):
+    """The sparse layers' attention kernels at the cell's size: decode
+    over the selected blocks (a list of 128 a slot and KV head), a
+    prefill round of ``rows`` chunks of ``c`` under its block mask."""
+    cache = (slots, h_kv, t, d)
+    if kind == "decode":
+        shapes = [(slots, h_kv * group, d), cache, cache,
+                  (slots, h_kv, 128), (slots, h_kv, 128), (slots,)]
+        dtypes = [jnp.bfloat16] * 3 + [jnp.int32, jnp.bool_, jnp.int32]
+        return functools.partial(hybrid.sparse_decode_attention,
+                                 scale=d ** -0.5, block=64,
+                                 mode="pallas"), dtypes, shapes
+    shapes = [(rows, c, h_kv * group, d), cache, cache,
+              (rows, h_kv, c, t // 64), (rows,), (rows,)]
+    dtypes = [jnp.bfloat16] * 3 + [jnp.bool_, jnp.int32, jnp.int32]
+    return functools.partial(hybrid.sparse_prefill_attention,
+                             scale=d ** -0.5, block=64,
+                             mode="pallas"), dtypes, shapes
+
+
 def _rms(rows, d):
     fn = functools.partial(norms._rms_norm_pallas, eps=1e-6, block_rows=256)
     return fn, [jnp.bfloat16, jnp.float32], [(rows, d), (d,)]
@@ -143,6 +178,14 @@ KERNELS = {
     "cache_prefill_gqa8": (_cache_prefill, (2, 8, 32768, 8, 4, 1, 4, 128), 1),
     # Dh 64 (the 200M flagship): the cache's rows do not merge, plain path
     "cache_decode_d64_plain": (_cache_decode, (8, 8, 512, 4, 4, 64), 0),
+    # MiniCPM-SALA's cell: 32 slots x 32,768, 32 heads x 128, 2 KV heads,
+    # rounds of 4 chunks of 256
+    "lightning_prefill_sala": (_lightning, ("prefill", 4, 256, 32, 128), 1),
+    "lightning_decode_sala": (_lightning, ("decode", 32, 0, 32, 128), 1),
+    "sparse_decode_sala": (
+        _sparse, ("decode", 32, 32768, 2, 16, 128, 0, 0), 1),
+    "sparse_prefill_sala": (
+        _sparse, ("prefill", 32, 32768, 2, 16, 128, 4, 256), 1),
     "rms_norm_train_rows": (_rms, (16384, 2048), 1),
     "rms_norm_decode_rows": (_rms, (8, 2048), 1),
 }

@@ -159,11 +159,18 @@ def _fuse_layer(lp: dict, dt) -> dict:
             [lp["w_gate"].astype(dt), lp["w_up"].astype(dt)], axis=-1),
         "w_down": lp["w_down"].astype(dt),
     }
+    if "w_ogate" in lp:
+        # a gated kind: q|k|v|gate, the gate H * Dv wide behind v
+        out["qkv"] = jnp.concatenate(
+            [out["qkv"], lp["w_ogate"].astype(dt)], axis=1)
     # Router, its selection bias and the sinks stay float32 (tiny, and a
     # near tie must not flip on a rounding the model never had).
     for name in ("router", "router_bias", "sink"):
         if name in lp:
             out[name] = lp[name].astype(jnp.float32)
+    for name in ("q_norm", "k_norm", "o_norm"):
+        if name in lp:
+            out[name] = lp[name].astype(dt)
     return out
 
 
@@ -195,6 +202,10 @@ def decode_param_specs(cfg: TransformerConfig) -> dict:
                 spec.update(gate_up=P(None, "tp"), w_down=P("tp", None))
             if attn == "window" and cfg.window_sink:
                 spec["sink"] = P()
+            if cfg.qk_norm:
+                spec.update(q_norm=P(), k_norm=P())
+            if attn in cfg.out_norm_kinds:
+                spec["o_norm"] = P()
             return spec
 
         return {**top, "layers": tuple(one(a, m) for a, m in cfg.layer_kinds)}
@@ -282,6 +293,7 @@ def rope_tables(cfg: TransformerConfig) -> dict:
         kind: rope_frequencies(cfg.rot_dim, cfg.max_seq,
                                theta=cfg.rope_theta_of(kind))
         for kind in dict.fromkeys(a for a, _ in cfg.layer_kinds)
+        if kind not in cfg.no_rope_kinds
     }
 
 
@@ -434,7 +446,7 @@ def _mlp(x, lp, cfg, token_mask=None, count_mask=None):
     dt = cfg.compute_dtype
     if "router" in lp:
         out, counts = _moe_mlp_decode(x, lp, cfg, token_mask, count_mask)
-        return x + out, counts
+        return _residual(x, out, cfg), counts
     hn = rms_norm(x, lp["ln2"], eps=cfg.rms_eps).astype(dt)
     gu = jnp.einsum("btd,df->btf", hn, lp["gate_up"])
     f = gu.shape[-1] // 2
@@ -442,7 +454,36 @@ def _mlp(x, lp, cfg, token_mask=None, count_mask=None):
         jax.nn.silu(gu[..., :f].astype(jnp.float32)).astype(dt)
         * gu[..., f:]
     )
-    return x + jnp.einsum("btf,fd->btd", act, lp["w_down"]), None
+    return _residual(x, jnp.einsum("btf,fd->btd", act, lp["w_down"]),
+                     cfg), None
+
+
+def _residual(x, y, cfg):
+    """x + residual_scale * y (a muP model scales every sub-block's
+    result into the residual stream)."""
+    if cfg.residual_scale == 1.0:
+        return x + y
+    return x + (y.astype(jnp.float32) * cfg.residual_scale).astype(x.dtype)
+
+
+def embed_scaled(x, cfg):
+    """The embedding rows as the first layer reads them."""
+    if cfg.embed_scale == 1.0:
+        return x
+    return (x.astype(jnp.float32) * cfg.embed_scale).astype(x.dtype)
+
+
+def _gated_output(o, gate, lp, attn, cfg):
+    """What the output projection of a gated kind reads: the attention
+    result, RMSNorm'd over all heads' dims where the kind has an output
+    norm, times sigmoid(h Wg). Float32 until the product is made."""
+    b, t, n_h, d_v = o.shape
+    o = o.astype(jnp.float32).reshape(b, t, n_h * d_v)
+    if attn in cfg.out_norm_kinds:
+        o = rms_norm(o, lp["o_norm"], eps=cfg.rms_eps, force_jax=True)
+    if gate is not None:
+        o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
+    return o.astype(cfg.compute_dtype).reshape(b, t, n_h, d_v)
 
 
 def serve_layer(x, lp, attn, cfg, ropes, positions, attend, *,
@@ -459,13 +500,19 @@ def serve_layer(x, lp, attn, cfg, ropes, positions, attend, *,
     b, t, _ = x.shape
     n_h, h_kv = cfg.n_heads, cfg.kv_heads_of(attn)
     h = rms_norm(x, lp["ln1"], eps=cfg.rms_eps).astype(dt)
+    gate = None
     if lp["qkv"].ndim == 2:
         # layered: q|k|v fused on the feature axis, widths of their own
+        # (and a gated kind's output gate behind them)
         flat = jnp.einsum("btd,df->btf", h, lp["qkv"])
         n_q, n_k = n_h * cfg.head_dim, h_kv * cfg.head_dim
+        n_v = h_kv * cfg.v_dim
         q = flat[..., :n_q].reshape(b, t, n_h, cfg.head_dim)
         k_new = flat[..., n_q:n_q + n_k].reshape(b, t, h_kv, cfg.head_dim)
-        v_new = flat[..., n_q + n_k:].reshape(b, t, h_kv, cfg.v_dim)
+        v_new = flat[..., n_q + n_k:n_q + n_k + n_v].reshape(
+            b, t, h_kv, cfg.v_dim)
+        if attn in cfg.gated_kinds:
+            gate = flat[..., n_q + n_k + n_v:]
         if cfg.v_scale != 1.0:
             v_new = (v_new.astype(jnp.float32) * cfg.v_scale).astype(dt)
     else:
@@ -473,17 +520,26 @@ def serve_layer(x, lp, attn, cfg, ropes, positions, attend, *,
         q = qkv[:, :, :n_h]
         k_new = qkv[:, :, n_h:n_h + h_kv]
         v_new = qkv[:, :, n_h + h_kv:]
-    q = _rope(q, ropes[attn], positions, cfg.rot_dim)
-    k_new = _rope(k_new, ropes[attn], positions, cfg.rot_dim)
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_norm"], eps=cfg.rms_eps, force_jax=True)
+        k_new = rms_norm(k_new, lp["k_norm"], eps=cfg.rms_eps,
+                         force_jax=True)
+    if attn not in cfg.no_rope_kinds:
+        q = _rope(q, ropes[attn], positions, cfg.rot_dim)
+        k_new = _rope(k_new, ropes[attn], positions, cfg.rot_dim)
     o = attend(q, k_new, v_new, attn, lp.get("sink"))
-    x = x + jnp.einsum("bthk,hkd->btd", o, lp["wo"])
+    if gate is not None or attn in cfg.out_norm_kinds:
+        o = _gated_output(o, gate, lp, attn, cfg)
+    x = _residual(x, jnp.einsum("bthk,hkd->btd", o, lp["wo"]), cfg)
     return _mlp(x, lp, cfg, token_mask, count_mask)
 
 
 def run_layers(x, params, k_all, v_all, cfg, layer):
     """Every layer in model order. ``layer(x, lp, attn, at, k_all,
     v_all) -> (x, k_all, v_all, counts)`` with ``at`` the layer's index
-    in its attention kind's cache stack. A uniform model: one
+    among the layers of its attention kind (a Python int in a layered
+    model's static loop, traced under a uniform model's scan: how a
+    caller's cache is indexed by it is the caller's). A uniform model: one
     ``lax.scan`` over the stacked layers, the caches as CARRY (as xs/ys
     the scan slices every layer's cache out and re-stacks it each call,
     the whole cache re-written per token; as carry a layer's update is
@@ -496,8 +552,7 @@ def run_layers(x, params, k_all, v_all, cfg, layer):
         for lp, (attn, _) in zip(params["layers"], cfg.layer_kinds):
             at = seen.get(attn, 0)
             seen[attn] = at + 1
-            x, k_all, v_all, counts = layer(x, lp, attn, jnp.int32(at),
-                                            k_all, v_all)
+            x, k_all, v_all, counts = layer(x, lp, attn, at, k_all, v_all)
             if counts is not None:
                 total = (counts if total is None
                          else jax.tree.map(jnp.add, total, counts))
@@ -523,6 +578,8 @@ def lm_head(x, params, cfg):
     BEFORE this, so no [B, S, V] logits are ever materialized."""
     x = rms_norm(x, params["final_norm"],
                  eps=cfg.rms_eps).astype(cfg.compute_dtype)
+    if cfg.logit_scale != 1.0:
+        x = (x.astype(jnp.float32) * cfg.logit_scale).astype(x.dtype)
     return jnp.einsum(
         "btd,dv->btv", x, params["unembed"]
     )[:, 0].astype(jnp.float32)
@@ -606,6 +663,8 @@ def advance(params: dict, cache: dict, tokens: jax.Array,
         # This caller's cache policy: the S new rows go in at
         # (layer, :, length) by a small ``dynamic_update_slice`` that XLA
         # aliases in place, and attention reads that layer's cache.
+        at = jnp.int32(at)     # a layered model's loop counts in Python
+
         def attend(q, k_new, v_new, attn, sink):
             nonlocal k_all, v_all
             k_all = lax.dynamic_update_slice(

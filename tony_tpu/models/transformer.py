@@ -105,10 +105,22 @@ class TransformerConfig:
     v_head_dim: int = 0          # value width; 0 = head_dim
     rotary_dim: int = 0          # leading dims of q/k that rotate; 0 = all
     v_scale: float = 1.0         # v = v_scale * (h @ Wv)
-    # Per layer "full" | "window"; () = every layer full. A window layer
-    # sees the last ``window`` positions (its own included), has its own
-    # KV head count and rope base, and with ``window_sink`` a learnt
-    # per-head bias in the softmax denominator.
+    # Per layer "full" | "window" | "linear" | "sparse"; () = every layer
+    # full. A window layer sees the last ``window`` positions (its own
+    # included), has its own KV head count and rope base, and with
+    # ``window_sink`` a learnt per-head bias in the softmax denominator.
+    # A linear layer (lightning attention) keeps no K/V rows: per head a
+    # float32 state [head_dim, head_dim] that decays by the head's slope
+    # and takes k^T v at every position (``ops/hybrid.py``); it has
+    # ``n_heads`` K/V heads. A sparse layer (InfLLM-V2 block-sparse
+    # attention) keeps K/V at ``n_kv_heads`` and, beside them, the means
+    # of its keys over ``sparse_kernel`` positions every
+    # ``sparse_stride``; a query at a position >= ``sparse_dense_len``
+    # attends the first ``sparse_init_blocks`` blocks of ``sparse_block``
+    # positions, the blocks that cover its last ``sparse_window``
+    # positions and the ``sparse_topk`` blocks those means score highest
+    # for its KV group, a query before it every key. Both are SERVED
+    # (``ServingEngine``) and not trained: no backward is written.
     attn_kinds: tuple = ()
     window: int = 0
     window_kv_heads: int = 0     # 0 = n_kv_heads
@@ -127,14 +139,46 @@ class TransformerConfig:
     # (first, count). The router stays ``n_experts`` wide and the layer
     # computes its own experts' part of the result; None = all.
     experts_held: tuple | None = None
+    # Block-sparse selection of the "sparse" layers (above).
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_block: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    sparse_dense_len: int = 8192
+    # RMSNorm with a learnt weight over each head's dims of q and of k,
+    # before the rope.
+    qk_norm: bool = False
+    # Attention kinds that rotate nothing, that gate their attention
+    # output by sigmoid(h Wg) before the output projection, and that
+    # RMSNorm it over all heads' dims before the gate.
+    no_rope_kinds: tuple = ()
+    gated_kinds: tuple = ()
+    out_norm_kinds: tuple = ()
+    # muP-style scales: x0 = embed_scale * E[token]; every sub-block adds
+    # residual_scale * f(norm(x)); the head reads norm(x) * logit_scale.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
 
     def __post_init__(self):
         if self.attn_kinds and len(self.attn_kinds) != self.n_layers:
             raise ValueError(
                 f"attn_kinds names {len(self.attn_kinds)} layers, the "
                 f"model has {self.n_layers}")
-        if set(self.attn_kinds) - {"full", "window"}:
+        if set(self.attn_kinds) - {"full", "window", "linear", "sparse"}:
             raise ValueError(f"unknown attention kind in {self.attn_kinds}")
+        if "sparse" in self.attn_kinds:
+            k, s, b = (self.sparse_kernel, self.sparse_stride,
+                       self.sparse_block)
+            if min(k, s, b, self.sparse_topk, self.sparse_init_blocks) < 1 \
+                    or k % s or b % s or self.sparse_window % b \
+                    or self.sparse_dense_len % b:
+                raise ValueError(
+                    "sparse layers need sparse_kernel and sparse_block in "
+                    "whole sparse_stride, sparse_window and "
+                    "sparse_dense_len in whole sparse_block, all >= 1")
         if "window" in self.attn_kinds and self.window < 1:
             raise ValueError("window layers need window >= 1")
         if self.router_scoring not in ("softmax", "sigmoid"):
@@ -161,6 +205,8 @@ class TransformerConfig:
         kv = self.n_kv_heads or self.n_heads
         if attn_kind == "window":
             kv = self.window_kv_heads or kv
+        if attn_kind == "linear":
+            kv = self.n_heads
         if self.n_heads % kv:
             raise ValueError(
                 f"n_kv_heads {kv} must divide n_heads {self.n_heads}"
@@ -191,7 +237,10 @@ class TransformerConfig:
             self.attn_kinds or self.v_head_dim or self.rotary_dim
             or self.v_scale != 1.0 or self.n_dense_layers
             or self.router_scoring != "softmax" or self.router_bias
-            or self.experts_held)
+            or self.experts_held or self.qk_norm or self.no_rope_kinds
+            or self.gated_kinds or self.out_norm_kinds
+            or (self.embed_scale, self.residual_scale,
+                self.logit_scale) != (1.0, 1.0, 1.0))
 
     @property
     def layer_kinds(self) -> tuple:
@@ -215,9 +264,11 @@ class TransformerConfig:
         if self.layered:
             raise ValueError(
                 f"{what} runs uniform layers only; this configuration "
-                f"has layer kinds, its own v/rotary widths, or a share "
-                f"of its experts (TransformerConfig.layered): serve it "
-                f"through ServingEngine")
+                f"has layer kinds (linear and sparse layers have no "
+                f"backward: they are served, not trained), its own "
+                f"v/rotary widths, or a share of its experts "
+                f"(TransformerConfig.layered): serve it through "
+                f"ServingEngine")
 
 
 # ---------------------------------------------------------------------------
@@ -298,6 +349,14 @@ def _init_group(key, cfg: TransformerConfig, name: str, n: int) -> dict:
     }
     if attn == "window" and cfg.window_sink:
         group["sink"] = norm(keys[4], (h,), 1.0)
+    if cfg.qk_norm:
+        group["q_norm"] = jnp.ones((n, dk), jnp.float32)
+        group["k_norm"] = jnp.ones((n, dk), jnp.float32)
+    if attn in cfg.gated_kinds:
+        group["w_ogate"] = norm(jax.random.fold_in(keys[4], 1),
+                                (d, h * dv), d ** -0.5)
+    if attn in cfg.out_norm_kinds:
+        group["o_norm"] = jnp.ones((n, h * dv), jnp.float32)
     if mlp == "moe":
         e, f = cfg.n_experts, cfg.d_ff
         held = cfg.held[1]

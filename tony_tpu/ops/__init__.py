@@ -16,6 +16,12 @@ from tony_tpu.ops.attention import (
     grouped_cache_attention,
 )
 from tony_tpu.ops.grouped import grouped_matmul
+from tony_tpu.ops.hybrid import (
+    lightning_decode,
+    lightning_prefill,
+    sparse_decode_attention,
+    sparse_prefill_attention,
+)
 from tony_tpu.ops.norms import rms_norm
 from tony_tpu.ops.rope import apply_rope, rope_frequencies, rotate_rope
 from tony_tpu.ops.losses import softmax_cross_entropy
@@ -27,6 +33,10 @@ __all__ = [
     "flash_attention",
     "flash_attention_lse",
     "grouped_matmul",
+    "lightning_decode",
+    "lightning_prefill",
+    "sparse_decode_attention",
+    "sparse_prefill_attention",
     "rms_norm",
     "apply_rope",
     "rope_frequencies",

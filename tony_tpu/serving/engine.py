@@ -52,6 +52,30 @@ zero-filled (``lane_tiles``: a width of 192 lies in 256 lanes on the
 device anyway, and as tiles the rows of 4 KV heads merge without a copy
 of the cache; the decode kernel sums the tiles' products).
 
+Two more kinds hold what is NOT a K/V row per position
+(``ops/hybrid.py`` has their arithmetic and layouts), each as one buffer
+per LAYER, not a stack: a layered model walks its layers in a static
+loop, so a layer's buffer is an argument of its own and nothing is ever
+sliced out of a stack (XLA copies a layer's slab out of a stacked buffer
+for a batched contraction, PERF.md section 6, PR 26). ``linear`` layers
+(lightning attention) keep a float32 recurrent state [S + 1, H, D, D]: it
+is not indexed by position, so the overwrite-before-read invariant below
+cannot protect it. Three rules stand in its place: a chunk whose
+``start == 0`` reads a zero state whatever the slot held (slot reuse
+still zeroes nothing); the state a round writes is the state after the
+chunk's VALID positions, carried to the prompt's next round; and
+``decode_window``, which runs all slots, maps every lane that is not
+decoding (free, or in mid-prefill: the parked ones) to the buffer's
+parking row S, so their states stay bit for bit. ``sparse`` layers
+(block-sparse attention) keep K and V head-major [S, Hkv, Tmax, D] and
+beside them ``K^c`` [S, Hkv, Tmax / stride + 1, D] float32, the means of
+the keys over ``sparse_kernel`` positions every ``sparse_stride``: a row
+is SET by the position that starts its kernel and added to by the rest,
+and only a kernel that lies wholly at or before the query is ever read,
+so K^c too is never zeroed. A model with either kind prefills in ALIGNED
+chunks, a prompt's last one padded (``n_valids``), never overlapped: a
+position must enter a state once.
+
 Both programs run over the fused ``decode_weights`` layout (weights fuse
 once per engine, exactly like ``DecodeSession``) and carry the stacked
 caches through the layers as scan CARRY (``run_layers``). KV buffers are
@@ -79,6 +103,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from tony_tpu.models.decode import (
+    embed_scaled,
     lm_head,
     rope_tables,
     run_layers,
@@ -90,6 +115,7 @@ from tony_tpu.ops import (
     cache_prefill_attention,
     grouped_cache_attention,
 )
+from tony_tpu.ops import hybrid
 from tony_tpu.ops.attention import (
     cache_rows_view,
     cache_slot_rows,
@@ -228,10 +254,10 @@ def init_slot_cache(
     dt = cfg.compute_dtype
     if cfg.layered:
         attn = [a for a, _ in cfg.layer_kinds]
-        if "full" not in attn:
+        if "full" not in attn and "sparse" not in attn:
             raise ValueError(
-                "a layered configuration needs a full-attention layer: "
-                "idle lanes park at its last position")
+                "a layered configuration needs a full or a sparse "
+                "attention layer: idle lanes park at its last position")
         rows = {"full": max_len,
                 "window": ring_rows(cfg, prefill_chunk) + 1}
 
@@ -248,9 +274,222 @@ def init_slot_cache(
             return {kind: stack(kind, width)
                     for kind in ("full", "window") if kind in attn}
 
+        if has_state(cfg):
+            return _init_state_cache(cfg, slots, max_len, prefill_chunk,
+                                     stacks)
         return stacks(cfg.head_dim), stacks(cfg.v_dim)
     shape = (cfg.n_layers, slots, max_len, cfg.kv_heads, cfg.head_dim)
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+
+
+def has_state(cfg: TransformerConfig) -> bool:
+    """Whether a model keeps per-slot state that is no K/V row of a
+    position (linear or sparse layers): it prefills in aligned chunks
+    and its cache is not exchanged by rows."""
+    return bool({"linear", "sparse"} & {a for a, _ in cfg.layer_kinds})
+
+
+def _init_state_cache(cfg, slots, max_len, prefill_chunk, stacks):
+    """The cache pair of a model with linear or sparse layers (module
+    docstring): the K side holds per kind what the kind keeps — a full
+    or window stack as ever, per sparse layer its K buffer and under
+    ``sparse_kc`` its K^c buffer, per linear layer its state — the V
+    side the stacks and the sparse layers' V buffers."""
+    attn = [a for a, _ in cfg.layer_kinds]
+    dt = cfg.compute_dtype
+    c, b, st = prefill_chunk, cfg.sparse_block, cfg.sparse_stride
+    if "sparse" in attn and (c % b or max_len % c):
+        raise ValueError(
+            f"sparse layers prefill in whole blocks: prefill_chunk {c} "
+            f"must be a multiple of sparse_block {b} and divide max_len "
+            f"{max_len}")
+    if cfg.head_dim != cfg.v_dim:
+        raise ValueError("linear and sparse layers keep k and v at one "
+                         "width")
+    k, v = stacks(cfg.head_dim), stacks(cfg.v_dim)
+    n_sp, n_lin = attn.count("sparse"), attn.count("linear")
+    if n_sp:
+        shape = (slots, cfg.kv_heads_of("sparse"), max_len, cfg.head_dim)
+        k["sparse"] = tuple(jnp.zeros(shape, dt) for _ in range(n_sp))
+        v["sparse"] = tuple(jnp.zeros(shape, dt) for _ in range(n_sp))
+        rows = shape[:2] + (max_len // st + 1, cfg.head_dim)
+        k["sparse_kc"] = tuple(jnp.zeros(rows, jnp.float32)
+                               for _ in range(n_sp))
+    if n_lin:
+        shape = (slots + 1, cfg.n_heads, cfg.head_dim, cfg.head_dim)
+        k["linear"] = tuple(jnp.zeros(shape, jnp.float32)
+                            for _ in range(n_lin))
+    return k, v
+
+
+def refuse_state_rows(cache, what: str) -> None:
+    if isinstance(cache, dict) and {"linear", "sparse"} & set(cache):
+        raise ValueError(
+            f"{what}: a model with linear or sparse layers keeps a "
+            f"recurrent state and compressed keys beside its K/V rows, "
+            f"and the row exchange of prefill/decode disaggregation does "
+            f"not carry them")
+
+
+def _rows_of_slots(buf, slots):
+    """``buf[slots]`` of a per-layer buffer as one small dynamic slice a
+    row (a gather over a large buffer lowers to slices of all of it)."""
+    return jnp.stack([lax.dynamic_index_in_dim(buf, slots[r], 0, False)
+                      for r in range(slots.shape[0])])
+
+
+def _set_at(buffers: tuple, i: int, new) -> tuple:
+    return buffers[:i] + (new,) + buffers[i + 1:]
+
+
+def _positions_kind(cache) -> str:
+    """The attention kind whose buffers hold every position of a slot:
+    idle lanes park at its last one."""
+    return "full" if not isinstance(cache, dict) or "full" in cache \
+        else "sparse"
+
+
+def _sparse_sizes(cfg: TransformerConfig) -> dict:
+    return dict(topk=cfg.sparse_topk, init=cfg.sparse_init_blocks,
+                window=cfg.sparse_window, block=cfg.sparse_block,
+                dense_len=cfg.sparse_dense_len)
+
+
+def _head_rows(buf, rows, at, how: str = "set"):
+    """``rows`` [S, Hkv, D] into a head-major buffer [S, Hkv, R, D] at
+    row ``at[slot]`` of every head: one scatter over the buffer seen as
+    [S * Hkv, R, D] — its window is the minor dim alone, so XLA updates
+    in place (with the heads inside the window it re-lays the whole
+    buffer out, twice a call). ``how``: set, add or multiply."""
+    s, h = buf.shape[:2]
+    flat = buf.reshape((s * h,) + buf.shape[2:])
+    put = getattr(flat.at[jnp.arange(s * h), jnp.repeat(at, h)], how)
+    return put(rows.reshape((s * h,) + rows.shape[2:]).astype(buf.dtype),
+               indices_are_sorted=True, unique_indices=True,
+               mode="promise_in_bounds").reshape(buf.shape)
+
+
+def _sparse_decode(q, k_new, v_new, k_all, v_all, i, pos, wpos, parked, cfg,
+                   scale):
+    """A sparse layer's decode step for every slot: the new K/V rows
+    into layer ``i``'s buffers at ``wpos``, the new key into the K^c rows
+    of the kernels that hold its position (SET where it starts one,
+    parked lanes into the parking row), the selection for each KV group
+    from K^c, and attention over the selected blocks only."""
+    kc, vc, comp = k_all["sparse"][i], v_all["sparse"][i], k_all["sparse_kc"][i]
+    n_s = wpos.shape[0]
+    kc = _head_rows(kc, k_new[:, 0], wpos)
+    vc = _head_rows(vc, v_new[:, 0], wpos)
+    kern, stride = cfg.sparse_kernel, cfg.sparse_stride
+    park = comp.shape[2] - 1
+    share = k_new[:, 0].astype(jnp.float32) / kern
+    for back in range(kern // stride):
+        row = wpos // stride - back
+        row = jnp.where(parked | (row < 0), park, row)
+        if back == 0:
+            keep = jnp.where(wpos % stride == 0, 0.0, 1.0)
+            comp = _head_rows(comp, jnp.broadcast_to(
+                keep[:, None, None], share.shape), row, "multiply")
+        comp = _head_rows(comp, share, row, "add")
+    h_kv = kc.shape[1]
+    t_max = kc.shape[2]
+    at = jnp.minimum(pos, t_max - 1)
+    qg = q.reshape(n_s, 1, h_kv, -1, q.shape[-1])
+    scores = hybrid.block_scores(
+        qg, comp, at[:, None], scale=scale, kernel=kern, stride=stride,
+        block=cfg.sparse_block)[:, :, 0]
+    idx, ok = hybrid.select_block_list(scores, at, **_sparse_sizes(cfg))
+    o = hybrid.sparse_decode_attention(
+        q[:, 0], kc, vc, idx, ok, at, scale=scale, block=cfg.sparse_block)
+    # what the kernel was handed, counted where it was chosen: the listed
+    # blocks' keys up to the query, per KV group, of the lanes that decode
+    block = cfg.sparse_block
+    keys = ok.sum(-1) * block - (block - 1 - at % block)[:, None]
+    read = jnp.where(parked[:, None], 0, keys).sum().astype(jnp.int32)
+    k_all = {**k_all, "sparse": _set_at(k_all["sparse"], i, kc),
+             "sparse_kc": _set_at(k_all["sparse_kc"], i, comp)}
+    v_all = {**v_all, "sparse": _set_at(v_all["sparse"], i, vc)}
+    return o[:, None], k_all, v_all, read
+
+
+def _sparse_prefill(q, k_new, v_new, k_all, v_all, i, slots, starts,
+                    n_valids, cfg, scale):
+    """A sparse layer's prefill round: each row's chunk into its slot's
+    K/V at ``starts``, its share of K^c (the kernels that start in the
+    chunk SET, the ones that started before it added to; a duplicated
+    padding row adds to the parking row), then the chunk's queries
+    against the slot's cache under each token's block mask — computed
+    only where some row of the round has passed ``sparse_dense_len``."""
+    kc, vc, comp = k_all["sparse"][i], v_all["sparse"][i], k_all["sparse_kc"][i]
+    p, c = q.shape[:2]
+    kern, stride, block = (cfg.sparse_kernel, cfg.sparse_stride,
+                           cfg.sparse_block)
+    park = comp.shape[2] - 1
+    rows, behind = hybrid.compress_rows(k_new, n_valids, kernel=kern,
+                                        stride=stride)
+    first = (slots != slots[0]) | (jnp.arange(p) == 0)
+    k_rows = k_new.astype(kc.dtype).transpose(0, 2, 1, 3)     # [P,Hkv,C,D]
+    v_rows = v_new.astype(vc.dtype).transpose(0, 2, 1, 3)
+
+    def write_one(r, bufs):
+        kc, vc, comp = bufs
+        kc = lax.dynamic_update_slice(kc, k_rows[r][None],
+                                      (slots[r], 0, starts[r], 0))
+        vc = lax.dynamic_update_slice(vc, v_rows[r][None],
+                                      (slots[r], 0, starts[r], 0))
+        comp = lax.dynamic_update_slice(
+            comp, rows[r][None], (slots[r], 0, starts[r] // stride, 0))
+        for back in range(1, kern // stride):
+            row = starts[r] // stride - back
+            row = jnp.where(first[r] & (row >= 0), row, park)
+            old = lax.dynamic_slice(
+                comp, (slots[r], 0, row, 0), (1, comp.shape[1], 1,
+                                              comp.shape[3]))
+            comp = lax.dynamic_update_slice(
+                comp, old + behind[r, back - 1][None, :, None, :],
+                (slots[r], 0, row, 0))
+        return kc, vc, comp
+
+    kc, vc, comp = lax.fori_loop(0, p, write_one, (kc, vc, comp))
+    ends = starts + c
+    qpos = starts[:, None] + jnp.arange(c)[None, :]
+    h_kv = kc.shape[1]
+    n_blocks = kc.shape[2] // block
+
+    def chosen(_):
+        scores = hybrid.block_scores(
+            q.reshape(p, c, h_kv, -1, q.shape[-1]),
+            _rows_of_slots(comp, slots), qpos, scale=scale,
+            kernel=kern, stride=stride, block=block)
+        return hybrid.select_blocks(scores, qpos, **_sparse_sizes(cfg))
+
+    sel = lax.cond(jnp.any(ends > cfg.sparse_dense_len), chosen,
+                   lambda _: jnp.ones((p, h_kv, c, n_blocks), bool), None)
+    o = hybrid.sparse_prefill_attention(q, kc, vc, sel, slots, ends,
+                                        scale=scale, block=block)
+    k_all = {**k_all, "sparse": _set_at(k_all["sparse"], i, kc),
+             "sparse_kc": _set_at(k_all["sparse_kc"], i, comp)}
+    v_all = {**v_all, "sparse": _set_at(v_all["sparse"], i, vc)}
+    return o, k_all, v_all
+
+
+def _linear_prefill(q, k_new, v_new, k_all, i, slots, starts, n_valids, cfg,
+                    scale):
+    """A lightning layer's prefill round: each row's state read out of
+    layer ``i``'s buffer (zero where the chunk starts a prompt), the
+    chunk in the chunked form, the states written back in row order — a
+    duplicated padding row read the same state and writes the same one."""
+    state = k_all["linear"][i]
+    p = q.shape[0]
+    s_in = jnp.where((starts == 0)[:, None, None, None], 0.0,
+                     _rows_of_slots(state, slots))
+    o, s_out = hybrid.lightning_prefill(
+        (q.astype(jnp.float32) * scale).astype(q.dtype), k_new, v_new, s_in,
+        hybrid.linear_decay_slopes(cfg.n_heads), n_valids)
+    state = lax.fori_loop(
+        0, p, lambda r, st: lax.dynamic_update_slice(
+            st, s_out[r][None], (slots[r], 0, 0, 0)), state)
+    return o, {**k_all, "linear": _set_at(k_all["linear"], i, state)}
 
 
 def cache_inject_rows(cache, slot: int, rows):
@@ -259,7 +498,9 @@ def cache_inject_rows(cache, slot: int, rows):
     exchange format is rows at their logical width, whatever the
     storage form). A layered cache takes what ``cache_export_rows``
     gave of one: a dict by attention kind, the window kind's rows being
-    the LAST positions of the prefix."""
+    the LAST positions of the prefix. A model with linear or sparse
+    layers is refused: rows do not carry its state or its K^c."""
+    refuse_state_rows(cache, "cache_inject_rows")
     if isinstance(cache, dict):
         length = rows["full"].shape[1]
         out = {}
@@ -282,7 +523,9 @@ def cache_export_rows(cache, slot: int, length: int, width: int = 0):
     documents. A layered cache exports a dict by attention kind at the
     logical ``width``: all ``length`` positions of its full layers, and
     of its window layers the last ``ring`` (or fewer), in position
-    order."""
+    order. A model with linear or sparse layers is refused, as in
+    ``cache_inject_rows``."""
+    refuse_state_rows(cache, "cache_export_rows")
     if isinstance(cache, dict):
         out = {}
         for kind, stack in cache.items():
@@ -421,17 +664,19 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
     Returns (k_all, v_all, window_tokens [S, steps] int32, counts): the
     expert layers' counters summed over the window (``pairs``: the
     (token, choice) pairs each held expert received; ``passes``: the
-    passes the layers ran, ``_moe_mlp_decode``), or None for a model
-    without experts.
+    passes the layers ran, ``_moe_mlp_decode``) and the sparse layers'
+    (``sparse_keys``: the keys their selection listed, per KV group, for
+    the lanes that decode), or None for a model with neither.
     """
     dt = cfg.compute_dtype
-    t_max = _cache_tmax(_kind(k_all, "full"))
+    t_max = _cache_tmax(_kind(k_all, _positions_kind(k_all)))
     ropes = rope_tables(cfg)
     scale = cfg.head_dim ** -0.5
 
     def one_step(carry, i):
         k_all, v_all, pos, wpos, tokens = carry
         x = params["embed"][tokens][:, None, :].astype(dt)  # [S, 1, d]
+        x = embed_scaled(x, cfg)
         # Visibility after the write: keys 0..pos inclusive (index pos
         # holds the token being fed this step). Inactive lanes' pos can
         # run past the table mid-window — clamp the RoPE gather (their
@@ -440,17 +685,38 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
         parked = wpos >= t_max - 1
 
         def layer(x, lp, attn, at, k_all, v_all):
+            sparse_keys = None
+
             def attend(q, k_new, v_new, attn, sink):
-                nonlocal k_all, v_all
+                nonlocal k_all, v_all, sparse_keys
+                if attn == "linear":
+                    # a lane that is not decoding reads and writes the
+                    # parking row: its slot's state stays as it is
+                    states = k_all["linear"]
+                    lane = jnp.arange(parked.shape[0])
+                    o, new = hybrid.lightning_decode(
+                        (q[:, 0].astype(jnp.float32) * scale).astype(dt),
+                        k_new[:, 0], v_new[:, 0], states[at],
+                        jnp.where(parked, parked.shape[0], lane),
+                        hybrid.linear_decay_slopes(cfg.n_heads))
+                    k_all = {**k_all,
+                             "linear": _set_at(states, at, new)}
+                    return o[:, None]
+                if attn == "sparse":
+                    o, k_all, v_all, sparse_keys = _sparse_decode(
+                        q, k_new, v_new, k_all, v_all, at, pos, wpos,
+                        parked, cfg, scale)
+                    return o
+                # a stacked kind: its layer is indexed on the device
                 kc, vc = _kind(k_all, attn), _kind(v_all, attn)
-                window, w_at = 0, wpos
+                row, window, w_at = jnp.int32(at), 0, wpos
                 if attn == "window":
                     window, ring = cfg.window, _cache_tmax(kc) - 1
                     w_at = jnp.where(parked, ring, wpos % ring)
-                kc = _write_rows(kc, at, k_new[:, 0], w_at)
-                vc = _write_rows(vc, at, v_new[:, 0], w_at)
+                kc = _write_rows(kc, row, k_new[:, 0], w_at)
+                vc = _write_rows(vc, row, v_new[:, 0], w_at)
                 o = cache_decode_attention(
-                    q[:, 0], kc, vc, at, pos, scale=scale,
+                    q[:, 0], kc, vc, row, pos, scale=scale,
                     window=window, sink=sink,
                 )[:, None]
                 k_all = _with_kind(k_all, attn, kc)
@@ -459,6 +725,8 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
 
             x, counts = serve_layer(x, lp, attn, cfg, ropes, rp, attend,
                                     token_mask=~parked[:, None])
+            if sparse_keys is not None:
+                counts = {**(counts or {}), "sparse_keys": sparse_keys}
             return x, k_all, v_all, counts
 
         x, k_all, v_all, counts = run_layers(x, params, k_all, v_all, cfg,
@@ -514,7 +782,7 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
     the passes the layers ran); None for a model without experts."""
     dt = cfg.compute_dtype
     p, c = tokens.shape
-    t_max = _cache_tmax(_kind(k_all, "full"))
+    t_max = _cache_tmax(_kind(k_all, _positions_kind(k_all)))
     ropes = rope_tables(cfg)
     scale = cfg.head_dim ** -0.5
     positions = starts[:, None] + jnp.arange(c)[None, :]       # [P, C]
@@ -522,9 +790,11 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
     # gather (values are garbage, discarded) — the write offset itself
     # is host-validated.
     rope_pos = jnp.minimum(positions, cfg.max_seq - 1)
-    x = params["embed"][tokens].astype(dt)                     # [P, C, d]
-    masks = {"full": (positions[:, :, None]
-                      >= jnp.arange(t_max)[None, None, :])}    # [P, C, T]
+    x = embed_scaled(params["embed"][tokens].astype(dt), cfg)  # [P, C, d]
+    masks = {}
+    if _positions_kind(k_all) == "full":
+        masks["full"] = (positions[:, :, None]
+                         >= jnp.arange(t_max)[None, None, :])  # [P, C, T]
     if isinstance(k_all, dict) and "window" in k_all:
         n_rows = _cache_tmax(k_all["window"])
         held = ring_positions(starts + c - 1, n_rows, n_rows - 1)
@@ -541,18 +811,30 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
     def layer(x, lp, attn, at, k_all, v_all):
         def attend(q, k_new, v_new, attn, sink):
             nonlocal k_all, v_all
+            if attn == "linear":
+                o, k_all = _linear_prefill(q, k_new, v_new, k_all, at,
+                                           slots, starts, n_valids, cfg,
+                                           scale)
+                return o
+            if attn == "sparse":
+                o, k_all, v_all = _sparse_prefill(
+                    q, k_new, v_new, k_all, v_all, at, slots, starts,
+                    n_valids, cfg, scale)
+                return o
+            # a stacked kind: its layer is indexed on the device
             kc, vc = _kind(k_all, attn), _kind(v_all, attn)
+            row = jnp.int32(at)
             write = _write_chunk_ring if attn == "window" else _write_chunk
 
             def write_one(i, kv):
-                where = (at, slots[i], starts[i])
+                where = (row, slots[i], starts[i])
                 return (write(kv[0], *where, k_new[i]),
                         write(kv[1], *where, v_new[i]))
 
             # Sequential writes, not a vmap-scatter: P is small and
             # duplicate (padding) rows must overwrite cleanly in order.
             kc, vc = lax.fori_loop(0, p, write_one, (kc, vc))
-            o = _attend_rows(q, kc, vc, at, slots, starts, masks[attn],
+            o = _attend_rows(q, kc, vc, row, slots, starts, masks[attn],
                              scale, sink, cfg, attn)
             k_all = _with_kind(k_all, attn, kc)
             v_all = _with_kind(v_all, attn, vc)

@@ -54,7 +54,16 @@ readback; ``stats()["experts"]`` sums them per held expert, and beside
 them the passes the expert layers ran (``passes``). A model with
 window layers has a cache stack per attention kind, and
 ``stats()["kv"]["kinds"]`` counts each (the top-level ``kv`` keys stay
-the full kind's).
+the full kind's). A model with sparse layers: ``stats()["sparse"]`` =
+``{keys_read, keys_live}``, the keys the selection listed for the decode
+kernel (counted on the device, back in the iteration's readback) against
+what a dense layer would have read, per KV group and summed over groups,
+sparse layers and decode iterations (the decode span carries the
+iteration's ``sparse_keys_read``); with
+linear layers: ``stats()["state"]`` = ``{slots_reset, live_state_ms}``,
+the prompts whose first chunk read a zero state and the slots holding a
+live state times the wall. Such a model prefills in aligned chunks and
+takes no part in the row exchange (``engine.has_state``).
 
 Greedy parity contract (pinned by tests/test_serving.py): a request
 decoded through the slot engine yields token-for-token the same output
@@ -192,19 +201,24 @@ def _summary(samples_ms: list[float]) -> dict:
             "max": ordered[-1]}
 
 
-def _chunk_plan(prompt_len: int, chunk: int) -> list[tuple[int, int]]:
+def _chunk_plan(prompt_len: int, chunk: int,
+                aligned: bool = False) -> list[tuple[int, int]]:
     """(start, n_valid) chunks covering a prompt. Prompts shorter than
     one chunk pad (garbage K/V past ``n_valid`` is overwritten before it
     is ever unmasked); longer prompts emit full chunks with an
     OVERLAPPED final chunk at ``P - chunk`` — re-writing identical K/V
     for the overlap instead of padding, so every chunk is fully valid
-    and no alignment constraint leaks into admission."""
+    and no alignment constraint leaks into admission. ``aligned`` (a
+    model with a recurrent state, into which a position must enter
+    once): every chunk starts on a multiple of ``chunk`` and the last
+    one pads."""
     if prompt_len <= chunk:
         return [(0, prompt_len)]
     full = prompt_len // chunk
     plan = [(i * chunk, chunk) for i in range(full)]
     if prompt_len % chunk:
-        plan.append((prompt_len - chunk, chunk))
+        plan.append((full * chunk, prompt_len % chunk) if aligned
+                    else (prompt_len - chunk, chunk))
     return plan
 
 
@@ -339,9 +353,22 @@ class ServingEngine:
         # layers against what their slots reserve there: whole key
         # blocks up to the chunk's end where the chunk attends through
         # ``cache_prefill_attention``, the reservation elsewhere.
-        self._pf_read_block = _engine.prefill_read_block(
-            cfg, self._k, self.prefill_batch, self.prefill_chunk)
         self._full_layers = sum(a == "full" for a, _ in cfg.layer_kinds)
+        self._pf_read_block = _engine.prefill_read_block(
+            cfg, self._k, self.prefill_batch, self.prefill_chunk
+        ) if self._full_layers else 0
+        # A model with linear or sparse layers (engine.py): aligned
+        # chunks, no row exchange, and the counters of stats()["sparse"]
+        # and ["state"].
+        self._sparse_layers = sum(a == "sparse" for a, _ in cfg.layer_kinds)
+        self._sparse_groups = (self._sparse_layers
+                               * cfg.kv_heads_of("sparse"))
+        self._linear_layers = sum(a == "linear" for a, _ in cfg.layer_kinds)
+        self._has_state = bool(self._sparse_layers or self._linear_layers)
+        self._sparse_keys_read = 0
+        self._sparse_keys_live = 0
+        self._state_slots_reset = 0
+        self._live_state_ns = 0
         self._prefill_keys_read = 0
         self._prefill_keys_reserved = 0
         self._live_position_ns = 0
@@ -353,7 +380,7 @@ class ServingEngine:
         self._live_window_position_ns = 0
         by_kind = [c if isinstance(c, dict) else {"full": c}
                    for c in (self._k, self._v)]
-        for kind in by_kind[0]:
+        for kind in by_kind[1]:      # the kinds that keep K AND V rows
             stacks = [c[kind] for c in by_kind]
             nbytes = sum(leaf.nbytes for leaf in
                          jax.tree_util.tree_leaves(stacks))
@@ -363,8 +390,8 @@ class ServingEngine:
                 "bytes_per_position": nbytes // (self.slots * rows),
                 "bytes_reserved": nbytes,
             }
-        self._kv_bytes_per_position = (
-            self._kv_kinds["full"]["bytes_per_position"])
+        self._kv_bytes_per_position = self._kv_kinds[
+            _engine._positions_kind(self._k)]["bytes_per_position"]
         # Pairs on the held experts, as the programs count them.
         self._expert_pairs = np.zeros(cfg.held[1], np.int64)
         self._expert_tokens = 0
@@ -463,6 +490,15 @@ class ServingEngine:
             )
         if temperature < 0.0:
             raise ValueError(f"temperature must be >= 0, got {temperature}")
+        if self._has_state:
+            if _prefill_only:
+                _engine.refuse_state_rows(self._k, "prefill_only")
+            if -(-prompt.size // self.prefill_chunk) * self.prefill_chunk \
+                    > self.max_len:
+                raise ValueError(
+                    f"prompt ({prompt.size}) in whole chunks of "
+                    f"{self.prefill_chunk} exceeds the slot capacity "
+                    f"({self.max_len})")
         req = ServingRequest(
             request_id or f"req-{next(self._ids)}", prompt,
             int(max_new_tokens), float(temperature), eos_id,
@@ -585,6 +621,8 @@ class ServingEngine:
         token; the slot's KV rows are written at admission and decode
         proceeds exactly as if prefill had run here — the per-slot KV
         layout makes the injection one targeted write."""
+        if self._has_state:
+            _engine.refuse_state_rows(self._k, "submit_with_kv")
         kv_k = jax.tree.map(np.asarray, kv_k)
         kv_v = jax.tree.map(np.asarray, kv_v)
         pos = int(pos)
@@ -674,6 +712,21 @@ class ServingEngine:
                     },
                 },
             }
+            if self._sparse_layers:
+                # Keys the sparse layers' selection listed for the decode
+                # kernel (the device's count: the listed blocks' keys up
+                # to the query) against what a dense layer would have read
+                # (every key up to the query), per KV group, summed over
+                # groups, sparse layers and decode iterations.
+                out["sparse"] = {"keys_read": self._sparse_keys_read,
+                                 "keys_live": self._sparse_keys_live}
+            if self._linear_layers:
+                # Prompts whose first chunk read a zero state in a slot
+                # that held another, and slots holding a live state
+                # (decoding, or between a prompt's rounds) x wall time.
+                out["state"] = {
+                    "slots_reset": self._state_slots_reset,
+                    "live_state_ms": self._live_state_ns / 1e6}
             if self.cfg.n_experts:
                 n_moe = sum(m == "moe" for _, m in self.cfg.layer_kinds)
                 out["experts"] = {
@@ -823,6 +876,7 @@ class ServingEngine:
                 # Positions held at the iteration's end, for its wall.
                 self._live_position_ns += live_positions[0] * wall_ns
                 self._live_window_position_ns += live_positions[1] * wall_ns
+                self._live_state_ns += live_positions[2] * wall_ns
         return working
 
     def _decode_some(self, step_start_ns: int) -> None:
@@ -856,7 +910,17 @@ class ServingEngine:
             # experts' counters come back in the same readback.
             toks, counts = jax.device_get((window, expert_counts))  # tony: noqa[TONY-X002] — intended per-window fence
             toks = np.asarray(toks)
-            if counts is not None:
+            if counts is not None and "sparse_keys" in counts:
+                # The selection's own count, made on the device where the
+                # blocks are listed, against every key up to the queries.
+                read = int(counts.pop("sparse_keys"))
+                at = self._pos[self._active].astype(np.int64)
+                live = (int(w * (at + 1).sum()) + n_active * w * (w - 1) // 2
+                        ) * self._sparse_groups
+                self._sparse_keys_read += read
+                self._sparse_keys_live += live
+                sp.set(sparse_keys_read=read)
+            if counts:
                 sp.set(expert_pairs=self._note_pairs(counts, n_active * w))
         it["decode_device"] = sp.dur_ns
         self._decode_iters += 1
@@ -890,7 +954,7 @@ class ServingEngine:
             self._note_rate(n_new)
         it["emit"] += sp.dur_ns
 
-    def _publish(self, decoded: bool) -> tuple[int, int]:
+    def _publish(self, decoded: bool) -> tuple[int, int, int]:
         """End of an iteration: gauges and the registry report. Returns
         the KV positions written so far in occupied slots (``_pos`` of
         the decoding ones plus the chunks done of the prefilling): all
@@ -915,7 +979,8 @@ class ServingEngine:
         live_positions = (
             int(written.sum()),
             int(np.minimum(written, self.cfg.window).sum())
-            if "window" in self._kv_kinds else 0)
+            if "window" in self._kv_kinds else 0,
+            int(written.size))     # slots that hold positions (a state)
         self._g_active.set(int(self._active.sum()))
         # Publish (throttled inside the registry): serving metrics only
         # reach the executor heartbeat via the $TONY_METRICS_FILE
@@ -957,7 +1022,8 @@ class ServingEngine:
                     injects.append((req, s))
                 else:
                     req._chunks = _chunk_plan(req.prompt.size,
-                                              self.prefill_chunk)
+                                              self.prefill_chunk,
+                                              aligned=self._has_state)
                     req._chunk_i = 0
                     self._pf.append((req, s))
             # Idle batch boundary + only foreign-model work queued:
@@ -1053,6 +1119,8 @@ class ServingEngine:
             self._pf_draws += 1
         it["prefill_assemble"] += sp.dur_ns
         self._prefill_rounds += 1
+        if self._linear_layers:
+            self._state_slots_reset += int((starts[:n] == 0).sum())
         self._prefill_tokens_valid += int(n_valids[:n].sum())
         self._prefill_rows_padded += pb - n
         keys_read = n * self.max_len

@@ -1,0 +1,113 @@
+"""The serving programs of the benchmark's configurations as text, for a
+described (not attached) v5e, debug locations stripped: what two checkouts
+are compared by to show that a change left a program as it was (PR 32's
+check; ``.claude/skills/verify/SKILL.md``).
+
+    JAX_PLATFORMS=cpu python3 tools/serving_hlo.py <checkout> <out dir> [configuration ...]
+    diff -r <out dir of the parent> <out dir of the change>
+
+Per configuration and program (``decode_window``, ``prefill_chunks``) it
+writes the StableHLO of ``lower()`` and the optimized HLO of ``compile()``.
+Each Mosaic kernel is serialised from a copy without its debug info (a
+kernel's bytecode holds its callers' paths and lines, so unstripped
+bodies differ between any two directories). One process at a time: the TPU
+library's lock. Nothing runs; no time comes from this."""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import sys
+from pathlib import Path
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+DEFAULT = ("mistral7b-serve-1chip", "mimo-v2-flash-serve-1chip")
+
+
+def clean(text: str) -> str:
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"loc\([^)]*\)", "", text)
+    return re.sub(r"(?s)(FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r".*?\n\n", "", text)
+
+
+def main() -> int:
+    repo, out = Path(sys.argv[1]).resolve(), Path(sys.argv[2])
+    names = sys.argv[3:] or DEFAULT
+    sys.path.insert(0, str(repo / "perfbench"))
+    sys.path.insert(0, str(repo))
+    import jax
+    import jax.numpy as jnp
+    from jax._src import tpu_custom_call
+    from jax._src.lib.mlir import ir
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    serialise = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def without_locations(module, **kw):
+        with module.context, module.operation.location:
+            bare = ir.Module.parse(
+                module.operation.get_asm(enable_debug_info=False))
+        return serialise(bare, **kw)
+
+    tpu_custom_call._lower_mosaic_module_to_asm = without_locations
+    import tony_tpu.ops as ops
+    from tony_tpu.models import decode_weights
+    from tony_tpu.serving import engine
+    from yardstick import spec
+
+    for module in ("attention", "norms", "hybrid"):
+        if hasattr(ops, module):
+            getattr(ops, module)._on_tpu = lambda mesh=None: True
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
+
+    def i32(*shape):
+        return sds(shape, jnp.int32)
+
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    out.mkdir(parents=True, exist_ok=True)
+    for name in names:
+        cfg = json.loads((repo / "perfbench" / "configs"
+                          / f"{name}.json").read_text())
+        run = cfg["run"]
+        model = spec.load_module(
+            repo / "perfbench" / "models" / f"{cfg['model']}.py",
+            "model_" + cfg["model"])
+        tcfg = model.program_config(cfg, run, max_seq=run["max_seq"],
+                                    dtype=run["weights_dtype"])
+        chunk = int(cfg.get("conf", {}).get("tony.serving.prefill-chunk", 32))
+        fused = on_chip(jax.eval_shape(lambda k: decode_weights(
+            model.program_params(k, cfg, jnp.bfloat16), tcfg),
+            jax.random.key(0)))
+        slots, t_max = run["slots"], run["max_seq"]
+        k, v = on_chip(jax.eval_shape(lambda: engine.init_slot_cache(
+            tcfg, slots, t_max, prefill_chunk=chunk)))
+        programs = {
+            "decode_window": engine.decode_window.lower(
+                fused, k, v, i32(slots), i32(slots), i32(slots),
+                sds((slots,), jnp.float32), key, i32(), cfg=tcfg, steps=1),
+            "prefill_chunks": engine.prefill_chunks.lower(
+                fused, k, v, i32(4, chunk), i32(4), i32(4), i32(4),
+                sds((4,), jnp.float32), key, i32(), cfg=tcfg),
+        }
+        for program, lowered in programs.items():
+            (out / f"{name}.{program}.stablehlo.txt").write_text(
+                clean(lowered.as_text()))
+            (out / f"{name}.{program}.hlo.txt").write_text(
+                clean(lowered.compile().as_text()))
+            print(name, program, flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
