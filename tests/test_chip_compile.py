@@ -87,11 +87,25 @@ def _flash_bwd(bh, t, d, block):
     return fn, dtypes, shapes
 
 
-def _cache_decode(layers, slots, t, h_kv, group, d):
+def _cache_decode(layers, slots, t, h_kv, group, d, tiles=1, window=0):
+    """K as ``tiles`` 128-lane tiles of a 192-wide row where ``tiles``
+    is 2; ``window`` > 0: a ring of ``t - 1`` positions with a sink."""
     cache = (layers, slots, t, h_kv, d)
-    shapes = [(slots, h_kv * group, d), cache, cache, (), (slots,)]
-    dtypes = [jnp.bfloat16] * 3 + [jnp.int32] * 2
-    return attention.cache_decode_attention, dtypes, shapes
+    d_k = 192 if tiles == 2 else d
+    shapes = [(slots, h_kv * group, d_k)] + [cache] * (tiles + 1) + [
+        (), (slots,)]
+    dtypes = [jnp.bfloat16] * (tiles + 2) + [jnp.int32] * 2
+    if window:
+        shapes.append((h_kv * group,))
+        dtypes.append(jnp.float32)
+
+    def fn(q, *rest):
+        k, (v, layer, pos, *sink) = rest[:tiles], rest[tiles:]
+        return attention.cache_decode_attention(
+            q, k if tiles > 1 else k[0], v, layer, pos, window=window,
+            sink=sink[0] if sink else None)
+
+    return fn, dtypes, shapes
 
 
 def _cache_prefill(layers, slots, t, h_kv, group, tiles, p, c):
@@ -174,6 +188,11 @@ KERNELS = {
     "flash_decode_tq1": (_flash_fwd, (64, 1, 2048, 128, 512), 1),
     "cache_decode_mistral7b": (_cache_decode, (16, 32, 2048, 8, 4, 128), 1),
     "cache_decode_mha32": (_cache_decode, (2, 8, 2048, 32, 1, 128), 1),
+    # MiMo's cell: the full layers' K in two lane tiles, and the rings
+    "cache_decode_mimo_full": (
+        _cache_decode, (2, 64, 8192, 4, 16, 128, 2), 1),
+    "cache_decode_mimo_ring": (
+        _cache_decode, (7, 64, 257, 8, 8, 128, 2, 128), 1),
     "cache_prefill_mimo": (_cache_prefill, (2, 64, 8192, 4, 16, 2, 4, 128), 1),
     "cache_prefill_gqa8": (_cache_prefill, (2, 8, 32768, 8, 4, 1, 4, 128), 1),
     # Dh 64 (the 200M flagship): the cache's rows do not merge, plain path

@@ -191,7 +191,7 @@ def test_close_leaves_the_counters_as_they_were(served):
     for key in ("working_iterations", "working_wall_ms", "phase_ms",
                 "decode_iterations", "decode_slots_sum", "prefill_rounds",
                 "prefill_tokens_valid", "prefill_rows_padded",
-                "prefill_keys", "kv",
+                "prefill_keys", "decode_keys", "kv",
                 "queue_wait_ms", "prefill_span_ms", "retired"):
         assert after[key] == before_close[key], key
 
@@ -327,6 +327,55 @@ def test_prefill_keys_count_what_a_chunk_is_given_to_read(monkeypatch,
     device = [e for e in _spans(eng)
               if e["name"] == "tony:engine.prefill_device"]
     assert sum(e["args"]["keys_read"] for e in device) == read
+
+
+@pytest.mark.parametrize("case,read,reserved", [
+    # no lane decodes: stale positions of the last tenants, nothing read
+    ("all-parked", 0, 3 * 64 * 2 * 2),
+    # a prompt of 15 tokens, 3 new ones: ONE window of 2 steps feeds
+    # positions 15 (block 0) and 16 (blocks 0 and 1); 2 layers
+    ("block-edge", (16 + 32) * 2, 3 * 64 * 2 * 2),
+    # one full layer of 1 KV head beside the rings: a prompt of 30
+    # tokens, 4 new ones, three iterations feed 30, 31 (one block of 32)
+    # and 32 (two); the other lane stays parked
+    ("ring", 32 + 32 + 64, 3 * 2 * 96),
+])
+def test_decode_keys_count_the_blocks_a_step_reads(monkeypatch, case, read,
+                                                   reserved):
+    """``stats()["decode_keys"]`` by the kernel's own rule of key blocks
+    (``decode_last_block``), per full layer, parked lanes at nothing;
+    the decode span carries each dispatch's share."""
+    from tony_tpu.ops import attention
+    from tony_tpu.serving import engine as engine_lib
+
+    engine_lib.decode_window.clear_cache()
+    try:
+        if case == "ring":
+            monkeypatch.setattr(attention, "DECODE_BLOCK_ROWS", 32)
+            eng = _layered_engine(slots=2, prefill_chunk=8, prefill_batch=2,
+                                  max_len=96, kv_quant="none")
+            assert eng._dc_read_block == 32
+            reqs = [eng.submit(np.arange(30, dtype=np.int32) % 64, 4)]
+        else:
+            monkeypatch.setattr(attention, "DECODE_BLOCK_ROWS", 32)
+            eng = _engine(slots=3, prefill_chunk=4, prefill_batch=2,
+                          max_len=64, decode_window=2)
+            assert eng._dc_read_block == 16       # 2 KV heads
+            reqs = []
+            if case == "all-parked":
+                eng._pos[:] = (5, 40, 63)
+                eng._decode_some(0)
+            else:
+                reqs = [eng.submit(np.arange(15, dtype=np.int32), 3)]
+        _drive(eng, reqs)
+        eng.close()
+    finally:
+        engine_lib.decode_window.clear_cache()
+    assert eng.stats()["decode_keys"] == {"read_positions": read,
+                                          "reserved_positions": reserved}
+    device = [e for e in _spans(eng)
+              if e["name"] == "tony:engine.decode_device"]
+    assert device and sum(e["args"]["keys_read"] for e in device) == read
 
 
 def test_a_uniform_model_has_one_cache_kind_and_no_expert_block(served):

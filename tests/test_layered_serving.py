@@ -10,6 +10,7 @@ module's leaf table) at tiny widths that keep every ratio."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import sys
 from pathlib import Path
 
@@ -482,6 +483,55 @@ def test_cache_decode_kernel_equals_the_plain_path(name, h_kv, window, sink,
     assert got.shape == (slots, n_h, 128)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
+
+
+@pytest.mark.parametrize("name,h_kv,window,block_rows", [
+    ("full-4", 4, 0, 64),         # blocks of 16 positions of 64
+    ("full-8", 8, 0, 64),         # blocks of 8 positions
+    ("ring-sink", 8, 16, 64),     # a ring of 32 positions in 4 blocks
+])
+def test_cache_decode_kernel_reads_a_slots_live_blocks_only(name, h_kv,
+                                                            window,
+                                                            block_rows):
+    """K 192 wide in two lane tiles. Slots at position 0, at the last
+    position of their first key block, at the first of the second, deep
+    in the cache (a ring: past its first wrap, every row live), and one
+    that decodes nothing (pos -1: zeros). NaN in every row of every key
+    block past a slot's last live one, the ring's parking row and all of
+    the idle slot: the result is finite, bit for bit what the clean
+    cache gives, and within 2e-2 of the plain path."""
+    from tony_tpu.ops.attention import decode_key_block, decode_last_block
+
+    slots, n_h, t = 5, 16, 33 if window else 64
+    t_read = t - 1 if window else t
+    block = decode_key_block(t_read, h_kv, block_rows)
+    keys = jax.random.split(jax.random.key(11), 4)
+    q = jax.random.normal(keys[0], (slots, n_h, 192), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (2, slots, t, h_kv, 192), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (2, slots, t, h_kv, 128), jnp.bfloat16)
+    b = jax.random.normal(keys[3], (n_h,)) * 2 if window else None
+    pos = jnp.asarray([0, block - 1, block, 45, -1], jnp.int32)
+    last = np.asarray(decode_last_block(pos, t_read, block))
+    assert last.tolist() == [0, 0, 1, t_read // block - 1
+                             if window else 45 // block, 0]
+    at = np.arange(t)[None, :]
+    dead = (at // block > last[:, None]) | (at >= t_read) \
+        | (np.asarray(pos) < 0)[:, None]
+    dead = jnp.asarray(dead)[None, :, :, None, None]
+    run = functools.partial(
+        cache_decode_attention, layer=jnp.int32(1), pos=pos, window=window,
+        sink=b, mode="interpret", block_rows=block_rows)
+    clean = run(q, engine_lib._lane_tiles(k, 2), v)
+    got = run(q, engine_lib._lane_tiles(jnp.where(dead, jnp.nan, k), 2),
+              jnp.where(dead, jnp.nan, v))
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(clean, np.float32))
+    assert not np.asarray(got[-1], np.float32).any()
+    want = cache_decode_attention(q, k, v, jnp.int32(1), pos, window=window,
+                                  sink=b, mode="jax")
+    np.testing.assert_allclose(np.asarray(got[:-1], np.float32),
+                               np.asarray(want[:-1], np.float32), atol=2e-2)
 
 
 def test_a_ring_is_masked_by_position_not_by_row():

@@ -2,6 +2,8 @@
 kernel math is validated without TPU hardware; the blockwise-JAX paths are
 checked against naive references and through grad."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -153,28 +155,102 @@ class TestCacheDecodeAttention:
     layer of the stacked cache. The kernel (interpret mode) is pinned to
     the plain-JAX path, which slices the layer out first."""
 
-    @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
-                                            (jnp.bfloat16, 2e-2)])
-    def test_kernel_interpret_matches_jax(self, dtype, atol):
-        n_l, n_s, t, h_kv, group, d = 3, 4, 64, 2, 4, 16
+    @staticmethod
+    def _case(dtype, h_kv=2, group=4, d=16, n_l=3, t=64):
+        """A stacked cache of ``n_l`` layers x 7 slots x ``t`` positions
+        under key blocks of 16 positions: slots at the first key, the
+        last of block 0, the first of block 1, mid-block, the last key,
+        an inactive lane whose position ran past the cache, and a lane
+        that decodes nothing."""
         keys = jax.random.split(jax.random.key(3), 3)
+        pos = jnp.asarray([0, 15, 16, 21, t - 1, t + 5, -1], jnp.int32)
+        n_s = pos.shape[0]
         q = jax.random.normal(keys[0], (n_s, h_kv * group, d), dtype)
         k_all = jax.random.normal(keys[1], (n_l, n_s, t, h_kv, d), dtype)
         v_all = jax.random.normal(keys[2], (n_l, n_s, t, h_kv, d), dtype)
-        # first key only, mid-block, the last key, and an inactive lane
-        # whose position ran past the cache
-        pos = jnp.asarray([0, 21, t - 1, t + 5], jnp.int32)
-        for layer in (0, n_l - 1):
+        return q, k_all, v_all, pos, 16 * h_kv
+
+    @pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                            (jnp.bfloat16, 2e-2)])
+    def test_kernel_interpret_matches_jax(self, dtype, atol):
+        q, k_all, v_all, pos, block_rows = self._case(dtype)
+        for layer in (0, 2):
             got, want = (
                 cache_decode_attention(q, k_all, v_all, jnp.int32(layer),
-                                       pos, block_rows=32, mode=mode)
+                                       pos, block_rows=block_rows, mode=mode)
                 for mode in ("interpret", "jax")
             )
             assert got.dtype == dtype and got.shape == q.shape
+            # the lane that decodes nothing: zeros from the kernel, some
+            # finite row from the plain path
+            assert not np.asarray(got[-1], np.float32).any()
+            assert np.isfinite(np.asarray(want[-1], np.float32)).all()
             np.testing.assert_allclose(
-                np.asarray(got, np.float32), np.asarray(want, np.float32),
-                atol=atol,
+                np.asarray(got[:-1], np.float32),
+                np.asarray(want[:-1], np.float32), atol=atol,
             )
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    @pytest.mark.parametrize("h_kv", [8, 4])
+    def test_blocks_past_the_last_live_one_are_never_read(self, dtype, h_kv):
+        """NaN in every row of every key block past a slot's last live
+        one (in all of the slot that decodes nothing): the result stays
+        finite and bit for bit, so those blocks are neither computed nor
+        left to the mask. And it is, bit for bit, what the same kernel
+        gives on a cache CUT to the live blocks of the longest slot."""
+        from tony_tpu.ops.attention import (decode_key_block,
+                                            decode_last_block)
+
+        q, k_all, v_all, pos, block_rows = self._case(dtype, h_kv=h_kv,
+                                                      group=2, n_l=2)
+        t = k_all.shape[2]
+        block = decode_key_block(t, h_kv, block_rows)
+        assert block == 16
+        last = np.asarray(decode_last_block(pos, t, block))
+        assert last.tolist() == [0, 0, 1, 1, 3, 3, 0]
+        dead = (np.arange(t)[None, :] // block > last[:, None]) \
+            | (np.asarray(pos) < 0)[:, None]
+        dead = jnp.asarray(dead)[None, :, :, None, None]
+        run = functools.partial(cache_decode_attention, layer=jnp.int32(1),
+                                block_rows=block_rows, mode="interpret")
+        clean = run(q, k_all, v_all, pos=pos)
+        got = run(q, jnp.where(dead, jnp.nan, k_all),
+                  jnp.where(dead, jnp.nan, v_all), pos=pos)
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(clean, np.float32))
+        # slots 0..3 live inside the first two blocks
+        cut = run(q[:4], k_all[:, :4, :2 * block], v_all[:, :4, :2 * block],
+                  pos=pos[:4])
+        np.testing.assert_array_equal(np.asarray(cut, np.float32),
+                                      np.asarray(clean[:4], np.float32))
+
+    def test_under_a_mesh_each_shard_bounds_by_its_own_slots(self):
+        """Slots split over dp 4 and KV heads over tp 2: the kernel runs
+        per shard, a shard's parked lanes name blocks of the shard's own
+        slots, and the result is the single-device kernel's (a shard's
+        block holds other positions, so the sums differ in their order)."""
+        from tony_tpu.parallel import MeshSpec, build_mesh
+
+        q, k_all, v_all, pos, block_rows = self._case(jnp.float32, n_l=2)
+        # eight slots: the odd lane out and a second lane that reads
+        # nothing, first in its shard
+        q, k_all, v_all = (jnp.concatenate([x, x[..., :1, :, :]
+                                            if x.ndim == 3 else x[:, :1]],
+                                           axis=0 if x.ndim == 3 else 1)
+                           for x in (q, k_all, v_all))
+        pos = jnp.concatenate([pos[4:5] * 0 - 1, pos])   # [-1, 0, 15, ...]
+        run = functools.partial(cache_decode_attention, layer=jnp.int32(1),
+                                block_rows=block_rows // 2, mode="interpret")
+        want = run(q, k_all, v_all, pos=pos)
+        mesh = build_mesh(MeshSpec(dp=4, tp=2))
+        with jax.sharding.set_mesh(mesh):
+            got = jax.jit(functools.partial(run, mesh=mesh))(
+                q, k_all, v_all, pos=pos)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5)
+        assert not np.asarray(got)[[0, 7]].any()     # the lanes at -1
 
     def test_jax_path_is_grouped_softmax_over_the_prefix(self):
         rng = np.random.default_rng(5)

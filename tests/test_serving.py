@@ -345,6 +345,70 @@ class TestCachePoliciesOverOneLayer:
             wpos[live] += 1
 
 
+    def test_decode_window_kernel_reads_nothing_for_a_parked_lane(
+            self, monkeypatch):
+        """``decode_window`` through the decode KERNEL (interpret mode,
+        key blocks of 8 positions) beside the plain path: a live slot
+        that crosses a block edge, a free lane with a last tenant's
+        stale position and a lane in prefill (pos 0), both parked, whose
+        slots hold NaN in every row. The live slot's tokens are the plain
+        path's; what the parked lanes wrote into their parking row in
+        the SECOND layer is finite, so the first layer's attention read
+        none of their rows."""
+        import functools
+
+        from tony_tpu.ops import cache_decode_attention
+
+        cfg = TransformerConfig(
+            vocab_size=64, d_model=32, n_layers=2, n_heads=4, head_dim=8,
+            d_ff=64, max_seq=96, dtype="float32", remat=False, n_kv_heads=2,
+        )
+        fused = decode_weights(init_params(jax.random.key(1), cfg), cfg)
+        t_max, n, live = 32, 6, 1
+        prompt = jnp.asarray(np.random.default_rng(9).integers(
+            0, cfg.vocab_size, (1, n)), jnp.int32)
+        logits, cache = decode_lib.advance(
+            fused, decode_lib.init_cache(cfg, 1, t_max), prompt, cfg,
+            prefill=True)
+        first = int(jnp.argmax(logits, axis=-1)[0])
+
+        def run(kernel: bool):
+            if kernel:
+                monkeypatch.setattr(
+                    engine_lib, "cache_decode_attention", functools.partial(
+                        cache_decode_attention, mode="interpret",
+                        block_rows=16))
+            engine_lib.decode_window.clear_cache()
+            k, v = (jnp.full_like(c, jnp.nan)
+                    for c in engine_lib.init_slot_cache(cfg, 3, t_max))
+            k = engine_lib.cache_inject_rows(
+                k.at[:, live].set(0), live, cache["k"][:, 0, :n])
+            v = engine_lib.cache_inject_rows(
+                v.at[:, live].set(0), live, cache["v"][:, 0, :n])
+            pos = np.array([20, n, 0], np.int32)
+            wpos = np.array([t_max - 1, n, t_max - 1], np.int32)
+            tok, toks = first, []
+            for step in range(5):
+                k, v, got, _ = engine_lib.decode_window(
+                    fused, k, v, jnp.asarray(pos), jnp.asarray(wpos),
+                    jnp.asarray([3, tok, 5], jnp.int32),
+                    jnp.zeros((3,), jnp.float32), jax.random.key(0),
+                    jnp.int32(step), cfg=cfg, steps=1)
+                tok = int(got[live, 0])
+                toks.append(tok)
+                pos[live] += 1
+                wpos[live] += 1
+            return toks, np.asarray(k[1, [0, 2], t_max - 1])
+
+        try:
+            want, _ = run(kernel=False)
+            got, parked_rows = run(kernel=True)
+        finally:
+            engine_lib.decode_window.clear_cache()
+        assert got == want
+        assert np.isfinite(parked_rows).all()
+
+
 def _module_ast(relpath: str):
     path = Path(__file__).resolve().parents[1] / "tony_tpu" / relpath
     return ast.parse(path.read_text())

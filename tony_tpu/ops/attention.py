@@ -964,7 +964,8 @@ def _softmax_result(m_ref, l_ref, acc_ref, sink_ref, dtype):
 
 
 def _cache_decode_kernel(
-    layer_ref, pos_ref, *refs, scale, h_kv, window, ring, has_sink, k_parts,
+    layer_ref, pos_ref, src_ref, last_ref, *refs, scale, h_kv, window, ring,
+    has_sink, k_parts,
 ):
     """One program = one (slot, key block). Refs: q_ref [Hq, Dk], o_ref
     [Hq, Dv]; k_ref [block, Dk] / v_ref [block, Dv], the rows (t,
@@ -974,13 +975,18 @@ def _cache_decode_kernel(
     (all but 1/Hkv of the MXU work is discarded; the MXU is otherwise
     idle in decode and the relayout it saves is not free).
 
+    A block past the slot's last live one (``last_ref``, from
+    ``decode_last_block``) is not computed, and not fetched: the index
+    map stays on the last live block. A slot that reads nothing (pos <
+    0) computes no block and writes a row of zeros.
+
     Full cache (``window`` 0): key block 0 holds position 0, which every
-    slot sees, so m is finite from the first block on and masked scores
-    underflow to p = 0 with no guard. Ring cache: row r holds position
-    pos - (pos - r) % ring, visible inside the last ``window`` positions
-    and not before position 0; a block may hold no visible row, so p is
-    zeroed under the mask. ``sink_ref`` [Hq, 1]: folded into the
-    denominator when the last block has been seen."""
+    slot that reads sees, so m is finite from the first block on and
+    masked scores underflow to p = 0 with no guard. Ring cache: row r
+    holds position pos - (pos - r) % ring, visible inside the last
+    ``window`` positions and not before position 0; a block may hold no
+    visible row, so p is zeroed under the mask. ``sink_ref`` [Hq, 1]:
+    folded into the denominator when the last block has been seen."""
     q_ref, *k_refs = refs[:1 + k_parts]
     rest = refs[1 + k_parts:]
     if has_sink:
@@ -995,32 +1001,72 @@ def _cache_decode_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # K in lane tiles (one, or several for a head wider than a tile):
-    # the score is the sum of the tiles' products.
-    lanes = k_refs[0].shape[-1]
-    s = sum(
-        lax.dot_general(
-            q_ref[:, i * lanes:(i + 1) * lanes], k_ref[...],
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        ) for i, k_ref in enumerate(k_refs)
-    ) * scale                                              # [Hq, block]
-    group = s.shape[0] // h_kv
-    head = lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-    row = lax.broadcasted_iota(jnp.int32, s.shape, 1) + kb * s.shape[1]
     cur = pos_ref[slot]
-    if window:
-        back = (cur - row // h_kv) % ring        # how far behind ``cur``
-        visible = (row % h_kv == head) & (back < window) & (back <= cur)
-    else:
-        visible = (row % h_kv == head) & (row // h_kv <= cur)
-    s = jnp.where(visible, s, NEG_INF)
-    _online_softmax_step(s, v_ref, m_ref, l_ref, acc_ref,
-                         visible=visible if window else None)
 
-    @pl.when(kb == pl.num_programs(1) - 1)
+    @pl.when((kb <= last_ref[slot]) & (cur >= 0))
+    def _block():
+        # K in lane tiles (one, or several for a head wider than a
+        # tile): the score is the sum of the tiles' products.
+        lanes = k_refs[0].shape[-1]
+        s = sum(
+            lax.dot_general(
+                q_ref[:, i * lanes:(i + 1) * lanes], k_ref[...],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            ) for i, k_ref in enumerate(k_refs)
+        ) * scale                                          # [Hq, block]
+        group = s.shape[0] // h_kv
+        head = lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+        row = lax.broadcasted_iota(jnp.int32, s.shape, 1) + kb * s.shape[1]
+        if window:
+            back = (cur - row // h_kv) % ring    # how far behind ``cur``
+            visible = ((row % h_kv == head) & (back < window)
+                       & (back <= cur))
+        else:
+            visible = (row % h_kv == head) & (row // h_kv <= cur)
+        s = jnp.where(visible, s, NEG_INF)
+        _online_softmax_step(s, v_ref, m_ref, l_ref, acc_ref,
+                             visible=visible if window else None)
+
+    seen_all = kb == pl.num_programs(1) - 1
+
+    @pl.when(seen_all & (cur >= 0))
     def _finalize():
         o_ref[...] = _softmax_result(m_ref, l_ref, acc_ref, sink_ref,
                                      o_ref.dtype)
+
+    @pl.when(seen_all & (cur < 0))
+    def _nothing_read():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+# Rows (position, kv-head) of K and of V that one grid step of the decode
+# kernel streams through VMEM (1 MB of bf16 each at Dh 128). Swept on a
+# v5e inside the serving programs with the bound by live blocks on
+# (``tools/sweep_decode_blocks.py``; PERF.md section 6, PR 39): a smaller
+# block follows the live lengths closer, and every grid step, dead ones
+# too, costs its fixed third of a microsecond. 2,048 and 4,096 read within
+# 1.2% of each other at Mistral-7B's and MiMo's shapes (2,048 ahead with
+# every lane decoding, 4,096 with three quarters parked), 1,024 2-5% and
+# 512 10-19% slower, 8,192 2-10% slower: one constant serves both, and it
+# stays, so the running softmax sums in the order it did.
+DECODE_BLOCK_ROWS = 4096
+
+
+def decode_key_block(t_read: int, h_kv: int,
+                     block_rows: int | None = None) -> int:
+    """Positions in one key block of ``cache_decode_attention``'s kernel:
+    about ``block_rows`` rows (position, kv-head), a divisor of the
+    ``t_read`` positions a slot's reservation (or its ring) holds."""
+    rows = DECODE_BLOCK_ROWS if block_rows is None else block_rows
+    return math.gcd(t_read, max(1, rows // h_kv))
+
+
+def decode_last_block(pos, t_read: int, block: int):
+    """The last key block of ``block`` positions that holds a live key of
+    a slot at ``pos`` (a numpy or a jax array alike): the kernel reads a
+    slot's blocks 0 .. this, whole. In a ring no row past ``pos`` holds
+    a position before the first wrap, and every row does after it."""
+    return pos.clip(0, t_read - 1) // block
 
 
 def _cache_decode_pallas(q, *operands, n_k, has_sink, scale, block_rows,
@@ -1041,30 +1087,41 @@ def _cache_decode_pallas(q, *operands, n_k, has_sink, scale, block_rows,
     # A ring is read over its ``ring`` positions only: the parking row
     # past them never enters a block.
     t_read = ring if window else t
-    block = math.gcd(t_read, max(1, block_rows // h_kv)) * h_kv
+    positions = decode_key_block(t_read, h_kv, block_rows)
+    block = positions * h_kv
+    pos = pos.astype(jnp.int32)
+    # A slot that reads nothing names, at every grid step, the block the
+    # step before it left in VMEM: the last live block of the nearest
+    # slot before it that reads (slot 0's first block where none does).
+    src = lax.cummax(jnp.where(pos >= 0, jnp.arange(n_s, dtype=jnp.int32), 0))
+    last = decode_last_block(pos, t_read, positions)[src]
     q_spec = pl.BlockSpec((None, n_h, n_k * lanes),
-                          lambda s, j, layer, pos: (s, 0, 0))
+                          lambda s, j, layer, pos, src, last: (s, 0, 0))
     o_spec = pl.BlockSpec((None, n_h, d_v),
-                          lambda s, j, layer, pos: (s, 0, 0))
+                          lambda s, j, layer, pos, src, last: (s, 0, 0))
+
+    def kv_map(s, j, layer, pos, src, last):
+        # past the slot's last live block: stay there, so the pipeline
+        # issues no new copy
+        at = jnp.where(pos[s] < 0, last[s], jnp.minimum(j, last[s]))
+        return (layer[0], src[s], at, 0)
 
     def kv_spec(d):
-        return pl.BlockSpec(
-            (None, None, block, d),
-            lambda s, j, layer, pos: (layer[0], s, j, 0))
+        return pl.BlockSpec((None, None, block, d), kv_map)
 
     in_specs = [q_spec] + [kv_spec(lanes)] * n_k + [kv_spec(d_v)]
     operands = [q, *k_parts, v_all]
     if has_sink:
-        in_specs.append(pl.BlockSpec((n_h, 1),
-                                     lambda s, j, layer, pos: (0, 0)))
+        in_specs.append(pl.BlockSpec(
+            (n_h, 1), lambda s, j, layer, pos, src, last: (0, 0)))
         operands.append(sink[0].astype(jnp.float32).reshape(n_h, 1))
     return pl.pallas_call(
         functools.partial(_cache_decode_kernel, scale=scale, h_kv=h_kv,
                           window=window, ring=ring, has_sink=has_sink,
                           k_parts=n_k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n_s, t_read * h_kv // block),
+            num_scalar_prefetch=4,
+            grid=(n_s, t_read // positions),
             in_specs=in_specs,
             out_specs=o_spec,
             scratch_shapes=[
@@ -1078,7 +1135,7 @@ def _cache_decode_pallas(q, *operands, n_k, has_sink, scale, block_rows,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(layer.reshape(1).astype(jnp.int32), pos.astype(jnp.int32), *operands)
+    )(layer.reshape(1).astype(jnp.int32), pos, src, last, *operands)
 
 
 # Dim roles of the decode query [S, Hq, Dh] and the stacked cache.
@@ -1094,7 +1151,7 @@ def cache_decode_attention(
     pos: jax.Array,
     *,
     scale: float | None = None,
-    block_rows: int = 4096,
+    block_rows: int | None = None,
     mode: str = "auto",
     mesh: Mesh | None = None,
     window: int = 0,
@@ -1104,11 +1161,16 @@ def cache_decode_attention(
 
     q: [S, Hq, Dk]; k_all: [L, S, Tmax, Hkv, Dk], v_all: [L, S, Tmax,
     Hkv, Dv] (Hq % Hkv == 0; Dv may differ from Dk); ``layer`` a traced
-    scalar; slot s sees keys 0..pos[s] inclusive (pos >= 0).
-    -> [S, Hq, Dv]. The bytes read are the layer's own K/V (all Tmax
-    positions), once; about ``block_rows`` rows (position, kv-head) of
-    each stream through VMEM per grid step (1 MB of bf16 at Dh 128;
-    2,048 to 8,192 rows measured alike on a v5e).
+    scalar; slot s sees keys 0..pos[s] inclusive. A slot with pos < 0
+    decodes nothing: it reads no key, and its row of the result is
+    finite and means nothing (zeros from the kernel).
+    -> [S, Hq, Dv]. The bytes read are a slot's LIVE key blocks of the
+    layer's own K/V, once: the blocks of ``decode_key_block`` positions
+    up to the one that holds position pos[s] (``decode_last_block``), in
+    a ring up to row min(pos[s], ring - 1); a grid step past that block
+    fetches and computes nothing, and costs its fixed third of a
+    microsecond. About ``block_rows`` rows (position, kv-head) of each
+    stream through VMEM per grid step (``DECODE_BLOCK_ROWS``).
 
     ``k_all`` may also be a TUPLE of 128-lane tiles [L, S, Tmax, Hkv,
     128] of a K wider than one tile and no multiple of it, the last

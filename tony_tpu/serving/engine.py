@@ -21,7 +21,8 @@ for eval generation, a wall for serving) —
   ``attend`` scatters the slots' new K/V rows into the stacked buffer at
   (layer, slot, wpos[slot]) and reads that layer where it lies
   (``ops.cache_decode_attention``), masked per slot with
-  ``key_index <= pos[slot]``.
+  ``key_index <= pos[slot]``: a slot's key blocks up to the one that
+  holds ``pos[slot]``, and nothing for a lane that does not decode.
 * ``prefill_chunks`` — one bounded chunk of the prompt of EACH of up to
   P pending slots into those slots' cache rows. Chunking bounds how long
   a new prompt can stall the in-flight decode streams: the host
@@ -83,9 +84,10 @@ donated and no program ever holds a layer's slab apart from them: per
 dispatch the cache takes S · Hkv · Dh elements per layer per buffer in
 decode and P · C · Hkv · Dh in prefill (2 MB and 8 MB over 16 layers of
 8 × 128 bf16 heads at 32 slots, 4 × 32-token chunks), and gives one pass
-over the layer's S · Tmax rows in decode (4.3 GB), over P · Tmax in
-prefill (0.5 GB; through the kernel, over the P slots' positions before
-each chunk's end, once per KV head).
+over the decoding slots' live key blocks in decode (of 4.3 GB reserved,
+what ``stats()["decode_keys"]`` counts), over P · Tmax in prefill
+(0.5 GB; through the kernel, over the P slots' positions before each
+chunk's end, once per KV head).
 
 Overwrite-before-read invariant: slot reuse never zeroes a cache row.
 A freed slot's stale K/V rows are only ever unmasked after the new
@@ -120,6 +122,8 @@ from tony_tpu.ops.attention import (
     cache_rows_view,
     cache_slot_rows,
     cache_take,
+    decode_key_block,
+    decode_last_block,
     prefill_key_block,
     ring_positions,
     rowwise_cache_attention,
@@ -583,6 +587,25 @@ def prefill_read_block(cfg: TransformerConfig, k_all, p: int, c: int) -> int:
     return prefill_key_block(t, cfg.kv_heads_of("full"))
 
 
+def decode_read_block(k_all) -> int:
+    """Positions in one key block of a decode step's read of the FULL
+    layers (``cache_decode_attention``'s kernel)."""
+    full = jax.tree.leaves(_kind(k_all, "full"))[0]
+    return decode_key_block(full.shape[2], full.shape[3])
+
+
+def decode_read_positions(pos, parked, t_max: int, block: int) -> int:
+    """Key positions decode steps read in ONE full layer, over all lanes
+    (host arrays ``pos`` and ``parked`` of one shape: [S], or [S, steps]
+    for a window): a lane that decodes reads whole blocks of ``block``
+    positions up to the one that holds its position — the kernel's own
+    ``decode_last_block`` — and a parked lane none (``decode_window``
+    hands the kernel -1 for it). Counted by the kernel's rule whatever
+    path runs, as ``prefill_read_block`` counts."""
+    blocks = decode_last_block(pos, t_max, block) + 1
+    return int(blocks[~parked].sum()) * block
+
+
 def _attend_rows(q, kc, vc, at, slots, starts, mask, scale, sink, cfg, attn):
     """A round's chunks [P, C, Hq, Dk] against layer ``at`` of their
     slots' cache, the chunks already written. Where the whole batch's
@@ -659,7 +682,9 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
     ``wpos``: ``wpos % ring``, and the ring's parking row for a parked
     lane (an active stream never writes at ``Tmax - 1``: its last fed
     token lies at ``Tmax - 2`` at most). Parked lanes send no pair to an
-    expert.
+    expert, and read no key of a stacked cache kind: the decode kernel
+    is handed -1 for them (their stale ``pos`` would name a last
+    tenant's blocks), and their rows of the result are discarded.
 
     Returns (k_all, v_all, window_tokens [S, steps] int32, counts): the
     expert layers' counters summed over the window (``pairs``: the
@@ -683,6 +708,9 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
         # output is discarded; the mask itself cannot overflow).
         rp = jnp.minimum(pos, cfg.max_seq - 1)[:, None]
         parked = wpos >= t_max - 1
+        # What a stacked kind's lane attends up to: a parked lane (free
+        # with a stale pos, or in prefill) reads no key at all.
+        seen = jnp.where(parked, -1, jnp.minimum(pos, t_max - 1))
 
         def layer(x, lp, attn, at, k_all, v_all):
             sparse_keys = None
@@ -716,7 +744,7 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
                 kc = _write_rows(kc, row, k_new[:, 0], w_at)
                 vc = _write_rows(vc, row, v_new[:, 0], w_at)
                 o = cache_decode_attention(
-                    q[:, 0], kc, vc, row, pos, scale=scale,
+                    q[:, 0], kc, vc, row, seen, scale=scale,
                     window=window, sink=sink,
                 )[:, None]
                 k_all = _with_kind(k_all, attn, kc)

@@ -37,7 +37,8 @@ and trace reductions match on them::
                                        (attrs keys_read, expert_pairs*)
         tony:engine.emit               first tokens, retirements
       tony:engine.decode_device    dispatch -> readback returned
-                                   (attrs slots, window, expert_pairs*)
+                                   (attrs slots, window, keys_read,
+                                   expert_pairs*)
       tony:engine.emit             the per-token loop, retirements
       tony:engine.publish          gauges + registry report
 
@@ -54,7 +55,12 @@ readback; ``stats()["experts"]`` sums them per held expert, and beside
 them the passes the expert layers ran (``passes``). A model with
 window layers has a cache stack per attention kind, and
 ``stats()["kv"]["kinds"]`` counts each (the top-level ``kv`` keys stay
-the full kind's). A model with sparse layers: ``stats()["sparse"]`` =
+the full kind's). ``stats()["decode_keys"]``, the twin of
+``["prefill_keys"]``: the key positions the decode steps read in the
+full layers by the decode kernel's rule of blocks
+(``engine.decode_read_positions``; a parked lane reads none) against
+what every slot reserves there; the decode span carries the dispatch's
+``keys_read``. A model with sparse layers: ``stats()["sparse"]`` =
 ``{keys_read, keys_live}``, the keys the selection listed for the decode
 kernel (counted on the device, back in the iteration's readback) against
 what a dense layer would have read, per KV group and summed over groups,
@@ -371,6 +377,13 @@ class ServingEngine:
         self._live_state_ns = 0
         self._prefill_keys_read = 0
         self._prefill_keys_reserved = 0
+        # Key positions the decode steps read in the full layers (every
+        # lane, parked ones at what they read: nothing) against what the
+        # slots reserve there, by the kernel's own rule of blocks.
+        self._dc_read_block = (_engine.decode_read_block(self._k)
+                               if self._full_layers else 0)
+        self._decode_keys_read = 0
+        self._decode_keys_reserved = 0
         self._live_position_ns = 0
         # The cache by attention kind (a uniform model has the one kind,
         # "full"): positions a slot reserves, bytes of one position over
@@ -700,6 +713,10 @@ class ServingEngine:
                     "read_positions": self._prefill_keys_read,
                     "reserved_positions": self._prefill_keys_reserved,
                 },
+                "decode_keys": {
+                    "read_positions": self._decode_keys_read,
+                    "reserved_positions": self._decode_keys_reserved,
+                },
                 "kv": {
                     "reserved_positions": self.slots * self.max_len,
                     "bytes_per_position": self._kv_bytes_per_position,
@@ -890,6 +907,17 @@ class ServingEngine:
         # a concurrent prefill into the same slot.
         wpos = np.where(self._active, self._pos,
                         np.int32(self.max_len - 1)).astype(np.int32)
+        keys_read = 0
+        if self._dc_read_block:
+            # step j of the window feeds position pos + j; a lane whose
+            # write has reached Tmax - 1 is parked from that step on
+            at = self._pos[:, None] + np.arange(w)
+            keys_read = self._full_layers * _engine.decode_read_positions(
+                at, ~self._active[:, None] | (at >= self.max_len - 1),
+                self.max_len, self._dc_read_block)
+            self._decode_keys_read += keys_read
+            self._decode_keys_reserved += (self.slots * self.max_len * w
+                                           * self._full_layers)
         # Decode draws live in [0, 2**30), prefill draws in
         # [2**30, 2**31): modular so a long-lived engine can neither
         # overflow int32 nor cross domains (keys repeat only after
@@ -897,7 +925,7 @@ class ServingEngine:
         # Span covers dispatch AND the readback sync — the wall the
         # chip actually spent on this window.
         with tr.span("tony:engine.decode_device", slots=n_active,
-                     window=w) as sp, \
+                     window=w, keys_read=keys_read) as sp, \
                 jit_sanitizer.step_region("serving_decode_window"):
             self._k, self._v, window, expert_counts = self._decode(
                 self.params, self._k, self._v, self._pos, wpos,

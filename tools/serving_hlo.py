@@ -6,7 +6,8 @@ check; ``.claude/skills/verify/SKILL.md``).
     JAX_PLATFORMS=cpu python3 tools/serving_hlo.py <checkout> <out dir> [configuration ...]
     diff -r <out dir of the parent> <out dir of the change>
 
-Per configuration and program (``decode_window``, ``prefill_chunks``) it
+Per configuration and program (``decode_window``, ``prefill_chunks``; a
+training configuration named on the command line: its ``train_step``) it
 writes the StableHLO of ``lower()`` and the optimized HLO of ``compile()``.
 Each Mosaic kernel is serialised from a copy without its debug info (a
 kernel's bytecode holds its callers' paths and lines, so unstripped
@@ -30,6 +31,35 @@ def clean(text: str) -> str:
     text = re.sub(r"loc\([^)]*\)", "", text)
     return re.sub(r"(?s)(FileNames|FunctionNames|FileLocations|StackFrames)"
                   r".*?\n\n", "", text)
+
+
+def train_step(repo: Path, name: str, cfg: dict, model, device, on_chip,
+               i32):
+    """A training configuration's step, lowered at its cell's batch (the
+    first workload of ``BENCHMARK.json`` that names the configuration)."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from tony_tpu.models import make_train_step
+    from tony_tpu.parallel.mesh import AXES
+
+    bench = json.loads((repo / "BENCHMARK.json").read_text())
+    traffic = next(w["traffic"] for w in bench["workloads"]
+                   if w["config"] == name)
+    tr = json.loads((repo / "perfbench" / "traffic"
+                     / f"{traffic}.json").read_text())
+    run = cfg["run"]
+    mesh = Mesh(np.array([device]).reshape((1,) * len(AXES)), AXES)
+    tcfg = model.program_config(cfg, run, max_seq=tr["seq"],
+                                dtype=run["compute_dtype"], remat=True,
+                                remat_policy=run["remat"])
+    hp = run["optimizer"]
+    init_fn, step_fn = make_train_step(
+        tcfg, mesh, learning_rate=hp["learning_rate"],
+        weight_decay=hp["weight_decay"], grad_clip=hp["grad_clip"])
+    state = on_chip(jax.eval_shape(init_fn.__wrapped__, jax.random.key(0)))
+    return step_fn.lower(state, i32(tr["batch"], tr["seq"] + 1))
 
 
 def main() -> int:
@@ -74,6 +104,14 @@ def main() -> int:
     def i32(*shape):
         return sds(shape, jnp.int32)
 
+    def write(name, programs):
+        for program, lowered in programs.items():
+            (out / f"{name}.{program}.stablehlo.txt").write_text(
+                clean(lowered.as_text()))
+            (out / f"{name}.{program}.hlo.txt").write_text(
+                clean(lowered.compile().as_text()))
+            print(name, program, flush=True)
+
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     out.mkdir(parents=True, exist_ok=True)
     for name in names:
@@ -83,6 +121,11 @@ def main() -> int:
         model = spec.load_module(
             repo / "perfbench" / "models" / f"{cfg['model']}.py",
             "model_" + cfg["model"])
+        if "optimizer" in run:
+            write(name, {"train_step": train_step(repo, name, cfg, model,
+                                                  topo.devices[0], on_chip,
+                                                  i32)})
+            continue
         tcfg = model.program_config(cfg, run, max_seq=run["max_seq"],
                                     dtype=run["weights_dtype"])
         chunk = int(cfg.get("conf", {}).get("tony.serving.prefill-chunk", 32))
@@ -100,12 +143,7 @@ def main() -> int:
                 fused, k, v, i32(4, chunk), i32(4), i32(4), i32(4),
                 sds((4,), jnp.float32), key, i32(), cfg=tcfg),
         }
-        for program, lowered in programs.items():
-            (out / f"{name}.{program}.stablehlo.txt").write_text(
-                clean(lowered.as_text()))
-            (out / f"{name}.{program}.hlo.txt").write_text(
-                clean(lowered.compile().as_text()))
-            print(name, program, flush=True)
+        write(name, programs)
     return 0
 
 

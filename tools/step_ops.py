@@ -70,6 +70,9 @@ def main() -> int:
                                 True, keep_work=kept)
         reduced = xplane.reduce(
             json.loads((kept / "trace_events.json").read_text()))
+        window = kept / "window.json"      # a serving job's own report
+        engine_stats = (json.loads(window.read_text()).get("engine_stats", {})
+                        if window.exists() else {})
     finally:
         shutil.rmtree(kept, ignore_errors=True)
     result = done["result"]
@@ -85,8 +88,14 @@ def main() -> int:
               f"{program['total_s']:.3f} s in all")
     for name, calls, ms in out["ops"]:
         print(f"{ms:9.3f} ms {calls:7.2f} x  {name}")
-    print(json.dumps({k: result[k] for k in
-                      ("correct", "metrics", "device", "compared")}))
+    # what the engine counted over its life: key positions its attention
+    # read against what the slots reserve (stats()["prefill_keys"], PR 31;
+    # ["decode_keys"], PR 39)
+    out["keys"] = {k: engine_stats[k] for k in ("prefill_keys", "decode_keys")
+                   if k in engine_stats}
+    print(json.dumps({**{k: result[k] for k in
+                         ("correct", "metrics", "device", "compared")},
+                      "keys": out["keys"]}))
     if args.out:
         Path(args.out).parent.mkdir(parents=True, exist_ok=True)
         Path(args.out).write_text(json.dumps(out))
