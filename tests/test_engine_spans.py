@@ -16,11 +16,30 @@ from tony_tpu.observability.metrics import MetricsRegistry
 from tony_tpu.serving import ServingEngine
 from tony_tpu.serving.scheduler import _PHASES, _chunk_plan, _summary
 
+DISPATCH_SPANS = {"tony:engine.prefill_launch", "tony:engine.prefill_readback",
+                  "tony:engine.decode_launch", "tony:engine.decode_readback"}
 ENGINE_SPANS = {"tony:engine.admit", "tony:engine.prefill_round",
                 "tony:engine.prefill_assemble", "tony:engine.prefill_device",
                 "tony:engine.decode_device", "tony:engine.emit",
-                "tony:engine.publish"}
+                "tony:engine.publish"} | DISPATCH_SPANS
 PROMPT_LENS = (5, 9, 13, 17, 3, 22)
+# The fixture's prefill rounds by hand, as (entries, of them at their last
+# chunk). Prompts a..f of PROMPT_LENS have 2, 3, 4, 5, 1 and 6 chunks of 4;
+# three slots, two entries a round, one chunk a pending slot an iteration,
+# six tokens a request (the first from its last chunk, then five decode
+# steps: five iterations at window 1, two at window 3). Window 1: a1 b1 |
+# c1 ; a2* b2 | c2 ; b3* c3 ; c4* ; three iterations of decode alone (a
+# retires, d takes its slot) ; d1 (b retires, e) ; d2 e1* (c retires, f) ;
+# d3 f1 ; d4 f2 ; d5* f3 ; f4 ; f5 ; f6*. Window 3 retires a after its
+# second iteration, so d joins c's last round: a1 b1 | c1 ; a2* b2 | c2 ;
+# b3* c3 ; c4* d1 ; d2 e1* ; d3 f1 ; d4 f2 ; d5* f3 ; f4 ; f5 ; f6*.
+ROUNDS = {
+    1: [(2, 0), (1, 0), (2, 1), (1, 0), (2, 1), (1, 1), (1, 0), (2, 1),
+        (2, 0), (2, 0), (2, 1), (1, 0), (1, 0), (1, 1)],
+    3: [(2, 0), (1, 0), (2, 1), (1, 0), (2, 1), (2, 1), (2, 1), (2, 0),
+        (2, 0), (2, 1), (1, 0), (1, 0), (1, 1)],
+}
+DECODE_ITERATIONS = {1: 17, 3: 9}
 
 
 def _engine(**kw) -> ServingEngine:
@@ -104,6 +123,8 @@ def test_engine_spans_descend_from_their_step_and_lie_inside_it(served):
         if e["name"] in ("tony:engine.prefill_assemble",
                          "tony:engine.prefill_device"):
             assert parent["name"] == "tony:engine.prefill_round"
+        elif e["name"] in DISPATCH_SPANS:        # a device span's halves
+            assert parent["name"] == e["name"].rsplit("_", 1)[0] + "_device"
         elif e["name"] != "tony:engine.emit":    # emit: round's or step's
             assert parent["name"] == "tony:engine.step"
         while parent["name"] != "tony:engine.step":
@@ -119,6 +140,73 @@ def test_engine_spans_descend_from_their_step_and_lie_inside_it(served):
                and e["args"]["window"] == eng.decode_window for e in decodes)
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_a_dispatch_is_split_where_the_jitted_call_returns(served, program):
+    """Every device span has one ``*_launch`` and one ``*_readback`` child:
+    both lie inside it, the launch ends before the readback starts, and
+    the bookkeeping after the readback is the parent's own."""
+    eng, _, _ = served
+    spans = [e for e in _spans(eng) if e["name"].startswith("tony:engine.")]
+    devices = [e for e in spans
+               if e["name"] == f"tony:engine.{program}_device"]
+    assert devices
+    for dev in devices:
+        mine = {e["name"].rsplit("_", 1)[1]: e for e in spans
+                if e["args"]["parent_id"] == dev["args"]["span_id"]}
+        assert sorted(mine) == ["launch", "readback"]
+        launch, readback = mine["launch"], mine["readback"]
+        assert launch["name"] == f"tony:engine.{program}_launch"
+        assert readback["name"] == f"tony:engine.{program}_readback"
+        # microsecond export: a stamp may round one tick either way
+        assert dev["ts"] <= launch["ts"]
+        assert launch["ts"] + launch["dur"] <= readback["ts"] + 1
+        assert (readback["ts"] + readback["dur"]
+                <= dev["ts"] + dev["dur"] + 1)
+        assert launch["args"]["h2d_arrays"] == (5 if program == "decode"
+                                                else 6)
+        assert launch["args"]["h2d_bytes"] > 0 < readback["args"]["d2h_bytes"]
+    firsts = [e["args"].get("first_tokens") for e in spans
+              if e["name"] == f"tony:engine.{program}_readback"]
+    if program == "prefill":
+        assert firsts == [f for _, f in ROUNDS[eng.decode_window]]
+        assert sum(firsts) == len(PROMPT_LENS)
+    else:
+        assert firsts == [None] * len(devices)
+
+
+def test_dispatch_counters_count_exactly(served):
+    """``stats()["dispatch"]``: calls, the bytes a call's host arguments
+    take up and its readback brings home, and the rounds whose readback
+    held no first token, all by hand; the two halves' times lie inside
+    their device phase."""
+    eng, _, _ = served
+    w = eng.decode_window
+    st = eng.stats()
+    decode, prefill = st["dispatch"]["decode"], st["dispatch"]["prefill"]
+    assert decode["calls"] == st["decode_iterations"] == DECODE_ITERATIONS[w]
+    assert prefill["calls"] == st["prefill_rounds"] == len(ROUNDS[w])
+    assert [e["args"]["batch"] for e in _spans(eng)
+            if e["name"] == "tony:engine.prefill_round"] == \
+        [n for n, _ in ROUNDS[w]]
+    assert prefill["rounds_without_first_token"] == \
+        sum(f == 0 for _, f in ROUNDS[w]) == len(ROUNDS[w]) - len(PROMPT_LENS)
+    # up: _pos, wpos, _last (int32) and _temp (float32) of 3 slots and the
+    # draw counter; 2 rows x 4 tokens, four arrays of 2 and the counter
+    assert decode["h2d_bytes"] == decode["calls"] * (4 * 3 * 4 + 4)
+    assert prefill["h2d_bytes"] == prefill["calls"] * (2 * 4 * 4 + 4 * 2 * 4
+                                                       + 4)
+    # back: int32 tokens of 3 slots x the window; 2 rows' first tokens
+    assert decode["d2h_bytes"] == decode["calls"] * 3 * w * 4
+    assert prefill["d2h_bytes"] == prefill["calls"] * 2 * 4
+    assert set(decode) == {"calls", "launch_ms", "readback_ms", "h2d_bytes",
+                           "d2h_bytes"}
+    assert set(prefill) == set(decode) | {"rounds_without_first_token"}
+    for program, row in st["dispatch"].items():
+        assert row["launch_ms"] > 0 < row["readback_ms"]
+        assert (row["launch_ms"] + row["readback_ms"]
+                <= st["phase_ms"][f"{program}_device"])
+
+
 def test_phases_are_inside_the_working_wall(served):
     eng, _, _ = served
     st = eng.stats()
@@ -130,9 +218,10 @@ def test_phases_are_inside_the_working_wall(served):
 
 
 def test_phases_sum_to_the_working_wall():
-    """Within 5%: what is left is host time between spans, some tens of
-    microseconds an iteration, so the model here is one whose iteration
-    takes milliseconds on a CPU even with its programs compiled."""
+    """Within 5%: what is left is the host's own (admit, assemble, emit,
+    publish and between spans), a hundred microseconds an iteration, so
+    the model here is one whose iteration takes milliseconds on a CPU
+    even with its programs compiled."""
     cfg = TransformerConfig(
         vocab_size=512, d_model=256, n_layers=4, n_heads=4, head_dim=64,
         d_ff=1024, max_seq=256, dtype="float32", remat=False,
@@ -189,7 +278,7 @@ def test_close_leaves_the_counters_as_they_were(served):
     eng, _, before_close = served
     after = eng.stats()
     for key in ("working_iterations", "working_wall_ms", "phase_ms",
-                "decode_iterations", "decode_slots_sum", "prefill_rounds",
+                "dispatch", "decode_iterations", "decode_slots_sum", "prefill_rounds",
                 "prefill_tokens_valid", "prefill_rows_padded",
                 "prefill_keys", "decode_keys", "kv",
                 "queue_wait_ms", "prefill_span_ms", "retired"):

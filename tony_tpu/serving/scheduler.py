@@ -35,20 +35,44 @@ and trace reductions match on them::
         tony:engine.prefill_assemble   host builds the batch
         tony:engine.prefill_device     dispatch -> readback returned
                                        (attrs keys_read, expert_pairs*)
+          tony:engine.prefill_launch     -> the jitted call returned
+                                         (attrs h2d_arrays, h2d_bytes)
+          tony:engine.prefill_readback   device_get of the first tokens
+                                         (attrs d2h_bytes, first_tokens)
         tony:engine.emit               first tokens, retirements
       tony:engine.decode_device    dispatch -> readback returned
                                    (attrs slots, window, keys_read,
                                    expert_pairs*)
+        tony:engine.decode_launch    -> the jitted call returned
+                                     (attrs h2d_arrays, h2d_bytes)
+        tony:engine.decode_readback  device_get of the window's tokens
+                                     (attr d2h_bytes)
       tony:engine.emit             the per-token loop, retirements
       tony:engine.publish          gauges + registry report
+
+A device span is split at the one moment the host can stamp inside it:
+the jitted call has returned. ``*_launch`` runs from the device span's
+start to that return (argument handling, the host arrays' copies up, the
+enqueue; ``h2d_*``: the numpy values among the call's arguments and
+their ``nbytes``), ``*_readback`` is the fenced ``jax.device_get`` of
+what the host needs back (``d2h_bytes``; a prefill round's
+``first_tokens``: its entries at their last chunk, the only ones whose
+token is used); the bookkeeping after it is the parent's own.
 
 and, per request, written when it retires and joined by ``request=``:
 ``tony:request.queue`` (submit -> slot), ``tony:request.prefill`` (slot
 -> first token, attr ``rounds``; none for a request injected with
 shipped KV) and ``tony:request.decode`` (first token -> done, attr
 ``tokens``; none for a request that never decoded). ``stats()`` serves
-the counters taken at the same boundaries (``phase_ms``, ``kv``,
-``queue_wait_ms`` ...). Idle polls record and count nothing.
+the counters taken at the same boundaries (``phase_ms``: the two device
+spans; ``kv``, ``queue_wait_ms`` ...), and under ``stats()["dispatch"]``
+the split of each program's dispatches: ``{"decode": {calls, launch_ms,
+readback_ms, h2d_bytes, d2h_bytes}, "prefill": {the same five,
+rounds_without_first_token}}`` — ``launch_ms + readback_ms`` is at most
+``phase_ms``' device phase, ``calls`` are ``decode_iterations`` and
+``prefill_rounds``, and a round without a first token is one whose
+readback brought home nothing the engine uses but the experts' counts.
+Idle polls record and count nothing.
 (*) A model with experts only: the dispatch's (token, choice) pairs on
 the experts held here, which come back with the tokens in the one
 readback; ``stats()["experts"]`` sums them per held expert, and beside
@@ -110,10 +134,15 @@ _MS_BUCKETS = (0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
 _RATE_WINDOW_S = 5.0
 
 # Host-clock phases of a working iteration, each summed from the span of
-# the same name (stats()["phase_ms"]); what is left of the iteration's
-# wall is host time between spans.
-_PHASES = ("admit", "prefill_assemble", "prefill_device", "decode_device",
-           "emit", "publish")
+# the same name (stats()["phase_ms"]): the two in which the device works;
+# what is left of the iteration's wall is the host's own.
+_PHASES = ("prefill_device", "decode_device")
+# Their halves (module docstring), summed the same way into
+# stats()["dispatch"]: <program>_launch and <program>_readback.
+_DISPATCH_SPANS = ("prefill_launch", "prefill_readback", "decode_launch",
+                   "decode_readback")
+# What a jitted call copies up: the numpy values among its arguments.
+_HOST_VALUES = (np.ndarray, np.generic)
 
 # Retired requests whose queue wait / prefill span stats() summarises.
 _LATENCY_RING = 512
@@ -346,8 +375,13 @@ class ServingEngine:
         # engine's life and untouched by close(). ``_it_ns`` is the
         # iteration in progress; it is committed only if the iteration
         # did work, so idle polls and model swaps count nowhere.
-        self._it_ns = dict.fromkeys(_PHASES, 0)
-        self._phase_ns = dict.fromkeys(_PHASES, 0)
+        self._it_ns = dict.fromkeys(_PHASES + _DISPATCH_SPANS, 0)
+        self._span_ns = dict(self._it_ns)
+        # Bytes a program's dispatches took up and brought back, and the
+        # prefill rounds in which no entry was at its last chunk.
+        self._h2d_bytes = {"prefill": 0, "decode": 0}
+        self._d2h_bytes = {"prefill": 0, "decode": 0}
+        self._rounds_without_first = 0
         self._working_iters = 0
         self._working_wall_ns = 0
         self._decode_iters = 0
@@ -703,7 +737,16 @@ class ServingEngine:
                                  | set(self._model_loaders)),
                 "working_iterations": self._working_iters,
                 "working_wall_ms": self._working_wall_ns / 1e6,
-                "phase_ms": {k: v / 1e6 for k, v in self._phase_ns.items()},
+                "phase_ms": {k: self._span_ns[k] / 1e6 for k in _PHASES},
+                "dispatch": {
+                    "decode": self._dispatch_stats(
+                        "decode", self._decode_iters),
+                    "prefill": dict(
+                        self._dispatch_stats("prefill",
+                                             self._prefill_rounds),
+                        rounds_without_first_token=(
+                            self._rounds_without_first)),
+                },
                 "decode_iterations": self._decode_iters,
                 "decode_slots_sum": self._decode_slots_sum,
                 "prefill_rounds": self._prefill_rounds,
@@ -760,6 +803,13 @@ class ServingEngine:
         out["queue_wait_ms"] = _summary(queue_wait)
         out["prefill_span_ms"] = _summary(prefill_span)
         return out
+
+    def _dispatch_stats(self, program: str, calls: int) -> dict:
+        return {"calls": calls,
+                "launch_ms": self._span_ns[f"{program}_launch"] / 1e6,
+                "readback_ms": self._span_ns[f"{program}_readback"] / 1e6,
+                "h2d_bytes": self._h2d_bytes[program],
+                "d2h_bytes": self._d2h_bytes[program]}
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> "ServingEngine":
@@ -869,25 +919,23 @@ class ServingEngine:
             self._publish(decoded=False)
             return False
         tr = self._tracer
-        it = self._it_ns = dict.fromkeys(_PHASES, 0)
+        it = self._it_ns = dict.fromkeys(self._span_ns, 0)
         with tr.span("tony:engine.step", iteration=self._iter) as step_span:
-            with tr.span("tony:engine.admit") as sp:
+            with tr.span("tony:engine.admit"):
                 self._admit()
-            it["admit"] = sp.dur_ns
             did_prefill = self._prefill_some()
             decoded = False
             if self._active.any():
                 self._decode_some(step_span.start_ns)
                 decoded = True
-            with tr.span("tony:engine.publish") as sp:
+            with tr.span("tony:engine.publish"):
                 live_positions = self._publish(decoded)
-            it["publish"] = sp.dur_ns
         working = did_prefill or decoded
         if working:
             wall_ns = step_span.dur_ns
             with self._cond:
-                for phase, ns in it.items():
-                    self._phase_ns[phase] += ns
+                for span, ns in it.items():
+                    self._span_ns[span] += ns
                 self._working_iters += 1
                 self._working_wall_ns += wall_ns
                 # Positions held at the iteration's end, for its wall.
@@ -927,16 +975,19 @@ class ServingEngine:
         with tr.span("tony:engine.decode_device", slots=n_active,
                      window=w, keys_read=keys_read) as sp, \
                 jit_sanitizer.step_region("serving_decode_window"):
-            self._k, self._v, window, expert_counts = self._decode(
-                self.params, self._k, self._v, self._pos, wpos,
-                self._last, self._temp, self._base_key,
-                np.int32((self._decode_calls * w) % 2**30),
-            )
+            with tr.span("tony:engine.decode_launch") as launch:
+                args = (self.params, self._k, self._v, self._pos, wpos,
+                        self._last, self._temp, self._base_key,
+                        np.int32((self._decode_calls * w) % 2**30))
+                launch.set(**self._count_h2d("decode", args))
+                self._k, self._v, window, expert_counts = self._decode(*args)
             self._decode_calls += 1
             # Iteration fence: EXPLICIT readback, so the armed
             # transfer guard (jit sanitizer) lets it through. The
             # experts' counters come back in the same readback.
-            toks, counts = jax.device_get((window, expert_counts))  # tony: noqa[TONY-X002] — intended per-window fence
+            with tr.span("tony:engine.decode_readback") as readback:
+                toks, counts = jax.device_get((window, expert_counts))  # tony: noqa[TONY-X002] — intended per-window fence
+                readback.set(**self._count_d2h("decode", (toks, counts)))
             toks = np.asarray(toks)
             if counts is not None and "sparse_keys" in counts:
                 # The selection's own count, made on the device where the
@@ -951,6 +1002,8 @@ class ServingEngine:
             if counts:
                 sp.set(expert_pairs=self._note_pairs(counts, n_active * w))
         it["decode_device"] = sp.dur_ns
+        it["decode_launch"] = launch.dur_ns
+        it["decode_readback"] = readback.dur_ns
         self._decode_iters += 1
         self._decode_slots_sum += n_active
         wall_ms = (sp.end_ns - step_start_ns) / 1e6
@@ -959,7 +1012,7 @@ class ServingEngine:
         # what capacity planning reads.
         self._h_inter.observe(wall_ms / w)
         self.inter_token_ms_samples.append(wall_ms / w)
-        with tr.span("tony:engine.emit") as sp:
+        with tr.span("tony:engine.emit"):
             n_new = 0
             for s in np.flatnonzero(self._active):
                 req = self._slot_req[s]
@@ -980,7 +1033,6 @@ class ServingEngine:
             self._c_tokens.inc(n_new)
             self._n_tokens += n_new
             self._note_rate(n_new)
-        it["emit"] += sp.dur_ns
 
     def _publish(self, decoded: bool) -> tuple[int, int, int]:
         """End of an iteration: gauges and the registry report. Returns
@@ -1119,7 +1171,7 @@ class ServingEngine:
         entry's prompt; a prompt's last chunk yields its first token."""
         tr, it = self._tracer, self._it_ns
         n, pb = len(entries), self.prefill_batch
-        with tr.span("tony:engine.prefill_assemble") as sp:
+        with tr.span("tony:engine.prefill_assemble"):
             toks = np.zeros((pb, self.prefill_chunk), np.int32)
             slots_a = np.zeros(pb, np.int32)
             starts = np.zeros(pb, np.int32)
@@ -1145,8 +1197,8 @@ class ServingEngine:
             # offset) so no prefill sample can ever share a decode
             # step's key.
             self._pf_draws += 1
-        it["prefill_assemble"] += sp.dur_ns
         self._prefill_rounds += 1
+        self._rounds_without_first += not any(finals)
         if self._linear_layers:
             self._state_slots_reset += int((starts[:n] == 0).sum())
         self._prefill_tokens_valid += int(n_valids[:n].sum())
@@ -1161,18 +1213,25 @@ class ServingEngine:
         self._prefill_keys_reserved += n * self.max_len * self._full_layers
         with tr.span("tony:engine.prefill_device", keys_read=keys_read) as sp, \
                 jit_sanitizer.step_region("serving_prefill_chunks"):
-            self._k, self._v, first_toks, _, expert_counts = self._prefill(
-                self.params, self._k, self._v, toks, slots_a, starts,
-                n_valids, temps, self._base_key,
-                np.int32(2**30 + self._pf_draws % 2**30),
-            )
-            firsts, counts = jax.device_get((first_toks, expert_counts))  # tony: noqa[TONY-X002] — intended per-round fence
+            with tr.span("tony:engine.prefill_launch") as launch:
+                args = (self.params, self._k, self._v, toks, slots_a, starts,
+                        n_valids, temps, self._base_key,
+                        np.int32(2**30 + self._pf_draws % 2**30))
+                launch.set(**self._count_h2d("prefill", args))
+                self._k, self._v, first_toks, _, expert_counts = \
+                    self._prefill(*args)
+            with tr.span("tony:engine.prefill_readback",
+                         first_tokens=sum(finals)) as readback:
+                firsts, counts = jax.device_get((first_toks, expert_counts))  # tony: noqa[TONY-X002] — intended per-round fence
+                readback.set(**self._count_d2h("prefill", (firsts, counts)))
             firsts = np.asarray(firsts)
             if counts is not None:
                 sp.set(expert_pairs=self._note_pairs(
                     counts, int(n_valids[:n].sum())))
         it["prefill_device"] += sp.dur_ns
-        with tr.span("tony:engine.emit") as sp:
+        it["prefill_launch"] += launch.dur_ns
+        it["prefill_readback"] += readback.dur_ns
+        with tr.span("tony:engine.emit"):
             now = time.perf_counter()
             requeue: list[tuple[ServingRequest, int]] = []
             for i, (req, slot) in enumerate(entries):
@@ -1215,7 +1274,21 @@ class ServingEngine:
             if requeue:
                 with self._cond:
                     self._pf.extend(requeue)
-        it["emit"] += sp.dur_ns
+
+    def _count_h2d(self, program: str, args: tuple) -> dict:
+        """A launch span's attrs: the numpy values among a jitted call's
+        arguments, which the call copies up, and their bytes."""
+        host = [a.nbytes for a in args if isinstance(a, _HOST_VALUES)]
+        nbytes = sum(host)
+        self._h2d_bytes[program] += nbytes
+        return {"h2d_arrays": len(host), "h2d_bytes": nbytes}
+
+    def _count_d2h(self, program: str, results) -> dict:
+        """A readback span's attr: the bytes of the host arrays its
+        ``device_get`` returned."""
+        nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(results))
+        self._d2h_bytes[program] += nbytes
+        return {"d2h_bytes": nbytes}
 
     def _note_pairs(self, counts: dict, tokens: int) -> int:
         """One dispatch's expert counters (host arrays: they came back

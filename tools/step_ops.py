@@ -14,12 +14,24 @@ train step stayed unnamed until ISSUE 33.
         --seed 2147600123 --seconds 51 [--repo .chip_scratch/parent] \\
         [--out chiprun_out/step_ops/parent.json]
 
+For a serving cell, below the operations, the ANATOMY OF A DISPATCH
+(``dispatches``): the kept trace holds the engine's host spans and the
+``XLA Modules`` line on one clock, so each ``tony:engine.*_device`` span
+is joined with the program that started inside it and the device's idle
+time is put down to where in the dispatch it lies (ISSUE 40): before the
+program (``lead``, and ``return_to_start`` against the launch span's
+end), inside it (``bubbles``), after it (``tail``) and between two
+dispatches (``between``); beside them the engine's own
+``stats()["dispatch"]`` over its life.
+
 Run on no CPU: the harness refuses one."""
 
 from __future__ import annotations
 
 import argparse
+import bisect
 import json
+import re
 import shutil
 import sys
 import tempfile
@@ -51,6 +63,108 @@ def table(reduced: dict) -> dict:
             "programs": programs, "ops": ops}
 
 
+ENGINE_SPAN = re.compile(r"^tony:engine\.(decode|prefill)_"
+                         r"(device|launch|readback)$")
+PARTS = ("lead", "return_to_start", "bubbles", "tail", "between")
+
+
+def _rank(values: list, pct: int) -> float:
+    """The value at rank ceil(pct/100 x n) (nearest rank)."""
+    return sorted(values)[-(-pct * len(values) // 100) - 1]
+
+
+def dispatches(trace: dict, reduced: dict, xplane) -> dict:
+    """The traced window's dispatches, one per ``tony:engine.*_device``
+    span that lies inside it with both of its halves and ONE program
+    started inside it, in nanoseconds on the trace's clock:
+
+    - ``lead``: program start - launch span start (the device idles
+      through all of it: the dispatch before was fenced);
+    - ``return_to_start``: program start - launch span END, negative
+      where the device began before the jitted call returned;
+    - ``bubbles``: program duration - the union of its operations;
+    - ``tail``: readback span end - program end;
+    - ``between``: the next dispatch's launch span start - this readback
+      span's end (host work: emit, publish, admit, assemble).
+
+    ``lead + bubbles + tail + between`` tile the time from the first
+    launch to the last readback but for the programs' busy time, so their
+    sum is the window's idle time less what lies at its two edges and
+    under no engine span (``remainder_s``). ``programs``: per program
+    {n, and per part {p50_ms, p90_ms, sum_s}}; ``rows``: every dispatch.
+    ``xplane``: the harness's reduction module, ``reduced`` its reduction
+    of ``trace`` (the window's idle time is its)."""
+    lo, hi = xplane.window_of(trace)
+    dev = trace["devices"][min(trace["devices"], key=int)]
+    ops = sorted((s, s + d) for _, s, d in dev["ops"] if d > 0)
+    starts = [s for s, _ in ops]
+    modules = sorted((s, s + d, re.sub(r"\(\d+\)$", "", n))
+                     for n, s, d in dev["modules"])
+    spans: dict[str, list] = {"device": [], "launch": [], "readback": []}
+    for name, s, d in trace["host_spans"]:
+        m = ENGINE_SPAN.match(name)
+        if m and lo <= s and s + d <= hi:
+            spans[m.group(2)].append((s, s + d, m.group(1)))
+    rows, unmatched = [], 0
+    for s, e, program in sorted(spans["device"]):
+        halves = [[h for h in spans[k] if s <= h[0] and h[1] <= e
+                   and h[2] == program] for k in ("launch", "readback")]
+        started = [m for m in modules if s <= m[0] < e]
+        if [len(h) for h in halves] != [1, 1] or len(started) != 1:
+            unmatched += 1
+            continue
+        (launch,), (readback,) = halves
+        p0, p1, name = started[0]
+        # a program's operations are those that start inside it
+        busy = xplane.total(xplane.union(
+            [[a, min(b, p1)] for a, b in ops[bisect.bisect_left(starts, p0):
+                                             bisect.bisect_left(starts, p1)]]))
+        rows.append({"program": name, "launch_start": launch[0],
+                     "readback_end": readback[1],
+                     "lead": p0 - launch[0],
+                     "return_to_start": p0 - launch[1],
+                     "bubbles": (p1 - p0) - busy,
+                     "tail": readback[1] - p1, "between": None})
+    for row, nxt in zip(rows, rows[1:]):
+        row["between"] = nxt["launch_start"] - row["readback_end"]
+    idle_s = reduced["window_s"] - reduced["busy_s"]
+    accounted_s = sum(row[k] or 0 for row in rows
+                      for k in ("lead", "bubbles", "tail", "between")) / 1e9
+    programs = {}
+    for name in sorted({row["program"] for row in rows}):
+        mine = [row for row in rows if row["program"] == name]
+        programs[name] = {"n": len(mine)}
+        for part in PARTS:
+            values = [row[part] for row in mine if row[part] is not None]
+            programs[name][part] = {
+                "p50_ms": _rank(values, 50) / 1e6,
+                "p90_ms": _rank(values, 90) / 1e6,
+                "sum_s": sum(values) / 1e9} if values else None
+    return {"window_s": reduced["window_s"], "idle_s": idle_s,
+            "accounted_s": accounted_s, "remainder_s": idle_s - accounted_s,
+            "unmatched_device_spans": unmatched, "programs": programs,
+            "rows": rows}
+
+
+def print_dispatches(table: dict, engine_dispatch: dict | None) -> None:
+    print(f"dispatches: idle {table['idle_s']:.4f} s of the window's "
+          f"{table['window_s']:.3f} s; lead + bubbles + tail + between "
+          f"{table['accounted_s']:.4f} s; remainder (the window's edges, no "
+          f"engine span) {table['remainder_s']:.4f} s = "
+          f"{100.0 * table['remainder_s'] / max(table['idle_s'], 1e-12):.1f}%"
+          f" of idle; {table['unmatched_device_spans']} device spans "
+          f"without one program and both halves")
+    for name, row in table["programs"].items():
+        print(f"  {name}: {row['n']} dispatches   (p50 ms / p90 ms / sum s)")
+        for part in PARTS:
+            if row[part]:
+                print(f"    {part:16s} {row[part]['p50_ms']:8.3f} "
+                      f"{row[part]['p90_ms']:8.3f} {row[part]['sum_s']:8.4f}")
+    if engine_dispatch:
+        print(f"  stats()[\"dispatch\"] over the engine's life: "
+              f"{json.dumps(engine_dispatch)}")
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--repo", default=str(Path(__file__).resolve().parents[1]))
@@ -68,8 +182,8 @@ def main() -> int:
     try:
         done = harness.run_cell(repo, args.workload, args.seed, args.seconds,
                                 True, keep_work=kept)
-        reduced = xplane.reduce(
-            json.loads((kept / "trace_events.json").read_text()))
+        trace = json.loads((kept / "trace_events.json").read_text())
+        reduced = xplane.reduce(trace)
         window = kept / "window.json"      # a serving job's own report
         engine_stats = (json.loads(window.read_text()).get("engine_stats", {})
                         if window.exists() else {})
@@ -88,6 +202,11 @@ def main() -> int:
               f"{program['total_s']:.3f} s in all")
     for name, calls, ms in out["ops"]:
         print(f"{ms:9.3f} ms {calls:7.2f} x  {name}")
+    if trace["devices"] and any(n.endswith("_launch") and ENGINE_SPAN.match(n)
+                                for n, _, _ in trace["host_spans"]):
+        out["dispatches"] = dispatches(trace, reduced, xplane)
+        print_dispatches(out["dispatches"], engine_stats.get("dispatch"))
+        out["engine_dispatch"] = engine_stats.get("dispatch")
     # what the engine counted over its life: key positions its attention
     # read against what the slots reserve (stats()["prefill_keys"], PR 31;
     # ["decode_keys"], PR 39)
