@@ -24,20 +24,27 @@ ENGINE_SPANS = {"tony:engine.admit", "tony:engine.prefill_round",
                 "tony:engine.publish"} | DISPATCH_SPANS
 PROMPT_LENS = (5, 9, 13, 17, 3, 22)
 # The fixture's prefill rounds by hand, as (entries, of them at their last
-# chunk). Prompts a..f of PROMPT_LENS have 2, 3, 4, 5, 1 and 6 chunks of 4;
-# three slots, two entries a round, one chunk a pending slot an iteration,
-# six tokens a request (the first from its last chunk, then five decode
-# steps: five iterations at window 1, two at window 3). Window 1: a1 b1 |
-# c1 ; a2* b2 | c2 ; b3* c3 ; c4* ; three iterations of decode alone (a
+# chunk, fenced). Prompts a..f of PROMPT_LENS have 2, 3, 4, 5, 1 and 6
+# chunks of 4; three slots, two entries a round, one chunk a pending slot an
+# iteration, six tokens a request (the first from its last chunk, then five
+# decode steps: five iterations at window 1, two at window 3). Window 1: a1
+# b1 | c1 ; a2* b2 | c2 ; b3* c3 ; c4* ; three iterations of decode alone (a
 # retires, d takes its slot) ; d1 (b retires, e) ; d2 e1* (c retires, f) ;
 # d3 f1 ; d4 f2 ; d5* f3 ; f4 ; f5 ; f6*. Window 3 retires a after its
 # second iteration, so d joins c's last round: a1 b1 | c1 ; a2* b2 | c2 ;
 # b3* c3 ; c4* d1 ; d2 e1* ; d3 f1 ; d4 f2 ; d5* f3 ; f4 ; f5 ; f6*.
+# A round is fenced for a first token (*), or where it closes a step that
+# decodes nothing: c1 (nothing decodes yet) and, at window 3, d4 f2 (e
+# retired the step before, d is at its fourth chunk) and f5 (d retired).
 ROUNDS = {
-    1: [(2, 0), (1, 0), (2, 1), (1, 0), (2, 1), (1, 1), (1, 0), (2, 1),
-        (2, 0), (2, 0), (2, 1), (1, 0), (1, 0), (1, 1)],
-    3: [(2, 0), (1, 0), (2, 1), (1, 0), (2, 1), (2, 1), (2, 1), (2, 0),
-        (2, 0), (2, 1), (1, 0), (1, 0), (1, 1)],
+    1: [(2, 0, False), (1, 0, True), (2, 1, True), (1, 0, False),
+        (2, 1, True), (1, 1, True), (1, 0, False), (2, 1, True),
+        (2, 0, False), (2, 0, False), (2, 1, True), (1, 0, False),
+        (1, 0, False), (1, 1, True)],
+    3: [(2, 0, False), (1, 0, True), (2, 1, True), (1, 0, False),
+        (2, 1, True), (2, 1, True), (2, 1, True), (2, 0, False),
+        (2, 0, True), (2, 1, True), (1, 0, False), (1, 0, True),
+        (1, 1, True)],
 }
 DECODE_ITERATIONS = {1: 17, 3: 9}
 
@@ -142,33 +149,45 @@ def test_engine_spans_descend_from_their_step_and_lie_inside_it(served):
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_a_dispatch_is_split_where_the_jitted_call_returns(served, program):
-    """Every device span has one ``*_launch`` and one ``*_readback`` child:
-    both lie inside it, the launch ends before the readback starts, and
-    the bookkeeping after the readback is the parent's own."""
+    """Every fenced device span has one ``*_launch`` and one ``*_readback``
+    child: both lie inside it, the launch ends before the readback starts,
+    and the bookkeeping after the readback is the parent's own. A prefill
+    round that is not fenced (``fenced=False``) has its launch alone."""
     eng, _, _ = served
     spans = [e for e in _spans(eng) if e["name"].startswith("tony:engine.")]
     devices = [e for e in spans
                if e["name"] == f"tony:engine.{program}_device"]
     assert devices
+    if program == "prefill":
+        assert [e["args"]["fenced"] for e in devices] == \
+            [fenced for _, _, fenced in ROUNDS[eng.decode_window]]
+    else:
+        assert not any("fenced" in e["args"] for e in devices)
     for dev in devices:
         mine = {e["name"].rsplit("_", 1)[1]: e for e in spans
                 if e["args"]["parent_id"] == dev["args"]["span_id"]}
-        assert sorted(mine) == ["launch", "readback"]
-        launch, readback = mine["launch"], mine["readback"]
+        launch = mine["launch"]
         assert launch["name"] == f"tony:engine.{program}_launch"
+        assert dev["ts"] <= launch["ts"]
+        assert launch["args"]["h2d_arrays"] == (5 if program == "decode"
+                                                else 6)
+        assert launch["args"]["h2d_bytes"] > 0
+        if not dev["args"].get("fenced", True):
+            assert sorted(mine) == ["launch"]
+            continue
+        assert sorted(mine) == ["launch", "readback"]
+        readback = mine["readback"]
         assert readback["name"] == f"tony:engine.{program}_readback"
         # microsecond export: a stamp may round one tick either way
-        assert dev["ts"] <= launch["ts"]
         assert launch["ts"] + launch["dur"] <= readback["ts"] + 1
         assert (readback["ts"] + readback["dur"]
                 <= dev["ts"] + dev["dur"] + 1)
-        assert launch["args"]["h2d_arrays"] == (5 if program == "decode"
-                                                else 6)
-        assert launch["args"]["h2d_bytes"] > 0 < readback["args"]["d2h_bytes"]
+        assert readback["args"]["d2h_bytes"] > 0
     firsts = [e["args"].get("first_tokens") for e in spans
               if e["name"] == f"tony:engine.{program}_readback"]
     if program == "prefill":
-        assert firsts == [f for _, f in ROUNDS[eng.decode_window]]
+        assert firsts == [f for _, f, fenced in ROUNDS[eng.decode_window]
+                          if fenced]
         assert sum(firsts) == len(PROMPT_LENS)
     else:
         assert firsts == [None] * len(devices)
@@ -176,9 +195,9 @@ def test_a_dispatch_is_split_where_the_jitted_call_returns(served, program):
 
 def test_dispatch_counters_count_exactly(served):
     """``stats()["dispatch"]``: calls, the bytes a call's host arguments
-    take up and its readback brings home, and the rounds whose readback
-    held no first token, all by hand; the two halves' times lie inside
-    their device phase."""
+    take up and its readback brings home, the rounds that held no first
+    token and those of them launched without a readback of their own, all
+    by hand; the two halves' times lie inside their device phase."""
     eng, _, _ = served
     w = eng.decode_window
     st = eng.stats()
@@ -187,20 +206,26 @@ def test_dispatch_counters_count_exactly(served):
     assert prefill["calls"] == st["prefill_rounds"] == len(ROUNDS[w])
     assert [e["args"]["batch"] for e in _spans(eng)
             if e["name"] == "tony:engine.prefill_round"] == \
-        [n for n, _ in ROUNDS[w]]
+        [n for n, _, _ in ROUNDS[w]]
     assert prefill["rounds_without_first_token"] == \
-        sum(f == 0 for _, f in ROUNDS[w]) == len(ROUNDS[w]) - len(PROMPT_LENS)
+        sum(f == 0 for _, f, _ in ROUNDS[w]) == \
+        len(ROUNDS[w]) - len(PROMPT_LENS)
+    fenced = sum(fenced for _, _, fenced in ROUNDS[w])
+    assert prefill["unfenced"] == len(ROUNDS[w]) - fenced == {1: 7, 3: 4}[w]
+    assert prefill["unfenced"] <= prefill["rounds_without_first_token"]
     # up: _pos, wpos, _last (int32) and _temp (float32) of 3 slots and the
     # draw counter; 2 rows x 4 tokens, four arrays of 2 and the counter
     assert decode["h2d_bytes"] == decode["calls"] * (4 * 3 * 4 + 4)
     assert prefill["h2d_bytes"] == prefill["calls"] * (2 * 4 * 4 + 4 * 2 * 4
                                                        + 4)
-    # back: int32 tokens of 3 slots x the window; 2 rows' first tokens
+    # back: int32 tokens of 3 slots x the window; a fenced round's 2 rows'
+    # first tokens, and nothing of an unfenced round (no experts here)
     assert decode["d2h_bytes"] == decode["calls"] * 3 * w * 4
-    assert prefill["d2h_bytes"] == prefill["calls"] * 2 * 4
+    assert prefill["d2h_bytes"] == fenced * 2 * 4
     assert set(decode) == {"calls", "launch_ms", "readback_ms", "h2d_bytes",
                            "d2h_bytes"}
-    assert set(prefill) == set(decode) | {"rounds_without_first_token"}
+    assert set(prefill) == set(decode) | {"rounds_without_first_token",
+                                          "unfenced"}
     for program, row in st["dispatch"].items():
         assert row["launch_ms"] > 0 < row["readback_ms"]
         assert (row["launch_ms"] + row["readback_ms"]
@@ -318,15 +343,20 @@ def served_layered():
 
 
 def test_device_spans_carry_the_pairs_on_held_experts(served_layered):
-    """``expert_pairs`` on every ``decode_device`` / ``prefill_device``
-    span is that dispatch's (token, choice) pairs on the held experts;
-    over all dispatches they are ``stats()["experts"]``."""
+    """``expert_pairs`` on a fenced ``decode_device`` / ``prefill_device``
+    span is the (token, choice) pairs on the held experts of that dispatch
+    and of the unfenced rounds it brought home; over all dispatches they
+    are ``stats()["experts"]``."""
     eng = served_layered
     ex = eng.stats()["experts"]
     device = [e for e in _spans(eng) if e["name"] in
               ("tony:engine.decode_device", "tony:engine.prefill_device")]
-    assert device and all("expert_pairs" in e["args"] for e in device)
-    assert sum(e["args"]["expert_pairs"] for e in device) == \
+    # a round that is not fenced has no counts yet: the span that fences
+    # it carries them beside its own
+    fenced = [e for e in device if e["args"].get("fenced", True)]
+    assert len(fenced) < len(device)
+    assert all(("expert_pairs" in e["args"]) == (e in fenced) for e in device)
+    assert sum(e["args"]["expert_pairs"] for e in fenced) == \
         ex["pairs_held"] == sum(ex["pairs_per_expert"])
     assert ex["held"] == [2, 4] and len(ex["pairs_per_expert"]) == 4
     assert ex["dispatches"] == len(device)

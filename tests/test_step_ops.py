@@ -23,11 +23,19 @@ def _load(path: Path, name: str):
 
 
 @pytest.fixture(scope="module")
-def table():
-    step_ops = _load(REPO / "tools" / "step_ops.py", "tools_step_ops")
-    xplane = _load(REPO / "perfbench" / "yardstick" / "xplane.py",
-                   "perfbench_xplane")
-    span = lambda name, s, e: [f"tony:engine.{name}", s, e - s]  # noqa: E731
+def modules():
+    return (_load(REPO / "tools" / "step_ops.py", "tools_step_ops"),
+            _load(REPO / "perfbench" / "yardstick" / "xplane.py",
+                  "perfbench_xplane"))
+
+
+def span(name, s, e):
+    return [f"tony:engine.{name}", s, e - s]
+
+
+@pytest.fixture(scope="module")
+def table(modules):
+    step_ops, xplane = modules
     trace = {
         "host_spans": [
             ["bench:traced", 500, 10500],
@@ -103,3 +111,69 @@ def test_programs_are_summed_by_nearest_rank(table):
     assert prefill["n"] == 1
     assert prefill["return_to_start"]["p50_ms"] == pytest.approx(-400e-6)
     assert prefill["bubbles"]["sum_s"] == 0.0
+
+
+def test_a_round_without_a_readback_is_a_dispatch_of_its_own(modules):
+    """Two prefill rounds launched and left (ISSUE 41: a device span with
+    its launch half alone) behind a fenced decode iteration, then the
+    iteration that fences them and a fenced round: a program is its
+    span's by ORDER (the second round's starts after its span ended, inside
+    the iteration's), an unfenced round has no ``tail`` and no ``between``,
+    and a program that queued behind another has the gap between the two as
+    its ``lead``. The last round's program seems to start before its span
+    does (the device's line sits on the host's clock to a millisecond or
+    so): still its own, with a negative ``lead`` that its ``tail`` makes
+    up."""
+    step_ops, xplane = modules
+    trace = {
+        "host_spans": [
+            ["bench:traced", 500, 13500],
+            span("decode_device", 1000, 3000),
+            span("decode_launch", 1000, 1400),
+            span("decode_readback", 1450, 2900),
+            # R1: the device is idle, its program starts before the return
+            span("prefill_device", 3200, 3700),
+            span("prefill_launch", 3200, 3700),
+            # R2: launched while R1 runs, its program queues behind R1's
+            span("prefill_device", 3800, 4300),
+            span("prefill_launch", 3800, 4300),
+            # the iteration behind them waits for all three programs
+            span("decode_device", 4500, 10500),
+            span("decode_launch", 4500, 5000),
+            span("decode_readback", 5050, 10400),
+            span("prefill_device", 10900, 13500),
+            span("prefill_launch", 10900, 11400),
+            span("prefill_readback", 11450, 13400),
+        ],
+        "devices": {"0": {
+            "modules": [["jit_decode_window(17)", 1300, 1300],
+                        ["jit_prefill_chunks(23)", 3600, 2400],
+                        ["jit_prefill_chunks(23)", 6010, 1990],
+                        ["jit_decode_window(17)", 8020, 1980],
+                        ["jit_prefill_chunks(23)", 10850, 1700]],
+            "ops": [["fusion:f32[8]", 1300, 1300], ["fusion:f32[4]", 3600, 2400],
+                    ["fusion:f32[4]", 6010, 1990], ["fusion:f32[8]", 8020, 1980],
+                    ["fusion:f32[4]", 10850, 1700]],
+        }},
+    }
+    table = step_ops.dispatches(trace, xplane.reduce(trace), xplane)
+    rows = [[r[k] for k in ("program", "lead", "return_to_start", "bubbles",
+                            "tail", "between")] for r in table["rows"]]
+    assert rows == [
+        ["jit_decode_window", 300, -100, 0, 300, 300],
+        ["jit_prefill_chunks", 400, -100, 0, None, None],
+        ["jit_prefill_chunks", 10, 1710, 0, None, None],
+        ["jit_decode_window", 20, 3020, 0, 400, 500],
+        ["jit_prefill_chunks", -50, -550, 0, 850, None],
+    ]
+    assert table["unmatched_device_spans"] == 0
+    prefill = table["programs"]["jit_prefill_chunks"]
+    assert (prefill["n"], prefill["unfenced"]) == (3, 2)
+    assert prefill["tail"]["sum_s"] == pytest.approx(850e-9)
+    assert prefill["between"] is None
+    assert table["programs"]["jit_decode_window"]["unfenced"] == 0
+    # busy 9,370 of 13,500; the edges 500 -> 1000 and 13,400 -> 14,000
+    assert table["idle_s"] == pytest.approx(4130e-9)
+    # lead 680 + tail 1550 + between 800
+    assert table["accounted_s"] == pytest.approx(3030e-9)
+    assert table["remainder_s"] == pytest.approx(1100e-9)

@@ -33,12 +33,15 @@ and trace reductions match on them::
       tony:engine.admit            queue -> free slots
       tony:engine.prefill_round    one prefill dispatch (attrs batch, chunk)
         tony:engine.prefill_assemble   host builds the batch
-        tony:engine.prefill_device     dispatch -> readback returned
-                                       (attrs keys_read, expert_pairs*)
+        tony:engine.prefill_device     dispatch -> readback returned, or
+                                       -> the jitted call returned where
+                                       the round is not fenced (attrs
+                                       keys_read, fenced, expert_pairs*)
           tony:engine.prefill_launch     -> the jitted call returned
                                          (attrs h2d_arrays, h2d_bytes)
-          tony:engine.prefill_readback   device_get of the first tokens
-                                         (attrs d2h_bytes, first_tokens)
+          tony:engine.prefill_readback   a fenced round only: device_get of
+                                         the first tokens (attrs d2h_bytes,
+                                         first_tokens)
         tony:engine.emit               first tokens, retirements
       tony:engine.decode_device    dispatch -> readback returned
                                    (attrs slots, window, keys_read,
@@ -59,7 +62,22 @@ what the host needs back (``d2h_bytes``; a prefill round's
 ``first_tokens``: its entries at their last chunk, the only ones whose
 token is used); the bookkeeping after it is the parent's own.
 
-and, per request, written when it retires and joined by ``request=``:
+**A prefill round is fenced only where the host needs something of it
+now**: an entry at its last chunk (its first token), or a ``step()``
+that would otherwise end with the round still on its way (no later round
+and no decode iteration to fence it). Any other round (``fenced=False``)
+is launched and left: its device span is its launch alone, with no
+``*_readback`` child, and the engine goes on to the next dispatch, whose
+arguments are host arrays that only a first token changes and caches
+that chain on the device from one jitted call to the next. What such a
+round would have brought home (a model with experts: its counts) waits
+in ``_in_flight`` and comes home IN the next fenced ``device_get`` of
+the same step, which is where the wait for the round is counted; the
+span that fences carries ``expert_pairs`` for itself and for what it
+brought home. A ``step()`` returns with nothing in flight, so a device
+error of an unfenced round surfaces inside the step that launched it.
+
+Per request, written when it retires and joined by ``request=``:
 ``tony:request.queue`` (submit -> slot), ``tony:request.prefill`` (slot
 -> first token, attr ``rounds``; none for a request injected with
 shipped KV) and ``tony:request.decode`` (first token -> done, attr
@@ -68,10 +86,14 @@ the counters taken at the same boundaries (``phase_ms``: the two device
 spans; ``kv``, ``queue_wait_ms`` ...), and under ``stats()["dispatch"]``
 the split of each program's dispatches: ``{"decode": {calls, launch_ms,
 readback_ms, h2d_bytes, d2h_bytes}, "prefill": {the same five,
-rounds_without_first_token}}`` — ``launch_ms + readback_ms`` is at most
-``phase_ms``' device phase, ``calls`` are ``decode_iterations`` and
-``prefill_rounds``, and a round without a first token is one whose
-readback brought home nothing the engine uses but the experts' counts.
+rounds_without_first_token, unfenced}}`` — ``launch_ms + readback_ms`` is
+at most ``phase_ms``' device phase, ``calls`` are ``decode_iterations``
+and ``prefill_rounds``, a round without a first token is one that has
+nothing for the engine but the experts' counts, and ``unfenced`` of them
+were launched without a readback of their own (the rest closed a step
+that ran no decode iteration). ``phase_ms.prefill_device`` holds an
+unfenced round's launch alone; the bytes its counts bring home are
+prefill's ``d2h_bytes`` whichever program's readback carried them.
 Idle polls record and count nothing.
 (*) A model with experts only: the dispatch's (token, choice) pairs on
 the experts held here, which come back with the tokens in the one
@@ -382,6 +404,13 @@ class ServingEngine:
         self._h2d_bytes = {"prefill": 0, "decode": 0}
         self._d2h_bytes = {"prefill": 0, "decode": 0}
         self._rounds_without_first = 0
+        # What the unfenced prefill rounds of the step in progress have
+        # for the host, in launch order: (a round's expert counts, still
+        # on the device; its valid tokens) — nothing for a model without
+        # experts. The step's next fenced readback takes them home; empty
+        # between steps.
+        self._in_flight: list[tuple[dict, int]] = []
+        self._rounds_unfenced = 0
         self._working_iters = 0
         self._working_wall_ns = 0
         self._decode_iters = 0
@@ -745,7 +774,8 @@ class ServingEngine:
                         self._dispatch_stats("prefill",
                                              self._prefill_rounds),
                         rounds_without_first_token=(
-                            self._rounds_without_first)),
+                            self._rounds_without_first),
+                        unfenced=self._rounds_unfenced),
                 },
                 "decode_iterations": self._decode_iters,
                 "decode_slots_sum": self._decode_slots_sum,
@@ -984,10 +1014,11 @@ class ServingEngine:
             self._decode_calls += 1
             # Iteration fence: EXPLICIT readback, so the armed
             # transfer guard (jit sanitizer) lets it through. The
-            # experts' counters come back in the same readback.
+            # experts' counters come back in the same readback, and so
+            # does what the step's unfenced prefill rounds left in flight.
             with tr.span("tony:engine.decode_readback") as readback:
-                toks, counts = jax.device_get((window, expert_counts))  # tony: noqa[TONY-X002] — intended per-window fence
-                readback.set(**self._count_d2h("decode", (toks, counts)))
+                (toks, counts), landed = self._fence(  # tony: noqa[TONY-X002] — intended per-window fence
+                    "decode", (window, expert_counts), readback)
             toks = np.asarray(toks)
             if counts is not None and "sparse_keys" in counts:
                 # The selection's own count, made on the device where the
@@ -1000,7 +1031,8 @@ class ServingEngine:
                 self._sparse_keys_live += live
                 sp.set(sparse_keys_read=read)
             if counts:
-                sp.set(expert_pairs=self._note_pairs(counts, n_active * w))
+                sp.set(expert_pairs=landed
+                       + self._note_pairs(counts, n_active * w))
         it["decode_device"] = sp.dur_ns
         it["decode_launch"] = launch.dur_ns
         it["decode_readback"] = readback.dur_ns
@@ -1161,14 +1193,24 @@ class ServingEngine:
             budget -= n
             with self._tracer.span("tony:engine.prefill_round", batch=n,
                                    chunk=self.prefill_chunk):
-                self._prefill_round(entries)
+                self._prefill_round(entries, last=budget == 0)
         return True
 
+    def _fence_follows(self, last: bool) -> bool:
+        """Whether a dispatch later in this ``step()`` is fenced, so that
+        a prefill round may be launched and left: a later round (the
+        step's last one asks here again), or the decode iteration, which
+        runs where a slot is active — and within a step's rounds a slot
+        only ever becomes active."""
+        return not last or bool(self._active.any())
+
     def _prefill_round(
-        self, entries: list[tuple[ServingRequest, int]],
+        self, entries: list[tuple[ServingRequest, int]], last: bool,
     ) -> None:
         """One ``prefill_chunks`` dispatch: the next chunk of each
-        entry's prompt; a prompt's last chunk yields its first token."""
+        entry's prompt; a prompt's last chunk yields its first token.
+        Fenced only for a first token, or as the ``last`` round of a step
+        that nothing fences after it (module docstring)."""
         tr, it = self._tracer, self._it_ns
         n, pb = len(entries), self.prefill_batch
         with tr.span("tony:engine.prefill_assemble"):
@@ -1201,7 +1243,8 @@ class ServingEngine:
         self._rounds_without_first += not any(finals)
         if self._linear_layers:
             self._state_slots_reset += int((starts[:n] == 0).sum())
-        self._prefill_tokens_valid += int(n_valids[:n].sum())
+        tokens = int(n_valids[:n].sum())
+        self._prefill_tokens_valid += tokens
         self._prefill_rows_padded += pb - n
         keys_read = n * self.max_len
         if self._pf_read_block:      # whole blocks up to each chunk's end
@@ -1211,7 +1254,9 @@ class ServingEngine:
         keys_read *= self._full_layers
         self._prefill_keys_read += keys_read
         self._prefill_keys_reserved += n * self.max_len * self._full_layers
-        with tr.span("tony:engine.prefill_device", keys_read=keys_read) as sp, \
+        fenced = any(finals) or not self._fence_follows(last)
+        with tr.span("tony:engine.prefill_device", keys_read=keys_read,
+                     fenced=fenced) as sp, \
                 jit_sanitizer.step_region("serving_prefill_chunks"):
             with tr.span("tony:engine.prefill_launch") as launch:
                 args = (self.params, self._k, self._v, toks, slots_a, starts,
@@ -1220,17 +1265,24 @@ class ServingEngine:
                 launch.set(**self._count_h2d("prefill", args))
                 self._k, self._v, first_toks, _, expert_counts = \
                     self._prefill(*args)
-            with tr.span("tony:engine.prefill_readback",
-                         first_tokens=sum(finals)) as readback:
-                firsts, counts = jax.device_get((first_toks, expert_counts))  # tony: noqa[TONY-X002] — intended per-round fence
-                readback.set(**self._count_d2h("prefill", (firsts, counts)))
-            firsts = np.asarray(firsts)
-            if counts is not None:
-                sp.set(expert_pairs=self._note_pairs(
-                    counts, int(n_valids[:n].sum())))
+            if fenced:
+                with tr.span("tony:engine.prefill_readback",
+                             first_tokens=sum(finals)) as readback:
+                    (firsts, counts), landed = self._fence(  # tony: noqa[TONY-X002] — intended per-round fence
+                        "prefill", (first_toks, expert_counts), readback)
+                it["prefill_readback"] += readback.dur_ns
+                firsts = np.asarray(firsts)
+                if counts is not None:
+                    sp.set(expert_pairs=landed
+                           + self._note_pairs(counts, tokens))
+            else:
+                # Launched and left: nothing of it is needed before the
+                # step's next fence, which brings its counts home.
+                self._rounds_unfenced += 1
+                if expert_counts is not None:
+                    self._in_flight.append((expert_counts, tokens))
         it["prefill_device"] += sp.dur_ns
         it["prefill_launch"] += launch.dur_ns
-        it["prefill_readback"] += readback.dur_ns
         with tr.span("tony:engine.emit"):
             now = time.perf_counter()
             requeue: list[tuple[ServingRequest, int]] = []
@@ -1289,6 +1341,22 @@ class ServingEngine:
         nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(results))
         self._d2h_bytes[program] += nbytes
         return {"d2h_bytes": nbytes}
+
+    def _fence(self, program: str, own, readback) -> tuple:
+        """A dispatch's fenced readback: ONE ``jax.device_get`` of its
+        ``own`` results and of what the step's unfenced prefill rounds
+        left in flight, whose counts go to ``stats()["experts"]`` in
+        launch order. Returns ``own`` as host arrays and the pairs on held
+        experts of the rounds brought home (the device span's attr holds
+        them beside its own)."""
+        flights, self._in_flight = self._in_flight, []
+        own, flown = jax.device_get((own, [c for c, _ in flights]))
+        attrs = self._count_d2h(program, own)
+        attrs["d2h_bytes"] += self._count_d2h("prefill", flown)["d2h_bytes"]
+        readback.set(**attrs)
+        landed = sum(self._note_pairs(counts, tokens)
+                     for counts, (_, tokens) in zip(flown, flights))
+        return own, landed
 
     def _note_pairs(self, counts: dict, tokens: int) -> int:
         """One dispatch's expert counters (host arrays: they came back

@@ -21,8 +21,10 @@ is joined with the program that started inside it and the device's idle
 time is put down to where in the dispatch it lies (ISSUE 40): before the
 program (``lead``, and ``return_to_start`` against the launch span's
 end), inside it (``bubbles``), after it (``tail``) and between two
-dispatches (``between``); beside them the engine's own
-``stats()["dispatch"]`` over its life.
+dispatches (``between``); a prefill round launched without a readback
+(ISSUE 41) has no ``tail`` and no ``between``, and the dispatch behind it
+measures its ``lead`` from the end of the round's program; beside them the
+engine's own ``stats()["dispatch"]`` over its life.
 
 Run on no CPU: the harness refuses one."""
 
@@ -66,6 +68,14 @@ def table(reduced: dict) -> dict:
 ENGINE_SPAN = re.compile(r"^tony:engine\.(decode|prefill)_"
                          r"(device|launch|readback)$")
 PARTS = ("lead", "return_to_start", "bubbles", "tail", "between")
+# How far before its device span a program may seem to start: a trace puts
+# the device's line on the host's clock to within about a millisecond, and
+# not the same way in every trace (ISSUE 41's traced run of chat-saturated
+# read every program 0.9 ms early beside the parent's run of the same call:
+# a fenced round's lead 0.3 and tail 2.6 ms against 1.26 and 1.51, the sums
+# equal). What moves with it is the split between ``lead`` and ``tail``,
+# never their sum.
+CLOCK_SLACK_NS = 1_500_000
 
 
 def _rank(values: list, pct: int) -> float:
@@ -75,65 +85,92 @@ def _rank(values: list, pct: int) -> float:
 
 def dispatches(trace: dict, reduced: dict, xplane) -> dict:
     """The traced window's dispatches, one per ``tony:engine.*_device``
-    span that lies inside it with both of its halves and ONE program
-    started inside it, in nanoseconds on the trace's clock:
+    span that lies inside it with its launch half, at most one readback
+    half and a program of its own, in nanoseconds on the trace's clock. A
+    span's program is the first of its kind (``decode`` / ``prefill`` in
+    the module's name) that no earlier span took and that starts after the
+    span does (``CLOCK_SLACK_NS`` allowed) and before its fence returns:
+    its own readback's end, or, for a prefill round launched without one
+    (ISSUE 41), that of the next span that has one. The parts:
 
-    - ``lead``: program start - launch span start (the device idles
-      through all of it: the dispatch before was fenced);
+    - ``lead``: program start - the LATER of the launch span's start and
+      the end of the program before it (the device idles through all of
+      it; behind an unfenced round the program before still runs when the
+      launch begins);
     - ``return_to_start``: program start - launch span END, negative
       where the device began before the jitted call returned;
     - ``bubbles``: program duration - the union of its operations;
-    - ``tail``: readback span end - program end;
+    - ``tail``: readback span end - program end (None: no readback);
     - ``between``: the next dispatch's launch span start - this readback
-      span's end (host work: emit, publish, admit, assemble).
+      span's end (host work: emit, publish, admit, assemble; None: no
+      readback, the device works through the host's time).
 
     ``lead + bubbles + tail + between`` tile the time from the first
     launch to the last readback but for the programs' busy time, so their
     sum is the window's idle time less what lies at its two edges and
     under no engine span (``remainder_s``). ``programs``: per program
-    {n, and per part {p50_ms, p90_ms, sum_s}}; ``rows``: every dispatch.
-    ``xplane``: the harness's reduction module, ``reduced`` its reduction
-    of ``trace`` (the window's idle time is its)."""
+    {n, unfenced, and per part {p50_ms, p90_ms, sum_s}}; ``rows``: every
+    dispatch. ``xplane``: the harness's reduction module, ``reduced`` its
+    reduction of ``trace`` (the window's idle time is its)."""
     lo, hi = xplane.window_of(trace)
     dev = trace["devices"][min(trace["devices"], key=int)]
     ops = sorted((s, s + d) for _, s, d in dev["ops"] if d > 0)
     starts = [s for s, _ in ops]
     modules = sorted((s, s + d, re.sub(r"\(\d+\)$", "", n))
                      for n, s, d in dev["modules"])
+    ends = sorted(m[1] for m in modules)
     spans: dict[str, list] = {"device": [], "launch": [], "readback": []}
     for name, s, d in trace["host_spans"]:
         m = ENGINE_SPAN.match(name)
         if m and lo <= s and s + d <= hi:
             spans[m.group(2)].append((s, s + d, m.group(1)))
-    rows, unmatched = [], 0
+    found = []     # (device span, its launch, its readback or None) or None
     for s, e, program in sorted(spans["device"]):
-        halves = [[h for h in spans[k] if s <= h[0] and h[1] <= e
-                   and h[2] == program] for k in ("launch", "readback")]
-        started = [m for m in modules if s <= m[0] < e]
-        if [len(h) for h in halves] != [1, 1] or len(started) != 1:
+        launch, readback = ([h for h in spans[k] if s <= h[0] and h[1] <= e
+                             and h[2] == program]
+                            for k in ("launch", "readback"))
+        found.append(((s, program), launch[0], readback[0] if readback
+                      else None)
+                     if len(launch) == 1 and len(readback) <= 1 else None)
+    rows, unmatched, taken = [], 0, set()
+    for i, dispatch in enumerate(found):
+        mine = None
+        if dispatch:
+            (s, program), launch, readback = dispatch
+            # the fence that waits for this dispatch's program
+            fence = next((d[2][1] for d in found[i:] if d and d[2]), s)
+            mine = next((m for m in modules if m not in taken
+                         and program in m[2]
+                         and s - CLOCK_SLACK_NS <= m[0] < fence), None)
+        if mine is None:
             unmatched += 1
             continue
-        (launch,), (readback,) = halves
-        p0, p1, name = started[0]
+        taken.add(mine)
+        p0, p1, name = mine
         # a program's operations are those that start inside it
         busy = xplane.total(xplane.union(
             [[a, min(b, p1)] for a, b in ops[bisect.bisect_left(starts, p0):
                                              bisect.bisect_left(starts, p1)]]))
+        before = bisect.bisect_right(ends, p0)
         rows.append({"program": name, "launch_start": launch[0],
-                     "readback_end": readback[1],
-                     "lead": p0 - launch[0],
+                     "readback_end": readback[1] if readback else None,
+                     "lead": p0 - max(launch[0],
+                                      ends[before - 1] if before else lo),
                      "return_to_start": p0 - launch[1],
                      "bubbles": (p1 - p0) - busy,
-                     "tail": readback[1] - p1, "between": None})
+                     "tail": readback[1] - p1 if readback else None,
+                     "between": None})
     for row, nxt in zip(rows, rows[1:]):
-        row["between"] = nxt["launch_start"] - row["readback_end"]
+        if row["readback_end"] is not None:
+            row["between"] = nxt["launch_start"] - row["readback_end"]
     idle_s = reduced["window_s"] - reduced["busy_s"]
     accounted_s = sum(row[k] or 0 for row in rows
                       for k in ("lead", "bubbles", "tail", "between")) / 1e9
     programs = {}
     for name in sorted({row["program"] for row in rows}):
         mine = [row for row in rows if row["program"] == name]
-        programs[name] = {"n": len(mine)}
+        programs[name] = {"n": len(mine), "unfenced": sum(
+            row["readback_end"] is None for row in mine)}
         for part in PARTS:
             values = [row[part] for row in mine if row[part] is not None]
             programs[name][part] = {
@@ -153,9 +190,10 @@ def print_dispatches(table: dict, engine_dispatch: dict | None) -> None:
           f"engine span) {table['remainder_s']:.4f} s = "
           f"{100.0 * table['remainder_s'] / max(table['idle_s'], 1e-12):.1f}%"
           f" of idle; {table['unmatched_device_spans']} device spans "
-          f"without one program and both halves")
+          f"without a launch half and a program of their own")
     for name, row in table["programs"].items():
-        print(f"  {name}: {row['n']} dispatches   (p50 ms / p90 ms / sum s)")
+        print(f"  {name}: {row['n']} dispatches, {row['unfenced']} of them "
+              f"without a readback   (p50 ms / p90 ms / sum s)")
         for part in PARTS:
             if row[part]:
                 print(f"    {part:16s} {row[part]['p50_ms']:8.3f} "
