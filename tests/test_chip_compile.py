@@ -593,3 +593,123 @@ def test_expert_products_keep_their_rows_and_follow_the_pairs(v5e, program):
     for rows in (512, 1024):
         assert _tiling(rows, 4096, 4096, 2) == (128, 4096, 256)
         assert _tiling(rows, 2048, 4096, 2) == (128, 2048, 512)
+
+
+_LFM2: dict = {}
+
+
+def _lfm2_program(v5e, program):
+    """``decode_window`` or ``prefill_chunks`` over an LFM2-shaped model at
+    published widths (d 2,048; 32 heads of 64 over 8 KV heads, two to a
+    row of the cache; a conv state of 2 rows a slot; ALL 32 experts of
+    width 1,792 held, top-4; a tied head over 65,536 rows; 128 slots x
+    4,096 positions, 2 chunks of 512 a round: the engine's default at
+    that chunk), depth cut to ``conv``
+    (dense), ``full`` and ``conv`` (experts), compiled once for the tests
+    below."""
+    from tony_tpu.serving import engine
+
+    if program in _LFM2:
+        return _LFM2[program]
+    cfg = TransformerConfig(
+        vocab_size=65_536, d_model=2048, n_layers=3, n_heads=32, head_dim=64,
+        n_kv_heads=8, rope_theta=1e6, rms_eps=1e-5, max_seq=4096,
+        attn_kinds=("conv", "full", "conv"), conv_kernel=3, qk_norm=True,
+        n_dense_layers=1, dense_d_ff=7168, d_ff=1792, n_experts=32,
+        expert_top_k=4, router_scoring="sigmoid", router_bias=True,
+        tie_embeddings=True, dtype="bfloat16", remat=False,
+    )
+    slots, t_max, p, c = 128, 4096, 2, 512
+    one_chip = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree
+        )
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fused = on_chip(jax.eval_shape(
+        lambda: decode_lib.decode_weights(
+            init_params(jax.random.key(0), cfg), cfg
+        )
+    ))
+    assert "unembed" not in fused
+    k, v = on_chip(jax.eval_shape(
+        lambda: engine.init_slot_cache(cfg, slots, t_max, prefill_chunk=c)
+    ))
+    # two 64-wide KV heads a row; a conv state a conv layer, on the K side
+    assert k["full"].shape == v["full"].shape == (1, 128, 4096, 4, 128)
+    assert [x.shape for x in k["conv"]] == [(128, 2, 2048)] * 2
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    if program == "decode_window":
+        lowered = engine.decode_window.lower(
+            fused, k, v, arr((slots,)), arr((slots,)), arr((slots,)),
+            arr((slots,), jnp.float32), key, arr(()), cfg=cfg, steps=1,
+        )
+    else:
+        lowered = engine.prefill_chunks.lower(
+            fused, k, v, arr((p, c)), arr((p,)), arr((p,)), arr((p,)),
+            arr((p,), jnp.float32), key, arr(()), cfg=cfg,
+        )
+    args = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((fused, k, v)))
+    _LFM2[program] = lowered.compile(), args
+    return _LFM2[program]
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunks"])
+def test_lfm2_serving_programs_copy_no_cache(v5e, program):
+    """The engine's two programs over a model of conv layers with a conv
+    state beside attention at a head width of 64: the cache's rows of two
+    KV heads cost 64 lanes a head (the buffers as compiled are the bytes
+    budgeted: ``bf16[1,128,4096,4,128]`` in tiles of (4, 128), nothing
+    padded; a [.., 8, 64] buffer did not merge to rows and fell to the plain
+    path, which reads a slot's whole reservation), both programs read them
+    through the KERNELS, and neither copies the cache nor holds a layer's
+    slab (one K buffer of the attention layer is 537 MB)."""
+    compiled, args = _lfm2_program(v5e, program)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "bf16[1,128,4096,4,128]{4,3,2,1,0:T(4,128)(2,1)}" in text
+    assert "bf16[1,128,4096,8,64]" not in text
+    assert abs(memory.argument_size_in_bytes - args) < 0.01 * args
+    assert memory.temp_size_in_bytes < 96e6
+    results = [r.split("{")[0] for r in _mosaic_results(text.splitlines())]
+    if program == "decode_window":
+        # the attention kernel returns both halves of its paired rows
+        assert results.count("bf16[128,32,128]") == 1
+    else:
+        # the chunks' attention through ``cache_prefill_attention``: 4 rows
+        # a position, 8 query heads a row: [P, rows, C * 8, 128]
+        assert results.count("bf16[2,4,4096,128]") == 1
+
+
+@pytest.mark.parametrize("program", ["decode_window", "prefill_chunks"])
+def test_every_expert_held_keeps_the_pairs_rows(v5e, program):
+    """With all 32 experts held every pair lands here. A decode iteration
+    keeps its 128 x 4 = 512 pair rows over 32 groups: two Mosaic calls an
+    expert layer with ``bf16[512,.]`` results, in no loop (the benchmark's
+    reader tells decode's expert products by that shape). A prefill round's
+    2 x 512 x 4 = 4,096 pair rows are what lands here at any routing, so
+    they go in ONE pass (``_pass_rows``: four times the mean is the worst
+    case itself), in no loop either."""
+    from tony_tpu.models.decode import _pass_rows
+    from tony_tpu.ops.grouped import _tiling
+
+    text = _lfm2_program(v5e, program)[0].as_text()
+    everywhere, in_loops = (
+        [r.split("{")[0] for r in _mosaic_results(lines)]
+        for lines in (text.splitlines(), _loop_bodies(text)[1]))
+    rows = 512 if program == "decode_window" else 4096
+    products = [r for r in everywhere
+                if re.match(rf"bf16\[{rows},(3584|2048)\]", r)]
+    # two expert layers, gate|up and down each
+    assert sorted(products) == sorted(
+        [f"bf16[{rows},3584]", f"bf16[{rows},2048]"] * 2)
+    assert not [r for r in in_loops if r.startswith(f"bf16[{rows},")]
+    weights = 32 * 3 * 2048 * 1792 * 2
+    assert _pass_rows(rows, 2048, 32, 32, 2, weights) == rows
+    assert _tiling(rows, 2048, 3584, 2) == (128, 2048, 512)
+    assert _tiling(rows, 1792, 2048, 2) == (128, 1792, 512)

@@ -470,7 +470,7 @@ def test_the_row_exchange_refuses_a_model_with_state(model, how):
     tcfg, fused = program(model)
     engine = ServingEngine(fused, tcfg, slots=1, max_len=128,
                            prefill_chunk=16)
-    with pytest.raises(ValueError, match="linear or sparse"):
+    with pytest.raises(ValueError, match="linear, sparse or conv"):
         if how == "prefill_only":
             engine.prefill_only(prompts([20])[0], 4)
         elif how == "submit_with_kv":

@@ -214,8 +214,10 @@ def test_expert_stats_are_those_of_a_fence_on_every_round(window):
     ref, _, _ = _serve("layered", True, window)
     mine, theirs = eng.stats()["experts"], ref.stats()["experts"]
     assert set(mine) == {"held", "pairs_per_expert", "pairs_held",
-                         "pairs_total", "dispatches", "passes"}
+                         "decode_pairs", "prefill_pairs", "pairs_total",
+                         "dispatches", "passes"}
     assert mine == theirs
+    assert mine["decode_pairs"] + mine["prefill_pairs"] == mine["pairs_held"]
     assert mine["dispatches"] == (eng.stats()["prefill_rounds"]
                                   + eng.stats()["decode_iterations"])
 

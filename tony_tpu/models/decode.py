@@ -112,8 +112,10 @@ def decode_weights(params: dict, cfg: TransformerConfig) -> dict:
     top = {
         "embed": c(params["embed"]),
         "final_norm": c(params["final_norm"]),
-        "unembed": c(params["unembed"]),
     }
+    if not cfg.tie_embeddings:
+        # a tied head reads ``embed``: one matrix on the device
+        top["unembed"] = c(params["unembed"])
     if cfg.layered:
         layers: list = [None] * cfg.n_layers
         for name, members in cfg.layer_groups.items():
@@ -147,18 +149,23 @@ def decode_weights(params: dict, cfg: TransformerConfig) -> dict:
 
 def _fuse_layer(lp: dict, dt) -> dict:
     """One layer of a layered configuration in the serving layout."""
-    d = lp["wq"].shape[0]
     out = {
         "ln1": lp["ln1"].astype(dt),
         "ln2": lp["ln2"].astype(dt),
-        "qkv": jnp.concatenate(
-            [lp[n].astype(dt).reshape(d, -1) for n in ("wq", "wk", "wv")],
-            axis=1),
-        "wo": lp["wo"].astype(dt),
         "gate_up": jnp.concatenate(
             [lp["w_gate"].astype(dt), lp["w_up"].astype(dt)], axis=-1),
         "w_down": lp["w_down"].astype(dt),
     }
+    if "in_proj" in lp:
+        # a conv layer: no q/k/v/o
+        for name in ("in_proj", "conv_w", "out_proj"):
+            out[name] = lp[name].astype(dt)
+    else:
+        d = lp["wq"].shape[0]
+        out["qkv"] = jnp.concatenate(
+            [lp[n].astype(dt).reshape(d, -1) for n in ("wq", "wk", "wv")],
+            axis=1)
+        out["wo"] = lp["wo"].astype(dt)
     if "w_ogate" in lp:
         # a gated kind: q|k|v|gate, the gate H * Dv wide behind v
         out["qkv"] = jnp.concatenate(
@@ -188,11 +195,15 @@ def decode_param_specs(cfg: TransformerConfig) -> dict:
     replicates (q, k and v columns of one head do not lie together)."""
     from jax.sharding import PartitionSpec as P
 
-    top = {"embed": P(), "final_norm": P(), "unembed": P(None, "tp")}
+    top = {"embed": P(), "final_norm": P(),
+           **({} if cfg.tie_embeddings else {"unembed": P(None, "tp")})}
     if cfg.layered:
         def one(attn, mlp):
-            spec = {"ln1": P(), "ln2": P(), "qkv": P(),
-                    "wo": P("tp", None, None)}
+            spec = {"ln1": P(), "ln2": P()}
+            if attn == "conv":
+                spec.update(in_proj=P(), conv_w=P(), out_proj=P())
+            else:
+                spec.update(qkv=P(), wo=P("tp", None, None))
             if mlp == "moe":
                 spec.update(gate_up=P("ep", None, "tp"),
                             w_down=P("ep", "tp", None), router=P())
@@ -202,7 +213,7 @@ def decode_param_specs(cfg: TransformerConfig) -> dict:
                 spec.update(gate_up=P(None, "tp"), w_down=P("tp", None))
             if attn == "window" and cfg.window_sink:
                 spec["sink"] = P()
-            if cfg.qk_norm:
+            if cfg.qk_norm and attn != "conv":
                 spec.update(q_norm=P(), k_norm=P())
             if attn in cfg.out_norm_kinds:
                 spec["o_norm"] = P()
@@ -293,7 +304,7 @@ def rope_tables(cfg: TransformerConfig) -> dict:
         kind: rope_frequencies(cfg.rot_dim, cfg.max_seq,
                                theta=cfg.rope_theta_of(kind))
         for kind in dict.fromkeys(a for a, _ in cfg.layer_kinds)
-        if kind not in cfg.no_rope_kinds
+        if kind not in cfg.no_rope_kinds and kind != "conv"
     }
 
 
@@ -486,20 +497,46 @@ def _gated_output(o, gate, lp, attn, cfg):
     return o.astype(cfg.compute_dtype).reshape(b, t, n_h, d_v)
 
 
+def _short_conv(h, lp, attend, dt):
+    """A conv layer's operator on the normed input ``h`` [b, t, d]:
+    ``[B | C | z] = h W_in``, ``g = B * z``, ``c_t = sum_j w[:, j] *
+    g_{t - (L - 1) + j}``, ``(C * c) W_out``. ``attend(g, None, None,
+    "conv", None)`` does for g what it does for K/V elsewhere: it
+    returns the L - 1 rows of g before the first of these (the slot's
+    state; zeros at a prompt's start) and writes the state that follows
+    the last VALID one. Elementwise work in float32."""
+    d = h.shape[-1]
+    flat = jnp.einsum("btd,df->btf", h, lp["in_proj"])
+    b_in, c_in, z = flat[..., :d], flat[..., d:2 * d], flat[..., 2 * d:]
+    g = (b_in.astype(jnp.float32) * z.astype(jnp.float32)).astype(dt)
+    before = attend(g, None, None, "conv", None)           # [b, L - 1, d]
+    rows = jnp.concatenate([before, g], axis=1).astype(jnp.float32)
+    w = lp["conv_w"].astype(jnp.float32)                   # [d, L]
+    t = g.shape[1]
+    conv = sum(rows[:, j:j + t] * w[:, j] for j in range(w.shape[1]))
+    y = (c_in.astype(jnp.float32) * conv).astype(dt)
+    return jnp.einsum("btd,de->bte", y, lp["out_proj"])
+
+
 def serve_layer(x, lp, attn, cfg, ropes, positions, attend, *,
                 token_mask=None, count_mask=None):
     """THE decoder layer of inference: pre-norm attention of kind
     ``attn`` (its own KV head count and rope base; q/k width
     ``head_dim`` of which ``rot_dim`` rotate, v width ``v_dim`` scaled
-    by ``v_scale``), then the layer's MLP by what ``lp`` holds (dense
-    SwiGLU or experts). ``attend(q, k_new, v_new, attn, sink) -> o``
-    writes the new rows into the caller's cache and reads it: the one
-    thing ``advance``, decode and prefill do differently. Returns
-    (x, counts): the expert layer's counters, None for a dense layer."""
+    by ``v_scale``) or, for the kind ``conv``, the gated short
+    convolution (``_short_conv``), then the layer's MLP by what ``lp``
+    holds (dense SwiGLU or experts). ``attend(q, k_new, v_new, attn,
+    sink) -> o`` writes the new rows into the caller's cache and reads
+    it: the one thing ``advance``, decode and prefill do differently.
+    Returns (x, counts): the expert layer's counters, None for a dense
+    layer."""
     dt = cfg.compute_dtype
+    h = rms_norm(x, lp["ln1"], eps=cfg.rms_eps).astype(dt)
+    if attn == "conv":
+        x = _residual(x, _short_conv(h, lp, attend, dt), cfg)
+        return _mlp(x, lp, cfg, token_mask, count_mask)
     b, t, _ = x.shape
     n_h, h_kv = cfg.n_heads, cfg.kv_heads_of(attn)
-    h = rms_norm(x, lp["ln1"], eps=cfg.rms_eps).astype(dt)
     gate = None
     if lp["qkv"].ndim == 2:
         # layered: q|k|v fused on the feature axis, widths of their own
@@ -573,16 +610,19 @@ def run_layers(x, params, k_all, v_all, cfg, layer):
 
 
 def lm_head(x, params, cfg):
-    """Final norm -> unembed -> float32 logits [B, V] of ``x`` [B, 1, d].
+    """Final norm -> unembed (the embedding's transpose where the model
+    ties them) -> float32 logits [B, V] of ``x`` [B, 1, d].
     Only one position per row is ever sampled: callers slice it out
     BEFORE this, so no [B, S, V] logits are ever materialized."""
     x = rms_norm(x, params["final_norm"],
                  eps=cfg.rms_eps).astype(cfg.compute_dtype)
     if cfg.logit_scale != 1.0:
         x = (x.astype(jnp.float32) * cfg.logit_scale).astype(x.dtype)
-    return jnp.einsum(
-        "btd,dv->btv", x, params["unembed"]
-    )[:, 0].astype(jnp.float32)
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("btd,vd->btv", x, params["embed"])
+    else:
+        logits = jnp.einsum("btd,dv->btv", x, params["unembed"])
+    return logits[:, 0].astype(jnp.float32)
 
 
 def advance(params: dict, cache: dict, tokens: jax.Array,

@@ -105,8 +105,8 @@ class TransformerConfig:
     v_head_dim: int = 0          # value width; 0 = head_dim
     rotary_dim: int = 0          # leading dims of q/k that rotate; 0 = all
     v_scale: float = 1.0         # v = v_scale * (h @ Wv)
-    # Per layer "full" | "window" | "linear" | "sparse"; () = every layer
-    # full. A window layer sees the last ``window`` positions (its own
+    # Per layer "full" | "window" | "linear" | "sparse" | "conv"; () =
+    # every layer full. A window layer sees the last ``window`` positions (its own
     # included), has its own KV head count and rope base, and with
     # ``window_sink`` a learnt per-head bias in the softmax denominator.
     # A linear layer (lightning attention) keeps no K/V rows: per head a
@@ -119,9 +119,15 @@ class TransformerConfig:
     # attends the first ``sparse_init_blocks`` blocks of ``sparse_block``
     # positions, the blocks that cover its last ``sparse_window``
     # positions and the ``sparse_topk`` blocks those means score highest
-    # for its KV group, a query before it every key. Both are SERVED
-    # (``ServingEngine``) and not trained: no backward is written.
+    # for its KV group, a query before it every key. A conv layer's token
+    # mixer is no attention at all: a gated short convolution (LFM2),
+    # ``[B | C | z] = h W_in`` ([d, 3d]), ``g = B * z``, a causal
+    # depthwise convolution of ``conv_kernel`` taps over g, times C,
+    # through ``W_out`` ([d, d]); it has no q/k/v/o and no rope, and keeps
+    # per slot the last ``conv_kernel - 1`` rows of g. All three are
+    # SERVED (``ServingEngine``) and not trained: no backward is written.
     attn_kinds: tuple = ()
+    conv_kernel: int = 3
     window: int = 0
     window_kv_heads: int = 0     # 0 = n_kv_heads
     window_rope_theta: float = 0.0   # 0 = rope_theta
@@ -161,14 +167,20 @@ class TransformerConfig:
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_scale: float = 1.0
+    # The output head reads the embedding matrix (logits = norm(x) E^T):
+    # the parameters hold no ``unembed``.
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         if self.attn_kinds and len(self.attn_kinds) != self.n_layers:
             raise ValueError(
                 f"attn_kinds names {len(self.attn_kinds)} layers, the "
                 f"model has {self.n_layers}")
-        if set(self.attn_kinds) - {"full", "window", "linear", "sparse"}:
+        if set(self.attn_kinds) - {"full", "window", "linear", "sparse",
+                                   "conv"}:
             raise ValueError(f"unknown attention kind in {self.attn_kinds}")
+        if "conv" in self.attn_kinds and self.conv_kernel < 2:
+            raise ValueError("conv layers need conv_kernel >= 2")
         if "sparse" in self.attn_kinds:
             k, s, b = (self.sparse_kernel, self.sparse_stride,
                        self.sparse_block)
@@ -239,6 +251,7 @@ class TransformerConfig:
             or self.router_scoring != "softmax" or self.router_bias
             or self.experts_held or self.qk_norm or self.no_rope_kinds
             or self.gated_kinds or self.out_norm_kinds
+            or self.tie_embeddings
             or (self.embed_scale, self.residual_scale,
                 self.logit_scale) != (1.0, 1.0, 1.0))
 
@@ -264,9 +277,9 @@ class TransformerConfig:
         if self.layered:
             raise ValueError(
                 f"{what} runs uniform layers only; this configuration "
-                f"has layer kinds (linear and sparse layers have no "
-                f"backward: they are served, not trained), its own "
-                f"v/rotary widths, or a share of its experts "
+                f"has layer kinds (linear, sparse and conv layers have "
+                f"no backward: they are served, not trained), its own "
+                f"v/rotary widths, a tied head, or a share of its experts "
                 f"(TransformerConfig.layered): serve it through "
                 f"ServingEngine")
 
@@ -294,12 +307,14 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> dict:
                               len(layers))
             for i, (name, layers) in enumerate(cfg.layer_groups.items())
         }
-        return {
+        top = {
             "embed": norm(keys[0], (cfg.vocab_size, d), 1.0),
             "layers": groups,
             "final_norm": jnp.ones((d,), jnp.float32),
-            "unembed": norm(keys[9], (d, cfg.vocab_size), d ** -0.5),
         }
+        if not cfg.tie_embeddings:
+            top["unembed"] = norm(keys[9], (d, cfg.vocab_size), d ** -0.5)
+        return top
 
     layer = {
         "ln1": jnp.ones((l, d), jnp.float32),
@@ -339,17 +354,27 @@ def _init_group(key, cfg: TransformerConfig, name: str, n: int) -> dict:
     def norm(k, shape, scale):
         return jax.random.normal(k, (n,) + shape, jnp.float32) * scale
 
-    group = {
-        "ln1": jnp.ones((n, d), jnp.float32),
-        "wq": norm(keys[0], (d, h, dk), d ** -0.5),
-        "wk": norm(keys[1], (d, hkv, dk), d ** -0.5),
-        "wv": norm(keys[2], (d, hkv, dv), d ** -0.5),
-        "wo": norm(keys[3], (h, dv, d), (h * dv) ** -0.5),
-        "ln2": jnp.ones((n, d), jnp.float32),
-    }
+    if attn == "conv":
+        group = {
+            "ln1": jnp.ones((n, d), jnp.float32),
+            "in_proj": norm(keys[0], (d, 3 * d), d ** -0.5),
+            "conv_w": norm(keys[1], (d, cfg.conv_kernel),
+                           cfg.conv_kernel ** -0.5),
+            "out_proj": norm(keys[3], (d, d), d ** -0.5),
+            "ln2": jnp.ones((n, d), jnp.float32),
+        }
+    else:
+        group = {
+            "ln1": jnp.ones((n, d), jnp.float32),
+            "wq": norm(keys[0], (d, h, dk), d ** -0.5),
+            "wk": norm(keys[1], (d, hkv, dk), d ** -0.5),
+            "wv": norm(keys[2], (d, hkv, dv), d ** -0.5),
+            "wo": norm(keys[3], (h, dv, d), (h * dv) ** -0.5),
+            "ln2": jnp.ones((n, d), jnp.float32),
+        }
     if attn == "window" and cfg.window_sink:
         group["sink"] = norm(keys[4], (h,), 1.0)
-    if cfg.qk_norm:
+    if cfg.qk_norm and attn != "conv":
         group["q_norm"] = jnp.ones((n, dk), jnp.float32)
         group["k_norm"] = jnp.ones((n, dk), jnp.float32)
     if attn in cfg.gated_kinds:

@@ -859,10 +859,53 @@ def cache_rows_merge(h_kv: int, d_k: int, d_v: int) -> bool:
     stacked cache as rows with no copy: D in whole 128-lane rows, Hkv a
     sublane tile (compiled for a v5e: 1, 2, 4, 8, 16, 24 merge; 12, and
     Dh 64, get another layout and the reshape would copy the whole
-    cache per call; so does Hkv 4 at Dh 256, which is why a wide K
-    comes in tiles)."""
+    cache per call, which is why heads of 64 lie two to a row,
+    ``cache_heads_per_row``; so does Hkv 4 at Dh 256, which is why a wide
+    K comes in tiles). ``h_kv`` and the widths are the buffer's own: its
+    rows a position and their lanes."""
     return (d_k % 128 == 0 and d_v % 128 == 0
             and (h_kv % 8 == 0 or (h_kv in (1, 2, 4) and d_k == 128)))
+
+
+def cache_heads_per_row(h_kv: int, d_k: int, d_v: int) -> int:
+    """KV heads that one row of the stacked cache holds: 2 where K and V
+    heads are 64 wide and pair up, else 1. A [.., Hkv, 64] bfloat16
+    buffer may take 128 lanes a row on the device, twice its bytes, and
+    its rows do not merge (``cache_rows_merge``); kept [.., Hkv / 2, 128]
+    — the same bytes in the same order, KV head 2r in lanes 0..63 of row
+    r and head 2r + 1 in lanes 64..127 — it costs 64 lanes a head and
+    merges as any 128-wide cache does. The attention functions below
+    know such a cache by its rows being twice the query's width."""
+    return 2 if d_k == d_v == 64 and h_kv % 2 == 0 else 1
+
+
+def _pairs_heads(q, k) -> bool:
+    return jax.tree.leaves(k)[0].shape[-1] == 2 * q.shape[-1]
+
+
+def _own_half_of(n_h: int, rows: int):
+    """[Hq] 0 or 1: the half of a paired row that a query head's KV head
+    lies in (``rows`` rows a position, two KV heads each)."""
+    return (jnp.arange(n_h) // (n_h // (2 * rows))) % 2
+
+
+def _pair_queries(q, rows: int):
+    """q [.., Hq, D] against a cache of paired heads: [.., Hq, 2D] with
+    each head's dims in its own KV head's half of the row and zeros in
+    the other, so that its product with a row is its score against its
+    own head alone, and the row's query heads (both KV heads' groups)
+    are one group of the row."""
+    n_h, d = q.shape[-2:]
+    mine = _own_half_of(n_h, rows)[:, None] == jnp.arange(2 * d) // d
+    return jnp.where(mine, jnp.concatenate([q, q], axis=-1), 0)
+
+
+def _own_half(o, rows: int):
+    """The result [.., Hq, 2D] over paired rows -> [.., Hq, D]: each
+    head's own KV head's half of the values."""
+    n_h, d = o.shape[-2], o.shape[-1] // 2
+    second = _own_half_of(n_h, rows)[:, None] == 1
+    return jnp.where(second, o[..., d:], o[..., :d])
 
 
 def cache_rows_view(buf):
@@ -906,11 +949,17 @@ def grouped_cache_attention(q, k, v, mask, *, scale=None, sink=None):
     accumulation and fp32 softmax (the decode.py recipe).
     mask: [B, Sq, T] True where the key is visible. ``sink`` [Hq]: one
     more softmax column per query head that takes mass and adds no
-    value. -> [B, Sq, Hq, Dv]."""
+    value. A cache of paired heads (k and v [B, T, Hkv / 2, 2D]
+    against q [.., D]) is read as it lies. -> [B, Sq, Hq, Dv]."""
     b, s, n_h, d = q.shape
     h_kv = k.shape[2]
     if scale is None:
         scale = d ** -0.5
+    if _pairs_heads(q, k):
+        # rows of two KV heads (``cache_heads_per_row``)
+        return _own_half(grouped_cache_attention(
+            _pair_queries(q, h_kv), k, v, mask, scale=scale, sink=sink),
+            h_kv)
     qg = q.reshape(b, s, h_kv, n_h // h_kv, d)
     scores = jnp.einsum(
         "bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32,
@@ -1178,7 +1227,9 @@ def cache_decode_attention(
     [Tmax, 4, 256] buffer does not merge to rows without a copy of the
     whole cache and a [Tmax, 4, 128] one does (compiled for a v5e), so
     such a cache is kept in tiles and the score is the sum of the tiles'
-    products.
+    products. Heads 64 wide lie two to a row ([L, S, Tmax, Hkv / 2, 128],
+    ``cache_heads_per_row``); the kernel then scores a query head
+    against its own half of a row.
 
     ``window`` > 0 reads the cache as a RING: its third axis holds
     ``ring`` = Tmax - 1 positions and one parking row; position p lies
@@ -1197,6 +1248,13 @@ def cache_decode_attention(
     ring = k_parts[0].shape[2] - 1 if window else 0
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if _pairs_heads(q, k_all):
+        # rows of two KV heads (``cache_heads_per_row``): each query head
+        # against its own half, through the same kernel
+        return _own_half(cache_decode_attention(
+            _pair_queries(q, h_kv), k_all, v_all, layer, pos, scale=scale,
+            block_rows=block_rows, mode=mode, mesh=mesh, window=window,
+            sink=sink), h_kv)
     if mode == "auto":
         merges = cache_rows_merge(h_kv, d, v_all.shape[-1])
         mode = "pallas" if merges and _on_tpu(mesh) else "jax"
@@ -1422,7 +1480,8 @@ def cache_prefill_attention(
     the whole reservation are too large to hold.
 
     q: [P, C, Hq, Dk]; k_all: [L, S, Tmax, Hkv, Dk] (or the tuple of
-    128-lane tiles a wide K is kept in), v_all: [L, S, Tmax, Hkv, Dv];
+    128-lane tiles a wide K is kept in, or the paired rows of heads 64
+    wide), v_all: [L, S, Tmax, Hkv, Dv];
     ``layer`` a traced scalar; row r reads slot ``slots[r]`` and its
     query i sees keys 0 .. ends[r] - C + i (``ends`` = the chunks' starts
     + C, in [C, Tmax]). ``sink`` [Hq] as in the decode kernel.
@@ -1447,6 +1506,11 @@ def cache_prefill_attention(
     c = q.shape[1]
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if _pairs_heads(q, k_all):
+        # rows of two KV heads, as in ``cache_decode_attention``
+        return _own_half(cache_prefill_attention(
+            _pair_queries(q, h_kv), k_all, v_all, layer, slots, ends,
+            scale=scale, sink=sink, mode=mode, block_rows=block_rows), h_kv)
     if mode == "auto":
         takes = (cache_rows_merge(h_kv, d, v_all.shape[-1]) and _on_tpu()
                  and math.prod(auto_axes().values()) == 1)

@@ -51,7 +51,12 @@ which, not a caller: a plain array, or — K rows wider than 128 lanes and
 no whole number of them — a tuple of 128-lane tiles, the last
 zero-filled (``lane_tiles``: a width of 192 lies in 256 lanes on the
 device anyway, and as tiles the rows of 4 KV heads merge without a copy
-of the cache; the decode kernel sums the tiles' products).
+of the cache; the decode kernel sums the tiles' products). And heads of
+64 lie TWO to a row, [.., Tmax, Hkv / 2, 128]
+(``ops.attention.cache_heads_per_row``): the bytes of [.., Hkv, 64] in the
+same order, but a buffer whose rows cost 64 lanes a head and merge as any
+128-wide cache's do, so that the kernels read it, a slot's live key blocks
+only; they score a query head against its own half of a row.
 
 Two more kinds hold what is NOT a K/V row per position
 (``ops/hybrid.py`` has their arithmetic and layouts), each as one buffer
@@ -73,9 +78,15 @@ beside them ``K^c`` [S, Hkv, Tmax / stride + 1, D] float32, the means of
 the keys over ``sparse_kernel`` positions every ``sparse_stride``: a row
 is SET by the position that starts its kernel and added to by the rest,
 and only a kernel that lies wholly at or before the query is ever read,
-so K^c too is never zeroed. A model with either kind prefills in ALIGNED
-chunks, a prompt's last one padded (``n_valids``), never overlapped: a
-position must enter a state once.
+so K^c too is never zeroed. ``conv`` layers (gated short convolutions)
+keep no K/V at all: per slot the gate g of its last ``conv_kernel - 1``
+positions, [S, L - 1, d_model] in the compute dtype, under the state's
+three rules (zeros at ``start == 0``; the rows after the chunk's VALID
+positions; a lane that does not decode keeps its rows — by a select over
+the lanes, which are the slots in order, so this buffer needs no parking
+row). A model with any of the three kinds prefills in ALIGNED chunks, a
+prompt's last one padded (``n_valids``), never overlapped: a position must
+enter a state once.
 
 Both programs run over the fused ``decode_weights`` layout (weights fuse
 once per engine, exactly like ``DecodeSession``) and carry the stacked
@@ -119,6 +130,7 @@ from tony_tpu.ops import (
 )
 from tony_tpu.ops import hybrid
 from tony_tpu.ops.attention import (
+    cache_heads_per_row,
     cache_rows_view,
     cache_slot_rows,
     cache_take,
@@ -173,10 +185,22 @@ def _lane_tiles(x, n: int) -> tuple:
 
 
 def _encode(cache, x):
-    """``x`` in the cache's storage form (the cache's own pytree)."""
+    """``x`` [.., Hkv, D] in the cache's storage form (the cache's own
+    pytree): lane tiles, or heads 64 wide two to a row (the same bytes in
+    the same order: a reshape), or as it is."""
     if isinstance(cache, tuple):
         return _lane_tiles(x.astype(cache[0].dtype), len(cache))
+    if cache.shape[-1] == 2 * x.shape[-1]:
+        x = x.reshape(x.shape[:-2] + (x.shape[-2] // 2, 2 * x.shape[-1]))
     return x.astype(cache.dtype)
+
+
+def _logical(rows, width: int):
+    """Rows read out of a cache at their logical ``width`` [.., Hkv, D]
+    (0 = as stored): the tiles' zero fill cut off, paired heads apart."""
+    if width and rows.shape[-1] == 2 * width:
+        return rows.reshape(rows.shape[:-2] + (2 * rows.shape[-2], width))
+    return rows[..., :width or None]
 
 
 def _write_rows(cache, layer, rows, wpos):
@@ -212,12 +236,15 @@ def _write_chunk(cache, layer, slot, start, chunk):
         cache, _encode(cache, chunk))
 
 
-def _read_slots(cache, layer, slots):
+def _read_slots(cache, layer, slots, width: int):
     """The rows [P, Tmax, Hkv, Dh] of ``slots`` [P] in one layer: P
     small dynamic slices (on the TPU a gather over the stacked buffer
-    lowers to slices of the WHOLE buffer)."""
-    return _materialize(jax.tree.map(
+    lowers to slices of the WHOLE buffer). Tiles side by side, their
+    zero fill past ``width`` cut off; paired heads as they lie
+    (``grouped_cache_attention`` reads them so)."""
+    rows = _materialize(jax.tree.map(
         lambda buf: cache_slot_rows(buf, layer, slots), cache))
+    return rows[..., :width] if isinstance(cache, tuple) else rows
 
 
 def ring_rows(cfg: TransformerConfig, prefill_chunk: int) -> int:
@@ -254,8 +281,17 @@ def init_slot_cache(
     has: ``full`` [Lf, S, Tmax, Hkv, ·] and ``window`` [Lw, S, ring + 1,
     Hkv_w, ·] (``ring_rows(cfg, prefill_chunk)`` positions and the
     parking row); a width that is more than one 128-lane tile and no
-    whole number of them comes as a tuple of tiles (``lane_tiles``)."""
+    whole number of them comes as a tuple of tiles (``lane_tiles``).
+    Either model's heads of 64 lie two to a row, [.., Hkv / 2, 128]
+    (``ops.attention.cache_heads_per_row``)."""
     dt = cfg.compute_dtype
+
+    def paired(kind, width):
+        """(rows a position, their width) of one kind's buffers."""
+        h_kv = cfg.kv_heads_of(kind)
+        n = cache_heads_per_row(h_kv, cfg.head_dim, cfg.v_dim)
+        return h_kv // n, width * n
+
     if cfg.layered:
         attn = [a for a, _ in cfg.layer_kinds]
         if "full" not in attn and "sparse" not in attn:
@@ -266,8 +302,8 @@ def init_slot_cache(
                 "window": ring_rows(cfg, prefill_chunk) + 1}
 
         def stack(kind, width):
-            shape = (attn.count(kind), slots, rows[kind],
-                     cfg.kv_heads_of(kind))
+            h_rows, width = paired(kind, width)
+            shape = (attn.count(kind), slots, rows[kind], h_rows)
             tiles = lane_tiles(width)
             if tiles:
                 return tuple(jnp.zeros(shape + (LANES,), dt)
@@ -282,23 +318,27 @@ def init_slot_cache(
             return _init_state_cache(cfg, slots, max_len, prefill_chunk,
                                      stacks)
         return stacks(cfg.head_dim), stacks(cfg.v_dim)
-    shape = (cfg.n_layers, slots, max_len, cfg.kv_heads, cfg.head_dim)
+    shape = (cfg.n_layers, slots, max_len) + paired("full", cfg.head_dim)
     return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+
+
+STATE_KINDS = ("linear", "sparse", "conv")
 
 
 def has_state(cfg: TransformerConfig) -> bool:
     """Whether a model keeps per-slot state that is no K/V row of a
-    position (linear or sparse layers): it prefills in aligned chunks
-    and its cache is not exchanged by rows."""
-    return bool({"linear", "sparse"} & {a for a, _ in cfg.layer_kinds})
+    position (linear, sparse or conv layers): it prefills in aligned
+    chunks and its cache is not exchanged by rows."""
+    return bool(set(STATE_KINDS) & {a for a, _ in cfg.layer_kinds})
 
 
 def _init_state_cache(cfg, slots, max_len, prefill_chunk, stacks):
-    """The cache pair of a model with linear or sparse layers (module
-    docstring): the K side holds per kind what the kind keeps — a full
-    or window stack as ever, per sparse layer its K buffer and under
-    ``sparse_kc`` its K^c buffer, per linear layer its state — the V
-    side the stacks and the sparse layers' V buffers."""
+    """The cache pair of a model with linear, sparse or conv layers
+    (module docstring): the K side holds per kind what the kind keeps —
+    a full or window stack as ever, per sparse layer its K buffer and
+    under ``sparse_kc`` its K^c buffer, per linear layer its state, per
+    conv layer its last ``conv_kernel - 1`` rows of g — the V side the
+    stacks and the sparse layers' V buffers."""
     attn = [a for a, _ in cfg.layer_kinds]
     dt = cfg.compute_dtype
     c, b, st = prefill_chunk, cfg.sparse_block, cfg.sparse_stride
@@ -307,11 +347,11 @@ def _init_state_cache(cfg, slots, max_len, prefill_chunk, stacks):
             f"sparse layers prefill in whole blocks: prefill_chunk {c} "
             f"must be a multiple of sparse_block {b} and divide max_len "
             f"{max_len}")
-    if cfg.head_dim != cfg.v_dim:
+    n_sp, n_lin = attn.count("sparse"), attn.count("linear")
+    if (n_sp or n_lin) and cfg.head_dim != cfg.v_dim:
         raise ValueError("linear and sparse layers keep k and v at one "
                          "width")
     k, v = stacks(cfg.head_dim), stacks(cfg.v_dim)
-    n_sp, n_lin = attn.count("sparse"), attn.count("linear")
     if n_sp:
         shape = (slots, cfg.kv_heads_of("sparse"), max_len, cfg.head_dim)
         k["sparse"] = tuple(jnp.zeros(shape, dt) for _ in range(n_sp))
@@ -323,16 +363,20 @@ def _init_state_cache(cfg, slots, max_len, prefill_chunk, stacks):
         shape = (slots + 1, cfg.n_heads, cfg.head_dim, cfg.head_dim)
         k["linear"] = tuple(jnp.zeros(shape, jnp.float32)
                             for _ in range(n_lin))
+    if "conv" in attn:
+        shape = (slots, cfg.conv_kernel - 1, cfg.d_model)
+        k["conv"] = tuple(jnp.zeros(shape, dt)
+                          for _ in range(attn.count("conv")))
     return k, v
 
 
 def refuse_state_rows(cache, what: str) -> None:
-    if isinstance(cache, dict) and {"linear", "sparse"} & set(cache):
+    if isinstance(cache, dict) and set(STATE_KINDS) & set(cache):
         raise ValueError(
-            f"{what}: a model with linear or sparse layers keeps a "
-            f"recurrent state and compressed keys beside its K/V rows, "
-            f"and the row exchange of prefill/decode disaggregation does "
-            f"not carry them")
+            f"{what}: a model with linear, sparse or conv layers keeps a "
+            f"recurrent state, compressed keys or a convolution's last "
+            f"rows beside its K/V rows, and the row exchange of "
+            f"prefill/decode disaggregation does not carry them")
 
 
 def _rows_of_slots(buf, slots):
@@ -496,6 +540,40 @@ def _linear_prefill(q, k_new, v_new, k_all, i, slots, starts, n_valids, cfg,
     return o, {**k_all, "linear": _set_at(k_all["linear"], i, state)}
 
 
+def _conv_decode(g, k_all, i, parked):
+    """A conv layer's decode step: every lane's state rows (the g of its
+    slot's last ``conv_kernel - 1`` positions) read, and shifted by the
+    new row ``g`` [S, 1, d]. The lanes ARE the slots, in order, so a lane
+    that does not decode (free, or in mid-prefill) keeps its rows by a
+    select, bit for bit: the state needs no parking row, and no scatter
+    (one over duplicate rows serialises on the TPU)."""
+    states = k_all["conv"]
+    before = states[i]                                      # [S, L - 1, d]
+    after = jnp.concatenate([before, g.astype(before.dtype)], axis=1)[:, 1:]
+    new = jnp.where(parked[:, None, None], before, after)
+    return before, {**k_all, "conv": _set_at(states, i, new)}
+
+
+def _conv_prefill(g, k_all, i, slots, starts, n_valids):
+    """A conv layer's prefill round: each row's state read out of layer
+    ``i``'s buffer (zeros where the chunk starts a prompt, whatever the
+    slot held), and the state after the chunk's VALID positions — the
+    ``conv_kernel - 1`` rows of (state, chunk) that end at its last valid
+    one — written back in row order; a duplicated padding row read the
+    same rows and writes the same ones."""
+    state = k_all["conv"][i]
+    p, keep = g.shape[0], state.shape[1]
+    before = jnp.where((starts == 0)[:, None, None], 0,
+                       _rows_of_slots(state, slots))       # [P, L - 1, d]
+    rows = jnp.concatenate([before, g.astype(state.dtype)], axis=1)
+    after = jax.vmap(lambda r, n: lax.dynamic_slice_in_dim(r, n, keep, 0))(
+        rows, n_valids)
+    state = lax.fori_loop(
+        0, p, lambda r, st: lax.dynamic_update_slice(
+            st, after[r][None], (slots[r], 0, 0)), state)
+    return before, {**k_all, "conv": _set_at(k_all["conv"], i, state)}
+
+
 def cache_inject_rows(cache, slot: int, rows):
     """Host-side write of float rows [L, P, Hkv, Dh] into one slot's
     prefix (the inject half of prefill/decode disaggregation; the
@@ -518,7 +596,7 @@ def cache_inject_rows(cache, slot: int, rows):
                 stack, _encode(stack, part))
         return out
     p = rows.shape[1]
-    return cache.at[:, slot, :p].set(jnp.asarray(rows, cache.dtype))
+    return cache.at[:, slot, :p].set(_encode(cache, jnp.asarray(rows)))
 
 
 def cache_export_rows(cache, slot: int, length: int, width: int = 0):
@@ -537,11 +615,10 @@ def cache_export_rows(cache, slot: int, length: int, width: int = 0):
             if kind == "window":
                 ring = _cache_tmax(stack) - 1
                 at = jnp.arange(max(0, length - ring), length) % ring
-            out[kind] = _materialize(
-                jax.tree.map(lambda buf: buf[:, slot, at], stack)
-            )[..., :width or None]
+            out[kind] = _logical(_materialize(
+                jax.tree.map(lambda buf: buf[:, slot, at], stack)), width)
         return out
-    return cache[:, slot, :length]
+    return _logical(cache[:, slot, :length], width)
 
 
 def _write_chunk_ring(cache, layer, slot, start, chunk):
@@ -581,10 +658,11 @@ def prefill_read_block(cfg: TransformerConfig, k_all, p: int, c: int) -> int:
     tokens attends its FULL layers through ``cache_prefill_attention``
     (a row then reads whole blocks up to its chunk's end); 0 where it
     reads every slot's whole reservation: scores that fit at once."""
-    t = _cache_tmax(_kind(k_all, "full"))
+    full = jax.tree.leaves(_kind(k_all, "full"))[0]
+    t = full.shape[2]
     if _scores_fit(p, c, cfg.n_heads, t):
         return 0
-    return prefill_key_block(t, cfg.kv_heads_of("full"))
+    return prefill_key_block(t, full.shape[3])
 
 
 def decode_read_block(k_all) -> int:
@@ -619,8 +697,8 @@ def _attend_rows(q, kc, vc, at, slots, starts, mask, scale, sink, cfg, attn):
     if attn == "full" and prefill_read_block(cfg, kc, p, c):
         return cache_prefill_attention(q, kc, vc, at, slots, starts + c,
                                        scale=scale, sink=sink)
-    k = _read_slots(kc, at, slots)[..., :d_k]
-    v = _read_slots(vc, at, slots)
+    k = _read_slots(kc, at, slots, d_k)
+    v = _read_slots(vc, at, slots, cfg.v_dim)
     attention = (grouped_cache_attention
                  if _scores_fit(p, c, n_h, k.shape[1])
                  else rowwise_cache_attention)
@@ -717,6 +795,9 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
 
             def attend(q, k_new, v_new, attn, sink):
                 nonlocal k_all, v_all, sparse_keys
+                if attn == "conv":
+                    before, k_all = _conv_decode(q, k_all, at, parked)
+                    return before
                 if attn == "linear":
                     # a lane that is not decoding reads and writes the
                     # parking row: its slot's state stays as it is
@@ -839,6 +920,10 @@ def prefill_chunks(params, k_all, v_all, tokens, slots, starts, n_valids,
     def layer(x, lp, attn, at, k_all, v_all):
         def attend(q, k_new, v_new, attn, sink):
             nonlocal k_all, v_all
+            if attn == "conv":
+                before, k_all = _conv_prefill(q, k_all, at, slots, starts,
+                                              n_valids)
+                return before
             if attn == "linear":
                 o, k_all = _linear_prefill(q, k_new, v_new, k_all, at,
                                            slots, starts, n_valids, cfg,

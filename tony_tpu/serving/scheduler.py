@@ -36,7 +36,8 @@ and trace reductions match on them::
         tony:engine.prefill_device     dispatch -> readback returned, or
                                        -> the jitted call returned where
                                        the round is not fenced (attrs
-                                       keys_read, fenced, expert_pairs*)
+                                       keys_read, fenced, expert_pairs*,
+                                       conv_layers**)
           tony:engine.prefill_launch     -> the jitted call returned
                                          (attrs h2d_arrays, h2d_bytes)
           tony:engine.prefill_readback   a fenced round only: device_get of
@@ -45,7 +46,7 @@ and trace reductions match on them::
         tony:engine.emit               first tokens, retirements
       tony:engine.decode_device    dispatch -> readback returned
                                    (attrs slots, window, keys_read,
-                                   expert_pairs*)
+                                   expert_pairs*, conv_layers**)
         tony:engine.decode_launch    -> the jitted call returned
                                      (attrs h2d_arrays, h2d_bytes)
         tony:engine.decode_readback  device_get of the window's tokens
@@ -97,8 +98,12 @@ prefill's ``d2h_bytes`` whichever program's readback carried them.
 Idle polls record and count nothing.
 (*) A model with experts only: the dispatch's (token, choice) pairs on
 the experts held here, which come back with the tokens in the one
-readback; ``stats()["experts"]`` sums them per held expert, and beside
-them the passes the expert layers ran (``passes``). A model with
+readback; ``stats()["experts"]`` sums them per held expert
+(``pairs_per_expert``) and by the program that counted them
+(``decode_pairs``, ``prefill_pairs``: their sum is ``pairs_held``, which
+equals ``pairs_total`` where every expert is held), and beside them the
+passes the expert layers ran (``passes``). (**) A model with conv layers
+only: their number. A model with
 window layers has a cache stack per attention kind, and
 ``stats()["kv"]["kinds"]`` counts each (the top-level ``kv`` keys stay
 the full kind's). ``stats()["decode_keys"]``, the twin of
@@ -112,9 +117,12 @@ kernel (counted on the device, back in the iteration's readback) against
 what a dense layer would have read, per KV group and summed over groups,
 sparse layers and decode iterations (the decode span carries the
 iteration's ``sparse_keys_read``); with
-linear layers: ``stats()["state"]`` = ``{slots_reset, live_state_ms}``,
-the prompts whose first chunk read a zero state and the slots holding a
-live state times the wall. Such a model prefills in aligned chunks and
+linear or conv layers: ``stats()["state"]`` = ``{slots_reset,
+live_state_ms}``, the prompts whose first chunk read a zero state and the
+slots holding a live state times the wall; with conv layers:
+``stats()["conv"]`` = ``{layers, rows_carried}``, the conv layers and the
+chunks, summed over them, that took their leading rows from a live conv
+state and not from zeros. Such a model prefills in aligned chunks and
 takes no part in the row exchange (``engine.has_state``).
 
 Greedy parity contract (pinned by tests/test_serving.py): a request
@@ -168,6 +176,11 @@ _HOST_VALUES = (np.ndarray, np.generic)
 
 # Retired requests whose queue wait / prefill span stats() summarises.
 _LATENCY_RING = 512
+
+# A prefill round by default (``prefill_batch`` None): the rows one
+# dispatch holds, and the tokens it holds at most.
+_ROUND_ROWS = 4
+_ROUND_TOKENS = 1024
 
 # Declared metric names — the tony_serving_* family (TONY-M001/M002
 # lint these module-scope constants).
@@ -304,7 +317,7 @@ class ServingEngine:
         max_len: int | None = None,
         prefill_chunk: int = 32,
         prefill_chunks_per_iter: int | None = None,
-        prefill_batch: int = 4,
+        prefill_batch: int | None = None,
         decode_window: int = 1,
         max_queue: int = 1024,
         max_resident_models: int = 4,
@@ -351,6 +364,15 @@ class ServingEngine:
             else max(1, int(prefill_chunks_per_iter))
         )
         self.decode_window = int(decode_window)
+        # None = auto: up to ``_ROUND_ROWS`` chunks a round while the
+        # round holds at most ``_ROUND_TOKENS`` tokens. A short round is
+        # padded with duplicates of its first row, computed in full, so
+        # past a round that fills the MXU more rows buy nothing and a
+        # padded one costs whole chunks (at chunks of 512 four rows made a
+        # round of 42 ms that carried 1.1 prompts on average: PERF.md
+        # section 6, PR 43).
+        if prefill_batch is None:
+            prefill_batch = min(_ROUND_ROWS, _ROUND_TOKENS // prefill_chunk)
         self.prefill_batch = max(1, int(prefill_batch))
         self.max_queue = int(max_queue)
         if is_fused(params):
@@ -426,14 +448,23 @@ class ServingEngine:
         self._pf_read_block = _engine.prefill_read_block(
             cfg, self._k, self.prefill_batch, self.prefill_chunk
         ) if self._full_layers else 0
-        # A model with linear or sparse layers (engine.py): aligned
-        # chunks, no row exchange, and the counters of stats()["sparse"]
-        # and ["state"].
+        # A model with linear, sparse or conv layers (engine.py): aligned
+        # chunks, no row exchange, and the counters of stats()["sparse"],
+        # ["state"] and ["conv"].
         self._sparse_layers = sum(a == "sparse" for a, _ in cfg.layer_kinds)
         self._sparse_groups = (self._sparse_layers
                                * cfg.kv_heads_of("sparse"))
-        self._linear_layers = sum(a == "linear" for a, _ in cfg.layer_kinds)
-        self._has_state = bool(self._sparse_layers or self._linear_layers)
+        self._conv_layers = sum(a == "conv" for a, _ in cfg.layer_kinds)
+        self._has_state = _engine.has_state(cfg)
+        # the kinds whose state a prompt's first chunk resets (stats "state")
+        self._state_layers = self._conv_layers + sum(
+            a == "linear" for a, _ in cfg.layer_kinds)
+        # Chunks, summed over conv layers, whose leading rows came from a
+        # live conv state and not from zeros (stats()["conv"]); the two
+        # device spans of a model with conv layers carry their number.
+        self._conv_rows_carried = 0
+        self._conv_attr = ({"conv_layers": self._conv_layers}
+                           if self._conv_layers else {})
         self._sparse_keys_read = 0
         self._sparse_keys_live = 0
         self._state_slots_reset = 0
@@ -468,8 +499,10 @@ class ServingEngine:
             }
         self._kv_bytes_per_position = self._kv_kinds[
             _engine._positions_kind(self._k)]["bytes_per_position"]
-        # Pairs on the held experts, as the programs count them.
+        # Pairs on the held experts, as the programs count them, and
+        # their sum by the program that counted.
         self._expert_pairs = np.zeros(cfg.held[1], np.int64)
+        self._program_pairs = {"decode": 0, "prefill": 0}
         self._expert_tokens = 0
         self._expert_dispatches = 0
         self._expert_passes = 0
@@ -810,19 +843,24 @@ class ServingEngine:
                 # groups, sparse layers and decode iterations.
                 out["sparse"] = {"keys_read": self._sparse_keys_read,
                                  "keys_live": self._sparse_keys_live}
-            if self._linear_layers:
+            if self._state_layers:
                 # Prompts whose first chunk read a zero state in a slot
                 # that held another, and slots holding a live state
                 # (decoding, or between a prompt's rounds) x wall time.
                 out["state"] = {
                     "slots_reset": self._state_slots_reset,
                     "live_state_ms": self._live_state_ns / 1e6}
+            if self._conv_layers:
+                out["conv"] = {"layers": self._conv_layers,
+                               "rows_carried": self._conv_rows_carried}
             if self.cfg.n_experts:
                 n_moe = sum(m == "moe" for _, m in self.cfg.layer_kinds)
                 out["experts"] = {
                     "held": list(self.cfg.held),
                     "pairs_per_expert": self._expert_pairs.tolist(),
                     "pairs_held": int(self._expert_pairs.sum()),
+                    "decode_pairs": self._program_pairs["decode"],
+                    "prefill_pairs": self._program_pairs["prefill"],
                     "pairs_total": (self._expert_tokens * n_moe
                                     * self.cfg.expert_top_k),
                     "dispatches": self._expert_dispatches,
@@ -1003,7 +1041,8 @@ class ServingEngine:
         # Span covers dispatch AND the readback sync — the wall the
         # chip actually spent on this window.
         with tr.span("tony:engine.decode_device", slots=n_active,
-                     window=w, keys_read=keys_read) as sp, \
+                     window=w, keys_read=keys_read,
+                     **self._conv_attr) as sp, \
                 jit_sanitizer.step_region("serving_decode_window"):
             with tr.span("tony:engine.decode_launch") as launch:
                 args = (self.params, self._k, self._v, self._pos, wpos,
@@ -1031,8 +1070,8 @@ class ServingEngine:
                 self._sparse_keys_live += live
                 sp.set(sparse_keys_read=read)
             if counts:
-                sp.set(expert_pairs=landed
-                       + self._note_pairs(counts, n_active * w))
+                sp.set(expert_pairs=landed + self._note_pairs(
+                    "decode", counts, n_active * w))
         it["decode_device"] = sp.dur_ns
         it["decode_launch"] = launch.dur_ns
         it["decode_readback"] = readback.dur_ns
@@ -1241,8 +1280,10 @@ class ServingEngine:
             self._pf_draws += 1
         self._prefill_rounds += 1
         self._rounds_without_first += not any(finals)
-        if self._linear_layers:
+        if self._state_layers:
             self._state_slots_reset += int((starts[:n] == 0).sum())
+        self._conv_rows_carried += (self._conv_layers
+                                    * int((starts[:n] > 0).sum()))
         tokens = int(n_valids[:n].sum())
         self._prefill_tokens_valid += tokens
         self._prefill_rows_padded += pb - n
@@ -1256,7 +1297,7 @@ class ServingEngine:
         self._prefill_keys_reserved += n * self.max_len * self._full_layers
         fenced = any(finals) or not self._fence_follows(last)
         with tr.span("tony:engine.prefill_device", keys_read=keys_read,
-                     fenced=fenced) as sp, \
+                     fenced=fenced, **self._conv_attr) as sp, \
                 jit_sanitizer.step_region("serving_prefill_chunks"):
             with tr.span("tony:engine.prefill_launch") as launch:
                 args = (self.params, self._k, self._v, toks, slots_a, starts,
@@ -1273,8 +1314,8 @@ class ServingEngine:
                 it["prefill_readback"] += readback.dur_ns
                 firsts = np.asarray(firsts)
                 if counts is not None:
-                    sp.set(expert_pairs=landed
-                           + self._note_pairs(counts, tokens))
+                    sp.set(expert_pairs=landed + self._note_pairs(
+                        "prefill", counts, tokens))
             else:
                 # Launched and left: nothing of it is needed before the
                 # step's next fence, which brings its counts home.
@@ -1354,24 +1395,27 @@ class ServingEngine:
         attrs = self._count_d2h(program, own)
         attrs["d2h_bytes"] += self._count_d2h("prefill", flown)["d2h_bytes"]
         readback.set(**attrs)
-        landed = sum(self._note_pairs(counts, tokens)
+        landed = sum(self._note_pairs("prefill", counts, tokens)
                      for counts, (_, tokens) in zip(flown, flights))
         return own, landed
 
-    def _note_pairs(self, counts: dict, tokens: int) -> int:
+    def _note_pairs(self, program: str, counts: dict, tokens: int) -> int:
         """One dispatch's expert counters (host arrays: they came back
         in the dispatch's fenced readback), summed over its expert
         layers, into stats()["experts"]: the (token, choice) pairs per
-        held expert and the passes the layers ran (``passes`` over
-        ``dispatches`` x expert layers = 1.0: no layer streamed its
-        weights twice). Returns the dispatch's pairs on held experts
-        (the device span's attr)."""
+        held expert, their sum under the ``program`` that counted them
+        (``decode_pairs``, ``prefill_pairs``) and the passes the layers
+        ran (``passes`` over ``dispatches`` x expert layers = 1.0: no
+        layer streamed its weights twice). Returns the dispatch's pairs
+        on held experts (the device span's attr)."""
+        pairs = int(counts["pairs"].sum())
         with self._cond:
             self._expert_pairs += counts["pairs"]
+            self._program_pairs[program] += pairs
             self._expert_tokens += tokens
             self._expert_dispatches += 1
             self._expert_passes += int(counts["passes"])
-        return int(counts["pairs"].sum())
+        return pairs
 
     def _retire(self, slot: int) -> None:
         req = self._slot_req[slot]

@@ -8,7 +8,8 @@ check; ``.claude/skills/verify/SKILL.md``).
 
 Per configuration and program (``decode_window``, ``prefill_chunks``; a
 training configuration named on the command line: its ``train_step``) it
-writes the StableHLO of ``lower()`` and the optimized HLO of ``compile()``.
+writes the StableHLO of ``lower()`` and the optimized HLO of ``compile()``,
+and prints the TPU compiler's own memory count of the program.
 Each Mosaic kernel is serialised from a copy without its debug info (a
 kernel's bytecode holds its callers' paths and lines, so unstripped
 bodies differ between any two directories). One process at a time: the TPU
@@ -108,9 +109,16 @@ def main() -> int:
         for program, lowered in programs.items():
             (out / f"{name}.{program}.stablehlo.txt").write_text(
                 clean(lowered.as_text()))
+            compiled = lowered.compile()
             (out / f"{name}.{program}.hlo.txt").write_text(
-                clean(lowered.compile().as_text()))
-            print(name, program, flush=True)
+                clean(compiled.as_text()))
+            m = compiled.memory_analysis()
+            print(name, program, "arguments %.3f GB, temporaries %.3f GB, "
+                  "results %.3f GB of which aliased %.3f GB" % tuple(
+                      n / 1e9 for n in (
+                          m.argument_size_in_bytes, m.temp_size_in_bytes,
+                          m.output_size_in_bytes, m.alias_size_in_bytes)),
+                  flush=True)
 
     key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
     out.mkdir(parents=True, exist_ok=True)
