@@ -447,7 +447,8 @@ def test_serving_programs_hold_no_layer_slab(v5e, program):
     if program == "decode_window":
         lowered = engine.decode_window.lower(
             fused, kv, kv, arr((slots,)), arr((slots,)), arr((slots,)),
-            arr((slots,), jnp.float32), key, arr(()), cfg=cfg, steps=1,
+            arr((slots,), jnp.float32), key, arr(()), arr((slots, 1)),
+            cfg=cfg, steps=1,
         )
     else:
         lowered = engine.prefill_chunks.lower(
@@ -512,7 +513,8 @@ def _layered_program(v5e, program):
     if program == "decode_window":
         lowered = engine.decode_window.lower(
             fused, k, v, arr((slots,)), arr((slots,)), arr((slots,)),
-            arr((slots,), jnp.float32), key, arr(()), cfg=cfg, steps=1,
+            arr((slots,), jnp.float32), key, arr(()), arr((slots, 1)),
+            cfg=cfg, steps=1,
         )
     else:
         lowered = engine.prefill_chunks.lower(
@@ -647,7 +649,8 @@ def _lfm2_program(v5e, program):
     if program == "decode_window":
         lowered = engine.decode_window.lower(
             fused, k, v, arr((slots,)), arr((slots,)), arr((slots,)),
-            arr((slots,), jnp.float32), key, arr(()), cfg=cfg, steps=1,
+            arr((slots,), jnp.float32), key, arr(()), arr((slots, 1)),
+            cfg=cfg, steps=1,
         )
     else:
         lowered = engine.prefill_chunks.lower(

@@ -27,26 +27,27 @@ PROMPT_LENS = (5, 9, 13, 17, 3, 22)
 # chunk, fenced). Prompts a..f of PROMPT_LENS have 2, 3, 4, 5, 1 and 6
 # chunks of 4; three slots, two entries a round, one chunk a pending slot an
 # iteration, six tokens a request (the first from its last chunk, then five
-# decode steps: five iterations at window 1, two at window 3). Window 1: a1
-# b1 | c1 ; a2* b2 | c2 ; b3* c3 ; c4* ; three iterations of decode alone (a
-# retires, d takes its slot) ; d1 (b retires, e) ; d2 e1* (c retires, f) ;
-# d3 f1 ; d4 f2 ; d5* f3 ; f4 ; f5 ; f6*. Window 3 retires a after its
-# second iteration, so d joins c's last round: a1 b1 | c1 ; a2* b2 | c2 ;
-# b3* c3 ; c4* d1 ; d2 e1* ; d3 f1 ; d4 f2 ; d5* f3 ; f4 ; f5 ; f6*.
-# A round is fenced for a first token (*), or where it closes a step that
-# decodes nothing: c1 (nothing decodes yet) and, at window 3, d4 f2 (e
-# retired the step before, d is at its fourth chunk) and f5 (d retired).
-ROUNDS = {
-    1: [(2, 0, False), (1, 0, True), (2, 1, True), (1, 0, False),
-        (2, 1, True), (1, 1, True), (1, 0, False), (2, 1, True),
-        (2, 0, False), (2, 0, False), (2, 1, True), (1, 0, False),
-        (1, 0, False), (1, 1, True)],
-    3: [(2, 0, False), (1, 0, True), (2, 1, True), (1, 0, False),
-        (2, 1, True), (2, 1, True), (2, 1, True), (2, 0, False),
-        (2, 0, True), (2, 1, True), (1, 0, False), (1, 0, True),
-        (1, 1, True)],
-}
-DECODE_ITERATIONS = {1: 17, 3: 9}
+# decode steps: five iterations at window 1, two at window 3). An iteration
+# is launched in one step and read back in the next, so a request's slot is
+# free for the step after the one that launched its last iteration, and
+# both windows make the same rounds: a1 b1 | c1 ; a2* b2 | c2 (a's first
+# iteration is launched) ; b3* c3 ; c4* ; steps of decode alone, in which a
+# is read to its end ; d1 in a's slot ; d2 e1* ; d3 f1 ; d4 f2 ; d5* f3 ;
+# f4 ; f5 ; f6*. A round is fenced for a first token (*), or where it
+# closes a step that decodes nothing: c1 (nothing decodes yet). Every other
+# step has a lane active, so its decode dispatch fences: at window 3 the
+# steps of d4 f2 and f5 launch nothing (e and d have their last iteration in
+# flight) and only read it back.
+ROUNDS = [(2, 0, False), (1, 0, True), (2, 1, True), (1, 0, False),
+          (2, 1, True), (1, 1, True), (1, 0, False), (2, 1, True),
+          (2, 0, False), (2, 0, False), (2, 1, True), (1, 0, False),
+          (1, 0, False), (1, 1, True)]
+# Iterations launched, and of them those launched while the one before was
+# not read back: all but the first at window 1; at window 3 the pipeline
+# fills three times (a's first, then d's and f's after a step that only
+# read back).
+DECODE_ITERATIONS = {1: 18, 3: 10}
+PIPELINED = {1: 17, 3: 7}
 
 
 def _engine(**kw) -> ServingEngine:
@@ -143,16 +144,20 @@ def test_engine_spans_descend_from_their_step_and_lie_inside_it(served):
     assert all(1 <= e["args"]["batch"] <= eng.prefill_batch
                and e["args"]["chunk"] == eng.prefill_chunk for e in rounds)
     decodes = [e for e in spans if e["name"] == "tony:engine.decode_device"]
-    assert all(1 <= e["args"]["slots"] <= eng.slots
+    assert all(0 <= e["args"]["slots"] <= eng.slots
                and e["args"]["window"] == eng.decode_window for e in decodes)
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_a_dispatch_is_split_where_the_jitted_call_returns(served, program):
-    """Every fenced device span has one ``*_launch`` and one ``*_readback``
+    """Every fenced prefill span has one ``*_launch`` and one ``*_readback``
     child: both lie inside it, the launch ends before the readback starts,
     and the bookkeeping after the readback is the parent's own. A prefill
-    round that is not fenced (``fenced=False``) has its launch alone."""
+    round that is not fenced (``fenced=False``) has its launch alone. A
+    decode span holds the launch of one iteration and the readback of the
+    one before (``pipelined``); the span that fills the pipeline has a
+    launch alone, one that launches nothing (``slots`` 0) a readback
+    alone."""
     eng, _, _ = served
     spans = [e for e in _spans(eng) if e["name"].startswith("tony:engine.")]
     devices = [e for e in spans
@@ -160,61 +165,78 @@ def test_a_dispatch_is_split_where_the_jitted_call_returns(served, program):
     assert devices
     if program == "prefill":
         assert [e["args"]["fenced"] for e in devices] == \
-            [fenced for _, _, fenced in ROUNDS[eng.decode_window]]
+            [fenced for _, _, fenced in ROUNDS]
     else:
         assert not any("fenced" in e["args"] for e in devices)
+    halves = []
     for dev in devices:
         mine = {e["name"].rsplit("_", 1)[1]: e for e in spans
                 if e["args"]["parent_id"] == dev["args"]["span_id"]}
-        launch = mine["launch"]
-        assert launch["name"] == f"tony:engine.{program}_launch"
-        assert dev["ts"] <= launch["ts"]
-        assert launch["args"]["h2d_arrays"] == (5 if program == "decode"
-                                                else 6)
-        assert launch["args"]["h2d_bytes"] > 0
-        if not dev["args"].get("fenced", True):
-            assert sorted(mine) == ["launch"]
-            continue
-        assert sorted(mine) == ["launch", "readback"]
-        readback = mine["readback"]
-        assert readback["name"] == f"tony:engine.{program}_readback"
-        # microsecond export: a stamp may round one tick either way
-        assert launch["ts"] + launch["dur"] <= readback["ts"] + 1
-        assert (readback["ts"] + readback["dur"]
-                <= dev["ts"] + dev["dur"] + 1)
-        assert readback["args"]["d2h_bytes"] > 0
+        halves.append(sorted(mine))
+        if program == "decode":
+            assert dev["args"]["pipelined"] == (len(mine) == 2)
+            assert ("launch" in mine) == (dev["args"]["slots"] > 0)
+        else:
+            assert sorted(mine) == (["launch", "readback"]
+                                    if dev["args"]["fenced"] else ["launch"])
+        launch, readback = mine.get("launch"), mine.get("readback")
+        if launch:
+            assert launch["name"] == f"tony:engine.{program}_launch"
+            assert dev["ts"] <= launch["ts"]
+            assert launch["args"]["h2d_arrays"] == (5 if program == "decode"
+                                                    else 6)
+            assert launch["args"]["h2d_bytes"] > 0
+        if readback:
+            assert readback["name"] == f"tony:engine.{program}_readback"
+            # microsecond export: a stamp may round one tick either way
+            assert dev["ts"] <= readback["ts"] + 1
+            assert (readback["ts"] + readback["dur"]
+                    <= dev["ts"] + dev["dur"] + 1)
+            assert readback["args"]["d2h_bytes"] > 0
+        if launch and readback:
+            assert launch["ts"] + launch["dur"] <= readback["ts"] + 1
+    if program == "decode":
+        # the pipeline fills with a launch and drains with a readback
+        assert halves[0] == ["launch"] and halves[-1] == ["readback"]
+        assert sum("launch" in h for h in halves) == \
+            sum("readback" in h for h in halves) == \
+            DECODE_ITERATIONS[eng.decode_window]
+        assert sum(len(h) == 2 for h in halves) == \
+            PIPELINED[eng.decode_window]
     firsts = [e["args"].get("first_tokens") for e in spans
               if e["name"] == f"tony:engine.{program}_readback"]
     if program == "prefill":
-        assert firsts == [f for _, f, fenced in ROUNDS[eng.decode_window]
+        assert firsts == [f for _, f, fenced in ROUNDS
                           if fenced]
         assert sum(firsts) == len(PROMPT_LENS)
     else:
-        assert firsts == [None] * len(devices)
+        assert firsts == [None] * DECODE_ITERATIONS[eng.decode_window]
 
 
 def test_dispatch_counters_count_exactly(served):
     """``stats()["dispatch"]``: calls, the bytes a call's host arguments
     take up and its readback brings home, the rounds that held no first
-    token and those of them launched without a readback of their own, all
+    token and those of them launched without a readback of their own, the
+    iterations launched before the one ahead of them was read back, all
     by hand; the two halves' times lie inside their device phase."""
     eng, _, _ = served
     w = eng.decode_window
     st = eng.stats()
     decode, prefill = st["dispatch"]["decode"], st["dispatch"]["prefill"]
     assert decode["calls"] == st["decode_iterations"] == DECODE_ITERATIONS[w]
-    assert prefill["calls"] == st["prefill_rounds"] == len(ROUNDS[w])
+    assert prefill["calls"] == st["prefill_rounds"] == len(ROUNDS)
     assert [e["args"]["batch"] for e in _spans(eng)
             if e["name"] == "tony:engine.prefill_round"] == \
-        [n for n, _, _ in ROUNDS[w]]
+        [n for n, _, _ in ROUNDS]
     assert prefill["rounds_without_first_token"] == \
-        sum(f == 0 for _, f, _ in ROUNDS[w]) == \
-        len(ROUNDS[w]) - len(PROMPT_LENS)
-    fenced = sum(fenced for _, _, fenced in ROUNDS[w])
-    assert prefill["unfenced"] == len(ROUNDS[w]) - fenced == {1: 7, 3: 4}[w]
+        sum(f == 0 for _, f, _ in ROUNDS) == \
+        len(ROUNDS) - len(PROMPT_LENS)
+    fenced = sum(fenced for _, _, fenced in ROUNDS)
+    assert prefill["unfenced"] == len(ROUNDS) - fenced == 7
     assert prefill["unfenced"] <= prefill["rounds_without_first_token"]
-    # up: _pos, wpos, _last (int32) and _temp (float32) of 3 slots and the
-    # draw counter; 2 rows x 4 tokens, four arrays of 2 and the counter
+    # up: positions, wpos, _last (int32) and _temp (float32) of 3 slots and
+    # the draw counter (the last window's tokens are on the device
+    # already); 2 rows x 4 tokens, four arrays of 2 and the counter
     assert decode["h2d_bytes"] == decode["calls"] * (4 * 3 * 4 + 4)
     assert prefill["h2d_bytes"] == prefill["calls"] * (2 * 4 * 4 + 4 * 2 * 4
                                                        + 4)
@@ -222,10 +244,11 @@ def test_dispatch_counters_count_exactly(served):
     # first tokens, and nothing of an unfenced round (no experts here)
     assert decode["d2h_bytes"] == decode["calls"] * 3 * w * 4
     assert prefill["d2h_bytes"] == fenced * 2 * 4
-    assert set(decode) == {"calls", "launch_ms", "readback_ms", "h2d_bytes",
-                           "d2h_bytes"}
-    assert set(prefill) == set(decode) | {"rounds_without_first_token",
-                                          "unfenced"}
+    both = {"calls", "launch_ms", "readback_ms", "h2d_bytes", "d2h_bytes"}
+    assert set(decode) == both | {"pipelined", "discarded_tokens"}
+    assert set(prefill) == both | {"rounds_without_first_token", "unfenced"}
+    assert decode["pipelined"] == PIPELINED[w] <= decode["calls"]
+    assert decode["discarded_tokens"] == 0       # no request ends by EOS
     for program, row in st["dispatch"].items():
         assert row["launch_ms"] > 0 < row["readback_ms"]
         assert (row["launch_ms"] + row["readback_ms"]
@@ -271,7 +294,9 @@ def test_counters_count_what_the_spans_show(served):
     spans = _spans(eng)
     decodes = [e for e in spans if e["name"] == "tony:engine.decode_device"]
     rounds = [e for e in spans if e["name"] == "tony:engine.prefill_round"]
-    assert st["decode_iterations"] == len(decodes)
+    # a span that launches nothing reads the last iteration back
+    assert st["decode_iterations"] == sum(e["args"]["slots"] > 0
+                                          for e in decodes)
     assert st["decode_slots_sum"] == sum(e["args"]["slots"] for e in decodes)
     assert st["prefill_rounds"] == len(rounds)
     rows = sum(e["args"]["batch"] for e in rounds)
@@ -343,23 +368,29 @@ def served_layered():
 
 
 def test_device_spans_carry_the_pairs_on_held_experts(served_layered):
-    """``expert_pairs`` on a fenced ``decode_device`` / ``prefill_device``
-    span is the (token, choice) pairs on the held experts of that dispatch
-    and of the unfenced rounds it brought home; over all dispatches they
-    are ``stats()["experts"]``."""
+    """``expert_pairs`` on a ``decode_device`` / ``prefill_device`` span
+    that reads back is the (token, choice) pairs on the held experts of
+    what it brought home: a fenced round's own, the iteration before the
+    one a decode span launched, and the unfenced rounds between; over all
+    dispatches they are ``stats()["experts"]``."""
     eng = served_layered
     ex = eng.stats()["experts"]
-    device = [e for e in _spans(eng) if e["name"] in
+    spans = _spans(eng)
+    device = [e for e in spans if e["name"] in
               ("tony:engine.decode_device", "tony:engine.prefill_device")]
-    # a round that is not fenced has no counts yet: the span that fences
-    # it carries them beside its own
-    fenced = [e for e in device if e["args"].get("fenced", True)]
+    # a round that is not fenced and an iteration just launched have no
+    # counts yet: the span that reads them back carries them
+    parents = {e["args"]["parent_id"] for e in spans
+               if e["name"].endswith("_readback")}
+    fenced = [e for e in device if e["args"]["span_id"] in parents]
     assert len(fenced) < len(device)
     assert all(("expert_pairs" in e["args"]) == (e in fenced) for e in device)
     assert sum(e["args"]["expert_pairs"] for e in fenced) == \
         ex["pairs_held"] == sum(ex["pairs_per_expert"])
     assert ex["held"] == [2, 4] and len(ex["pairs_per_expert"]) == 4
-    assert ex["dispatches"] == len(device)
+    # every dispatch launches but the one that reads the last iteration
+    assert ex["dispatches"] == sum(e["args"].get("slots", 1) > 0
+                                   for e in device)
     # tokens through the layers: every chunk's valid ones (a prompt's
     # last chunk overlaps) and every served token but a request's last;
     # 2 expert layers, 3 choices a token
@@ -449,10 +480,12 @@ def test_prefill_keys_count_what_a_chunk_is_given_to_read(monkeypatch,
 
 
 @pytest.mark.parametrize("case,read,reserved", [
-    # no lane decodes: stale positions of the last tenants, nothing read
-    ("all-parked", 0, 3 * 64 * 2 * 2),
     # a prompt of 15 tokens, 3 new ones: ONE window of 2 steps feeds
-    # positions 15 (block 0) and 16 (blocks 0 and 1); 2 layers
+    # positions 15 (block 0) and 16 (blocks 0 and 1); 2 layers. The other
+    # two lanes are parked at stale positions of their last tenants (40
+    # and 63: three and four blocks), and read nothing
+    ("stale-parked", (16 + 32) * 2, 3 * 64 * 2 * 2),
+    # the same with the other lanes at 0
     ("block-edge", (16 + 32) * 2, 3 * 64 * 2 * 2),
     # one full layer of 1 KV head beside the rings: a prompt of 30
     # tokens, 4 new ones, three iterations feed 30, 31 (one block of 32)
@@ -480,12 +513,9 @@ def test_decode_keys_count_the_blocks_a_step_reads(monkeypatch, case, read,
             eng = _engine(slots=3, prefill_chunk=4, prefill_batch=2,
                           max_len=64, decode_window=2)
             assert eng._dc_read_block == 16       # 2 KV heads
-            reqs = []
-            if case == "all-parked":
+            if case == "stale-parked":
                 eng._pos[:] = (5, 40, 63)
-                eng._decode_some(0)
-            else:
-                reqs = [eng.submit(np.arange(15, dtype=np.int32), 3)]
+            reqs = [eng.submit(np.arange(15, dtype=np.int32), 3)]
         _drive(eng, reqs)
         eng.close()
     finally:

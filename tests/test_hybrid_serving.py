@@ -424,6 +424,50 @@ def test_a_reused_slot_reads_nothing_of_its_last_tenant(model, lengths):
     assert (after == fresh).all()
 
 
+# -- (d') with decode iterations pipelined one deep ----------------------------
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "t0.8"])
+@pytest.mark.parametrize("window", [1, 2], ids=["window1", "window2"])
+def test_tokens_are_those_of_an_engine_drained_after_every_step(
+        model, window, temperature):
+    """The state and the selection's K^c advance on the device from one
+    launched window to the next: every request's tokens are those of the
+    engine that reads each iteration back before it launches the next.
+    Greedy requests reuse two slots; sampled ones have a slot each."""
+    from test_unfenced_rounds import serve_pipelined_and_drained
+
+    tcfg, fused = program(model)
+    lens, budgets = (40, 9, 70, 33), (9, 5, 2, 7)
+    eng, reqs, ref, ref_reqs = serve_pipelined_and_drained(
+        lambda **kw: ServingEngine(fused, tcfg, max_len=128, prefill_chunk=16,
+                                   slots=4 if temperature else 2, **kw),
+        window=window, temperature=temperature, prompt_lens=lens,
+        budgets=budgets, vocab=TINY["vocab_size"])
+    assert [r.tokens for r in reqs] == [r.tokens for r in ref_reqs]
+    assert [len(r.tokens) for r in reqs] == list(budgets)
+    decode = eng.stats()["dispatch"]["decode"]
+    assert 0 < decode["pipelined"] <= decode["calls"]
+    assert ref.stats()["dispatch"]["decode"]["pipelined"] == 0
+    # the device's own count of the keys its selection listed, against
+    # the positions the lanes were LAUNCHED at
+    assert eng.stats()["sparse"] == ref.stats()["sparse"]
+
+
+@pytest.mark.parametrize("window", [1, 2], ids=["window1", "window2"])
+def test_a_slot_freed_by_a_late_eos_holds_a_zero_state(model, window):
+    """The window launched for a lane that had ended moved its slot's
+    state and K^c once more; the next tenant's first chunk reads zeros
+    all the same."""
+    from test_unfenced_rounds import reused_slot_after_a_late_eos
+
+    tcfg, fused = program(model)
+    got, fresh, engine = reused_slot_after_a_late_eos(
+        lambda: ServingEngine(fused, tcfg, slots=2, max_len=128,
+                              prefill_chunk=16, decode_window=window),
+        prompts([20, 50, 37], seed=9))
+    assert got == fresh
+    assert engine.stats()["state"]["slots_reset"] == 3
+
+
 # -- (e) a decode dispatch leaves a slot in mid-prefill as it is ---------------
 @pytest.mark.parametrize("steps", [1, 3])
 def test_decode_leaves_a_slot_in_mid_prefill_bit_for_bit(model, steps):

@@ -282,8 +282,11 @@ def test_the_device_spans_carry_the_conv_layers_and_the_pairs(model):
     experts = engine.stats()["experts"]
     device = [e["args"] for e in spans if e["name"] in (
         "tony:engine.decode_device", "tony:engine.prefill_device")]
+    # every dispatch launches but the last, which reads the last
+    # iteration back
     assert len(device) == (engine.stats()["decode_iterations"]
-                           + engine.stats()["prefill_rounds"])
+                           + engine.stats()["prefill_rounds"] + 1)
+    assert [a["slots"] for a in device if "slots" in a][-1] == 0
     assert all(a["conv_layers"] == 4 for a in device)
     assert sum(a.get("expert_pairs", 0) for a in device) \
         == experts["pairs_held"] \
@@ -322,6 +325,53 @@ def test_a_reused_slot_reads_a_zero_state(model, reference):
         at = np.arange(prompt.size - 1, row.size - 1)
         assert (ref[at].argmax(-1) == row[at + 1]).all()
     assert engine.stats()["state"]["slots_reset"] == 2
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "t0.8"])
+@pytest.mark.parametrize("window", [1, 2], ids=["window1", "window2"])
+def test_tokens_are_those_of_an_engine_drained_after_every_step(
+        model, window, temperature):
+    """The conv states advance on the device from one launched window to
+    the next: every request's tokens are those of the engine that reads
+    each iteration back before it launches the next. Greedy requests
+    reuse two slots; sampled ones have a slot each."""
+    from test_unfenced_rounds import serve_pipelined_and_drained
+
+    tcfg, fused = program(model)
+    lens, budgets = (30, 5, 21, 12), (9, 5, 2, 7)
+    eng, reqs, ref, ref_reqs = serve_pipelined_and_drained(
+        lambda **kw: ServingEngine(fused, tcfg, max_len=128, prefill_chunk=8,
+                                   prefill_batch=2,
+                                   slots=4 if temperature else 2, **kw),
+        window=window, temperature=temperature, prompt_lens=lens,
+        budgets=budgets, vocab=TINY["vocab_size"])
+    assert [r.tokens for r in reqs] == [r.tokens for r in ref_reqs]
+    assert [len(r.tokens) for r in reqs] == list(budgets)
+    decode = eng.stats()["dispatch"]["decode"]
+    assert 0 < decode["pipelined"] <= decode["calls"]
+    assert ref.stats()["dispatch"]["decode"]["pipelined"] == 0
+    # the same tokens through the expert layers, in more or fewer
+    # dispatches where a slot frees a step later
+    for key in ("pairs_per_expert", "pairs_held", "decode_pairs",
+                "prefill_pairs", "pairs_total"):
+        assert eng.stats()["experts"][key] == ref.stats()["experts"][key]
+
+
+@pytest.mark.parametrize("window", [1, 2], ids=["window1", "window2"])
+def test_a_slot_freed_by_a_late_eos_holds_a_zero_conv_state(model, window):
+    """The window launched for a lane that had ended shifted its slot's
+    conv rows once more; the next tenant's first chunk reads zeros all the
+    same."""
+    from test_unfenced_rounds import reused_slot_after_a_late_eos
+
+    tcfg, fused = program(model)
+    got, fresh, engine = reused_slot_after_a_late_eos(
+        lambda: ServingEngine(fused, tcfg, slots=2, max_len=128,
+                              prefill_chunk=8, prefill_batch=2,
+                              decode_window=window),
+        prompts([10, 27, 19], seed=9))
+    assert got == fresh
+    assert engine.stats()["state"]["slots_reset"] == 3
 
 
 def test_a_lane_parked_in_mid_prefill_keeps_its_state(model):

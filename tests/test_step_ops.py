@@ -3,7 +3,9 @@ list, in the form the harness keeps (``trace_events.json``: host spans and
 one device's operations and programs on one clock, nanoseconds): a launch
 that returns before its program starts and one that returns after, a
 program with a gap between two operations, a ``while`` that holds its
-body, a device span cut by the window's edge and one with no program."""
+body, a device span cut by the window's edge and one with no program; rounds
+launched without a readback (ISSUE 41); iterations launched before the one
+ahead of them is read back (ISSUE 44)."""
 
 from __future__ import annotations
 
@@ -177,3 +179,79 @@ def test_a_round_without_a_readback_is_a_dispatch_of_its_own(modules):
     # lead 680 + tail 1550 + between 800
     assert table["accounted_s"] == pytest.approx(3030e-9)
     assert table["remainder_s"] == pytest.approx(1100e-9)
+
+
+def test_a_pipelined_iteration_is_fenced_by_the_next_spans_readback(modules):
+    """ISSUE 44: a decode span holds the launch of one iteration and the
+    readback of the one BEFORE. The trace begins with an iteration k0 on
+    the device that was launched before it (no launch span): programs go
+    to launches by order from the one moment the queue is known empty (a
+    fenced round's readback returns), so k0 is nobody's; each iteration
+    is fenced by the first decode readback that returns after it ended
+    (the last one's lies in a span that launches nothing); the host's wait
+    and work behind a program are idle only as far as no program was
+    launched behind it, so an iteration's ``tail`` and ``between`` are 0
+    and ``home`` says when its tokens came; what stays exposed is the
+    fenced round's ``tail``, the work behind it and the ``lead`` of the
+    iteration launched then."""
+    step_ops, xplane = modules
+    trace = {
+        "host_spans": [
+            ["bench:traced", 500, 8000],
+            # launches k1 behind k0, which still runs; reads k0 back
+            span("decode_device", 700, 2100),
+            span("decode_launch", 700, 1100),
+            span("decode_readback", 1150, 2000),
+            # R1, launched and left, then k2; k1 is read back
+            span("prefill_device", 2200, 2500),
+            span("prefill_launch", 2200, 2500),
+            span("decode_device", 2600, 3100),
+            span("decode_launch", 2600, 2900),
+            span("decode_readback", 2950, 3050),
+            # R2 holds a first token: fenced, behind k2
+            span("prefill_device", 3200, 6000),
+            span("prefill_launch", 3200, 3500),
+            span("prefill_readback", 3550, 5900),
+            span("emit", 6000, 6100),
+            span("decode_device", 6200, 6900),
+            span("decode_launch", 6200, 6500),
+            span("decode_readback", 6550, 6800),
+            # no lane is left: nothing launched, k3 read back
+            span("decode_device", 7000, 8000),
+            span("decode_readback", 7000, 7900),
+        ],
+        "devices": {"0": {
+            "modules": [["jit_decode_window(17)", 600, 1000],     # k0
+                        ["jit_decode_window(17)", 1600, 1000],    # k1
+                        ["jit_prefill_chunks(23)", 2600, 1000],   # R1
+                        ["jit_decode_window(17)", 3600, 1000],    # k2
+                        ["jit_prefill_chunks(23)", 4600, 1000],   # R2
+                        ["jit_decode_window(17)", 6700, 1000]],   # k3
+            "ops": [["fusion:f32[8]", start, 1000]
+                    for start in (600, 1600, 2600, 3600, 4600, 6700)],
+        }},
+    }
+    table = step_ops.dispatches(trace, xplane.reduce(trace), xplane)
+    rows = [[r[k] for k in ("program", "pipelined", "lead",
+                            "return_to_start", "tail", "between", "home")]
+            for r in table["rows"]]
+    assert rows == [
+        ["jit_decode_window", True, 0, 500, 0, 0, 450],
+        ["jit_prefill_chunks", False, 0, 100, None, None, None],
+        ["jit_decode_window", True, 0, 700, 0, 0, 2200],
+        ["jit_prefill_chunks", False, 0, 1100, 300, 300, 300],
+        ["jit_decode_window", True, 500, 200, 200, None, 200],
+    ]
+    assert [r["launch_start"] for r in table["rows"]] == \
+        [700, 2200, 2600, 3200, 6200]
+    assert table["unmatched_device_spans"] == 0
+    decode = table["programs"]["jit_decode_window"]
+    assert (decode["n"], decode["unfenced"], decode["pipelined"]) == (3, 0, 3)
+    prefill = table["programs"]["jit_prefill_chunks"]
+    assert (prefill["n"], prefill["unfenced"], prefill["pipelined"]) == \
+        (2, 1, 0)
+    # busy 600 -> 5600 and 6700 -> 7700 of 500 -> 8500
+    assert table["idle_s"] == pytest.approx(2000e-9)
+    # lead 500 + tail 500 + between 300; the edges 500 -> 600, 7900 -> 8500
+    assert table["accounted_s"] == pytest.approx(1300e-9)
+    assert table["remainder_s"] == pytest.approx(700e-9)

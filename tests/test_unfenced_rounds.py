@@ -1,9 +1,13 @@
-"""A prefill round that yields no first token is launched and left
-(``serving/scheduler.py``'s docstring): the decode iteration behind it, or
-an earlier round that does hold a first token, brings its counts home in
-its own readback, and a ``step()`` returns with nothing in flight. The
+"""What the engine leaves on the device past a dispatch
+(``serving/scheduler.py``'s docstring). A prefill round that yields no
+first token is launched and left: the step's decode dispatch, or a later
+round that does hold a first token, brings its counts home in its own
+readback. A decode iteration is launched before the one ahead of it is
+read back, and stays in flight past a ``step()`` that leaves a lane
+active; an engine with no lane active has nothing in flight. The
 programs, their arguments and the order of dispatches are those of an
-engine that fences every round, so every token is; ``step()`` is driven by
+engine that fences every round, and the tokens those of one that reads
+every iteration back before it launches the next; ``step()`` is driven by
 hand on toy models, so the counts are exact.
 """
 
@@ -12,6 +16,10 @@ from __future__ import annotations
 import jax
 import numpy as np
 import pytest
+
+from tony_tpu.models import init_params
+from tony_tpu.observability.metrics import MetricsRegistry
+from tony_tpu.serving import ServingEngine
 
 from test_engine_spans import _drive, _engine, _layered_engine, _spans
 
@@ -22,6 +30,32 @@ def _fence_every_round(eng) -> None:
     """The engine as it was before rounds went unfenced: no round is told
     that a fence follows it."""
     eng._fence_follows = lambda last: False
+
+
+def _drain_every_step(eng) -> None:
+    """The engine as it was before iterations were pipelined: every
+    iteration is read back in the step that launched it, so the next one
+    is launched knowing every token."""
+    step = eng.step
+
+    def drained() -> bool:
+        working = step()
+        if eng._flight is not None:
+            eng._decode_dispatch(0, np.zeros(eng.slots, bool))
+        return working
+
+    eng.step = drained
+
+
+def _nothing_left_behind(eng) -> None:
+    """The invariant between steps: an iteration is in flight only with a
+    lane active (the next step reads it back), and unfenced rounds'
+    counts wait only for such an iteration's readback."""
+    if not eng._active.any():
+        assert eng._flight is None
+    if eng._flight is None:
+        assert eng._in_flight == []
+        assert not eng._ahead.any()
 
 
 def _record_dispatches(eng) -> list:
@@ -57,9 +91,10 @@ def _serve(model: str, fence_all: bool, window: int = 1,
         if all(r.done() for r in reqs):
             break
         eng.step()
-        assert eng._in_flight == []
+        _nothing_left_behind(eng)
     else:
         raise AssertionError("requests did not retire")
+    assert eng.step() is False and eng._flight is None
     eng.close()
     return eng, reqs, calls
 
@@ -101,18 +136,20 @@ def _count_device_gets(monkeypatch) -> list:
 
 def test_one_readback_brings_the_window_and_the_round_home(monkeypatch):
     """A step with one round without a first token and an active slot
-    calls ``jax.device_get`` once: the window's tokens, the iteration's
-    counts and the round's counts come back together."""
+    calls ``jax.device_get`` once, after it has launched its iteration:
+    the tokens and counts of the iteration launched the step before and
+    the round's counts come back together."""
     eng = _layered_engine(slots=2, prefill_chunk=4, prefill_batch=2,
                           max_len=64, decode_window=2, kv_quant="none")
     eng.submit(np.arange(3, dtype=np.int32), 9)
-    eng.step()                       # its one chunk, fenced; then it decodes
-    assert eng._active.sum() == 1
+    eng.step()          # its one chunk, fenced; its first iteration launched
+    assert eng._active.sum() == 1 and eng._flight is not None
     eng.submit(np.arange(13, dtype=np.int32), 2)     # four chunks
     before = eng.stats()
     gets = _count_device_gets(monkeypatch)
     eng.step()
     assert len(gets) == 1 and eng._in_flight == []
+    assert eng._flight is not None and eng.stats()["decode_iterations"] == 2
     (toks, counts), flown = gets[0]
     assert np.asarray(toks).shape == (2, 2)
     assert len(flown) == 1
@@ -153,7 +190,7 @@ def test_a_step_that_decodes_nothing_fences_its_last_round(monkeypatch,
     gets = _count_device_gets(monkeypatch)
     for step in range(3):            # chunks 1..3 of 4: no first token
         eng.step()
-        assert eng._in_flight == []
+        assert eng._in_flight == [] and eng._flight is None
         assert len(gets) == step + 1
         # the first round's counts, brought home by the second's readback
         assert len(gets[-1][1]) == (model == "layered")
@@ -167,7 +204,8 @@ def test_a_step_that_decodes_nothing_fences_its_last_round(monkeypatch,
         assert st["experts"]["dispatches"] == 6
         assert st["experts"]["pairs_total"] == 6 * 4 * 2 * 3
     _drive(eng, reqs)
-    assert eng._in_flight == []
+    _nothing_left_behind(eng)
+    assert not eng._active.any()
 
 
 @pytest.mark.parametrize("prompts,batch,rounds", [
@@ -290,5 +328,277 @@ def test_a_readback_that_raises_fails_every_pending_request(
         assert eng.stats()["dispatch"]["prefill"]["unfenced"] == unfenced
         with pytest.raises(RuntimeError, match="shut down"):
             eng.submit(np.arange(3, dtype=np.int32), 2)
+    finally:
+        eng.close()
+
+
+# -- decode iterations pipelined one deep --------------------------------------
+PROMPT_LENS = (5, 9, 13, 17, 3, 22)
+BUDGETS = (7, 2, 5, 1, 9, 4)
+
+
+def serve_pipelined_and_drained(make, *, window: int,
+                                temperature: float = 0.0, eos=None,
+                                prompt_lens=PROMPT_LENS, budgets=BUDGETS,
+                                vocab: int = 64):
+    """The same requests through an engine as it is and through one
+    drained after every step; ``make(decode_window=)`` builds one. Returns
+    both engines (closed) and both lists of requests."""
+    served = []
+    for drained in (False, True):
+        eng = make(decode_window=window)
+        if drained:
+            _drain_every_step(eng)
+        rng = np.random.default_rng(3)
+        reqs = [eng.submit(rng.integers(0, vocab, n).astype(np.int32), new,
+                           temperature=temperature,
+                           eos_id=None if eos is None else eos[i])
+                for i, (n, new) in enumerate(zip(prompt_lens, budgets))]
+        for _ in range(500):
+            if all(r.done() for r in reqs):
+                break
+            eng.step()
+            _nothing_left_behind(eng)
+        else:
+            raise AssertionError("requests did not retire")
+        assert eng.step() is False and eng._flight is None
+        eng.close()
+        served.append((eng, reqs))
+    (eng, reqs), (ref, ref_reqs) = served
+    return eng, reqs, ref, ref_reqs
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "t0.8"])
+@pytest.mark.parametrize("window", [1, 2], ids=["window1", "window2"])
+@pytest.mark.parametrize("model", ["uniform", "layered"])
+def test_tokens_are_those_of_an_engine_drained_after_every_step(
+        model, window, temperature):
+    """Mixed prompt and output lengths: every request's tokens are those
+    of the engine that reads each iteration back before it launches the
+    next (the order before the pipeline). Greedy requests share three
+    slots, so slots are reused; sampled ones have a slot each, since a
+    slot freed a step later would shift the draws of those behind it."""
+    eng, reqs, ref, ref_reqs = serve_pipelined_and_drained(
+        lambda **kw: MAKE[model](slots=6 if temperature else 3,
+                                 prefill_chunk=4, prefill_batch=2,
+                                 max_len=64, kv_quant="none", **kw),
+        window=window, temperature=temperature)
+    assert [r.tokens for r in reqs] == [r.tokens for r in ref_reqs]
+    assert [len(r.tokens) for r in reqs] == list(BUDGETS)
+    decode, theirs = (e.stats()["dispatch"]["decode"] for e in (eng, ref))
+    assert 0 < decode["pipelined"] <= decode["calls"]
+    assert theirs["pipelined"] == 0
+    assert decode["discarded_tokens"] == theirs["discarded_tokens"] == 0
+    assert eng.tokens_generated == sum(BUDGETS)
+    if temperature:      # a slot each: the same launches step for step
+        assert decode["calls"] == theirs["calls"]
+
+
+def _eos_in_the_middle(make, window: int):
+    """``eos_id`` per request of PROMPT_LENS / BUDGETS taken from its own
+    greedy continuation (its middle token; None where it makes fewer than
+    three), and where that token FIRST occurs in it."""
+    eng = make(decode_window=window)
+    rng = np.random.default_rng(3)
+    reqs = [eng.submit(rng.integers(0, 64, n).astype(np.int32), new)
+            for n, new in zip(PROMPT_LENS, BUDGETS)]
+    _drive(eng, reqs)
+    eng.close()
+    eos = [r.tokens[len(r.tokens) // 2] if len(r.tokens) >= 3 else None
+           for r in reqs]
+    ends = [None if e is None else r.tokens.index(e)
+            for r, e in zip(reqs, eos)]
+    return reqs, eos, ends
+
+
+@pytest.mark.parametrize("window", [1, 2], ids=["window1", "window2"])
+@pytest.mark.parametrize("model", ["uniform", "layered"])
+def test_a_request_that_ends_by_eos_is_found_one_iteration_late(model,
+                                                                window):
+    """Its tokens are the drained engine's (its continuation up to the
+    EOS), the window launched for it meanwhile is counted in
+    ``discarded_tokens`` and in nothing else, and ``tokens_generated`` is
+    what the clients received."""
+    def make(**kw):
+        return MAKE[model](slots=3, prefill_chunk=4, prefill_batch=2,
+                           max_len=64, kv_quant="none", **kw)
+
+    plain, eos, ends = _eos_in_the_middle(make, window)
+    assert sum(e is not None for e in eos) >= 3
+    eng, reqs, ref, ref_reqs = serve_pipelined_and_drained(
+        make, window=window, eos=eos)
+    assert [r.tokens for r in reqs] == [r.tokens for r in ref_reqs] == \
+        [p.tokens if at is None else p.tokens[:at + 1]
+         for p, at in zip(plain, ends)]
+    # Token ``at`` (0 is the prompt's first token) comes home in window
+    # ceil(at / w) of ceil((budget - 1) / w) that the count allows: one
+    # more was launched unless it was the last, or the first token itself.
+    late = sum(at is not None and 0 < at
+               and -(-at // window) < -(-(new - 1) // window)
+               for at, new in zip(ends, BUDGETS))
+    assert late > 0
+    decode = eng.stats()["dispatch"]["decode"]
+    assert decode["discarded_tokens"] == late * window
+    assert ref.stats()["dispatch"]["decode"]["discarded_tokens"] == 0
+    assert eng.tokens_generated == ref.tokens_generated == \
+        sum(len(r.tokens) for r in reqs)
+    assert eng.stats()["retired"] == len(reqs)
+
+
+def reused_slot_after_a_late_eos(make, prompts, new: int = 12):
+    """Two slots: A decodes for long, B ends by an EOS from its own
+    continuation while its next window is already launched, and C takes
+    B's slot with that window still in flight. Returns C's tokens, C's
+    from an engine that served nothing else, and the engine."""
+    a, b, c = prompts
+    alone = []
+    for prompt in (b, c):
+        eng = make()
+        req = eng.submit(prompt, new)
+        _drive(eng, [req])
+        eng.close()
+        alone.append(req.tokens)
+    eos = alone[0][3]
+    assert alone[0].index(eos) > 0           # not the prompt's first token
+    eng = make()
+    ra = eng.submit(a, 4 * new)
+    rb = eng.submit(b, new, eos_id=eos)
+    rc = eng.submit(c, new)
+    seen_in_flight = False
+    for _ in range(500):
+        if rc.done():
+            break
+        eng.step()
+        _nothing_left_behind(eng)
+        if rb.done() and not rc.tokens and eng._flight is not None:
+            # B's window is on its way while its slot is C's or free
+            seen_in_flight |= any(req is rb for _, req in eng._flight.lanes)
+    assert seen_in_flight and not ra.done()
+    assert rb.tokens == alone[0][:alone[0].index(eos) + 1]
+    assert eng.stats()["dispatch"]["decode"]["discarded_tokens"] > 0
+    eng.close()
+    return rc.tokens, alone[1], eng
+
+
+@pytest.mark.parametrize("window", [1, 2], ids=["window1", "window2"])
+@pytest.mark.parametrize("model", ["uniform", "layered"])
+def test_a_slot_freed_by_a_late_eos_is_clean_for_its_next_tenant(model,
+                                                                 window):
+    """Full rows and ring rows: the window launched for a lane that had
+    ended wrote into its own slot, and the next tenant's chunks overwrite
+    before they read."""
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, 64, n).astype(np.int32) for n in (6, 21, 9)]
+    got, fresh, _ = reused_slot_after_a_late_eos(
+        lambda: MAKE[model](slots=2, prefill_chunk=4, prefill_batch=2,
+                            max_len=64, decode_window=window,
+                            kv_quant="none"), prompts)
+    assert got == fresh
+
+
+def test_close_drops_the_iteration_in_flight():
+    eng = _engine(slots=2, prefill_chunk=4, max_len=64)
+    req = eng.submit(np.arange(3, dtype=np.int32), 9)
+    eng.step()
+    eng.step()
+    assert eng._flight is not None and not req.done()
+    eng.close()
+    assert eng._flight is None and eng._in_flight == []
+    assert not eng._ahead.any()
+    with pytest.raises(RuntimeError, match="engine shut down"):
+        req.result(timeout=1)
+
+
+def test_drain_waits_for_the_last_iteration():
+    eng = _layered_engine(slots=2, prefill_chunk=4, prefill_batch=2,
+                          max_len=64, kv_quant="none")
+    eng.start()
+    try:
+        reqs = [eng.submit(np.arange(n, dtype=np.int32), 5) for n in (3, 14)]
+        assert eng.drain(timeout=120)
+        assert all(len(r.result(timeout=1)["tokens"]) == 5 for r in reqs)
+        assert eng._flight is None and eng._in_flight == []
+        decode = eng.stats()["dispatch"]["decode"]
+        assert 0 < decode["pipelined"] <= decode["calls"]
+    finally:
+        eng.close()
+
+
+def test_a_model_is_switched_with_nothing_in_flight(monkeypatch):
+    """Every lane ends by EOS with its next window launched: the engine
+    reads that window back in the same step, so the swap at the idle
+    boundary that follows finds nothing of the old weights on its way."""
+    eng = _engine(slots=2, prefill_chunk=4, max_len=64)
+    other = init_params(jax.random.key(5), eng.cfg)
+    eng.add_model("other", other)
+    prompt = np.arange(5, dtype=np.int32)
+    plain = eng.submit(prompt, 6)
+    _drive(eng, [plain])
+    switched = []
+    real = eng._switch_model
+
+    def checked(name):
+        switched.append((name, eng._flight, list(eng._in_flight)))
+        real(name)
+
+    monkeypatch.setattr(eng, "_switch_model", checked)
+    first = eng.submit(prompt, 6, eos_id=plain.tokens[2])
+    second = eng.submit(prompt, 4, model="other")
+    _drive(eng, [first, second])
+    assert first.tokens == plain.tokens[:plain.tokens.index(plain.tokens[2])
+                                        + 1]
+    assert switched == [("other", None, [])]
+    assert eng.stats()["dispatch"]["decode"]["discarded_tokens"] == 1
+    fresh = ServingEngine(other, eng.cfg, slots=2, prefill_chunk=4,
+                          max_len=64, registry=MetricsRegistry())
+    want = fresh.submit(prompt, 4)
+    _drive(fresh, [want])
+    assert second.tokens == want.tokens
+
+
+def _fail_the_decode_readback(monkeypatch) -> None:
+    real = jax.device_get
+
+    def failing(x):
+        own, _flights = x
+        if getattr(own[0], "ndim", 0) == 2:      # a window's [S, w] tokens
+            raise RuntimeError("the device fell over")
+        return real(x)
+
+    monkeypatch.setattr(jax, "device_get", failing)
+
+
+def test_a_failing_iteration_raises_in_the_step_after_its_launch(
+        monkeypatch):
+    eng = _engine(slots=2, prefill_chunk=4, max_len=64)
+    eng.submit(np.arange(3, dtype=np.int32), 9)
+    _fail_the_decode_readback(monkeypatch)
+    eng.step()                  # the prompt's one chunk; iteration 1 launched
+    assert eng.stats()["decode_iterations"] == 1 and eng._flight is not None
+    with pytest.raises(RuntimeError, match="the device fell over"):
+        eng.step()              # launches iteration 2, reads iteration 1 back
+    assert eng.stats()["decode_iterations"] == 2
+    eng.close()
+    assert eng._flight is None
+
+
+def test_a_dead_loop_leaves_nothing_in_flight(monkeypatch):
+    """The iteration's error reaches every pending request through the
+    loop-death path, which drops what was launched behind it."""
+    eng = _layered_engine(slots=2, prefill_chunk=4, prefill_batch=2,
+                          max_len=64, max_queue=8, kv_quant="none")
+    _fail_the_decode_readback(monkeypatch)
+    reqs = [eng.submit(np.arange(n, dtype=np.int32), 6) for n in (3, 14, 5)]
+    eng.start()
+    try:
+        for req in reqs:
+            with pytest.raises(RuntimeError, match="engine loop failed: "
+                                                   "the device fell over"):
+                req.result(timeout=60)
+        eng._thread.join(timeout=30)
+        assert not eng._thread.is_alive()
+        assert eng._stop.is_set()
+        assert eng._flight is None and eng._in_flight == []
+        assert eng.stats()["decode_iterations"] == 2
     finally:
         eng.close()

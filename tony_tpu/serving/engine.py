@@ -728,7 +728,7 @@ def _sample_slots(logits, temp, key):
     jax.jit, static_argnames=("cfg", "steps"), donate_argnums=(1, 2)
 )
 def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
-                  base_key, draw0, cfg: TransformerConfig,
+                  base_key, draw0, prev=None, *, cfg: TransformerConfig,
                   steps: int = 1):
     """``steps`` decode iterations for every slot in ONE dispatch: feed
     ``tokens`` [S] at each slot's own ``pos``, write the new K/V row at
@@ -741,12 +741,20 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
     lane-steps per retiring stream.
 
     pos/wpos/temp live on the HOST between windows (tiny [S] arrays;
-    the scheduler mutates them freely on admit/retire) and ride in as
-    arguments; only the KV caches are device-resident state (donated —
-    the caller must adopt the returned buffers). Sampling keys derive
-    INSIDE the jit (``fold_in(base_key, draw0 + i)`` — a host-side
-    fold_in is a whole extra dispatch per iteration), so the schedule
-    is positional and reproducible from (seed, draw counter).
+    the scheduler mutates them freely on admit/retire, and they advance
+    by rule, not by what was sampled) and ride in as arguments. The fed
+    tokens do so only where the host has one the device has not: with
+    ``prev`` (the window before this one, ``[S, steps]`` as it was
+    returned and never read back before this launch) a lane whose
+    ``tokens`` entry is negative is fed ``prev[:, -1]``, so the next
+    window can be launched while the last one is still on its way home;
+    a lane that became active since (a prompt's first token, shipped KV)
+    gives its token as a value >= 0. Without ``prev`` every entry of
+    ``tokens`` is fed as it is. The KV caches are device-resident state
+    (donated — the caller must adopt the returned buffers). Sampling
+    keys derive INSIDE the jit (``fold_in(base_key, draw0 + i)`` — a
+    host-side fold_in is a whole extra dispatch per iteration), so the
+    schedule is positional and reproducible from (seed, draw counter).
 
     Inactive slots still compute (the lane array is fixed) and still
     WRITE — the scheduler parks their ``wpos`` at ``Tmax - 1``, the one
@@ -775,6 +783,8 @@ def decode_window(params, k_all, v_all, pos, wpos, tokens, temp,
     t_max = _cache_tmax(_kind(k_all, _positions_kind(k_all)))
     ropes = rope_tables(cfg)
     scale = cfg.head_dim ** -0.5
+    if prev is not None:
+        tokens = jnp.where(tokens < 0, prev[:, -1], tokens)
 
     def one_step(carry, i):
         k_all, v_all, pos, wpos, tokens = carry

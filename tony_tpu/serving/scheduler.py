@@ -9,10 +9,22 @@ thread. Each iteration:
 2. **prefill** — run at most ``prefill_chunks_per_iter`` bounded chunks
    of admitted prompts (chunked so a long prompt can never stall the
    in-flight decode streams for more than a chunk's worth of compute);
-3. **decode** — a ``decode_window`` for every slot; read the sampled
-   tokens back, append to each active request, and retire sequences at
-   EOS (or their token budget), returning the slot to the pool —
-   immediately at window 1, within the window otherwise.
+3. **decode** — LAUNCH a ``decode_window`` for every slot, then read
+   the sampled tokens of the window launched the iteration BEFORE back,
+   append them to each active request, and retire sequences at EOS (or
+   their token budget), returning the slot to the pool. Decode
+   iterations are pipelined one deep: the last tokens stay on the device
+   (``decode_window``'s ``prev``), positions and the draw counter
+   advance by rule, so the emit loop, the gauges, the next admission,
+   the next iteration's prefill rounds and its launch all run while a
+   program is on the device. A sequence that ends by its token budget is
+   not launched again (a count needs no token's value); one that ends by
+   EOS is found when its window is home, one iteration late: the window
+   launched for it meanwhile is discarded whole, as a deep window's
+   tokens past a retirement are (never appended, never counted in
+   ``tokens_generated``; ``discarded_tokens``), and what it wrote lies in
+   rows and states of its own slot that the next tenant's first chunk
+   overwrites or resets before it reads.
 
 Requests of different lengths therefore share every decode iteration
 (iteration-level scheduling), and wall throughput tracks the marginal
@@ -44,13 +56,20 @@ and trace reductions match on them::
                                          the first tokens (attrs d2h_bytes,
                                          first_tokens)
         tony:engine.emit               first tokens, retirements
-      tony:engine.decode_device    dispatch -> readback returned
-                                   (attrs slots, window, keys_read,
-                                   expert_pairs*, conv_layers**)
+      tony:engine.decode_device    the launch of one iteration and the
+                                   readback of the one before it (attrs
+                                   slots, window, keys_read: of the one
+                                   launched; pipelined: it was launched
+                                   with the one before still to read;
+                                   expert_pairs*, sparse_keys_read: of
+                                   what came home; conv_layers**)
         tony:engine.decode_launch    -> the jitted call returned
-                                     (attrs h2d_arrays, h2d_bytes)
-        tony:engine.decode_readback  device_get of the window's tokens
-                                     (attr d2h_bytes)
+                                     (attrs h2d_arrays, h2d_bytes); none
+                                     where no lane goes on (slots 0)
+        tony:engine.decode_readback  device_get of the tokens of the
+                                     window launched before (attr
+                                     d2h_bytes); none where nothing was
+                                     in flight (the pipeline fills)
       tony:engine.emit             the per-token loop, retirements
       tony:engine.publish          gauges + registry report
 
@@ -61,22 +80,36 @@ enqueue; ``h2d_*``: the numpy values among the call's arguments and
 their ``nbytes``), ``*_readback`` is the fenced ``jax.device_get`` of
 what the host needs back (``d2h_bytes``; a prefill round's
 ``first_tokens``: its entries at their last chunk, the only ones whose
-token is used); the bookkeeping after it is the parent's own.
+token is used); the bookkeeping after it is the parent's own. A decode
+span's two halves belong to two iterations: it launches iteration k+1
+and THEN reads iteration k back, so its readback waits for a program
+that an earlier span launched (``tools/step_ops.py`` joins them).
 
 **A prefill round is fenced only where the host needs something of it
 now**: an entry at its last chunk (its first token), or a ``step()``
-that would otherwise end with the round still on its way (no later round
-and no decode iteration to fence it). Any other round (``fenced=False``)
+that would otherwise end with the round still on its way and nothing
+to read it back later (no later round and no lane active, so no decode
+dispatch). Any other round (``fenced=False``)
 is launched and left: its device span is its launch alone, with no
 ``*_readback`` child, and the engine goes on to the next dispatch, whose
 arguments are host arrays that only a first token changes and caches
 that chain on the device from one jitted call to the next. What such a
 round would have brought home (a model with experts: its counts) waits
-in ``_in_flight`` and comes home IN the next fenced ``device_get`` of
-the same step, which is where the wait for the round is counted; the
-span that fences carries ``expert_pairs`` for itself and for what it
-brought home. A ``step()`` returns with nothing in flight, so a device
-error of an unfenced round surfaces inside the step that launched it.
+in ``_in_flight`` and comes home IN the next fenced ``device_get``,
+which is where the wait for the round is counted; the span that fences
+carries ``expert_pairs`` for what it brought home.
+
+**What is in flight when ``step()`` returns**: a decode iteration
+(``_flight``), only past a step that leaves a lane active, and with it
+the counts of that step's unfenced rounds where nothing was read back in
+it (the pipeline was filling). The next step's decode dispatch reads
+both back, so a device error of an iteration or a round surfaces no
+later than the step after the one that launched it. Where the emit loop
+leaves no lane active the iteration just launched is read back in the
+same step (its tokens are nobody's), so an engine with no active lane —
+an idle one (``step()`` returns False), one about to swap models, one
+drained — has nothing in flight; ``close()`` and the loop-death path
+wait for what is left and drop it.
 
 Per request, written when it retires and joined by ``request=``:
 ``tony:request.queue`` (submit -> slot), ``tony:request.prefill`` (slot
@@ -86,15 +119,22 @@ shipped KV) and ``tony:request.decode`` (first token -> done, attr
 the counters taken at the same boundaries (``phase_ms``: the two device
 spans; ``kv``, ``queue_wait_ms`` ...), and under ``stats()["dispatch"]``
 the split of each program's dispatches: ``{"decode": {calls, launch_ms,
-readback_ms, h2d_bytes, d2h_bytes}, "prefill": {the same five,
-rounds_without_first_token, unfenced}}`` — ``launch_ms + readback_ms`` is
-at most ``phase_ms``' device phase, ``calls`` are ``decode_iterations``
-and ``prefill_rounds``, a round without a first token is one that has
+readback_ms, h2d_bytes, d2h_bytes, pipelined, discarded_tokens},
+"prefill": {the same five, rounds_without_first_token, unfenced}}`` —
+``launch_ms + readback_ms`` is at most ``phase_ms``' device phase,
+``calls`` are ``decode_iterations`` (those LAUNCHED) and
+``prefill_rounds``, ``pipelined`` of the iterations were launched while
+the one before had not been read back (``pipelined <= calls``: all but
+the first since the engine last had no active lane),
+``discarded_tokens`` are the tokens of windows launched for lanes found
+ended one iteration late, a round without a first token is one that has
 nothing for the engine but the experts' counts, and ``unfenced`` of them
 were launched without a readback of their own (the rest closed a step
-that ran no decode iteration). ``phase_ms.prefill_device`` holds an
-unfenced round's launch alone; the bytes its counts bring home are
-prefill's ``d2h_bytes`` whichever program's readback carried them.
+that ran no decode dispatch). ``phase_ms.prefill_device`` holds an
+unfenced round's launch alone and ``phase_ms.decode_device`` a launch and
+the wait for the iteration before, not a program's run; the bytes a
+round's counts bring home are prefill's ``d2h_bytes`` whichever
+program's readback carried them.
 Idle polls record and count nothing.
 (*) A model with experts only: the dispatch's (token, choice) pairs on
 the experts held here, which come back with the tokens in the one
@@ -140,9 +180,10 @@ import logging
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Callable
+from typing import Callable, NamedTuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 log = logging.getLogger(__name__)
@@ -292,6 +333,16 @@ def _chunk_plan(prompt_len: int, chunk: int,
     return plan
 
 
+class _Iteration(NamedTuple):
+    """A decode iteration launched and not read back: what its readback
+    and its emit loop need of the launch."""
+
+    window: jax.Array       # [S, w] sampled tokens, on the device
+    counts: dict | None     # the experts' and sparse layers' counters
+    lanes: list[tuple[int, ServingRequest]]   # (slot, request) launched
+    pos: np.ndarray         # the positions those lanes were fed at
+
+
 class ServingEngine:
     """Continuous-batching engine over a fixed slot batch.
 
@@ -396,8 +447,22 @@ class ServingEngine:
         )
         self._pos = np.zeros(self.slots, np.int32)
         self._active = np.zeros(self.slots, bool)
-        self._last = np.zeros(self.slots, np.int32)
+        # A lane's next fed token where the host holds it (a prompt's
+        # first token, shipped KV); -1 where it is the last token of the
+        # window launched before, which stays on the device (``_window``).
+        self._last = np.full(self.slots, -1, np.int32)
         self._temp = np.zeros(self.slots, np.float32)
+        # Decode iterations are pipelined one deep (module docstring):
+        # ``_flight`` is the iteration launched and not read back,
+        # ``_window`` the tokens of the last one launched, ``_ahead`` a
+        # lane's tokens in flight (it is fed at ``_pos + _ahead``) and
+        # ``_left`` what its request's budget still allows to launch.
+        self._flight: _Iteration | None = None
+        self._window = jnp.zeros((self.slots, int(decode_window)), jnp.int32)
+        self._ahead = np.zeros(self.slots, np.int32)
+        self._left = np.zeros(self.slots, np.int32)
+        self._pipelined = 0
+        self._discarded_tokens = 0
         self._slot_req: list[ServingRequest | None] = [None] * self.slots
         self._queue: deque[ServingRequest] = deque()
         self._pf: deque[tuple[ServingRequest, int]] = deque()
@@ -801,8 +866,10 @@ class ServingEngine:
                 "working_wall_ms": self._working_wall_ns / 1e6,
                 "phase_ms": {k: self._span_ns[k] / 1e6 for k in _PHASES},
                 "dispatch": {
-                    "decode": self._dispatch_stats(
-                        "decode", self._decode_iters),
+                    "decode": dict(
+                        self._dispatch_stats("decode", self._decode_iters),
+                        pipelined=self._pipelined,
+                        discarded_tokens=self._discarded_tokens),
                     "prefill": dict(
                         self._dispatch_stats("prefill",
                                              self._prefill_rounds),
@@ -899,9 +966,13 @@ class ServingEngine:
             self._cond.notify_all()
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline and not self._stop.is_set():
-            s = self.stats()
-            if (s["queue_depth"] == 0 and s["active_slots"] == 0
-                    and s["prefilling"] == 0):
+            # A slot is its request's from admission to retirement: through
+            # a prefill round in progress (its entry is then in no queue)
+            # and while its last window is on its way home. With no slot
+            # taken no lane is active, so nothing is in flight.
+            with self._cond:
+                done = not self._queue and not any(self._slot_req)
+            if done:
                 self._zero_gauges()
                 return True
             time.sleep(0.05)
@@ -917,6 +988,7 @@ class ServingEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+        self._abandon_flight()
         with self._cond:
             pending = list(self._queue) + [
                 r for r in self._slot_req if r is not None
@@ -929,6 +1001,23 @@ class ServingEngine:
                 req.error = "engine shut down"
                 req._done.set()
         self._zero_gauges()
+
+    def _abandon_flight(self) -> None:
+        """Teardown (``close()``, a dead loop): what is on its way home
+        is nobody's any more. The iteration in flight is waited for, so
+        that nothing of the engine's still runs on the device, and
+        dropped with the unfenced rounds' counts; an error of it is the
+        caller's to report (a dead loop has, ``close()`` fails whatever
+        is pending anyway)."""
+        landing, self._flight = self._flight, None
+        self._in_flight = []
+        self._ahead[:] = 0
+        if landing is not None:
+            try:
+                jax.block_until_ready(landing.window)
+            except Exception:  # noqa: BLE001 — teardown must go on
+                log.debug("the decode iteration in flight failed",
+                          exc_info=True)
 
     def _zero_gauges(self) -> None:
         """A retired or drained replica must not leave stale
@@ -960,6 +1049,7 @@ class ServingEngine:
             # accepting work and every client long-polls to timeout.
             log.exception("serving engine loop died")
             self._stop.set()
+            self._abandon_flight()
             with self._cond:
                 pending = list(self._queue) + [
                     r for r in self._slot_req if r is not None
@@ -975,10 +1065,12 @@ class ServingEngine:
 
     # -- the iteration -----------------------------------------------------
     def step(self) -> bool:
-        """One engine iteration (admit -> prefill chunk(s) -> decode
-        window for all slots -> retire). Public so tests and the bench
-        can drive the loop without threads. Returns False when fully
-        idle."""
+        """One engine iteration (admit -> prefill chunk(s) -> launch the
+        next decode window for all slots -> read the one before it back
+        -> retire). Public so tests and the bench can drive the loop
+        without threads. Returns False when fully idle, and then nothing
+        is in flight: a decode iteration stays on its way only past a
+        step that leaves a lane active, which the next step reads."""
         with self._cond:
             waiting = bool(self._queue) or bool(self._pf)
         if not waiting and not self._active.any():
@@ -1013,70 +1105,86 @@ class ServingEngine:
         return working
 
     def _decode_some(self, step_start_ns: int) -> None:
-        """One ``decode_window`` for every slot, then the tokens to
-        their requests."""
+        """The step's decode dispatch: the next ``decode_window`` is
+        launched for every lane that goes on, THEN the one before it is
+        read back and its tokens go to their requests. A lane goes on
+        while its request's budget has a token left beyond those in
+        flight (a count, which needs no token's value); one that ends by
+        its ``eos_id`` is found when its window is home, one iteration
+        late. Where that leaves no lane active, the iteration just
+        launched is nobody's and is read back at once, so that an engine
+        without an active lane has nothing in flight."""
+        self._decode_dispatch(step_start_ns,
+                              self._active & (self._left > 0))
+        if self._flight is not None and not self._active.any():
+            self._decode_dispatch(step_start_ns,
+                                  np.zeros(self.slots, bool))
+
+    def _decode_dispatch(self, step_start_ns: int, lanes: np.ndarray) -> None:
+        """One ``tony:engine.decode_device`` span: the launch of an
+        iteration for ``lanes`` (where there is one) and the readback of
+        the iteration in flight (where there is one), then its emit
+        loop."""
         tr, it = self._tracer, self._it_ns
         w = self.decode_window
-        n_active = int(self._active.sum())
-        # Inactive lanes park their write at Tmax-1 (engine.py's
-        # wpos contract): writing at their stale pos would clobber
-        # a concurrent prefill into the same slot.
-        wpos = np.where(self._active, self._pos,
-                        np.int32(self.max_len - 1)).astype(np.int32)
+        landing, self._flight = self._flight, None
+        n_lanes = int(lanes.sum())
+        pos = self._pos + self._ahead
         keys_read = 0
-        if self._dc_read_block:
+        if n_lanes and self._dc_read_block:
             # step j of the window feeds position pos + j; a lane whose
             # write has reached Tmax - 1 is parked from that step on
-            at = self._pos[:, None] + np.arange(w)
+            at = pos[:, None] + np.arange(w)
             keys_read = self._full_layers * _engine.decode_read_positions(
-                at, ~self._active[:, None] | (at >= self.max_len - 1),
+                at, ~lanes[:, None] | (at >= self.max_len - 1),
                 self.max_len, self._dc_read_block)
             self._decode_keys_read += keys_read
             self._decode_keys_reserved += (self.slots * self.max_len * w
                                            * self._full_layers)
-        # Decode draws live in [0, 2**30), prefill draws in
-        # [2**30, 2**31): modular so a long-lived engine can neither
-        # overflow int32 nor cross domains (keys repeat only after
-        # 2**30 draws of the same kind — billions of tokens).
-        # Span covers dispatch AND the readback sync — the wall the
-        # chip actually spent on this window.
-        with tr.span("tony:engine.decode_device", slots=n_active,
+        # Span covers the launch AND the readback sync of the iteration
+        # before — the wall the host spent handing the chip its next
+        # window and waiting for its last.
+        with tr.span("tony:engine.decode_device", slots=n_lanes,
                      window=w, keys_read=keys_read,
+                     pipelined=bool(n_lanes and landing is not None),
                      **self._conv_attr) as sp, \
                 jit_sanitizer.step_region("serving_decode_window"):
-            with tr.span("tony:engine.decode_launch") as launch:
-                args = (self.params, self._k, self._v, self._pos, wpos,
-                        self._last, self._temp, self._base_key,
-                        np.int32((self._decode_calls * w) % 2**30))
-                launch.set(**self._count_h2d("decode", args))
-                self._k, self._v, window, expert_counts = self._decode(*args)
-            self._decode_calls += 1
-            # Iteration fence: EXPLICIT readback, so the armed
-            # transfer guard (jit sanitizer) lets it through. The
-            # experts' counters come back in the same readback, and so
-            # does what the step's unfenced prefill rounds left in flight.
-            with tr.span("tony:engine.decode_readback") as readback:
-                (toks, counts), landed = self._fence(  # tony: noqa[TONY-X002] — intended per-window fence
-                    "decode", (window, expert_counts), readback)
-            toks = np.asarray(toks)
-            if counts is not None and "sparse_keys" in counts:
-                # The selection's own count, made on the device where the
-                # blocks are listed, against every key up to the queries.
-                read = int(counts.pop("sparse_keys"))
-                at = self._pos[self._active].astype(np.int64)
-                live = (int(w * (at + 1).sum()) + n_active * w * (w - 1) // 2
-                        ) * self._sparse_groups
-                self._sparse_keys_read += read
-                self._sparse_keys_live += live
-                sp.set(sparse_keys_read=read)
-            if counts:
-                sp.set(expert_pairs=landed + self._note_pairs(
-                    "decode", counts, n_active * w))
-        it["decode_device"] = sp.dur_ns
-        it["decode_launch"] = launch.dur_ns
-        it["decode_readback"] = readback.dur_ns
-        self._decode_iters += 1
-        self._decode_slots_sum += n_active
+            if n_lanes:
+                with tr.span("tony:engine.decode_launch") as launch:
+                    self._launch_iteration(lanes, pos, launch)
+                it["decode_launch"] += launch.dur_ns
+                self._decode_iters += 1
+                self._decode_slots_sum += n_lanes
+                self._pipelined += landing is not None
+            if landing is not None:
+                # Iteration fence: EXPLICIT readback, so the armed
+                # transfer guard (jit sanitizer) lets it through. The
+                # experts' counters come back in the same readback, and
+                # so does what the unfenced prefill rounds left in flight.
+                with tr.span("tony:engine.decode_readback") as readback:
+                    (toks, counts), landed = self._fence(  # tony: noqa[TONY-X002] — intended per-window fence
+                        "decode", (landing.window, landing.counts), readback)
+                it["decode_readback"] += readback.dur_ns
+                n_landed = len(landing.lanes)
+                if counts is not None and "sparse_keys" in counts:
+                    # The selection's own count, made on the device where
+                    # the blocks are listed, against every key up to the
+                    # queries.
+                    read = int(counts.pop("sparse_keys"))
+                    at = landing.pos.astype(np.int64)
+                    live = (int(w * (at + 1).sum())
+                            + n_landed * w * (w - 1) // 2
+                            ) * self._sparse_groups
+                    self._sparse_keys_read += read
+                    self._sparse_keys_live += live
+                    sp.set(sparse_keys_read=read)
+                if counts:
+                    sp.set(expert_pairs=landed + self._note_pairs(
+                        "decode", counts, n_landed * w))
+        it["decode_device"] += sp.dur_ns
+        if landing is None:
+            return
+        toks = np.asarray(toks)
         wall_ms = (sp.end_ns - step_start_ns) / 1e6
         # Recorded PER TOKEN (wall / window): with a deep window the
         # client sees bursts, but the sustained per-stream gap is
@@ -1085,8 +1193,14 @@ class ServingEngine:
         self.inter_token_ms_samples.append(wall_ms / w)
         with tr.span("tony:engine.emit"):
             n_new = 0
-            for s in np.flatnonzero(self._active):
-                req = self._slot_req[s]
+            for s, req in landing.lanes:
+                if self._slot_req[s] is not req:
+                    # Its request ended by EOS in the window before, which
+                    # came home after this one was launched: these tokens
+                    # are nobody's (the slot may have a new tenant).
+                    self._discarded_tokens += w
+                    continue
+                self._ahead[s] -= w
                 for j in range(w):
                     tok = int(toks[s, j])
                     req.tokens.append(tok)
@@ -1100,10 +1214,41 @@ class ServingEngine:
                         break
                 else:
                     self._pos[s] += w
-                    self._last[s] = int(toks[s, -1])
             self._c_tokens.inc(n_new)
             self._n_tokens += n_new
             self._note_rate(n_new)
+
+    def _launch_iteration(self, lanes: np.ndarray, pos: np.ndarray,
+                          launch) -> None:
+        """Enqueue one ``decode_window`` for ``lanes`` fed at ``pos``,
+        behind whatever is on the device. Its arguments are what was
+        LAUNCHED before, advanced by rule: nothing of the iteration in
+        flight is waited for. The host arrays go up as copies, since
+        admissions and retirements write to them while the call may not
+        have taken them yet."""
+        w = self.decode_window
+        # Inactive lanes park their write at Tmax-1 (engine.py's
+        # wpos contract): writing at their stale pos would clobber
+        # a concurrent prefill into the same slot.
+        wpos = np.where(lanes, pos,
+                        np.int32(self.max_len - 1)).astype(np.int32)
+        # Decode draws live in [0, 2**30), prefill draws in
+        # [2**30, 2**31): modular so a long-lived engine can neither
+        # overflow int32 nor cross domains (keys repeat only after
+        # 2**30 draws of the same kind — billions of tokens).
+        args = (self.params, self._k, self._v, pos, wpos,
+                self._last.copy(), self._temp.copy(), self._base_key,
+                np.int32((self._decode_calls * w) % 2**30), self._window)
+        launch.set(**self._count_h2d("decode", args))
+        self._k, self._v, self._window, counts = self._decode(*args)
+        self._decode_calls += 1
+        self._flight = _Iteration(
+            self._window, counts,
+            [(s, self._slot_req[s]) for s in np.flatnonzero(lanes)],
+            pos[lanes])
+        self._last[lanes] = -1
+        self._ahead[lanes] += w
+        self._left[lanes] -= w
 
     def _publish(self, decoded: bool) -> tuple[int, int, int]:
         """End of an iteration: gauges and the registry report. Returns
@@ -1195,8 +1340,6 @@ class ServingEngine:
         directly — the decode half of the prefill/decode split. One
         targeted ``.at[:, slot, :pos]`` write per request; runs off the
         decode hot path (admission), outside the engine condition."""
-        import jax.numpy as jnp
-
         kv_k, kv_v, pos, last = req._inject
         self._k = _engine.cache_inject_rows(
             self._k, slot, jax.tree.map(jnp.asarray, kv_k)
@@ -1204,8 +1347,16 @@ class ServingEngine:
         self._v = _engine.cache_inject_rows(
             self._v, slot, jax.tree.map(jnp.asarray, kv_v)
         )
+        self._enter_decode(slot, pos, last, req.max_new_tokens)
+
+    def _enter_decode(self, slot: int, pos: int, token: int,
+                      left: int) -> None:
+        """A slot's lane joins the decode iterations from the next launch
+        on: fed ``token``, which the host holds, at ``pos``, for ``left``
+        tokens of its request's budget."""
         self._pos[slot] = pos
-        self._last[slot] = last
+        self._last[slot] = token
+        self._left[slot] = left
         self._active[slot] = True
 
     def _prefill_some(self) -> bool:
@@ -1236,11 +1387,13 @@ class ServingEngine:
         return True
 
     def _fence_follows(self, last: bool) -> bool:
-        """Whether a dispatch later in this ``step()`` is fenced, so that
-        a prefill round may be launched and left: a later round (the
-        step's last one asks here again), or the decode iteration, which
-        runs where a slot is active — and within a step's rounds a slot
-        only ever becomes active."""
+        """Whether a fence follows a prefill round, so that it may be
+        launched and left: a later round (the step's last one asks here
+        again), or the decode dispatch, which runs where a slot is active
+        — and within a step's rounds a slot only ever becomes active. It
+        reads the iteration in flight back or, where there is none,
+        leaves its own in flight with a lane active, which the next step
+        reads back."""
         return not last or bool(self._active.any())
 
     def _prefill_round(
@@ -1338,8 +1491,6 @@ class ServingEngine:
                 ttft = (now - req.t_submit) * 1000.0
                 self._h_ttft.observe(ttft)
                 self.ttft_ms_samples.append(ttft)
-                self._pos[slot] = req.prompt.size
-                self._last[slot] = first
                 req.tokens.append(first)
                 self._c_tokens.inc()
                 self._n_tokens += 1
@@ -1363,7 +1514,8 @@ class ServingEngine:
                         or req.max_new_tokens <= 1):
                     self._retire(slot)
                 else:
-                    self._active[slot] = True
+                    self._enter_decode(slot, req.prompt.size, first,
+                                       req.max_new_tokens - 1)
             if requeue:
                 with self._cond:
                     self._pf.extend(requeue)
@@ -1421,6 +1573,7 @@ class ServingEngine:
         req = self._slot_req[slot]
         decoded = bool(self._active[slot])   # else it ends at prefill
         self._active[slot] = False
+        self._ahead[slot] = self._left[slot] = 0
         self._slot_req[slot] = None
         # Reset the lane temperature: a stale hot value would keep the
         # all-greedy lax.cond fast path disabled (threefry over [S, V]

@@ -17,6 +17,7 @@ library's lock. Nothing runs; no time comes from this."""
 
 from __future__ import annotations
 
+import inspect
 import json
 import os
 import re
@@ -143,10 +144,15 @@ def main() -> int:
         slots, t_max = run["slots"], run["max_seq"]
         k, v = on_chip(jax.eval_shape(lambda: engine.init_slot_cache(
             tcfg, slots, t_max, prefill_chunk=chunk)))
+        # as the scheduler calls it: since PR 44 with the window before
+        # (``prev``), whose last tokens a lane is fed where the host has none
+        prev = ((i32(slots, 1),) if "prev" in inspect.signature(
+            engine.decode_window).parameters else ())
         programs = {
             "decode_window": engine.decode_window.lower(
                 fused, k, v, i32(slots), i32(slots), i32(slots),
-                sds((slots,), jnp.float32), key, i32(), cfg=tcfg, steps=1),
+                sds((slots,), jnp.float32), key, i32(), *prev, cfg=tcfg,
+                steps=1),
             "prefill_chunks": engine.prefill_chunks.lower(
                 fused, k, v, i32(4, chunk), i32(4), i32(4), i32(4),
                 sds((4,), jnp.float32), key, i32(), cfg=tcfg),

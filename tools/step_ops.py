@@ -23,8 +23,13 @@ program (``lead``, and ``return_to_start`` against the launch span's
 end), inside it (``bubbles``), after it (``tail``) and between two
 dispatches (``between``); a prefill round launched without a readback
 (ISSUE 41) has no ``tail`` and no ``between``, and the dispatch behind it
-measures its ``lead`` from the end of the round's program; beside them the
-engine's own ``stats()["dispatch"]`` over its life.
+measures its ``lead`` from the end of the round's program; a decode
+iteration's readback lies in the NEXT decode span (ISSUE 44: iteration k+1
+is launched before iteration k is read back), so its ``tail`` and
+``between`` are idle only where nothing was launched behind it, and
+``home`` says when its tokens reached the host; beside them the engine's
+own ``stats()["dispatch"]`` over its life (``pipelined`` and
+``discarded_tokens`` among the decode program's).
 
 Run on no CPU: the harness refuses one."""
 
@@ -67,15 +72,14 @@ def table(reduced: dict) -> dict:
 
 ENGINE_SPAN = re.compile(r"^tony:engine\.(decode|prefill)_"
                          r"(device|launch|readback)$")
-PARTS = ("lead", "return_to_start", "bubbles", "tail", "between")
-# How far before its device span a program may seem to start: a trace puts
-# the device's line on the host's clock to within about a millisecond, and
-# not the same way in every trace (ISSUE 41's traced run of chat-saturated
-# read every program 0.9 ms early beside the parent's run of the same call:
-# a fenced round's lead 0.3 and tail 2.6 ms against 1.26 and 1.51, the sums
-# equal). What moves with it is the split between ``lead`` and ``tail``,
-# never their sum.
-CLOCK_SLACK_NS = 1_500_000
+PARTS = ("lead", "return_to_start", "bubbles", "tail", "between", "home")
+# A trace puts the device's line on the host's clock to within about a
+# millisecond, and not the same way in every trace (ISSUE 41's traced run of
+# chat-saturated read every program 0.9 ms early beside the parent's run of
+# the same call: a fenced round's lead 0.3 and tail 2.6 ms against 1.26 and
+# 1.51, the sums equal). What moves with it is the split between ``lead``
+# and ``tail``, never their sum; a program goes to its launch by order, so
+# one that seems to start before its span does is still its own.
 
 
 def _rank(values: list, pct: int) -> float:
@@ -83,94 +87,142 @@ def _rank(values: list, pct: int) -> float:
     return sorted(values)[-(-pct * len(values) // 100) - 1]
 
 
+def _offset(launches: list, modules: list, fences: list) -> int:
+    """How many of the trace's first programs were launched before it
+    began. The device runs its programs in the order they were launched,
+    so launch i's program is program ``offset + i``, and one point where
+    the queue is known to be empty fixes the offset: the return of a
+    fenced prefill round's readback, which waits for the round and so for
+    everything launched before it (a decode readback does not say so: it
+    may return with the next iteration queued). Every program that
+    started before that moment belongs to a launch made before it. A
+    trace without such a readback: the programs that start before its
+    first launch does."""
+    if not launches:
+        return 0
+    at = min(fences, default=launches[0]["launch"][0])
+    return max(0, sum(m[0] < at for m in modules)
+               - sum(row["launch"][0] < at for row in launches))
+
+
 def dispatches(trace: dict, reduced: dict, xplane) -> dict:
     """The traced window's dispatches, one per ``tony:engine.*_device``
-    span that lies inside it with its launch half, at most one readback
-    half and a program of its own, in nanoseconds on the trace's clock. A
-    span's program is the first of its kind (``decode`` / ``prefill`` in
-    the module's name) that no earlier span took and that starts after the
-    span does (``CLOCK_SLACK_NS`` allowed) and before its fence returns:
-    its own readback's end, or, for a prefill round launched without one
-    (ISSUE 41), that of the next span that has one. The parts:
+    span that lies inside it with a launch half and a program of its own,
+    in nanoseconds on the trace's clock. Programs go to launches by ORDER
+    (``_offset``), kinds agreeing (``decode`` / ``prefill`` in the
+    module's name); a launch whose turn holds another kind has no program.
+    The readback that FENCES a program: a prefill round's own, if its span
+    has one (ISSUE 41: a round launched without one has none); a decode
+    iteration's is the first decode readback that returns after the
+    program ended, which since ISSUE 44 lies in the NEXT decode span (an
+    iteration is launched before the one ahead of it is read back;
+    ``pipelined`` counts the rows fenced so), and in a span without a
+    launch where the pipeline drains. The parts put the device's idle time
+    behind a program, up to the start of the next one, down to what the
+    host was doing:
 
     - ``lead``: program start - the LATER of the launch span's start and
       the end of the program before it (the device idles through all of
-      it; behind an unfenced round the program before still runs when the
-      launch begins);
+      it; behind a program that still runs when the launch begins, as an
+      unfenced round or the iteration in flight does, only the gap
+      between the two);
     - ``return_to_start``: program start - launch span END, negative
       where the device began before the jitted call returned;
     - ``bubbles``: program duration - the union of its operations;
-    - ``tail``: readback span end - program end (None: no readback);
-    - ``between``: the next dispatch's launch span start - this readback
-      span's end (host work: emit, publish, admit, assemble; None: no
-      readback, the device works through the host's time).
+    - ``tail``: the fence's return - program end, as far as it lies
+      before the next dispatch's launch began (None: no fence; 0 where
+      the next program was launched before this one ended);
+    - ``between``: from there to the next dispatch's launch span start
+      (host work: emit, publish, admit, assemble; None: no fence);
+    - ``home``: the fence's return - program end, idle or not: how long
+      after a program's end the host holds its result.
 
     ``lead + bubbles + tail + between`` tile the time from the first
     launch to the last readback but for the programs' busy time, so their
     sum is the window's idle time less what lies at its two edges and
     under no engine span (``remainder_s``). ``programs``: per program
-    {n, unfenced, and per part {p50_ms, p90_ms, sum_s}}; ``rows``: every
-    dispatch. ``xplane``: the harness's reduction module, ``reduced`` its
-    reduction of ``trace`` (the window's idle time is its)."""
+    {n, unfenced, pipelined, and per part {p50_ms, p90_ms, sum_s}};
+    ``rows``: every dispatch. ``xplane``: the harness's reduction module,
+    ``reduced`` its reduction of ``trace`` (the window's idle time is
+    its)."""
     lo, hi = xplane.window_of(trace)
     dev = trace["devices"][min(trace["devices"], key=int)]
     ops = sorted((s, s + d) for _, s, d in dev["ops"] if d > 0)
     starts = [s for s, _ in ops]
     modules = sorted((s, s + d, re.sub(r"\(\d+\)$", "", n))
-                     for n, s, d in dev["modules"])
-    ends = sorted(m[1] for m in modules)
+                     for n, s, d in dev["modules"]
+                     if "decode" in n or "prefill" in n)
     spans: dict[str, list] = {"device": [], "launch": [], "readback": []}
     for name, s, d in trace["host_spans"]:
         m = ENGINE_SPAN.match(name)
-        if m and lo <= s and s + d <= hi:
+        if m:
             spans[m.group(2)].append((s, s + d, m.group(1)))
-    found = []     # (device span, its launch, its readback or None) or None
+    launches, unmatched = [], 0
     for s, e, program in sorted(spans["device"]):
         launch, readback = ([h for h in spans[k] if s <= h[0] and h[1] <= e
                              and h[2] == program]
                             for k in ("launch", "readback"))
-        found.append(((s, program), launch[0], readback[0] if readback
-                      else None)
-                     if len(launch) == 1 and len(readback) <= 1 else None)
-    rows, unmatched, taken = [], 0, set()
-    for i, dispatch in enumerate(found):
-        mine = None
-        if dispatch:
-            (s, program), launch, readback = dispatch
-            # the fence that waits for this dispatch's program
-            fence = next((d[2][1] for d in found[i:] if d and d[2]), s)
-            mine = next((m for m in modules if m not in taken
-                         and program in m[2]
-                         and s - CLOCK_SLACK_NS <= m[0] < fence), None)
-        if mine is None:
+        inside = lo <= s and e <= hi
+        if len(launch) == 1 and len(readback) <= 1:
+            launches.append({"end": e, "program": program,
+                             "inside": inside, "launch": launch[0],
+                             "readback": readback[0] if readback else None})
+        elif launch or len(readback) > 1:
+            unmatched += inside
+    returns = {k: sorted(h[1] for h in spans["readback"] if h[2] == k)
+               for k in ("decode", "prefill")}
+    turn = _offset(launches, modules, returns["prefill"])
+    for row in launches:        # a program's turn passes only to its kind
+        row["turn"] = None
+        if turn < len(modules) and row["program"] in modules[turn][2]:
+            row["turn"], turn = turn, turn + 1
+    rows = []
+    for i, row in enumerate(launches):
+        if not row["inside"]:
+            continue
+        if row["turn"] is None:
             unmatched += 1
             continue
-        taken.add(mine)
-        p0, p1, name = mine
+        p0, p1, name = modules[row["turn"]]
+        fence = row["readback"][1] if row["readback"] else None
+        if row["program"] == "decode":
+            after = bisect.bisect_left(returns["decode"], p1)
+            fence = (returns["decode"][after]
+                     if after < len(returns["decode"]) else None)
         # a program's operations are those that start inside it
         busy = xplane.total(xplane.union(
             [[a, min(b, p1)] for a, b in ops[bisect.bisect_left(starts, p0):
                                              bisect.bisect_left(starts, p1)]]))
-        before = bisect.bisect_right(ends, p0)
-        rows.append({"program": name, "launch_start": launch[0],
-                     "readback_end": readback[1] if readback else None,
-                     "lead": p0 - max(launch[0],
-                                      ends[before - 1] if before else lo),
-                     "return_to_start": p0 - launch[1],
+        before = modules[row["turn"] - 1][1] if row["turn"] else lo
+        nxt = next((r for r in launches[i + 1:]
+                    if r["turn"] is not None and r["inside"]), None)
+        tail = between = None
+        if fence is not None and nxt:
+            # the device's idle time behind this program, before the next
+            # launch began: the host waits for the fence, then works
+            begun = max(nxt["launch"][0], p1)
+            tail = min(max(fence, p1), begun) - p1
+            between = begun - p1 - tail
+        elif fence is not None:
+            tail = fence - p1
+        rows.append({"program": name, "launch_start": row["launch"][0],
+                     "readback_end": fence,
+                     "pipelined": fence is not None and fence > row["end"],
+                     "lead": p0 - max(row["launch"][0], before),
+                     "return_to_start": p0 - row["launch"][1],
                      "bubbles": (p1 - p0) - busy,
-                     "tail": readback[1] - p1 if readback else None,
-                     "between": None})
-    for row, nxt in zip(rows, rows[1:]):
-        if row["readback_end"] is not None:
-            row["between"] = nxt["launch_start"] - row["readback_end"]
+                     "tail": tail, "between": between,
+                     "home": None if fence is None else fence - p1})
     idle_s = reduced["window_s"] - reduced["busy_s"]
     accounted_s = sum(row[k] or 0 for row in rows
                       for k in ("lead", "bubbles", "tail", "between")) / 1e9
     programs = {}
     for name in sorted({row["program"] for row in rows}):
         mine = [row for row in rows if row["program"] == name]
-        programs[name] = {"n": len(mine), "unfenced": sum(
-            row["readback_end"] is None for row in mine)}
+        programs[name] = {
+            "n": len(mine),
+            "unfenced": sum(row["readback_end"] is None for row in mine),
+            "pipelined": sum(row["pipelined"] for row in mine)}
         for part in PARTS:
             values = [row[part] for row in mine if row[part] is not None]
             programs[name][part] = {
@@ -193,7 +245,8 @@ def print_dispatches(table: dict, engine_dispatch: dict | None) -> None:
           f"without a launch half and a program of their own")
     for name, row in table["programs"].items():
         print(f"  {name}: {row['n']} dispatches, {row['unfenced']} of them "
-              f"without a readback   (p50 ms / p90 ms / sum s)")
+              f"without a readback, {row['pipelined']} read back in a later "
+              f"span   (p50 ms / p90 ms / sum s)")
         for part in PARTS:
             if row[part]:
                 print(f"    {part:16s} {row[part]['p50_ms']:8.3f} "
